@@ -1,0 +1,629 @@
+#!/usr/bin/env python3
+"""Start the two served paths on the chip and check what comes out.
+
+One process owns the accelerator and drives, through the entry points a
+deployment uses and with the options ``OpenrConfig`` ships
+(``solver_backend="device"``, jnp kernels, no ``OPENR_*`` variable set):
+
+- ``pipeline_fabric_1008`` / ``pipeline_fabric_10k``: KvStore -> Decision
+  -> Fib wired as ``daemon.py`` wires them (``SustainedLoadHarness``):
+  bulk LSDB load to the first ``RouteDatabase`` in Fib, a short run of
+  the seeded default ``EventMix``, drain, and ``check_parity()`` against
+  the unshedded host-Dijkstra replay. 1008 nodes is the dense
+  formulation, 10,000 the resident sliced-ELL one. A sealed machine
+  cannot hold 10k daemons; the harness is the repo's stand-in for the
+  LSDB they would flood.
+- ``ksp2_fabric_1008``: every prefix ``KSP2_ED_ECMP`` through
+  ``SpfSolver(backend="device")`` over a few churn events,
+  ``RouteDatabase`` equal to ``backend="host"``.
+- ``serve``: ``SolverService`` behind ``CtrlServer`` in this process,
+  JAX-free client processes over the ctrl wire, every FIB digest equal
+  to one built by ``SpfSolver(backend="host")``.
+- ``kernels``: every Pallas entry compiled with ``interpret=False`` at
+  the shapes the legs above hand its jnp twin, compared bit for bit.
+- ``mesh4`` (>= 4 devices only): the KSP2 leg and a 10k route-engine
+  churn sharded over four devices.
+
+The run fails — exit code != 0, no result line — without a TPU, on any
+exception or parity miss, on any fallback counter above zero, and when
+a mechanism the legs exist to exercise never ran. On a pass stdout ends
+with a ``summary: {...}`` line (per-leg verdicts, counters, compile
+counts, peak bytes; also written to ``chiprun_out/chip_smoke.json``) and
+then, as its last line, the verdict and nothing else:
+``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
+Wall seconds in the summary are for budgeting the run, not a metric.
+Nothing here is a benchmark.
+"""
+
+from __future__ import annotations
+
+import functools
+import json
+import os
+import sys
+import tempfile
+import time
+from dataclasses import replace
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+# what the chip tool copies back after a run
+ARTEFACT_DIR = os.path.join(REPO, "chiprun_out")
+
+# any of these above zero means a leg ended on a path that hides the
+# device (a degradation rung, a host Dijkstra, a plain-jit retry)
+FALLBACK_COUNTERS = (
+    "decision.backend_switches",
+    "decision.fallbacks",
+    "decision.degradations",
+    "decision.device_state_resets",
+    "decision.spf_host_fallback",
+    "decision.ksp2_host_fallbacks",
+    "route_engine.fallbacks",
+    "ops.aot_fallbacks",
+    "ops.autotune_disqualified",
+    "serve.errors",
+)
+
+# "the mechanism ran": a pass with any of these at zero took some other
+# path than the one the leg names
+MECHANISM_COUNTERS = (
+    "decision.ell_cold_solves",
+    "decision.ell_warm_solves",
+    "decision.ell_incremental_syncs",
+    "decision.ksp2_device_batches",
+    "decision.ksp2_incremental_syncs",
+    "tenancy.dispatches",
+    "tenancy.warm_solves",
+    "tenancy.wave_joins",
+    "serve.waves",
+)
+
+
+class SmokeFailure(Exception):
+    """A leg produced a wrong or unproven result."""
+
+
+def _require(cond: bool, msg: str) -> None:
+    if not cond:
+        raise SmokeFailure(msg)
+
+
+# -- compile accounting ------------------------------------------------------
+
+
+def _compile_summary() -> dict:
+    """Backend compiles and persistent-cache traffic of this process, as
+    ``telemetry.jax_hooks`` counted them off ``jax.monitoring``. A cache
+    hit still reports a backend-compile event (its duration is the
+    retrieval), and jax counts a miss only for an executable it then
+    stores (one that took over a second to build) — so on a warm cache
+    ``persistent_cache_misses`` counts only compiles that straddle that
+    threshold and ``compile_max_s`` is about a second, not a cold
+    build."""
+    from openr_tpu.telemetry import get_registry
+
+    reg = get_registry()
+    name = "jax.duration_ms.jax.core.compile.backend_compile_duration"
+    hist = reg.histogram_if_exists(name)
+    stats = hist.stats() if hist is not None else {}
+    count = int(stats.get(name + ".count", 0))
+    return {
+        "compiles": count,
+        "compile_s": round(stats.get(name + ".avg", 0.0) * count / 1e3, 3),
+        "compile_max_s": round(stats.get(name + ".max", 0.0) / 1e3, 3),
+        "persistent_cache_hits": int(reg.counter_get(
+            "jax.events.jax.compilation_cache.cache_hits"
+        )),
+        "persistent_cache_misses": int(reg.counter_get(
+            "jax.events.jax.compilation_cache.cache_misses"
+        )),
+    }
+
+
+# -- shared fixtures ---------------------------------------------------------
+
+
+def _fabric(nodes: int, **topo_kwargs):
+    from openr_tpu.graph.linkstate import LinkState
+    from openr_tpu.models import topologies
+
+    topo = topologies.fat_tree_nodes(nodes, **topo_kwargs)
+    ls = LinkState(area=topo.area)
+    for name in sorted(topo.adj_dbs):
+        ls.update_adjacency_database(topo.adj_dbs[name])
+    return topo, ls
+
+
+def _bump_metric(ls, node: str, step: int) -> str:
+    """One adjacency metric change on ``node``; returns the peer."""
+    db = ls.get_adjacency_databases()[node]
+    adjs = list(db.adjacencies)
+    adjs[0] = replace(adjs[0], metric=2 + step % 5)
+    ls.update_adjacency_database(replace(db, adjacencies=tuple(adjs)))
+    return adjs[0].other_node_name
+
+
+def _counter_delta(before: dict, names) -> dict:
+    from openr_tpu.telemetry import get_registry
+
+    reg = get_registry()
+    return {k: reg.counter_get(k) - before.get(k, 0) for k in names}
+
+
+def _counter_snapshot(names) -> dict:
+    from openr_tpu.telemetry import get_registry
+
+    reg = get_registry()
+    return {k: reg.counter_get(k) for k in names}
+
+
+# -- legs --------------------------------------------------------------------
+
+
+def leg_pipeline(nodes: int, events: int = 20, rate: int = 4,
+                 timeout_s: float = 600.0) -> dict:
+    """KvStore -> Decision -> Fib at ``nodes`` on the device backend."""
+    from openr_tpu.load.harness import SustainedLoadHarness
+
+    watched = (
+        "decision.ell_cold_solves", "decision.ell_warm_solves",
+        "decision.ell_structural_warm_solves",
+        "decision.ell_incremental_syncs", "decision.ell_full_compiles",
+        "decision.ladder_walks",
+    )
+    before = _counter_snapshot(watched)
+    # the debounce window and emit staging OpenrConfig ships
+    harness = SustainedLoadHarness(
+        nodes=nodes,
+        solver_backend="device",
+        debounce_min_s=0.010,
+        debounce_max_s=0.250,
+        pipelined_emit=False,
+    )
+    harness.start(initial_timeout_s=timeout_s)
+    try:
+        report = harness.run_fixed_rate(
+            rate, events / float(rate), drain_grace_s=timeout_s
+        )
+        _require(report.drained, f"pipeline {nodes}: backlog never drained")
+        _require(
+            report.published > 0, f"pipeline {nodes}: nothing published"
+        )
+        routes = len(harness.fib.get_route_db().unicast_routes)
+        parity = harness.check_parity()
+    finally:
+        harness.stop()
+    _require(parity, f"pipeline {nodes}: RouteDatabase != host replay")
+    _require(routes > 0, f"pipeline {nodes}: Fib holds no routes")
+    return {
+        "nodes": len(harness.topo.adj_dbs),
+        "published": report.published,
+        "fib_unicast_routes": routes,
+        "parity": parity,
+        "counts": _counter_delta(before, watched),
+    }
+
+
+def _shard_holders(array) -> list:
+    return sorted({s.device.id for s in array.addressable_shards})
+
+
+def leg_ksp2(nodes: int, events: int = 4, shard_devices=None) -> dict:
+    """All-KSP2 fabric through ``SpfSolver``: device == host. With
+    ``shard_devices`` (the engine mesh's device ids) the engine's
+    resident all-pairs matrix must hold a shard on each of them."""
+    from openr_tpu.decision.prefix_state import PrefixState
+    from openr_tpu.decision.spf_solver import SpfSolver
+    from openr_tpu.types.lsdb import (
+        PrefixForwardingAlgorithm,
+        PrefixForwardingType,
+    )
+    from openr_tpu.utils import wire
+
+    watched = (
+        "decision.ksp2_device_batches", "decision.ksp2_cold_builds",
+        "decision.ksp2_incremental_syncs",
+        "decision.ksp2_warm_dispatches",
+    )
+    before = _counter_snapshot(watched)
+    topo, ls = _fabric(
+        nodes,
+        forwarding_algorithm=PrefixForwardingAlgorithm.KSP2_ED_ECMP,
+        forwarding_type=PrefixForwardingType.SR_MPLS,
+    )
+    ps = PrefixState()
+    for pdb in topo.prefix_dbs.values():
+        ps.update_prefix_database(pdb)
+    area_ls = {topo.area: ls}
+    names = sorted(topo.adj_dbs)
+    rsw = next(k for k in names if k.startswith("rsw"))
+    fsw = next(k for k in names if k.startswith("fsw"))
+    device = SpfSolver(rsw, backend="device")
+    host = SpfSolver(rsw, backend="host")
+    routes = 0
+    for step in range(events + 1):
+        if step:
+            _bump_metric(ls, fsw, step)
+        got = device.build_route_db(rsw, area_ls, ps).to_route_db(rsw)
+        want = host.build_route_db(rsw, area_ls, ps).to_route_db(rsw)
+        _require(
+            wire.dumps(got) == wire.dumps(want),
+            f"ksp2 {nodes}: device RouteDatabase != host at event {step}",
+        )
+        routes = len(got.unicast_routes)
+    _require(routes > 0, f"ksp2 {nodes}: empty RouteDatabase")
+    out = {
+        "nodes": ls.num_nodes,
+        "events": events,
+        "unicast_routes": routes,
+        "parity": True,
+        "counts": _counter_delta(before, watched),
+    }
+    if shard_devices is not None:
+        engine = device._ksp2_engines.get(ls)
+        _require(engine is not None, f"ksp2 {nodes}: no resident engine")
+        out["shard_devices"] = _shard_holders(engine.d_prev_dev)
+        _require(
+            out["shard_devices"] == sorted(shard_devices),
+            f"ksp2 {nodes}: all-pairs matrix lives on devices "
+            f"{out['shard_devices']}, mesh is {sorted(shard_devices)}",
+        )
+    return out
+
+
+def leg_serve(clients: int = 2, tenant_sizes=(("grid", 32), ("mesh", 1000)),
+              tenants_per_client: int = 4, rounds: int = 3) -> dict:
+    """``SolverService`` behind the ctrl wire, driven by JAX-free client
+    processes; every FIB digest checked against the host solver."""
+    from openr_tpu.ctrl.server import CtrlServer
+    from openr_tpu.ctrl.solver import SolverCtrlHandler
+    from openr_tpu.load import multi_client
+    from openr_tpu.serve.service import SolverService
+
+    watched = (
+        "tenancy.dispatches", "tenancy.warm_solves", "tenancy.cold_solves",
+        "tenancy.wave_joins", "tenancy.admissions", "serve.waves",
+        "serve.requests", "serve.errors",
+    )
+    before = _counter_snapshot(watched)
+    specs = {}
+    for c in range(clients):
+        ids = range(c * tenants_per_client, (c + 1) * tenants_per_client)
+        specs[f"c{c}"] = [
+            multi_client.TenantSpec(
+                f"c{c}t{n}", *tenant_sizes[n % len(tenant_sizes)], seed=n + 1
+            )
+            for n in ids
+        ]
+    all_specs = [s for lst in specs.values() for s in lst]
+
+    svc = SolverService().start()
+    srv = CtrlServer(SolverCtrlHandler(svc))
+    srv.start()
+    procs = []
+    try:
+        with tempfile.TemporaryDirectory() as out_dir:
+            procs = multi_client.spawn_clients(
+                "127.0.0.1", srv.port, specs, rounds, out_dir,
+                fib_every=1,
+            )
+            # the host-Dijkstra replay (no device kernel) runs while the
+            # children drive the wire
+            want = multi_client.oracle_fib_digests(
+                all_specs, rounds, every=1, backend="host"
+            )
+            results = multi_client.harvest(procs)
+    finally:
+        for p, _path in procs:
+            if p.is_alive():
+                p.kill()
+            p.join(timeout=10)
+        srv.stop()
+        svc.stop()
+
+    errors = [e for r in results for e in r.get("errors", [])]
+    _require(not errors, f"serve: client errors {errors[:4]}")
+    short = [r["client_id"] for r in results if r.get("rounds") != rounds]
+    _require(not short, f"serve: clients short of {rounds} rounds: {short}")
+    got = {}
+    for r in results:
+        got.update(r.get("fib", {}))
+    diverged = sorted(t for t in want if got.get(t) != want[t])
+    _require(
+        not diverged, f"serve: FIB digests differ from host for {diverged}"
+    )
+    return {
+        "clients": clients,
+        "tenants": len(all_specs),
+        "rounds": rounds,
+        "fib_digests_checked": sum(len(v) for v in want.values()),
+        "parity": True,
+        "counts": _counter_delta(before, watched),
+    }
+
+
+def leg_kernels(interpret: bool, dense_nodes: int = 1008,
+                grouped_nodes: int = 10000, grouped_batch: int = 128) -> dict:
+    """Every Pallas entry against its jnp twin, bit for bit, on the
+    tensors the fabric legs build: a partially relaxed distance panel
+    over the real snapshot / segment layout, so INF and finite cells
+    mix. A kernel that does not lower raises here with the compiler's
+    message — a selectable kernel that cannot run is a defect."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from openr_tpu.graph.snapshot import compile_snapshot
+    from openr_tpu.ops import pallas_minplus, spf, spf_grouped
+
+    kernels: dict = {}
+
+    def check(name: str, shapes, pallas_thunk, want) -> None:
+        try:
+            got = np.asarray(pallas_thunk())
+        except Exception as exc:  # noqa: BLE001 - re-raised with shapes
+            raise SmokeFailure(
+                f"kernels: {name} did not lower at {shapes}: "
+                f"{type(exc).__name__}: {exc}"
+            ) from exc
+        same = bool(np.array_equal(got, np.asarray(want)))
+        kernels[name] = {
+            "shapes": shapes, "lowered": True, "bit_identical": same,
+        }
+        _require(same, f"kernels: {name} differs from its jnp twin")
+
+    # -- dense min-plus at the {source} + neighbors batch ----------------
+    topo, ls = _fabric(dense_nodes)
+    rsw = next(k for k in sorted(topo.adj_dbs) if k.startswith("rsw"))
+    snap = compile_snapshot(ls)
+    dev = snap.device_arrays()
+    _srcs, srcs_dev = spf.source_batch(snap, snap.id_of(rsw))
+
+    # operands are prepared under one jit each: op-by-op eager dispatch
+    # would pay a compile per op
+    @jax.jit
+    def dense_operands(metric, overloaded, srcs):
+        t = spf._mask_transit_rows(metric, overloaded)
+        d0 = metric[srcs, :].at[jnp.arange(srcs.shape[0]), srcs].set(0)
+        return jnp.minimum(d0, spf._minplus(d0, t)), t
+
+    d1, t = dense_operands(dev.metric, dev.overloaded, srcs_dev)
+    check(
+        "pallas_minplus.minplus",
+        [list(d1.shape), list(t.shape)],
+        lambda: pallas_minplus.minplus(d1, t, interpret=interpret),
+        jax.jit(spf._minplus)(d1, t),
+    )
+
+    # -- grouped block contractions over the fabric's segment shapes -----
+    _topo, ls = _fabric(grouped_nodes)
+    graph = spf_grouped.compile_grouped(ls)
+    src_t, w_t = spf_grouped.device_tensors(graph)
+    ov = jnp.asarray(graph.overloaded)
+    meta = spf_grouped.band_meta(graph)
+
+    @jax.jit
+    def segment_operands(src_t, w_t, ov):
+        ids = jnp.arange(grouped_batch, dtype=jnp.int32)
+        d = jnp.full((grouped_batch, graph.n_pad), spf.INF, jnp.int32)
+        d = d.at[ids, ids].set(0)
+        for _ in range(2):
+            d = spf_grouped._grouped_relax(d, meta, src_t, w_t, ov, None)
+        return [d[:, src] for src in src_t]  # [B, G, S] per segment
+
+    gaths = segment_operands(src_t, w_t, ov)
+    shapes = [
+        [int(g.shape[0]), *(int(x) for x in w.shape)]
+        for g, w in zip(gaths, w_t)
+    ]
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def contract_all(impl):
+        return jnp.concatenate([
+            spf_grouped._contract(g, w, impl).reshape(g.shape[0], -1)
+            for g, w in zip(gaths, w_t)
+        ], axis=1)
+
+    want = contract_all(spf.JNP)
+    for entry, name in (
+        ("batched_minplus", "pallas"), ("batched_minplus_t", "pallas_t"),
+    ):
+        impl = spf.KernelImpl(name, interpret)
+        check(
+            f"pallas_grouped.{entry}", shapes,
+            lambda impl=impl: contract_all(impl), want,
+        )
+    return {"interpret": interpret, "kernels": kernels}
+
+
+def leg_mesh4(nodes_ksp2: int = 1008, nodes_engine: int = 10000,
+              events: int = 4) -> dict:
+    """Four devices: the KSP2 leg under the engine mesh ``main.py``
+    installs, and a 10k route-engine churn sharded over the same mesh,
+    equal to its one-device twin, with no reshard and every device
+    holding shards of the resident state."""
+    import jax
+
+    from openr_tpu.decision import ksp2_engine
+    from openr_tpu.ops import route_engine, route_sweep
+    from openr_tpu.parallel.mesh import make_mesh
+
+    watched = (
+        "ops.reshard_events", "ops.shard_readback_bytes",
+        "decision.ksp2.spec_mesh_fallbacks",
+    )
+    before = _counter_snapshot(watched)
+    devices = jax.devices()[:4]
+    ids = sorted(d.id for d in devices)
+    mesh = make_mesh(devices)
+    ksp2_engine.set_engine_mesh(mesh)
+    try:
+        ksp2 = leg_ksp2(nodes_ksp2, events=events, shard_devices=ids)
+    finally:
+        ksp2_engine.set_engine_mesh(None)
+
+    topo, ls = _fabric(nodes_engine)
+    names = sorted(topo.adj_dbs)
+    rsw = next(k for k in names if k.startswith("rsw"))
+    fsw = next(k for k in names if k.startswith("fsw"))
+    sharded = route_engine.RouteSweepEngine(ls, [rsw], mesh=mesh)
+    single = route_engine.RouteSweepEngine(ls, [rsw])
+    for step in range(events):
+        affected = {fsw, _bump_metric(ls, fsw, step)}
+        sharded.churn(ls, affected)
+        single.churn(ls, affected)
+    _require(
+        route_sweep.digests_by_name(sharded.result)
+        == route_sweep.digests_by_name(single.result),
+        "mesh4: sharded route-engine digests != one-device engine",
+    )
+    holders = _shard_holders(sharded._packed_dev)
+    _require(
+        holders == ids,
+        f"mesh4: route product lives on devices {holders}, mesh is {ids}",
+    )
+    counts = _counter_delta(before, watched)
+    _require(
+        counts["ops.reshard_events"] == 0,
+        f"mesh4: {counts['ops.reshard_events']} reshard event(s)",
+    )
+    _require(
+        counts["decision.ksp2.spec_mesh_fallbacks"] == 0,
+        "mesh4: the KSP2 fast path dropped to one chip under the mesh",
+    )
+    _require(
+        counts["ops.shard_readback_bytes"] > 0,
+        "mesh4: no per-shard readback ran",
+    )
+    return {
+        "devices": len(devices),
+        "ksp2": ksp2,
+        "engine_nodes": sharded.graph.n,
+        "engine_events": events,
+        "engine_incremental_events": sharded.incremental_events,
+        "shard_devices": holders,
+        "counts": counts,
+    }
+
+
+# -- driver ------------------------------------------------------------------
+
+
+def _versions() -> dict:
+    import jax
+    import jaxlib
+
+    out = {"jax": jax.__version__, "jaxlib": jaxlib.__version__}
+    try:
+        import libtpu
+
+        out["libtpu"] = getattr(libtpu, "__version__", "unknown")
+    except ImportError:
+        out["libtpu"] = None
+    return out
+
+
+def run(legs, device: dict, setup: dict) -> int:
+    """Drive ``legs`` ([(name, thunk)]) in order, then judge the
+    counters. An exception in a leg is not caught: it ends the process
+    with its traceback and a non-zero code."""
+    import jax
+
+    dev0 = jax.devices()[0]
+    summary = {
+        "ok": False, "device": device, **setup,
+        "legs": {}, "wall_s": {}, "peak_bytes_in_use": {},
+    }
+    before = _counter_snapshot(FALLBACK_COUNTERS + MECHANISM_COUNTERS)
+    for name, thunk in legs:
+        t0 = time.monotonic()
+        print(f"[{name}] start", flush=True)
+        result = thunk()
+        summary["wall_s"][name] = round(time.monotonic() - t0, 1)
+        summary["legs"][name] = result
+        # the process-wide high-water mark once this leg is done (the
+        # CPU backend reports none)
+        summary["peak_bytes_in_use"][name] = (
+            dev0.memory_stats() or {}
+        ).get("peak_bytes_in_use")
+        print(f"[{name}] ok {json.dumps(result)}", flush=True)
+
+    fallbacks = _counter_delta(before, FALLBACK_COUNTERS)
+    mechanisms = _counter_delta(before, MECHANISM_COUNTERS)
+    failures = [f"{k} = {v}" for k, v in fallbacks.items() if v]
+    failures += [f"{k} never ran" for k, v in mechanisms.items() if not v]
+    summary.update(
+        fallback_counters=fallbacks,
+        mechanism_counters=mechanisms,
+        compile=_compile_summary(),
+        failures=failures,
+    )
+    summary["ok"] = not failures
+    summary["claim"] = None
+    os.makedirs(ARTEFACT_DIR, exist_ok=True)
+    with open(os.path.join(ARTEFACT_DIR, "chip_smoke.json"), "w") as f:
+        json.dump(summary, f, indent=1)
+    if failures:
+        print("chip_smoke FAILED:", file=sys.stderr)
+        for failure in failures:
+            print(f"  - {failure}", file=sys.stderr)
+        return 1
+    print(f"summary: {json.dumps(summary)}", flush=True)
+    # the verdict line holds these two keys and no other; everything
+    # else is in the summary line above and in chip_smoke.json
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+def main() -> int:
+    import jax
+
+    dev0 = jax.devices()[0]
+    device = {
+        "platform": dev0.platform,
+        "kind": dev0.device_kind,
+        "count": len(jax.devices()),
+    }
+    if dev0.platform != "tpu":
+        # without a chip the installed jax does not raise, it carries on
+        # to the CPU backend — so the refusal has to be ours
+        print(
+            f"chip_smoke: no TPU (jax found {device}); this check only "
+            "means something on the chip", file=sys.stderr,
+        )
+        return 2
+
+    from openr_tpu.graph import native_spf
+    from openr_tpu.telemetry import jax_hooks
+    from openr_tpu.utils import compile_cache
+
+    setup = {
+        "versions": _versions(),
+        "compile_cache_dir": compile_cache.enable(),
+        "compile_cache_from_env": bool(
+            os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        ),
+        # built now, from the tracked source, before anything loads it;
+        # a missing or failing compiler raises here
+        "native_core": os.path.relpath(native_spf.build(), REPO),
+    }
+    print(f"device: {device}", flush=True)
+    print(f"setup: {setup}", flush=True)
+    jax_hooks.install()
+
+    legs = [
+        ("pipeline_fabric_1008", lambda: leg_pipeline(1008)),
+        ("pipeline_fabric_10k", lambda: leg_pipeline(10000)),
+        ("ksp2_fabric_1008", lambda: leg_ksp2(1008)),
+        ("serve", leg_serve),
+        ("kernels", lambda: leg_kernels(interpret=False)),
+    ]
+    if device["count"] >= 4:
+        legs.append(("mesh4", leg_mesh4))
+    return run(legs, device, setup)
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -239,10 +239,8 @@ def unwrap_aot_call(
     node: ast.Call,
 ) -> Optional[Tuple[str, List[ast.expr]]]:
     """See through ``aot_call(tag, fn, (dyn...), {statics})`` (the
-    committed-dispatch executable cache, ops.aot_cache) and its
-    impl-aware wrapper ``ell_dispatch`` (spf_sparse — same positional
-    layout, the tag is re-keyed on the armed relax impl before the
-    underlying aot_call): returns the wrapped dispatch's (dotted name,
+    committed-dispatch executable cache, ops.aot_cache): returns the
+    wrapped dispatch's (dotted name,
     positional dyn-arg expressions) so call-site rules —
     donation-hazard, sharding-spec — keep their precision after a hot
     dispatch moves behind the AOT cache. The statics mapping is
@@ -250,7 +248,7 @@ def unwrap_aot_call(
     tuples, n, k, mesh), never device buffers."""
     callee = dotted_name(node.func)
     if callee is None or callee.split(".")[-1] not in (
-        "aot_call", "warm", "ell_dispatch",
+        "aot_call", "warm",
     ):
         return None
     if len(node.args) < 3:

@@ -239,15 +239,10 @@ class Decision:
             world_batch=world_batch,
         )
         # degradation ladder for the rebuild path: warm device solve →
-        # device-state reset + cold rebuild → non-device backend. The
-        # fallback backend is "native" when the configured backend is
-        # the device (SpfView itself degrades native → host when the
-        # toolchain is absent); for an already-host backend all rungs
+        # device-state reset + cold rebuild → non-device backend (see
+        # _fallback_backend); for an already-host backend all rungs
         # run the same solve, which is harmless.
         self._primary_backend = solver_backend
-        self._fallback_backend = (
-            "native" if solver_backend == "device" else solver_backend
-        )
         self.supervisor = DegradationSupervisor("decision")
         # standing anomaly set (p99 breach vs rolling baseline,
         # compile-after-warmup, reshard delta): always-on from the
@@ -643,6 +638,18 @@ class Decision:
         # must not die for a probe.
         get_auditor().on_converge()
 
+    def _fallback_backend(self) -> str:
+        """Backend of the ladder's last rung: the native C++ core for a
+        device-configured Decision, or the Python oracle on a machine
+        that has no compiler to build it with (native_spf logs that
+        once). Resolved when the rung runs, so a healthy daemon never
+        pays the native build."""
+        if self._primary_backend != "device":
+            return self._primary_backend
+        from openr_tpu.graph import native_spf
+
+        return "native" if native_spf.is_available() else "host"
+
     def _route_staleness_ms(self) -> float:
         """How long the installed routes have been serving without a
         verified-good refresh: 0 while the ladder is warm and no engine
@@ -787,7 +794,7 @@ class Decision:
                         lambda: self._solve_update(
                             True,
                             reset=True,
-                            backend=self._fallback_backend,
+                            backend=self._fallback_backend(),
                         ),
                     ),
                 )

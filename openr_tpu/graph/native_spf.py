@@ -1,72 +1,114 @@
 """ctypes bindings for the native SPF core (native/spfcore.cpp).
 
-Compiles the shared library on first use (g++ available in the target
-image); all callers gracefully fall back to the Python/JAX paths when the
-toolchain or library is unavailable (``is_available()``).
+The shared library is compiled from the tracked source on first use and
+stored under the SHA-256 of that source and of the compile command, so a
+library is only ever loaded if it was built from exactly the source in
+this checkout: a stale copy left on disk by another commit can never be
+picked up. ``build()`` compiles unconditionally (what ``make native`` and
+``chip_smoke.py`` call).
+
+A machine without a C++ compiler has no native core: ``is_available()``
+says so once, loudly, and callers that can (the KSP2 tracer, Decision's
+fallback rung) use the Python paths. A compiler that is present and
+FAILS is a defect in the tree and raises ``NativeBuildError`` with the
+compiler's message wherever the native core was asked for.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
+import shutil
 import subprocess
 import threading
 from typing import Optional, Tuple
 
 import numpy as np
 
+log = logging.getLogger(__name__)
+
 _REPO_ROOT = os.path.dirname(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 )
 _SRC = os.path.join(_REPO_ROOT, "native", "spfcore.cpp")
-_LIB = os.path.join(_REPO_ROOT, "native", "libspfcore.so")
+_CXX = "g++"
+_CXXFLAGS = ("-O3", "-std=c++17", "-shared", "-fPIC", "-pthread")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_load_failed = False
+_no_compiler = False
 
 
-def _build() -> bool:
+class NativeBuildError(RuntimeError):
+    """The native core could not be built or loaded."""
+
+
+def lib_path() -> str:
+    """Where the library built from the current source lives."""
+    h = hashlib.sha256(" ".join((_CXX,) + _CXXFLAGS).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return os.path.join(
+        _REPO_ROOT, "native", f"libspfcore-{h.hexdigest()[:16]}.so"
+    )
+
+
+def build() -> str:
+    """Compile native/spfcore.cpp now; returns the library path. Raises
+    ``NativeBuildError`` when the compiler is missing or fails."""
+    out = lib_path()
+    # build beside the target and rename: a concurrent loader never
+    # sees a half-written library
+    tmp = f"{out}.{os.getpid()}.tmp"
     try:
         subprocess.run(
-            [
-                "g++",
-                "-O3",
-                "-std=c++17",
-                "-shared",
-                "-fPIC",
-                "-pthread",
-                _SRC,
-                "-o",
-                _LIB,
-            ],
+            [_CXX, *_CXXFLAGS, _SRC, "-o", tmp],
             check=True,
             capture_output=True,
-            timeout=120,
+            text=True,
+            timeout=300,
         )
-        return True
-    except Exception:
-        return False
+        os.replace(tmp, out)
+    except FileNotFoundError as exc:
+        raise NativeBuildError(f"no C++ compiler: {exc}") from exc
+    except subprocess.CalledProcessError as exc:
+        raise NativeBuildError(
+            f"{_CXX} failed on {_SRC} (exit {exc.returncode}):\n"
+            f"{exc.stderr}"
+        ) from exc
+    except subprocess.TimeoutExpired as exc:
+        raise NativeBuildError(f"{_CXX} timed out on {_SRC}") from exc
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    return out
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _load_failed
+    """The bound library, or None on a machine with no compiler."""
+    global _lib, _no_compiler
     with _lock:
         if _lib is not None:
             return _lib
-        if _load_failed:
+        if _no_compiler:
             return None
-        if not os.path.exists(_LIB) or os.path.getmtime(
-            _LIB
-        ) < os.path.getmtime(_SRC):
-            if not _build():
-                _load_failed = True
+        path = lib_path()
+        if not os.path.exists(path):
+            if shutil.which(_CXX) is None:
+                _no_compiler = True
+                log.error(
+                    "native SPF core unavailable: no %s on PATH; the "
+                    "KSP2 tracer and the Decision fallback rung run in "
+                    "Python", _CXX,
+                )
                 return None
+            build()
         try:
-            lib = ctypes.CDLL(_LIB)
-        except OSError:
-            _load_failed = True
-            return None
+            lib = ctypes.CDLL(path)
+        except OSError as exc:
+            raise NativeBuildError(f"cannot load {path}: {exc}") from exc
         i32p = ctypes.POINTER(ctypes.c_int32)
         u8p = ctypes.POINTER(ctypes.c_uint8)
         lib.spf_from_sources.argtypes = [

@@ -305,14 +305,20 @@ def oracle_digests(
 
 
 def oracle_fib_digests(
-    specs: List[TenantSpec], rounds: int, every: int
+    specs: List[TenantSpec], rounds: int, every: int,
+    backend: str = "device",
 ) -> Dict[str, List[int]]:
     """Never-migrated FIB oracle: replay each tenant's schedule on a
-    local ``SpfSolver`` through the SAME recipe the ctrl handler uses
-    (``fleet_preload_views`` over the packed ELL view, then
-    ``build_route_db`` -> canonical ``RouteDatabase``), digesting the
-    wire form on the rounds ``run_client(fib_every=every)`` samples.
-    Imports jax — parent/gate side only."""
+    local ``SpfSolver`` and digest the canonical ``RouteDatabase`` wire
+    form on the rounds ``run_client(fib_every=every)`` samples.
+
+    ``backend="device"`` goes through the SAME recipe the ctrl handler
+    uses (``fleet_preload_views`` over the packed ELL view, then
+    ``build_route_db``) — the reference for migration/promotion gates,
+    which ask "same as a service that never moved". ``backend="host"``
+    is the host Dijkstra solver with no device kernel involved — the
+    reference for "is the served answer right at all"
+    (``chip_smoke.py``). Imports jax — parent/gate side only."""
     import numpy as np
 
     from openr_tpu.decision.prefix_state import PrefixState
@@ -338,7 +344,7 @@ def oracle_fib_digests(
         pfx = PrefixState()
         for _name, pdb in sorted(spec.build_prefix_dbs().items()):
             pfx.update_prefix_database(pdb)
-        solver = SpfSolver(root, backend="device")
+        solver = SpfSolver(root, backend=backend)
         digests: List[int] = []
         for i in range(rounds):
             if i > 0:
@@ -346,12 +352,13 @@ def oracle_fib_digests(
                 ls.update_adjacency_database(dbs[node])
             if not every or (i + 1) % every != 0:
                 continue
-            graph = compile_ell(ls)
-            srcs = ell_source_batch(graph, ls, root)
-            packed = np.asarray(
-                ell_view_batch_packed(graph, srcs)
-            ).astype(np.int32)
-            fleet_preload_views(ls, [(graph, srcs, packed)])
+            if backend == "device":
+                graph = compile_ell(ls)
+                srcs = ell_source_batch(graph, ls, root)
+                packed = np.asarray(
+                    ell_view_batch_packed(graph, srcs)
+                ).astype(np.int32)
+                fleet_preload_views(ls, [(graph, srcs, packed)])
             ddb = solver.build_route_db(root, {"0": ls}, pfx)
             blob = wire.dumps(ddb.to_route_db(root))
             h = 0x811C9DC5

@@ -22,10 +22,12 @@ share this process-global cache.
 
 Fallback ladder (never raises past the jitted semantics): a failed
 lower/compile poisons the key and the call rides the plain jitted
-function (``ops.aot_fallbacks``); a failed EXECUTABLE call (placement
-drift, donated-buffer reuse, transfer guards) falls back the same way
-per call. The executables themselves ride jax's persistent compilation
-cache when one is configured, so "compile on miss" is a disk load, not
+function; a failed EXECUTABLE call (placement drift, donated-buffer
+reuse, transfer guards) falls back the same way per call. Neither is
+quiet: each logs the exception with its traceback and counts
+``ops.aot_fallbacks``, which ``chip_smoke.py`` requires to be zero on
+the chip. The executables themselves ride jax's persistent compilation
+cache (utils.compile_cache), so "compile on miss" is a disk load, not
 an XLA run, across processes.
 
 Counters: ``ops.aot_compiles`` / ``ops.aot_hits`` /
@@ -35,10 +37,10 @@ Counters: ``ops.aot_compiles`` / ``ops.aot_hits`` /
 
 from __future__ import annotations
 
-import os
+import logging
 import threading
 import time
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import numpy as np
@@ -46,6 +48,8 @@ import numpy as np
 from openr_tpu.ops import dispatch_accounting
 from openr_tpu.telemetry import get_registry
 from openr_tpu.telemetry.profiler import get_profiler
+
+log = logging.getLogger(__name__)
 
 _UNCOMPILABLE = object()  # poison marker: lower/compile failed once
 
@@ -65,17 +69,6 @@ def _profiled(tag: str, thunk):
     device_ms = prof.on_dispatch(tag, out, host_ms)
     dispatch_accounting.attribute_stage(tag, host_ms, device_ms)
     return out
-
-
-def cache_dir() -> Optional[str]:
-    """Directory the persistent artifacts (autotune winners, jax's
-    compilation cache when the caller wires it) live in. None when
-    ``OPENR_CACHE_DIR`` is unset — in-memory only, no disk writes."""
-    d = os.environ.get("OPENR_CACHE_DIR")
-    if not d:
-        return None
-    os.makedirs(d, exist_ok=True)
-    return d
 
 
 def _leaf_sig(leaf: Any) -> Tuple:
@@ -141,6 +134,10 @@ class AotDispatchCache:
             try:
                 exe = fn.lower(*dyn_args, **statics).compile()
             except Exception:  # noqa: BLE001 - poison + jitted path
+                log.exception(
+                    "aot %s: lower/compile failed, key poisoned, "
+                    "riding the jitted function", tag,
+                )
                 with self._lock:
                     self._exes[key] = _UNCOMPILABLE
                 reg.counter_bump("ops.aot_fallbacks")
@@ -155,6 +152,12 @@ class AotDispatchCache:
             # time and no longer exist as parameters of the executable
             return _profiled(tag, lambda: exe(*dyn_args))
         except Exception:  # noqa: BLE001 - absorb into jitted path
+            # a donated operand the failed call already consumed makes
+            # the retry raise in its own right; that one propagates
+            log.exception(
+                "aot %s: executable call failed, retrying through the "
+                "jitted function", tag,
+            )
             reg.counter_bump("ops.aot_fallbacks")
             return _profiled(tag, lambda: fn(*dyn_args, **statics))
 
@@ -170,7 +173,8 @@ class AotDispatchCache:
             return True
         try:
             exe = fn.lower(*dyn_args, **statics).compile()
-        except Exception:  # noqa: BLE001 - poison, warm is best-effort
+        except Exception:  # noqa: BLE001 - poison; the call path counts
+            log.exception("aot %s: prewarm lower/compile failed", tag)
             with self._lock:
                 self._exes[key] = _UNCOMPILABLE
             return False

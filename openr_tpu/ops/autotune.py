@@ -1,22 +1,26 @@
 """Measured per-shape kernel autotuner.
 
-BENCH rounds keep flipping the jnp-vs-pallas min-plus winner with
-shape and round (0.337 vs 1.815 ms in r03, 1.049 vs 0.131 ms in r05 on
-the same leg): neither implementation dominates, so hardcoding either
-leaves measured milliseconds on the table somewhere. Instead of a
+Earlier on-chip captures flipped the jnp-vs-pallas min-plus winner with
+shape and run on the same leg: neither implementation dominates, so
+hardcoding either leaves time on the table somewhere. Instead of a
 global default, ``impl="auto"`` resolves to a MEASURED winner per
 ``(platform, kernel, shape)`` key at build time: time each candidate on
 synthetic operands of the real shape (one warmup for compile, best of
-``reps`` timed runs), memoize the winner in process, and persist it as
-JSON next to the AOT/persistent compile cache (``aot_cache.cache_dir``,
-set via ``OPENR_CACHE_DIR``) so later processes skip the measurement.
+``reps`` timed runs) and memoize the winner for the life of the
+process. Nothing is read from or written to disk: a winner is only as
+good as the machine, the jax and the kernels it was measured with, and
+the measurement costs a handful of dispatches (its compiles ride jax's
+persistent compilation cache).
 
 Resolution happens in the PUBLIC eager wrappers (``spf.
 all_pairs_distances`` et al.) before jit entry — the winner is an
 ordinary static ``impl`` argument by the time a trace sees it, so
 "auto" never appears inside a compiled executable's key. A candidate
-that raises (pallas without a TPU lowering for the shape) is
-disqualified for that key, never fatal.
+that raises is disqualified for that key: the exception is logged and
+counted (``ops.autotune_disqualified``) — every selectable kernel is
+proven to lower on the chip by ``chip_smoke.py``, so a disqualification
+there is a defect, and the smoke fails on it. If every candidate raises
+there is nothing to run and the last exception propagates.
 
 The measurer is injectable (``Autotuner(measure=...)``) so tests drive
 deterministic winner selection without timing noise.
@@ -25,78 +29,24 @@ deterministic winner selection without timing noise.
 from __future__ import annotations
 
 import functools
-import json
-import os
+import logging
 import time
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from openr_tpu.ops.aot_cache import cache_dir
+from openr_tpu.ops.spf import INF, KernelImpl
 from openr_tpu.telemetry import get_registry
 
-_PERSIST_FILE = "autotune.json"
+log = logging.getLogger(__name__)
 
-# kernel family -> legal winner names. Persistence is keyed on the
-# family and every loaded entry is validated against it, so a winner
-# measured for one family can never be replayed onto a dispatch of
-# another that shares the same (platform, shape) — e.g. a dense
-# "pallas_t" minplus winner silently arming the sparse ell_relax
-# dispatch, which has no such implementation. Unknown families and
-# out-of-family winners are dropped on load (re-measured), never fatal.
+# kernel family -> legal winner names: a winner recorded for one family
+# can never arm a dispatch of another that shares the same shape key
 _FAMILY_CANDIDATES = {
     "minplus": ("jnp", "pallas"),
     "grouped_minplus": ("jnp", "pallas", "pallas_t"),
-    "ell_relax": ("jnp", "pallas"),
 }
-
-_SCHEMA_VERSION = 2
-
-
-def _valid_entry(key: str, entry) -> Optional[Tuple[str, str]]:
-    """(family, winner) when the persisted entry is adoptable, else
-    None. Keys are ``platform:family:shape``; v2 entries also carry an
-    explicit ``family`` field that must agree with the key (a mismatch
-    means the file was hand-edited or corrupted — re-measure)."""
-    if not isinstance(entry, dict):
-        return None
-    winner = entry.get("winner")
-    parts = key.split(":")
-    if len(parts) != 3 or not isinstance(winner, str):
-        return None
-    family = parts[1]
-    if family not in _FAMILY_CANDIDATES:
-        return None
-    if winner not in _FAMILY_CANDIDATES[family]:
-        return None
-    tagged = entry.get("family")
-    if tagged is not None and tagged != family:
-        return None
-    return family, winner
-
-
-def _parse_persisted(data) -> Dict[str, Dict]:
-    """Lenient reader for both schemas: v2 ``{"version": 2, "winners":
-    {...}}`` and the legacy flat ``{key: {"winner": ...}}`` dict.
-    Invalid/unknown entries are dropped (those keys re-measure)."""
-    if not isinstance(data, dict):
-        return {}
-    winners = data.get("winners", data)
-    if not isinstance(winners, dict):
-        return {}
-    out: Dict[str, Dict] = {}
-    for key, entry in winners.items():
-        ok = _valid_entry(key, entry)
-        if ok is None:
-            continue
-        family, winner = ok
-        out[key] = {
-            "family": family,
-            "winner": winner,
-            "ms": entry.get("ms", {}),
-        }
-    return out
 
 
 def _default_measure(thunk: Callable[[], None], reps: int = 3) -> float:
@@ -112,77 +62,24 @@ def _default_measure(thunk: Callable[[], None], reps: int = 3) -> float:
 
 
 class Autotuner:
-    def __init__(self, measure: Optional[Callable] = None,
-                 persist: bool = True):
+    def __init__(self, measure: Optional[Callable] = None):
         self._measure = measure or _default_measure
-        self._persist = persist
         self._winners: Dict[str, str] = {}
-        self._loaded = False
 
-    def _path(self) -> Optional[str]:
-        d = cache_dir()
-        return os.path.join(d, _PERSIST_FILE) if d else None
-
-    def _load(self) -> None:
-        if self._loaded:
-            return
-        self._loaded = True
-        path = self._path() if self._persist else None
-        if path and os.path.exists(path):
-            try:
-                with open(path) as f:
-                    data = json.load(f)
-                self._winners.update({
-                    k: v["winner"]
-                    for k, v in _parse_persisted(data).items()
-                })
-            except Exception:  # noqa: BLE001 - cache is best-effort
-                pass
-
-    def _save(self, key: str, winner: str,
-              timings: Dict[str, float]) -> None:
-        path = self._path() if self._persist else None
-        if not path:
-            return
-        try:
-            winners = {}
-            if os.path.exists(path):
-                with open(path) as f:
-                    # legacy flat files migrate here: valid entries are
-                    # rewritten under the v2 schema, invalid ones drop
-                    winners = _parse_persisted(json.load(f))
-            family = key.split(":")[1]
-            winners[key] = {
-                "family": family, "winner": winner, "ms": timings,
-            }
-            with open(path, "w") as f:
-                json.dump(
-                    {"version": _SCHEMA_VERSION, "winners": winners},
-                    f, indent=1, sort_keys=True,
-                )
-        except Exception:  # noqa: BLE001 - cache is best-effort
-            pass
-
-    def record(self, kernel: str, shape_key: str, winner: str,
-               timings: Optional[Dict[str, float]] = None) -> None:
+    def record(self, kernel: str, shape_key: str, winner: str) -> None:
         """Adopt an EXTERNALLY measured winner (e.g. bench.py's oracle-
         gated probe, which times the real reconverge loop rather than a
-        synthetic contraction) — memoized and persisted exactly like a
-        ``pick`` result, so later processes inherit the bench's
-        measurement."""
+        synthetic contraction) — memoized exactly like a ``pick``
+        result for the rest of the process."""
         assert kernel in _FAMILY_CANDIDATES, kernel
         assert winner in _FAMILY_CANDIDATES[kernel], (kernel, winner)
-        self._load()
         platform = jax.devices()[0].platform
-        key = f"{platform}:{kernel}:{shape_key}"
-        self._winners[key] = winner
-        self._save(key, winner, timings or {})
+        self._winners[f"{platform}:{kernel}:{shape_key}"] = winner
 
     def pick(self, kernel: str, shape_key: str,
              candidates: Dict[str, Callable[[], None]]) -> str:
         """Winner name for (platform, kernel, shape): memoized, then
-        persisted, then measured."""
-        self._load()
+        measured."""
         platform = jax.devices()[0].platform
         key = f"{platform}:{kernel}:{shape_key}"
         got = self._winners.get(key)
@@ -190,17 +87,23 @@ class Autotuner:
             return got
         reg = get_registry()
         timings: Dict[str, float] = {}
+        last_exc: Optional[Exception] = None
         for name, thunk in candidates.items():
             try:
                 timings[name] = self._measure(thunk)
-            except Exception:  # noqa: BLE001 - disqualified candidate
+            except Exception as exc:  # noqa: BLE001 - counted, logged
+                last_exc = exc
                 reg.counter_bump("ops.autotune_disqualified")
+                log.exception(
+                    "autotune: %s candidate %r disqualified at %s",
+                    kernel, name, key,
+                )
         if not timings:
-            winner = next(iter(candidates))
-        else:
-            winner = min(timings, key=timings.get)
+            raise RuntimeError(
+                f"autotune: every {kernel} candidate failed at {key}"
+            ) from last_exc
+        winner = min(timings, key=timings.get)
         self._winners[key] = winner
-        self._save(key, winner, timings)
         reg.counter_bump("ops.autotune_measurements")
         return winner
 
@@ -224,28 +127,29 @@ def _minplus_probe(a, b, impl):
     return _minplus(a, b, impl)
 
 
-def resolve_minplus(shape: Tuple[int, ...]) -> str:
+def resolve_minplus(shape: Tuple[int, ...], interpret: bool) -> KernelImpl:
     """Measured jnp-vs-pallas winner for the dense min-plus contraction
     at this [S, N] x [N, N] shape (spf's public wrappers call this when
-    the impl is "auto", before jit entry)."""
-    from openr_tpu.ops.spf import INF
-
+    the impl is "auto", before jit entry). ``interpret`` is how the
+    Pallas candidate runs, here and as the winner."""
     s = int(shape[0])
     n = int(shape[-1])
 
-    def thunk(impl):
+    def thunk(name):
         a = jnp.full((s, n), INF // 2, jnp.int32)
         b = jnp.full((n, n), INF // 2, jnp.int32)
+        impl = KernelImpl(name, interpret)
 
         def run():
             _minplus_probe(a, b, impl).block_until_ready()
 
         return run
 
-    return _TUNER.pick(
+    winner = _TUNER.pick(
         "minplus", f"{s}x{n}",
         {"jnp": thunk("jnp"), "pallas": thunk("pallas")},
     )
+    return KernelImpl(winner, interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))
@@ -255,62 +159,26 @@ def _grouped_probe(gath, w, impl):
     return _contract(gath, w, impl)
 
 
-def resolve_grouped(shape: Tuple[int, int, int, int]) -> str:
+def resolve_grouped(
+    shape: Tuple[int, int, int, int], interpret: bool
+) -> KernelImpl:
     """Measured winner for the grouped [B, G, S] x [G, S, R] block
-    contraction."""
-    from openr_tpu.ops.spf import INF
-
+    contraction; ``interpret`` as in resolve_minplus."""
     b, g, s, r = (int(x) for x in shape)
 
-    def thunk(impl):
+    def thunk(name):
         gath = jnp.full((b, g, s), INF // 2, jnp.int32)
         w = jnp.full((g, s, r), INF // 2, jnp.int32)
+        impl = KernelImpl(name, interpret)
 
         def run():
             _grouped_probe(gath, w, impl).block_until_ready()
 
         return run
 
-    return _TUNER.pick(
+    winner = _TUNER.pick(
         "grouped_minplus", f"{b}x{g}x{s}x{r}",
         {"jnp": thunk("jnp"), "pallas": thunk("pallas"),
          "pallas_t": thunk("pallas_t")},
     )
-
-
-@functools.partial(jax.jit, static_argnames=("impl",))
-def _ell_relax_probe(d, src, w, overloaded, impl):
-    from openr_tpu.ops.spf_sparse import _uniform_relax
-
-    return _uniform_relax(d, src, w, overloaded, impl=impl)
-
-
-def resolve_ell_relax(shape: Tuple[int, int]) -> str:
-    """Measured jnp-vs-pallas winner for the sliced-ELL relaxation at
-    this (n_pad, k_slot) band shape. The probe runs the single-band
-    uniform relax (identical algebra to the banded kernel — the slot
-    class the shape key describes) on synthetic operands: a
-    [TILE_S, n] distance panel against [n, k] slot tensors. The S
-    extent is excluded from the key on purpose: it varies per dispatch
-    (view batches, all-sources blocks, sweep batches) while the band
-    geometry — which decides gather locality, the thing being measured
-    — does not."""
-    from openr_tpu.ops.spf import INF
-
-    n, k = (int(x) for x in shape)
-
-    def thunk(impl):
-        d = jnp.full((8, n), INF // 2, jnp.int32)
-        src = jnp.zeros((n, k), jnp.int32)
-        w = jnp.full((n, k), INF // 2, jnp.int32)
-        ov = jnp.zeros((n,), jnp.bool_)
-
-        def run():
-            _ell_relax_probe(d, src, w, ov, impl).block_until_ready()
-
-        return run
-
-    return _TUNER.pick(
-        "ell_relax", f"{n}x{k}",
-        {"jnp": thunk("jnp"), "pallas": thunk("pallas")},
-    )
+    return KernelImpl(winner, interpret)

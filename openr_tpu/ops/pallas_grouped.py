@@ -30,7 +30,10 @@ leading block dims are unconstrained. Padding rows/cols are inert
 
 Like the dense kernel, selection is BY MEASUREMENT: the scale bench
 times both impls at the segment shapes and runs the winner
-(spf_grouped.set_grouped_impl); interpret mode covers CPU tests.
+(spf_grouped.set_grouped_impl). ``interpret`` is always passed by the
+caller — True in CPU tests, False on the chip, where ``chip_smoke.py``
+compiles both layouts at the 10k fabric's segment shapes and compares
+them with the jnp contraction bit for bit.
 """
 
 from __future__ import annotations
@@ -201,7 +204,7 @@ def _kernel_t(g_ref, w_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def batched_minplus_t(
-    gath_t: jnp.ndarray, w: jnp.ndarray, interpret: bool = False
+    gath_t: jnp.ndarray, w: jnp.ndarray, *, interpret: bool
 ) -> jnp.ndarray:
     """[G, S, B] (x) [G, S, R] -> [G, R, B] over (min, +): the
     lane-efficient layout for small R. Padding discipline matches
@@ -245,7 +248,7 @@ def batched_minplus_t(
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def batched_minplus(
-    gath: jnp.ndarray, w: jnp.ndarray, interpret: bool = False
+    gath: jnp.ndarray, w: jnp.ndarray, *, interpret: bool
 ) -> jnp.ndarray:
     """[G, B, S] (x) [G, S, R] -> [G, B, R] over (min, +), saturating
     at INF. Inputs are padded here; INF weight padding keeps padded

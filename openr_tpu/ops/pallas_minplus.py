@@ -16,9 +16,11 @@ Tiling: grid (S/TS, N/TN, K/TK) with k innermost; the output tile is
 revisited across k and accumulated with minimum (initialized to INF at
 k == 0 via pl.when).
 
-Enable through ``openr_tpu.ops.spf.set_minplus_impl("pallas")`` (bench
-auto-probes and falls back to the jnp formulation on any failure);
-interpret mode is used for CPU correctness tests.
+Enable through ``openr_tpu.ops.spf.set_minplus_impl("pallas")``.
+``interpret`` is always passed by the caller — True in CPU correctness
+tests, False on the chip, where ``chip_smoke.py`` compiles the kernel at
+the fabric-1008 shape and compares it with the jnp formulation bit for
+bit.
 """
 
 from __future__ import annotations
@@ -66,7 +68,7 @@ def _minplus_kernel(a_ref, b_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def minplus(a: jnp.ndarray, b: jnp.ndarray, interpret: bool = False):
+def minplus(a: jnp.ndarray, b: jnp.ndarray, *, interpret: bool):
     """(a (x) b) over (min, +): [S, K] x [K, N] -> [S, N] int32.
 
     Shapes must be multiples of the tile sizes (the snapshot layer pads

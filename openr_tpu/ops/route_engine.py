@@ -87,7 +87,6 @@ from openr_tpu.ops.spf_sparse import (
     _out_edges,
     _tenant_view_solve,
     compile_ell,
-    ell_dispatch,
     ell_patch,
     pad_patch_rows,
 )
@@ -568,7 +567,7 @@ def _overflow_chain(
 
 from jax.sharding import Mesh, PartitionSpec as P  # noqa: E402
 
-from openr_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 from openr_tpu.ops.spf_sparse import SOURCES_AXIS  # noqa: E402
 from openr_tpu.parallel.mesh import (  # noqa: E402
@@ -1122,7 +1121,7 @@ class RouteSweepEngine(ResidentEngineContract):
         if self.mesh is None:
             # openr-lint: disable=sharding-spec -- single-chip cold
             # build (mesh is None): one device, no axis to spec
-            return ell_dispatch(
+            return aot_call(
                 "ell_full_resident", _full_resident_sweep,
                 (
                     self.sweeper.v_t, self.sweeper.w_t,
@@ -1133,7 +1132,7 @@ class RouteSweepEngine(ResidentEngineContract):
                 ),
                 dict(bands=graph.bands, n=graph.n_pad),
             )
-        return ell_dispatch(
+        return aot_call(
             "ell_full_resident_sharded", _sharded_full_resident,
             (
                 self.sweeper.v_t, self.sweeper.w_t,
@@ -1306,7 +1305,7 @@ class RouteSweepEngine(ResidentEngineContract):
              # openr-lint: disable=sharding-spec -- single-chip churn
              # dispatch (mesh is None): no mesh axis to spec; the mesh
              # branch below rides _sharded_churn_step's shard_map specs
-             packed_dev) = ell_dispatch(
+             packed_dev) = aot_call(
                 "ell_churn_step", _churn_step,
                 (
                     ctx["in_v"], ctx["in_w"],
@@ -1332,7 +1331,7 @@ class RouteSweepEngine(ResidentEngineContract):
             if ctx["patched_bands"] is None:
                 ctx["patched_bands"] = self._dispatch_patch(ctx)
             new_v, new_w_t = ctx["patched_bands"]
-            dr, digests, packed_res, packed_dev = ell_dispatch(
+            dr, digests, packed_res, packed_dev = aot_call(
                 "ell_churn_step_sharded", _sharded_churn_step,
                 (
                     new_v, new_w_t,
@@ -1537,7 +1536,7 @@ class RouteSweepEngine(ResidentEngineContract):
         if self.mesh is None:
             # openr-lint: disable=sharding-spec -- single-chip frontier
             # probe (mesh is None): no mesh axis to spec
-            return ell_dispatch(
+            return aot_call(
                 "ell_frontier_probe", _frontier_probe,
                 (
                     self.sweeper.v_t, self.sweeper.w_t, self._dr,
@@ -1548,7 +1547,7 @@ class RouteSweepEngine(ResidentEngineContract):
                     max_jumps=_FRONTIER_MAX_JUMPS,
                 ),
             )
-        return ell_dispatch(
+        return aot_call(
             "ell_frontier_probe_sharded", _sharded_frontier_probe,
             (
                 self.sweeper.v_t, self.sweeper.w_t, self._dr,
@@ -1572,7 +1571,7 @@ class RouteSweepEngine(ResidentEngineContract):
         if self.mesh is None:
             # openr-lint: disable=sharding-spec -- single-chip frontier
             # re-solve (mesh is None): no mesh axis to spec
-            return ell_dispatch(
+            return aot_call(
                 "ell_frontier_step", _frontier_step,
                 (
                     self.sweeper.v_t, self.sweeper.w_t, cone, self._dr,
@@ -1583,7 +1582,7 @@ class RouteSweepEngine(ResidentEngineContract):
                 ),
                 dict(bands=self.graph.bands, n=self.graph.n_pad),
             )
-        return ell_dispatch(
+        return aot_call(
             "ell_frontier_step_sharded", _sharded_frontier_step,
             (
                 self.sweeper.v_t, self.sweeper.w_t, cone, self._dr,
@@ -1619,7 +1618,7 @@ class RouteSweepEngine(ResidentEngineContract):
         if self.mesh is None:
             # openr-lint: disable=sharding-spec -- single-chip fused
             # overflow chain (mesh is None): no mesh axis to spec
-            return ell_dispatch(
+            return aot_call(
                 "ell_overflow_chain", _overflow_chain,
                 (
                     self.sweeper.v_t, self.sweeper.w_t, new_v, new_w,
@@ -1634,7 +1633,7 @@ class RouteSweepEngine(ResidentEngineContract):
                     n_real=self.graph.n, max_jumps=_FRONTIER_MAX_JUMPS,
                 ),
             )
-        return ell_dispatch(
+        return aot_call(
             "ell_overflow_chain_sharded", _sharded_overflow_chain,
             (
                 self.sweeper.v_t, self.sweeper.w_t, new_v, new_w,

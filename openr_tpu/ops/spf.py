@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import functools
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +57,19 @@ def _mask_transit_rows(d: jnp.ndarray, overloaded: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(overloaded[:, None], ident_row, d)
 
 
+class KernelImpl(NamedTuple):
+    """A relaxation kernel choice as a jitted function sees it: which
+    implementation and, for a Pallas one, whether it runs interpreted
+    (CPU tests) or compiled (the chip). Never inferred from the
+    platform: whoever selects a Pallas kernel says which. Hashable, so
+    it rides the static ``impl`` argument of every dispatch."""
+
+    name: str
+    interpret: bool = False
+
+
+JNP = KernelImpl("jnp")
+
 # min-plus implementation selector: "jnp" (XLA fused broadcast+reduce),
 # "pallas" (explicit VMEM tiling, openr_tpu.ops.pallas_minplus), or
 # "auto" — a MEASURED per-shape winner picked by ops.autotune at the
@@ -63,39 +77,43 @@ def _mask_transit_rows(d: jnp.ndarray, overloaded: jnp.ndarray) -> jnp.ndarray:
 # flips with shape and hardware; see ops/autotune.py). Resolution
 # happens in the public wrappers below, before jit entry, so traces
 # only ever see a concrete impl as their static argument.
-_MINPLUS_IMPL = os.environ.get("OPENR_MINPLUS", "jnp")
+_MINPLUS_IMPL = KernelImpl(os.environ.get("OPENR_MINPLUS", "jnp"))
 
 
-def set_minplus_impl(impl: str) -> None:
+def set_minplus_impl(name: str, interpret: bool = False) -> None:
     global _MINPLUS_IMPL
-    assert impl in ("jnp", "pallas", "auto"), impl
-    _MINPLUS_IMPL = impl
+    assert name in ("jnp", "pallas", "auto"), name
+    _MINPLUS_IMPL = KernelImpl(name, interpret)
 
 
-def get_minplus_impl() -> str:
+def get_minplus_impl() -> KernelImpl:
     return _MINPLUS_IMPL
 
 
-def _impl_for(shape) -> str:
+def _impl_for(shape) -> KernelImpl:
     """Concrete impl for one dispatch: "auto" resolves to the measured
     per-shape winner ([rows, n] against [n, n])."""
-    if _MINPLUS_IMPL != "auto":
+    if _MINPLUS_IMPL.name != "auto":
         return _MINPLUS_IMPL
     from openr_tpu.ops import autotune
 
-    return autotune.resolve_minplus(tuple(shape))
+    return autotune.resolve_minplus(
+        tuple(shape), _MINPLUS_IMPL.interpret
+    )
 
 
-def _minplus(a: jnp.ndarray, b: jnp.ndarray, impl: str = "jnp") -> jnp.ndarray:
+def _minplus(
+    a: jnp.ndarray, b: jnp.ndarray, impl: KernelImpl = JNP
+) -> jnp.ndarray:
     """(a (x) b)[s, j] = min_k a[s, k] + b[k, j], saturating at INF.
 
     jnp path: XLA fuses the broadcast-add into the min-reduction, so the
     [S, N, N] intermediate is never materialized in HBM.
     """
-    if impl == "pallas":
+    if impl.name == "pallas":
         from openr_tpu.ops.pallas_minplus import minplus as pallas_minplus
 
-        return pallas_minplus(a, b)
+        return pallas_minplus(a, b, interpret=impl.interpret)
     return jnp.minimum(
         jnp.min(a[:, :, None] + b[None, :, :], axis=1), INF
     ).astype(jnp.int32)
@@ -103,7 +121,7 @@ def _minplus(a: jnp.ndarray, b: jnp.ndarray, impl: str = "jnp") -> jnp.ndarray:
 
 @functools.partial(jax.jit, static_argnames=("impl",))
 def _all_pairs_distances(
-    w: jnp.ndarray, overloaded: jnp.ndarray, impl: str
+    w: jnp.ndarray, overloaded: jnp.ndarray, impl: KernelImpl
 ) -> jnp.ndarray:
     n = w.shape[0]
     eye = (
@@ -142,7 +160,7 @@ def _distances_from_sources(
     w: jnp.ndarray,
     overloaded: jnp.ndarray,
     src_ids: jnp.ndarray,
-    impl: str,
+    impl: KernelImpl,
 ) -> jnp.ndarray:
     n = w.shape[0]
     t = _mask_transit_rows(w, overloaded)
@@ -243,7 +261,7 @@ def _spf_view_batch(
     overloaded: jnp.ndarray,
     srcs: jnp.ndarray,
     use_link_metric: bool,
-    impl: str,
+    impl: KernelImpl,
 ):
     n = metric.shape[0]
     b = srcs.shape[0]
@@ -285,7 +303,7 @@ def _spf_view_batch(
     direct_ok = col_is_self & (is_neighbor & (w_sv == d_src[srcs]))[:, None]
     fh = (transit_ok | direct_ok) & reachable[None, :]
     # pack into one output buffer: a single device->host fetch returns
-    # both (per-transfer latency dominates on relay-backed platforms)
+    # both
     return jnp.concatenate([d, fh.astype(jnp.int32)], axis=0)
 
 
@@ -340,7 +358,7 @@ def _reconverge_step(
     overloaded: jnp.ndarray,
     srcs: jnp.ndarray,
     use_link_metric: bool,
-    impl: str,
+    impl: KernelImpl,
 ):
     m = metric.at[patch_ids, :].set(patch_vals)
     packed = _spf_view_batch(m, overloaded, srcs, use_link_metric, impl)
@@ -376,7 +394,7 @@ def _spf_from_source_with_first_hops(
     overloaded: jnp.ndarray,
     src_id: jnp.ndarray,
     use_link_metric: bool,
-    impl: str,
+    impl: KernelImpl,
 ):
     w = metric if use_link_metric else hop
     d_all = _all_pairs_distances(w, overloaded, impl)

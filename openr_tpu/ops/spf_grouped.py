@@ -60,10 +60,10 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from openr_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 import numpy as np
 
-from openr_tpu.ops.spf import INF
+from openr_tpu.ops.spf import INF, JNP, KernelImpl
 from openr_tpu.ops.spf_sparse import (
     _as_device_ids,
     _in_edges,
@@ -78,44 +78,44 @@ from openr_tpu.ops.spf_sparse import (
 # contraction's tiling is dominated by platform, not by the exact
 # segment dims). Like the dense path (ops.spf minplus), the bench also
 # probes all three ON REAL HARDWARE and can pin the winner explicitly.
-_GROUPED_IMPL = os.environ.get("OPENR_GROUPED_IMPL", "jnp")
+_GROUPED_IMPL = KernelImpl(os.environ.get("OPENR_GROUPED_IMPL", "jnp"))
 
 # representative [B, G, S, R] probe block for the "auto" measurement
 _AUTO_PROBE_SHAPE = (32, 8, 8, 16)
 
 
-def set_grouped_impl(impl: str) -> None:
+def set_grouped_impl(name: str, interpret: bool = False) -> None:
     global _GROUPED_IMPL
-    assert impl in ("jnp", "pallas", "pallas_t", "auto"), impl
-    _GROUPED_IMPL = impl
+    assert name in ("jnp", "pallas", "pallas_t", "auto"), name
+    _GROUPED_IMPL = KernelImpl(name, interpret)
 
 
-def get_grouped_impl() -> str:
-    if _GROUPED_IMPL != "auto":
+def get_grouped_impl() -> KernelImpl:
+    """The concrete contraction every grouped dispatch passes as its
+    static ``impl`` ("auto" resolved to the measured winner)."""
+    if _GROUPED_IMPL.name != "auto":
         return _GROUPED_IMPL
     from openr_tpu.ops import autotune
 
-    return autotune.resolve_grouped(_AUTO_PROBE_SHAPE)
+    return autotune.resolve_grouped(
+        _AUTO_PROBE_SHAPE, _GROUPED_IMPL.interpret
+    )
 
 
-def _contract(gath, w, impl):
-    """c[b, g, r] = min_s gath[b, g, s] + w[g, s, r] (INF-saturating).
-    The pallas path runs in interpret mode off-TPU so CPU tests cover
-    the same code path."""
-    if impl == "pallas":
+def _contract(gath, w, impl: KernelImpl):
+    """c[b, g, r] = min_s gath[b, g, s] + w[g, s, r] (INF-saturating)."""
+    if impl.name == "pallas":
         from openr_tpu.ops import pallas_grouped
 
-        interpret = jax.devices()[0].platform == "cpu"
         c = pallas_grouped.batched_minplus(
-            jnp.transpose(gath, (1, 0, 2)), w, interpret=interpret
+            jnp.transpose(gath, (1, 0, 2)), w, interpret=impl.interpret
         )  # [G, B, R]
         return jnp.transpose(c, (1, 0, 2))
-    if impl == "pallas_t":
+    if impl.name == "pallas_t":
         from openr_tpu.ops import pallas_grouped
 
-        interpret = jax.devices()[0].platform == "cpu"
         c = pallas_grouped.batched_minplus_t(
-            jnp.transpose(gath, (1, 2, 0)), w, interpret=interpret
+            jnp.transpose(gath, (1, 2, 0)), w, interpret=impl.interpret
         )  # [G, R, B] — lanes carry the batch, sublanes carry R
         return jnp.transpose(c, (2, 0, 1))
     return jnp.min(
@@ -378,7 +378,7 @@ def device_tensors(graph: GroupedGraph):
 
 
 def _grouped_relax(d, meta, srcs_t, ws_t, overloaded, t_ids,
-                   impl="jnp"):
+                   impl=JNP):
     """One relaxation [B, N] -> [B, N] over the grouped bands as dense
     per-segment contractions. ``t_ids`` None => forward transit mask
     (edge origin overloaded); else the reverse row-dependent mask
@@ -414,7 +414,7 @@ def _grouped_relax(d, meta, srcs_t, ws_t, overloaded, t_ids,
 
 def _grouped_fixed_point(
     meta, srcs_t, ws_t, overloaded, ids, n, reverse, vote=None,
-    impl="jnp", init=None,
+    impl=JNP, init=None,
 ):
     """Distance fixed point from unit init. ``reverse=False``: rows are
     SOURCES (forward all-sources; init = one unmasked relax so an
@@ -480,7 +480,7 @@ def grouped_distances_from_sources(
     st = state if state is not None else GroupedState(graph)
     return _grouped_from_sources(
         st.src, st.w, st.overloaded,
-        _as_device_ids(src_ids), st.meta, graph.n_pad, _GROUPED_IMPL,
+        _as_device_ids(src_ids), st.meta, graph.n_pad, get_grouped_impl(),
     )
 
 
@@ -638,7 +638,7 @@ def _grouped_cone_expand(sel_dr, meta, srcs_t, ws_t, e_u, e_v, e_w_old,
 
 def _grouped_route_block_body(
     srcs_t, ws_t, overloaded, t_ids, samp_ids, samp_v, samp_w, pos_w,
-    meta, n, vote=None, impl="jnp",
+    meta, n, vote=None, impl=JNP,
 ):
     """Grouped twin of route_sweep._route_block_body: same packed
     layout, same digest algebra — only the relaxation backend differs,
@@ -725,7 +725,7 @@ class GroupedRouteSweeper:
             _as_device_ids(t_ids),
             self._samp_ids_dev, self._samp_v_dev, self._samp_w_dev,
             self._pos_w_dev, self.meta, self.graph.n_pad,
-            _GROUPED_IMPL,
+            get_grouped_impl(),
         )
 
     # the block loop and result assembly are layout-independent —
@@ -909,7 +909,7 @@ def sharded_grouped_route_sweep(graph: GroupedGraph, sample_names, mesh):
             jnp.asarray(np.arange(n, dtype=np.int32)),
             sweeper._samp_ids_dev, sweeper._samp_v_dev,
             sweeper._samp_w_dev, sweeper._pos_w_dev,
-            sweeper.meta, n, mesh, _GROUPED_IMPL,
+            sweeper.meta, n, mesh, get_grouped_impl(),
         )
     )
     return rs.assemble_result(sweeper, packed)

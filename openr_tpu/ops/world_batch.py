@@ -74,6 +74,7 @@ from openr_tpu.integrity import ResidentEngineContract, get_auditor
 from openr_tpu.integrity import kernels as integrity_kernels
 from openr_tpu.analysis.annotations import committed_dispatch, thread_confined
 from openr_tpu.ops import dispatch_accounting as da
+from openr_tpu.ops.aot_cache import aot_call
 from openr_tpu.ops.route_engine import (
     FAULT_CORRUPT,
     FAULT_DEVICE_LOST,
@@ -85,7 +86,6 @@ from openr_tpu.ops.spf_sparse import (
     EllGraph,
     band_row_edge_changes,
     compile_ell,
-    ell_dispatch,
     ell_pack_uniform,
     ell_patch,
     ell_source_batch,
@@ -1274,10 +1274,7 @@ class WorldManager(ResidentEngineContract):
         with _get_profiler().labels(
             bucket=f"{bucket.s}x{bucket.n}x{bucket.k}", slo=dominant,
         ):
-            # ell_dispatch (not plain aot_call): the fused solve bakes
-            # the uniform-block relax impl into its trace, so the tag
-            # must re-key when a kernel is armed for this (n, k)
-            packed, d, src_new, w_new, ch_count, out = ell_dispatch(
+            packed, d, src_new, w_new, ch_count, out = aot_call(
                 "world_dispatch", world_dispatch,
                 (
                     bucket.src_dev, bucket.w_dev, bucket.ov_dev,
@@ -1286,7 +1283,6 @@ class WorldManager(ResidentEngineContract):
                     bucket.packed_dev,
                 ),
                 dict(cap=cap),
-                shape=(n, k),
             )
         bucket.src_dev = src_new
         bucket.w_dev = w_new

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from openr_tpu.utils.jax_compat import shard_map
+from jax import shard_map
 
 from openr_tpu.ops.spf import INF, _mask_transit_rows, _minplus
 

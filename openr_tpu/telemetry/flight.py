@@ -241,7 +241,10 @@ class FlightRecorder:
         self._frozen = False
         self._seq = 0
         self._dumps = 0
-        self._last_dump_t = 0.0
+        # monotonic time of the last dump; -inf so the first one is never
+        # rate-limited (time.monotonic() counts from boot, and a freshly
+        # booted machine is younger than min_dump_interval_s)
+        self._last_dump_t = float("-inf")
         self._triggers: List[Trigger] = []
         self._pending: Optional[tuple] = None
         # -- event journal: pub/mark ring + rolling base LSDB ---------

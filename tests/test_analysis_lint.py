@@ -1238,7 +1238,7 @@ def test_sharding_declared_jit_is_clean(tmp_path):
 
 def test_sharding_shard_map_body_counts_as_declared(tmp_path):
     report = lint_ops(tmp_path, SHARDING_PREAMBLE + """
-    from openr_tpu.utils.jax_compat import shard_map
+    from jax import shard_map
 
     @functools.partial(jax.jit, static_argnames=("mesh",))
     def sharded_step(dr, mesh):
@@ -1393,28 +1393,6 @@ def test_flight_callback_decorator_is_runtime_inert(tmp_path):
 
     assert cb(2) == 3
     assert getattr(cb, FLIGHT_CALLBACK_ATTR)
-
-
-def test_sharding_sees_through_ell_dispatch(tmp_path):
-    """The impl-aware ELL wrapper (spf_sparse.ell_dispatch) has the
-    same positional layout as aot_call — tag, fn, dyn tuple, statics —
-    and re-keys the tag before delegating; the unwrapper must see the
-    resident flow through it exactly as through a bare aot_call."""
-    report = lint_ops(tmp_path, SHARDING_PREAMBLE + """
-    from openr_tpu.ops.spf_sparse import ell_dispatch
-
-    @jax.jit
-    def step(dr, x):
-        return dr + x
-
-    @resident_buffers("_dr")
-    class Engine:
-        def churn(self, x):
-            return ell_dispatch("tag", step, (self._dr, x), dict(n=4))
-    """)
-    hits = rule_hits(report, "sharding-spec")
-    assert len(hits) == 1
-    assert "_dr" in hits[0].message
 
 
 # ---------------------------------------------------------------------

@@ -103,22 +103,3 @@ class TestKsp2ChurnLeg:
         assert out["incremental_syncs"] == 0  # no engine in play
         assert out["sp_route_reuses_per_event"] > 50
         assert out["median_ms"] > 0
-
-
-class TestEllKernelLeg:
-    def test_ell_kernel_bench_smoke(self):
-        """The official bench's sliced-ELL kernel leg (bench.py
-        OPENR_BENCH_ELLKERN): both impls measured on the real band
-        structure, bit-identity oracle gate green, and on CPU the
-        winner is NOT recorded into the autotuner (interpret-mode
-        timings are a correctness witness, not a speed claim)."""
-        from benchmarks.bench_scale import ell_kernel_bench
-
-        out = ell_kernel_bench(100, sources=32)
-        assert out["bench"] == "ell_kernel"
-        assert out["oracle_parity"] is True
-        assert isinstance(out["device_ms"].get("jnp"), float)
-        assert isinstance(out["device_ms"].get("pallas"), float)
-        assert out["winner"] in ("jnp", "pallas")
-        assert out["vmem_bytes"] > 0
-        assert out["winner_recorded"] is False  # CPU leg never records

@@ -1,0 +1,116 @@
+"""``chip_smoke.py`` on the CPU: the legs at tiny sizes, and the rules
+by which the script fails. What only the chip can show — that the same
+legs run there at 1008 and 10k nodes — is the script's own job."""
+
+import json
+import os
+import sys
+
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import chip_smoke  # noqa: E402
+from openr_tpu.decision import spf_solver  # noqa: E402
+from openr_tpu.telemetry import get_registry  # noqa: E402
+
+DEVICE = {"platform": "cpu", "kind": "cpu", "count": 8}
+
+
+@pytest.fixture
+def artefacts(tmp_path, monkeypatch):
+    monkeypatch.setattr(chip_smoke, "ARTEFACT_DIR", str(tmp_path / "out"))
+    return tmp_path / "out"
+
+
+def test_refuses_to_run_without_a_tpu(capsys):
+    assert chip_smoke.main() == 2
+    out, err = capsys.readouterr()
+    assert out == ""  # no result of any kind on stdout
+    assert "no TPU" in err
+
+
+def test_every_leg_passes_at_tiny_sizes(artefacts, monkeypatch, capsys):
+    def sparse_pipeline():
+        # 64 nodes on the resident sliced-ELL formulation: the path the
+        # 10k leg takes on the chip
+        with monkeypatch.context() as patch:
+            patch.setattr(spf_solver, "SPARSE_NODE_THRESHOLD", 32)
+            return chip_smoke.leg_pipeline(64, events=10, rate=20)
+
+    legs = [
+        ("pipeline_dense",
+         lambda: chip_smoke.leg_pipeline(64, events=6, rate=20)),
+        ("pipeline_sparse", sparse_pipeline),
+        ("ksp2", lambda: chip_smoke.leg_ksp2(64, events=2)),
+        ("serve", lambda: chip_smoke.leg_serve(
+            tenant_sizes=(("grid", 4), ("mesh", 20)), rounds=3,
+        )),
+        ("kernels", lambda: chip_smoke.leg_kernels(
+            interpret=True, dense_nodes=64, grouped_nodes=120,
+            grouped_batch=8,
+        )),
+        ("mesh4", lambda: chip_smoke.leg_mesh4(
+            nodes_ksp2=64, nodes_engine=64, events=1,
+        )),
+    ]
+    rc = chip_smoke.run(legs, DEVICE, {"versions": chip_smoke._versions()})
+    out, err = capsys.readouterr()
+    assert rc == 0, err
+    lines = out.strip().splitlines()
+    # the last line is the verdict, with exactly these keys
+    assert json.loads(lines[-1]) == {"ok": True, "device": DEVICE}
+    assert lines[-2].startswith("summary: ")
+    summary = json.loads(lines[-2][len("summary: "):])
+    assert summary["ok"] is True
+    assert summary["device"] == DEVICE
+    assert list(summary)[-1] == "claim" and summary["claim"] is None
+    assert set(summary["legs"]) == {name for name, _ in legs}
+    assert not any(summary["fallback_counters"].values())
+    assert all(summary["mechanism_counters"].values())
+    for leg in ("pipeline_dense", "pipeline_sparse", "ksp2", "serve"):
+        assert summary["legs"][leg]["parity"] is True
+    kernels = summary["legs"]["kernels"]["kernels"]
+    assert len(kernels) == 3
+    assert all(k["lowered"] and k["bit_identical"] for k in kernels.values())
+    assert summary["legs"]["mesh4"]["shard_devices"] == [0, 1, 2, 3]
+    assert summary["compile"]["compiles"] >= 0
+    with open(artefacts / "chip_smoke.json") as f:
+        assert json.load(f) == summary
+
+
+def test_a_raising_leg_is_not_caught(artefacts):
+    def boom():
+        raise chip_smoke.SmokeFailure("parity miss")
+
+    with pytest.raises(chip_smoke.SmokeFailure):
+        chip_smoke.run([("boom", boom)], DEVICE, {})
+    assert not (artefacts / "chip_smoke.json").exists()
+
+
+def test_a_fallback_counter_fails_the_run(artefacts, capsys):
+    reg = get_registry()
+    prev = reg.counter_get("ops.aot_fallbacks")
+
+    def quiet_retry():
+        reg.counter_bump("ops.aot_fallbacks")
+        return {}
+
+    try:
+        assert chip_smoke.run([("leg", quiet_retry)], DEVICE, {}) == 1
+    finally:
+        # the registry is process-wide and other tests assert on it
+        reg.counter_set("ops.aot_fallbacks", prev)
+    out, err = capsys.readouterr()
+    assert "ops.aot_fallbacks = 1" in err
+    # no result line: only the progress lines reach stdout
+    assert not out.strip().splitlines()[-1].startswith("{")
+    with open(artefacts / "chip_smoke.json") as f:
+        assert json.load(f)["ok"] is False
+
+
+def test_a_mechanism_that_never_ran_fails_the_run(artefacts, capsys):
+    assert chip_smoke.run([("noop", dict)], DEVICE, {}) == 1
+    _out, err = capsys.readouterr()
+    assert "decision.ell_warm_solves never ran" in err
+    assert "tenancy.wave_joins never ran" in err

@@ -392,7 +392,7 @@ class TestKsp2View:
 
 class TestKsp2CommittedChain:
     def test_ksp2_window_accounting(self, monkeypatch):
-        """Satellite: the KSP2 relay round trip rides the committed
+        """Satellite: the KSP2 device round trip rides the committed
         chain — each sync() runs inside the ksp2_window accounting
         window (one histogram observation per event) and warm syncs
         hit the AOT executable cache instead of re-deriving jit
@@ -414,7 +414,7 @@ class TestKsp2CommittedChain:
         c0 = h.count
         hits0 = reg.counter_get("ops.aot_hits")
         # same churn shape again: one window observation, zero new
-        # executables — the relay round trip rides the committed cache
+        # executables — the device round trip rides the committed cache
         _mutate_metric(ls, names[1], 0, 4)
         affected = eng.sync(ls, dsts)
         assert affected is not None  # warm incremental path ran

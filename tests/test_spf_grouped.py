@@ -169,7 +169,7 @@ class TestGroupedRouteSweep:
         graph = sg.compile_out_grouped(ls)
         sweeper = sg.GroupedRouteSweeper(graph, [names[0]])
         jnp_result = sweeper.sweep(block=16)
-        sg.set_grouped_impl(impl)
+        sg.set_grouped_impl(impl, interpret=True)
         try:
             pallas_result = sweeper.sweep(block=16)
         finally:
@@ -192,7 +192,7 @@ class TestGroupedRouteSweep:
             pods=2, ssw_per_plane=2, fsw_per_pod=2, rsw_per_pod=3
         )
         ls = load(topo)
-        sg.set_grouped_impl(impl)
+        sg.set_grouped_impl(impl, interpret=True)
         try:
             assert_forward_parity(ls)
         finally:

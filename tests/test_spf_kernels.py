@@ -361,12 +361,83 @@ class TestPallasMinplus:
         w = jnp.asarray(snap.metric)
         ov = jnp.asarray(snap.overloaded)
         d_jnp = np.asarray(spf_ops.all_pairs_distances(w, ov))
-        assert spf_ops.get_minplus_impl() == "jnp"
-        # pallas path on CPU runs via interpret-incapable lowering; only
-        # assert the dispatch plumbing stays consistent
-        spf_ops.set_minplus_impl("jnp")
-        d_again = np.asarray(spf_ops.all_pairs_distances(w, ov))
-        np.testing.assert_array_equal(d_jnp, d_again)
+        assert spf_ops.get_minplus_impl() == spf_ops.JNP
+        # the selector carries interpret explicitly: nothing infers it
+        # from the platform, so the CPU run says interpret=True
+        spf_ops.set_minplus_impl("pallas", interpret=True)
+        try:
+            d_pallas = np.asarray(spf_ops.all_pairs_distances(w, ov))
+        finally:
+            spf_ops.set_minplus_impl("jnp")
+        np.testing.assert_array_equal(d_jnp, d_pallas)
+
+
+class TestAutotuner:
+    """Measured kernel selection: in-process only, family-checked, and
+    never quiet about a candidate that raises."""
+
+    def test_auto_resolves_to_measured_winner_with_interpret(self):
+        from openr_tpu.ops import autotune
+        from openr_tpu.ops import spf as spf_ops
+
+        calls = []
+
+        def measure(thunk, reps=3):
+            thunk()  # both candidates must actually run (interpreted)
+            calls.append(1)
+            return float(len(calls))  # first candidate (jnp) is fastest
+
+        prev = autotune.get_autotuner()
+        autotune.set_autotuner(autotune.Autotuner(measure=measure))
+        try:
+            got = autotune.resolve_minplus((8, 128), interpret=True)
+            assert got == spf_ops.KernelImpl("jnp", True)
+            assert len(calls) == 2
+            # memoized for the life of the process: no re-measure
+            autotune.resolve_minplus((8, 128), interpret=True)
+            assert len(calls) == 2
+        finally:
+            autotune.set_autotuner(prev)
+
+    def test_record_rejects_out_of_family_winner(self):
+        from openr_tpu.ops import autotune
+
+        t = autotune.Autotuner()
+        t.record("minplus", "8x128", "pallas")
+        assert t.pick("minplus", "8x128", {"jnp": None, "pallas": None}) \
+            == "pallas"
+        with pytest.raises(AssertionError):
+            t.record("minplus", "8x128", "pallas_t")
+        with pytest.raises(AssertionError):
+            t.record("ell_relax", "128x4", "pallas")
+
+    def test_disqualified_candidate_is_counted_and_logged(self, caplog):
+        from openr_tpu.ops import autotune
+        from openr_tpu.telemetry import get_registry
+
+        def boom():
+            raise NotImplementedError("Only 2D gather is supported")
+
+        reg = get_registry()
+        d0 = reg.counter_get("ops.autotune_disqualified")
+        t = autotune.Autotuner(measure=lambda thunk, reps=3: thunk() or 1.0)
+        with caplog.at_level("ERROR", logger="openr_tpu.ops.autotune"):
+            winner = t.pick(
+                "minplus", "8x256", {"jnp": lambda: None, "pallas": boom}
+            )
+        assert winner == "jnp"
+        assert reg.counter_get("ops.autotune_disqualified") == d0 + 1
+        assert "Only 2D gather is supported" in caplog.text
+
+    def test_every_candidate_failing_raises(self):
+        from openr_tpu.ops import autotune
+
+        def boom():
+            raise NotImplementedError("no lowering")
+
+        t = autotune.Autotuner(measure=lambda thunk, reps=3: thunk() or 1.0)
+        with pytest.raises(RuntimeError, match="every minplus candidate"):
+            t.pick("minplus", "8x512", {"jnp": boom, "pallas": boom})
 
 
 class TestPallasGroupedTiling:

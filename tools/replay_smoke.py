@@ -143,6 +143,10 @@ def main(argv=None) -> int:
     # -- gates 3+4: fresh-process deterministic reproduction ---------------
     verdict = None
     if bundle_path:
+        # one process per chip: this parent has touched jax, so a child
+        # that needed the accelerator would fail or hang. The child is
+        # forced onto the CPU backend — which is all this gate (itself
+        # CPU-pinned) checks: the replay is deterministic, not fast
         proc = subprocess.run(
             [sys.executable, "-m", "openr_tpu.twin.replay",
              bundle_path, "--json", "--twice"],
