@@ -54,6 +54,7 @@ from openr_tpu.telemetry import (
     get_registry,
     get_tracer,
     install_default_triggers,
+    install_gc_hook,
 )
 from openr_tpu.utils import keys as keyutil
 from openr_tpu.utils import wire
@@ -248,6 +249,9 @@ class Decision:
         # compile-after-warmup, reshard delta): always-on from the
         # moment a pipeline exists, idempotent across instances
         install_default_triggers()
+        # full garbage collections are what reaches convergence's tail
+        # on a large LSDB: counted process-wide, installed once
+        install_gc_hook()
         # monotonic stamp of the last route db installed while the
         # ladder was fully warm and no engine sat in integrity
         # quarantine — the staleness gauge ages from it while degraded
@@ -348,6 +352,10 @@ class Decision:
     # -- queue handlers (run on the module thread) ------------------------
 
     def _on_publication(self, pub: Publication) -> None:
+        if pub.trace is not None:
+            # kvstore.publish -> here: the ReplicateQueue hop and this
+            # thread's wake-up, closed on arrival
+            pub.trace.gap_span("decision.queue_wait")
         if self._admission is not None:
             # admission path: observe backlog depth (adapting the
             # debounce ceiling) and, under a deep backlog, drain +
@@ -385,7 +393,9 @@ class Decision:
             if self._admission is None or self._admission.allow_prewarm(
                 self._kv_reader.size()
             ):
-                self.spf_solver.prewarm(self.area_link_states)
+                self.spf_solver.prewarm(
+                    self.area_link_states, trace=self.pending.trace
+                )
             self._rebuild_debounced()
             # debounce-terminal speculation: once the window's backoff
             # saturates, further publications can only JOIN the window,
@@ -768,9 +778,8 @@ class Decision:
         # trace span; pending is NOT reset on that path, so the next
         # publication retriggers the rebuild.
         payload = None
-        win = None
         try:
-            with da.event_window("decision.rebuild") as win:
+            with da.event_window("decision.rebuild"):
                 payload = self.supervisor.run(
                     (
                     (
@@ -804,14 +813,6 @@ class Decision:
                 "decision.rebuild_ms",
                 (time.perf_counter() - t_rebuild0) * 1000.0,
             )
-            if rebuild_span is not None and win is not None:
-                # the committed-dispatch discipline, visible per
-                # rebuild: 2 touches = one submit run + one reap run
-                rebuild_span.attrs.update(
-                    host_touches=win.touches,
-                    host_dispatches=win.dispatches,
-                    blocking_syncs=win.blocking_syncs,
-                )
             if trace is not None:
                 tracer.deactivate()
                 if payload is None:
@@ -851,12 +852,19 @@ class Decision:
         installed one, apply, and publish. In pipelined mode this runs
         on the single-worker emit executor (which then exclusively owns
         route_db); in eager mode it runs inline on the module thread."""
+        tracer = get_tracer()
         kind, value = payload
         if kind == "db":
             # the diff runs HERE, not in the solve rung: route_db is
             # mutated by this stage, so reading it from the (possibly
             # concurrent) solve would race in pipelined mode
-            update = self.route_db.calculate_update(value)
+            with tracer.span("decision.route_diff", trace=trace) as span:
+                update = self.route_db.calculate_update(value)
+                if span is not None:
+                    span.attrs.update(
+                        updated=len(update.unicast_routes_to_update),
+                        deleted=len(update.unicast_routes_to_delete),
+                    )
         else:
             update = value
         if trace is not None:
@@ -865,15 +873,18 @@ class Decision:
                 routes_updated=len(update.unicast_routes_to_update),
                 routes_deleted=len(update.unicast_routes_to_delete),
             )
-        self.route_db.update(update)
-        if (
-            self.supervisor.state is HealthState.HEALTHY
-            and not quarantine_active()
-        ):
-            with self._emit_mu:
-                self._last_good_route_ts = time.monotonic()
-        update.perf_events = perf_events
-        update.trace = trace
+        # closed BEFORE the push: once the update is on the queue the
+        # trace is Fib's, which records the hop as fib.queue_wait
+        with tracer.span("decision.emit", trace=trace):
+            self.route_db.update(update)
+            if (
+                self.supervisor.state is HealthState.HEALTHY
+                and not quarantine_active()
+            ):
+                with self._emit_mu:
+                    self._last_good_route_ts = time.monotonic()
+            update.perf_events = perf_events
+            update.trace = trace
         self.route_updates_queue.push(update)
 
     @fault_boundary
@@ -899,19 +910,42 @@ class Decision:
             self.spf_solver.reset_device_state()
         if flipped:
             self.spf_solver.set_backend(backend)
-        update = DecisionRouteUpdate()
-        if full or reset or flipped:
-            new_db = (
-                self.spf_solver.build_route_db(
-                    self.my_node_name, self.area_link_states, self.prefix_state
+        full_build = full or reset or flipped
+        prefixes = (
+            self.prefix_state.prefixes()
+            if full_build
+            else self.pending.updated_prefixes
+        )
+        # everything this rung does to turn LSDB into routes; a view
+        # built on a cache miss nests its own spans inside, so this
+        # span's self time is route materialisation
+        with get_tracer().span(
+            "decision.route_build",
+            full=full_build,
+            prefixes=len(prefixes),
+            rung=(
+                "warm" if not reset
+                else "cold" if backend == self._primary_backend
+                else "host"
+            ),
+        ):
+            if full_build:
+                new_db = (
+                    self.spf_solver.build_route_db(
+                        self.my_node_name,
+                        self.area_link_states,
+                        self.prefix_state,
+                    )
+                    or DecisionRouteDb()
                 )
-                or DecisionRouteDb()
-            )
-            if self.rib_policy is not None and self.rib_policy.is_active():
-                self.rib_policy.apply_policy(new_db.unicast_routes)
-            return ("db", new_db)
-        else:
-            for prefix in self.pending.updated_prefixes:
+                if (
+                    self.rib_policy is not None
+                    and self.rib_policy.is_active()
+                ):
+                    self.rib_policy.apply_policy(new_db.unicast_routes)
+                return ("db", new_db)
+            update = DecisionRouteUpdate()
+            for prefix in prefixes:
                 entry = self.spf_solver.create_route_for_prefix(
                     self.my_node_name,
                     self.area_link_states,
@@ -927,7 +961,7 @@ class Decision:
                     update.unicast_routes_to_update
                 )
                 update.unicast_routes_to_delete.extend(change.deleted_routes)
-        return ("delta", update)
+            return ("delta", update)
 
     # -- public (thread-safe) APIs ---------------------------------------
 

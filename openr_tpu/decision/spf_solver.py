@@ -170,6 +170,7 @@ SPARSE_NODE_THRESHOLD = 4096
 # so OpenrCtrl.get_counters / breeze / bench artifacts see these names
 # without a per-module merge loop.
 from openr_tpu.telemetry import get_registry as _get_registry
+from openr_tpu.telemetry import get_tracer as _get_tracer
 
 SPF_COUNTERS = _get_registry().counter_dict(
     [
@@ -189,6 +190,9 @@ SPF_COUNTERS = _get_registry().counter_dict(
         "decision.ksp2_route_reuses",
         "decision.sp_route_reuses",
         "decision.ell_prewarms",
+        # a view's solve program was dispatched to the device (dense
+        # spf_view_batch or ELL reconverge; a preloaded view is not one)
+        "decision.device_solves",
         "decision.device_state_resets",
         "decision.backend_switches",
         # multi-area batched dispatch (ops.world_batch): builds whose
@@ -362,20 +366,34 @@ class SpfView:
         Readback is O(B x N), not O(N^2)."""
         from openr_tpu.ops import spf as spf_ops
 
-        self._snap: GraphSnapshot = _SNAPSHOTS.get(self._ls)
-        sid = self._snap.id_of(self._root)
-        self._sid = sid
+        tracer = _get_tracer()
         self._d_all = None
         self._fh = None
-        if sid is None:
-            return
-        srcs, srcs_dev = spf_ops.source_batch(self._snap, sid)
-        dev = self._snap.device_arrays()
-        packed = spf_ops.spf_view_batch_packed(
-            dev.metric, dev.overloaded, srcs_dev
-        )
-        packed_host = np.asarray(packed)  # one device->host transfer
+        # LinkState -> device-resident arrays, host side (the row
+        # patch's jit launch included)
+        with tracer.span("graph.view_sync", formulation="dense") as span:
+            self._snap: GraphSnapshot = _SNAPSHOTS.get(self._ls)
+            sid = self._snap.id_of(self._root)
+            self._sid = sid
+            if sid is None:
+                return
+            if span is not None:
+                span.attrs["rows"] = self._snap.rows_to_upload()
+            srcs, srcs_dev = spf_ops.source_batch(self._snap, sid)
+            dev = self._snap.device_arrays()
         bucket = srcs_dev.shape[0]
+        SPF_COUNTERS["decision.device_solves"] += 1
+        # the dispatch returns before the device is done ...
+        with tracer.span(
+            "ops.spf_view_batch", batch=bucket, n_pad=self._snap.n_pad
+        ):
+            packed = spf_ops.spf_view_batch_packed(
+                dev.metric, dev.overloaded, srcs_dev
+            )
+        # ... and the host waits for the rest of it here: one
+        # device->host transfer
+        with tracer.span("ops.solve_readback", bytes=packed.nbytes):
+            packed_host = np.asarray(packed)
         self._d = packed_host[:bucket]
         self._fh_batch = packed_host[bucket:].astype(bool)
         self._batch_srcs = srcs  # row i of _d is distances from srcs[i]
@@ -675,10 +693,23 @@ class _EllResidentCache:
             ):
                 del self._preloaded[i]
                 return graph, srcs, packed
-        state, pending = self._sync(ls)
-        graph = pending if pending is not None else state.graph
-        srcs = spf_sparse.ell_source_batch(graph, ls, root)
-        packed = np.asarray(state.reconverge(graph, srcs))
+        tracer = _get_tracer()
+        with tracer.span("graph.view_sync", formulation="ell") as span:
+            state, pending = self._sync(ls)
+            graph = pending if pending is not None else state.graph
+            srcs = spf_sparse.ell_source_batch(graph, ls, root)
+            if span is not None:
+                span.attrs["rows"] = (
+                    sum(len(r) for r in (pending.changed or {}).values())
+                    if pending is not None
+                    else 0
+                )
+        SPF_COUNTERS["decision.device_solves"] += 1
+        packed_dev = state.reconverge(graph, srcs)
+        # reconverge's own span (ops.ell_reconverge) ends when the
+        # dispatch returns; the host waits for the device here
+        with tracer.span("ops.solve_readback", bytes=packed_dev.nbytes):
+            packed = np.asarray(packed_dev)
         self._cache[ls] = (ls.topology_version, state)
         return state.graph, srcs, packed
 
@@ -926,7 +957,9 @@ class SpfSolver:
 
     # -- SPF views --------------------------------------------------------
 
-    def prewarm(self, area_link_states: AreaLinkStates) -> None:
+    def prewarm(
+        self, area_link_states: AreaLinkStates, trace=None
+    ) -> None:
         """Publication-time overlap hook (called by the decision module
         as publications land, BEFORE the debounced rebuild fires): push
         pending topology deltas into the device-resident ELL bands now,
@@ -943,7 +976,12 @@ class SpfSolver:
         spf_sparse.EllState._note_patch), so N prewarmed publications
         inside one debounce window still leave the debounced rebuild on
         the warm-solve path — burst churn pays one fused dispatch, not
-        a forced cold seed."""
+        a forced cold seed.
+
+        ``trace`` is the debounce window's: the patch runs on the
+        caller's thread before the timer is armed, so it is time inside
+        ``decision.debounce`` that is work, not policy, and gets a span
+        of its own there (none when there is nothing to patch)."""
         if self.backend != "device":
             return
         for ls in area_link_states.values():
@@ -951,7 +989,12 @@ class SpfSolver:
                 entry = _ELL_RESIDENT._cache.get(ls)
                 if entry is None or entry[0] == ls.topology_version:
                     continue
-                _ELL_RESIDENT.state_for(ls)
+                with _get_tracer().span(
+                    "decision.prewarm",
+                    trace=trace,
+                    rows=len(ls.affected_since(entry[0]) or ()),
+                ):
+                    _ELL_RESIDENT.state_for(ls)
                 SPF_COUNTERS["decision.ell_prewarms"] += 1
             except Exception:
                 continue

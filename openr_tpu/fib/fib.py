@@ -192,9 +192,12 @@ class Fib:
         """reference: Fib.cpp:316 processRouteUpdates."""
         t0 = time.perf_counter()
         trace = getattr(update, "trace", None)
-        program_span = (
-            trace.begin_span("fib.program") if trace is not None else None
-        )
+        program_span = None
+        if trace is not None:
+            # decision.emit's end -> here: the route-updates queue hop
+            # and this thread's wake-up, closed on arrival
+            trace.gap_span("fib.queue_wait")
+            program_span = trace.begin_span("fib.program")
         if update.perf_events is not None:
             update.perf_events.add(self.my_node_name, "FIB_ROUTE_DB_RECVD")
             self.perf_db.append(update.perf_events)

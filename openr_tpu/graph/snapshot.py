@@ -145,6 +145,21 @@ class GraphSnapshot:
         self._parent = None
         return rows, self.metric[rows, :]
 
+    def rows_to_upload(self) -> int:
+        """Metric rows the next ``device_arrays()`` sends to the device:
+        none once resident, the changed rows of an unrealized patch,
+        else the whole matrix."""
+        if self._dev is not None:
+            return 0
+        parent = self._parent
+        if (
+            parent is not None
+            and parent._dev is not None
+            and self._changed_rows is not None
+        ):
+            return len(self._changed_rows)
+        return self.n_pad
+
     def device_arrays(self):
         """(metric, hop, overloaded) as device arrays. Patched snapshots
         update their parent's resident arrays with a row scatter. The hop

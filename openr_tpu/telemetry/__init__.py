@@ -18,6 +18,8 @@ Three pieces, one export surface:
 - ``profiler.py``: always-on device-time attribution — measured
   ``ops.device_ms.<tag>`` / ``ops.host_ms.<tag>`` per dispatch tag and
   a live ``ops.host_overhead_ratio`` gauge.
+- ``gc_pauses.py``: generation-2 garbage collections as the counters
+  ``process.gc_gen2_collections`` / ``process.gc_gen2_pause_ms``.
 - ``flight.py``: the flight recorder — a lock-cheap activity ring that
   survives trace-ring overflow, with anomaly triggers that freeze it
   and dump post-mortem bundles.
@@ -35,6 +37,7 @@ from openr_tpu.telemetry.trace import (  # noqa: F401
     Tracer,
     get_tracer,
 )
+from openr_tpu.telemetry.gc_pauses import install_gc_hook  # noqa: F401
 from openr_tpu.telemetry.profiler import (  # noqa: F401
     Profiler,
     get_profiler,
@@ -72,6 +75,7 @@ __all__ = [
     "get_registry",
     "get_tracer",
     "install_default_triggers",
+    "install_gc_hook",
     "load_bundle",
     "reset_flight_recorder",
     "reset_profiler",

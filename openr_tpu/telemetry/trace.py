@@ -17,6 +17,17 @@ Design points:
   plumbing: the tracer keeps a per-thread *active trace* stack
   (``activate()``), and ``span_active()`` attaches to whatever trace
   the enclosing module activated — a no-op when none is.
+- A stage that opens and closes on one thread takes the scoped form,
+  ``with tracer.span(name):``. While a ``jax.profiler`` session is
+  collecting, such a span is also a ``TraceAnnotation`` on the
+  session's host plane, on the profiler's own clock, beside the XLA
+  launches it caused. The non-lexical stages (``decision.debounce``,
+  ``decision.rebuild``, ``fib.program``: opened in one function and
+  closed in another, or on another thread) keep ``begin_span`` /
+  ``end_span`` and carry no annotation.
+- A hand-off between threads is never an open span: the receiving
+  side records it, closed, with ``Trace.gap_span()`` (from the end of
+  the trace's last span to now).
 - ``finish()`` validates that every span is closed and properly
   nested; violations bump ``telemetry.traces_unclosed_spans`` /
   ``telemetry.traces_bad_nesting`` instead of raising, and the trace
@@ -27,13 +38,15 @@ Design points:
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, ContextManager, Dict, List, Optional, Tuple
 
 from openr_tpu.analysis.annotations import thread_confined
 from openr_tpu.telemetry.registry import get_registry
@@ -47,10 +60,18 @@ class Span:
 
     __slots__ = ("name", "ts_ms", "dur_ms", "attrs", "_t0", "depth")
 
-    def __init__(self, name: str, depth: int = 0) -> None:
+    def __init__(
+        self,
+        name: str,
+        depth: int = 0,
+        start: Optional[Tuple[float, float]] = None,
+    ) -> None:
+        """``start`` is a ``(ts_ms, perf_counter)`` pair read earlier
+        (``end_mark``); without it the span starts now."""
         self.name = name
-        self.ts_ms = time.time() * 1000.0
-        self._t0 = time.perf_counter()
+        if start is None:
+            start = (time.time() * 1000.0, time.perf_counter())
+        self.ts_ms, self._t0 = start
         self.dur_ms: Optional[float] = None
         self.attrs: Dict[str, Any] = {}
         self.depth = depth
@@ -65,6 +86,13 @@ class Span:
         self.attrs.update(attrs)
         return self
 
+    def end_mark(self) -> Tuple[float, float]:
+        """Where this (closed) span ended, on both clocks."""
+        return (
+            self.ts_ms + self.dur_ms,
+            self._t0 + self.dur_ms / 1000.0,
+        )
+
     def to_dict(self) -> Dict[str, Any]:
         return {
             "name": self.name,
@@ -75,7 +103,7 @@ class Span:
         }
 
 
-@thread_confined("owner", "spans", "_stack", "complete")
+@thread_confined("owner", "spans", "_stack", "_last_end", "complete")
 class Trace:
     """An ordered list of spans sharing one trace id. Not thread-safe
     by itself — a trace is owned by exactly one module thread at a
@@ -83,7 +111,10 @@ class Trace:
     ``"owner"`` confinement above states exactly that hand-off
     discipline for the shared-state rule."""
 
-    __slots__ = ("trace_id", "origin", "ts_ms", "spans", "_stack", "complete")
+    __slots__ = (
+        "trace_id", "origin", "ts_ms", "spans", "_stack", "_last_end",
+        "complete",
+    )
 
     def __init__(self, origin: str = "kvstore.publish") -> None:
         self.trace_id = next(_trace_ids)
@@ -91,6 +122,8 @@ class Trace:
         self.ts_ms = time.time() * 1000.0
         self.spans: List[Span] = []
         self._stack: List[Span] = []
+        # where the span that closed last ended (Span.end_mark)
+        self._last_end: Optional[Tuple[float, float]] = None
         self.complete = False
 
     def begin_span(self, name: str, **attrs: Any) -> Span:
@@ -102,6 +135,7 @@ class Trace:
 
     def end_span(self, span: Span, **attrs: Any) -> Span:
         span.end(**attrs)
+        self._last_end = span.end_mark()
         # pop through the stack to this span; anything above it left
         # open is a nesting bug the finish() validator will count
         while self._stack:
@@ -115,8 +149,21 @@ class Trace:
         span = Span(name, depth=len(self._stack))
         span.attrs.update(attrs)
         span.dur_ms = 0.0
+        self._last_end = span.end_mark()
         self.spans.append(span)
         return span
+
+    def gap_span(self, name: str, **attrs: Any) -> Optional[Span]:
+        """A closed span from where the trace's last span closed to
+        now: the wait of a hand-off (a queue hop and the receiving
+        thread's wake-up), recorded by the receiver, so that no span
+        is left open while the trace changes owner. None on a trace
+        in which nothing has closed yet."""
+        if self._last_end is None:
+            return None
+        span = Span(name, depth=len(self._stack), start=self._last_end)
+        self.spans.append(span)
+        return self.end_span(span, **attrs)
 
     @property
     def e2e_ms(self) -> Optional[float]:
@@ -152,6 +199,34 @@ class Trace:
             "complete": self.complete,
             "spans": [s.to_dict() for s in self.spans],
         }
+
+
+class _ScopedSpan:
+    """``Tracer.span``'s context manager: one span, opened and closed
+    on the entering thread, inside a profiler annotation of the same
+    name when the process has a ``jax`` to annotate with."""
+
+    __slots__ = ("_trace", "_name", "_attrs", "_annotation", "_span")
+
+    def __init__(self, trace: Trace, name: str, attrs, annotation) -> None:
+        self._trace = trace
+        self._name = name
+        self._attrs = attrs
+        self._annotation = annotation
+
+    def __enter__(self) -> Span:
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._span = self._trace.begin_span(self._name, **self._attrs)
+        return self._span
+
+    def __exit__(self, *exc_info) -> None:
+        self._trace.end_span(self._span)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc_info)
+
+
+_NO_SPAN = contextlib.nullcontext()
 
 
 class Tracer:
@@ -261,6 +336,33 @@ class Tracer:
         t = self.active()
         if t is not None and span is not None:
             t.end_span(span, **attrs)
+
+    def span(
+        self, name: str, trace: Optional[Trace] = None, **attrs: Any
+    ) -> ContextManager[Optional[Span]]:
+        """``with tracer.span("decision.route_build") as s:`` — a span
+        around the block, on ``trace`` or else on this thread's active
+        trace, closed when the block exits, by exception too. ``s`` is
+        the span (set what is only known afterwards on ``s.attrs``), or
+        None when there is no trace to put it on: the block then runs
+        untraced."""
+        t = trace if trace is not None else self.active()
+        if t is None:
+            return _NO_SPAN
+        return _ScopedSpan(t, name, attrs, self._annotation(name))
+
+    @staticmethod
+    def _annotation(name: str):
+        """A ``jax.profiler.TraceAnnotation`` for a scoped span, from a
+        jax this process has already imported; never imports it, so
+        the JAX-free users of this module (ctrl clients, breeze) stay
+        so. Looked up per span (two dictionary reads) rather than kept:
+        spans open on several threads. Inactive, and near free, unless
+        a profiler session is collecting."""
+        cls = getattr(
+            sys.modules.get("jax.profiler"), "TraceAnnotation", None
+        )
+        return cls(name) if cls is not None else None
 
     # -- export -----------------------------------------------------
     def traces(self, limit: int = 0) -> List[Trace]:

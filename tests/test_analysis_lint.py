@@ -1881,9 +1881,9 @@ def test_seeded_decision_emit_mu_deletion_trips(tmp_path):
         [DECISION_PY],
         "decision.py",
         lambda src: src.replace(
-            "            with self._emit_mu:\n"
+            "                with self._emit_mu:\n"
+            "                    self._last_good_route_ts = time.monotonic()\n",
             "                self._last_good_route_ts = time.monotonic()\n",
-            "            self._last_good_route_ts = time.monotonic()\n",
             1,
         ),
     )

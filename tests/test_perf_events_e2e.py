@@ -9,20 +9,25 @@ reports against:
 - event timestamps are monotonically non-decreasing along the chain,
 - the telemetry trace born at kvstore publication is finished by Fib
   with every span closed (publication -> debounce -> rebuild ->
-  program).
+  program),
+- with the device backend the trace is the whole span tree (queue
+  waits, route build with the view's sync / dispatch / readback inside
+  it, diff, emit), well nested in eager and in pipelined-emit mode.
 """
 
+import dataclasses
 import time
 
 import pytest
 
+from openr_tpu.decision import spf_solver
 from openr_tpu.decision.decision import Decision
 from openr_tpu.fib.fib import Fib
 from openr_tpu.kvstore.wrapper import KvStoreWrapper
 from openr_tpu.messaging.queue import ReplicateQueue
 from openr_tpu.models import topologies
 from openr_tpu.platform.fib_service import MockFibAgent
-from openr_tpu.telemetry import get_tracer
+from openr_tpu.telemetry import get_registry, get_tracer
 from openr_tpu.types import AdjacencyDatabase, PerfEvent, PerfEvents
 from openr_tpu.utils import keys as keyutil
 from openr_tpu.utils import wire
@@ -41,7 +46,9 @@ class PipelineHarness:
     """KvStore -> Decision -> Fib wired through real queues (host
     solver: these tests assert accounting, not kernels)."""
 
-    def __init__(self, my_node="a"):
+    def __init__(
+        self, my_node="a", solver_backend="host", pipelined_emit=False
+    ):
         self.store = KvStoreWrapper(f"store:{my_node}")
         self.route_q = ReplicateQueue(name="routeUpdates")
         self.decision = Decision(
@@ -50,7 +57,8 @@ class PipelineHarness:
             route_updates_queue=self.route_q,
             debounce_min_s=0.05,
             debounce_max_s=0.25,
-            solver_backend="host",
+            solver_backend=solver_backend,
+            pipelined_emit=pipelined_emit,
         )
         self.agent = MockFibAgent()
         self.fib = Fib(
@@ -191,3 +199,175 @@ class TestPerfEventsEndToEnd:
             s for s in t.spans if s.name == "decision.debounce"
         )
         assert debounce.dur_ms >= 40.0  # 50ms debounce, clock slack
+
+
+# the span tree of one adjacency event through the dense device path,
+# in the order the spans open, with their depths
+ADJ_EVENT_TREE = [
+    ("kvstore.publish", 0),
+    ("decision.queue_wait", 0),
+    ("decision.debounce", 0),
+    ("decision.rebuild", 0),
+    ("decision.route_build", 1),
+    ("graph.view_sync", 2),
+    ("ops.spf_view_batch", 2),
+    ("ops.solve_readback", 2),
+    ("decision.route_diff", 1),
+    ("decision.emit", 0),
+    ("fib.queue_wait", 0),
+    ("fib.program", 0),
+]
+# the same event over the resident sliced-ELL bands: the patch runs at
+# publication time inside the debounce span, and the solve is the
+# fused reconverge
+ELL_ADJ_EVENT_TREE = (
+    ADJ_EVENT_TREE[:3]
+    + [("decision.prewarm", 1)]
+    + ADJ_EVENT_TREE[3:6]
+    + [("ops.ell_reconverge", 2)]
+    + ADJ_EVENT_TREE[7:]
+)
+PREFIX_EVENT_TREE = [
+    ("kvstore.publish", 0),
+    ("decision.queue_wait", 0),
+    ("decision.debounce", 0),
+    ("decision.rebuild", 0),
+    ("decision.route_build", 1),
+    ("decision.emit", 0),
+    ("fib.queue_wait", 0),
+    ("fib.program", 0),
+]
+SLACK_MS = 0.05  # two clocks (wall for starts, perf_counter for lengths)
+
+
+def _end(span):
+    return span.ts_ms + span.dur_ms
+
+
+def _assert_tree(trace, expected):
+    assert trace.complete and trace.well_formed(), trace.to_dict()
+    assert [(s.name, s.depth) for s in trace.spans] == expected
+    stack = []  # the open ancestors of the span at hand
+    last_at_depth = {}
+    for span in trace.spans:
+        del stack[span.depth:]
+        if stack:
+            parent = stack[-1]
+            assert parent.ts_ms - SLACK_MS <= span.ts_ms
+            assert _end(span) <= _end(parent) + SLACK_MS, (
+                span.name, parent.name)
+        older = last_at_depth.get(span.depth)
+        if older is not None:
+            # siblings (and successive top-level stages) never overlap
+            assert _end(older) <= span.ts_ms + SLACK_MS, (
+                older.name, span.name)
+        # a deeper span seen earlier belongs to an older parent
+        last_at_depth = {
+            d: s for d, s in last_at_depth.items() if d < span.depth
+        }
+        last_at_depth[span.depth] = span
+        stack.append(span)
+
+
+class TestSpanTreeEndToEnd:
+    @pytest.mark.parametrize(
+        "formulation,pipelined_emit",
+        [("dense", False), ("dense", True), ("ell", False)],
+        ids=["dense-eager", "dense-pipelined_emit", "ell-eager"],
+    )
+    def test_device_pipeline_yields_the_whole_tree(
+        self, formulation, pipelined_emit, monkeypatch
+    ):
+        reg, tracer = get_registry(), get_tracer()
+        if formulation == "ell":
+            monkeypatch.setattr(spf_solver, "SPARSE_NODE_THRESHOLD", 2)
+
+        def counters():
+            return {
+                name: reg.counter_get(name)
+                for name in (
+                    "decision.device_solves",
+                    "telemetry.traces_bad_nesting",
+                    "telemetry.traces_unclosed_spans",
+                    "ops.host_dispatches",
+                )
+            }
+
+        def event_trace(key, n_before):
+            """The finished trace of the publication of ``key``."""
+            def find():
+                return [
+                    t for t in tracer.traces()
+                    if t.trace_id > n_before
+                    and t.spans[0].attrs.get("keys") == [key]
+                ]
+            assert wait_until(lambda: bool(find())), [
+                t.to_dict() for t in tracer.traces()[-3:]]
+            return find()[-1]
+
+        h = PipelineHarness(
+            solver_backend="device", pipelined_emit=pipelined_emit
+        )
+        try:
+            topo = line_topology()
+            for db in topo.adj_dbs.values():
+                h.publish_adj(db)
+            for pdb in topo.prefix_dbs.values():
+                h.publish_prefixes(pdb)
+            assert wait_until(lambda: len(h.fib.unicast_routes) >= 2)
+            time.sleep(0.4)  # the last debounce window of the load
+            before = counters()
+            newest = max(t.trace_id for t in tracer.traces())
+
+            # an adjacency event: a metric change on b's links
+            b = topo.adj_dbs["b"]
+            h.publish_adj(dataclasses.replace(b, adjacencies=tuple(
+                dataclasses.replace(adj, metric=adj.metric + 3)
+                for adj in b.adjacencies
+            )))
+            trace = event_trace("adj:b", newest)
+            by_name = {s.name: s for s in trace.spans}
+            if formulation == "ell":
+                _assert_tree(trace, ELL_ADJ_EVENT_TREE)
+                # prewarm patched b and its two neighbours' rows ahead
+                # of the timer, so the rebuild finds the bands current
+                assert by_name["decision.prewarm"].attrs == {"rows": 3}
+                assert by_name["graph.view_sync"].attrs == {
+                    "formulation": "ell", "rows": 0}
+            else:
+                _assert_tree(trace, ADJ_EVENT_TREE)
+                assert by_name["graph.view_sync"].attrs == {
+                    "formulation": "dense", "rows": 3}
+                assert set(by_name["ops.spf_view_batch"].attrs) == {
+                    "batch", "n_pad"}
+            assert by_name["decision.route_build"].attrs == {
+                "full": True, "prefixes": 3, "rung": "warm"}
+            assert by_name["ops.solve_readback"].attrs["bytes"] > 0
+            assert set(by_name["decision.route_diff"].attrs) == {
+                "updated", "deleted"}
+            # the route engine's accounting is not the rebuild's
+            assert set(by_name["decision.rebuild"].attrs) == {
+                "full_rebuild", "routes_updated", "routes_deleted"}
+            after_adj = counters()
+            assert after_adj["decision.device_solves"] == (
+                before["decision.device_solves"] + 1)
+
+            # a prefix-only event: the per-prefix branch, no view, no
+            # device solve, no full-db diff
+            h.publish_prefixes(dataclasses.replace(
+                topo.prefix_dbs["c"], prefix_entries=()))
+            trace = event_trace("prefix:c", newest)
+            _assert_tree(trace, PREFIX_EVENT_TREE)
+            build = next(
+                s for s in trace.spans if s.name == "decision.route_build")
+            assert build.attrs == {
+                "full": False, "prefixes": 1, "rung": "warm"}
+            after_prefix = counters()
+            assert after_prefix["decision.device_solves"] == (
+                after_adj["decision.device_solves"])
+            for name in ("telemetry.traces_bad_nesting",
+                         "telemetry.traces_unclosed_spans",
+                         "ops.host_dispatches"):
+                assert after_prefix[name] == before[name], name
+        finally:
+            h.stop()

@@ -1,7 +1,12 @@
 """Telemetry spine unit tests: registry, histograms, counter shims,
 tracer span model, monitor satellites, and export-surface parity."""
 
+import gc
+import glob
 import json
+import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -14,8 +19,8 @@ from openr_tpu.telemetry import (
     get_registry,
     get_tracer,
 )
-from openr_tpu.telemetry import jax_hooks
-from openr_tpu.telemetry.trace import Tracer
+from openr_tpu.telemetry import install_gc_hook, jax_hooks
+from openr_tpu.telemetry.trace import Trace, Tracer
 
 
 class TestHistogram:
@@ -211,6 +216,177 @@ class TestTracer:
         assert len(lines) == 2
         parsed = json.loads(lines[-1])
         assert parsed["complete"] and parsed["spans"]
+
+
+class TestScopedSpans:
+    def test_scoped_span_nests_on_the_active_or_an_explicit_trace(self):
+        tracer = Tracer()
+        t = tracer.start("kvstore.publish")
+        outer = t.begin_span("decision.rebuild")
+        tracer.activate(t)
+        with tracer.span("decision.route_build", full=True) as build:
+            with tracer.span("graph.view_sync", formulation="dense") as sync:
+                sync.attrs["rows"] = 3
+            deep = tracer.span_active("ops.ell_reconverge")
+            tracer.end_span_active(deep)
+        tracer.deactivate()
+        # no active trace on this thread any more: an explicit one
+        with tracer.span("decision.route_diff", trace=t) as diff:
+            pass
+        t.end_span(outer)
+        tracer.finish(t)
+        assert t.complete and t.well_formed()
+        assert [(s.name, s.depth) for s in t.spans] == [
+            ("kvstore.publish", 0),
+            ("decision.rebuild", 0),
+            ("decision.route_build", 1),
+            ("graph.view_sync", 2),
+            ("ops.ell_reconverge", 2),
+            ("decision.route_diff", 1),
+        ]
+        assert build.attrs == {"full": True}
+        assert sync.attrs == {"formulation": "dense", "rows": 3}
+        assert all(s.closed for s in (build, sync, diff))
+        assert sync.dur_ms <= build.dur_ms <= outer.dur_ms
+
+    def test_scoped_span_closes_on_exception(self):
+        tracer = Tracer()
+        t = tracer.start()
+        with pytest.raises(RuntimeError):
+            with tracer.span("decision.route_build", trace=t) as span:
+                with tracer.span("ops.solve_readback", trace=t) as inner:
+                    raise RuntimeError("device fell over")
+        assert span.closed and inner.closed and not t._stack
+        tracer.finish(t)
+        assert t.complete and t.well_formed()
+
+    def test_scoped_span_is_a_noop_without_a_trace(self):
+        tracer = Tracer()
+        ran = []
+        with tracer.span("decision.route_build", full=True) as span:
+            ran.append(span)
+        assert ran == [None]
+        # and from a thread other than the one that activated a trace
+        t = tracer.start()
+        tracer.activate(t)
+        seen = []
+
+        def probe():
+            with tracer.span("graph.view_sync") as s:
+                seen.append(s)
+
+        th = threading.Thread(target=probe)
+        th.start()
+        th.join(timeout=10)
+        tracer.deactivate()
+        assert seen == [None]
+        assert [s.name for s in t.spans] == ["kvstore.publish"]
+
+    def test_gap_span_runs_from_the_last_close_to_now(self):
+        tracer = Tracer()
+        t = tracer.start("kvstore.publish")
+        time.sleep(0.003)
+        wait = t.gap_span("decision.queue_wait")
+        publish = t.spans[0]
+        assert wait.closed and wait.depth == 0 and wait.dur_ms >= 3.0
+        assert wait.ts_ms == publish.ts_ms  # an instant ends where it starts
+        work = t.begin_span("decision.emit")
+        time.sleep(0.002)
+        t.end_span(work)
+        handed_off = time.perf_counter()
+        time.sleep(0.003)
+        hop = t.gap_span("fib.queue_wait", reader="fib")
+        assert hop.closed and hop.attrs == {"reader": "fib"}
+        assert hop.dur_ms >= 3.0
+        # it starts where decision.emit ended, on both clocks
+        assert hop.ts_ms == pytest.approx(work.ts_ms + work.dur_ms)
+        assert hop._t0 <= handed_off
+        tracer.finish(t)
+        assert t.complete and t.well_formed()
+        assert [s.name for s in t.spans] == [
+            "kvstore.publish", "decision.queue_wait", "decision.emit",
+            "fib.queue_wait",
+        ]
+        # nothing has closed yet: nothing to measure from
+        assert Trace("kvstore.publish").gap_span("decision.queue_wait") is None
+
+    def test_tracing_never_imports_jax(self):
+        """ctrl clients and breeze use this module without jax; a scoped
+        span annotates only from a jax the process already has."""
+        code = (
+            "import sys\n"
+            "from openr_tpu.telemetry import get_tracer\n"
+            "tracer = get_tracer()\n"
+            "t = tracer.start()\n"
+            "with tracer.span('decision.route_build', trace=t) as s:\n"
+            "    pass\n"
+            "assert s.closed\n"
+            "assert not [m for m in sys.modules if m.split('.')[0] == 'jax']\n"
+        )
+        subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+    def test_profiler_session_carries_scoped_spans(self, tmp_path):
+        """Inside a ``jax.profiler`` session a scoped span is also an
+        event of the session's host plane, on the session's clock; a
+        ``begin_span`` / ``end_span`` pair is not."""
+        import jax
+        from jax.profiler import ProfileData
+
+        tracer = Tracer()
+        t = tracer.start()
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            plain = t.begin_span("decision.rebuild")
+            with tracer.span("decision.route_build", trace=t) as scoped:
+                time.sleep(0.005)
+            t.end_span(plain)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(
+            os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb")
+        )
+        host = [
+            ev
+            for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines
+            for ev in line.events
+        ]
+        mine = [ev for ev in host if ev.name == "decision.route_build"]
+        assert len(mine) == 1
+        assert mine[0].duration_ns / 1e6 == pytest.approx(
+            scoped.dur_ms, abs=1.0
+        )
+        assert not [ev for ev in host if ev.name == "decision.rebuild"]
+
+
+class TestGcHook:
+    def test_counts_full_collections_only(self):
+        install_gc_hook()
+        install_gc_hook()  # idempotent
+        hooks = [cb for cb in gc.callbacks
+                 if type(cb).__name__ == "_Gen2Pauses"]
+        assert len(hooks) == 1
+        reg = get_registry()
+
+        def read():
+            return (
+                reg.counter_get("process.gc_gen2_collections"),
+                reg.counter_get("process.gc_gen2_pause_ms"),
+            )
+
+        n0, ms0 = read()
+        gc.collect(0)
+        gc.collect(1)
+        assert read() == (n0, ms0)
+        gc.collect(2)
+        n1, ms1 = read()
+        assert n1 == n0 + 1 and ms1 > ms0
+        gc.collect()  # a full collection by its default argument
+        assert read()[0] == n0 + 2
+        assert "process.gc_gen2_pause_ms" in reg.snapshot()
 
 
 class TestMonitorSatellites:
