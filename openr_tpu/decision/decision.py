@@ -864,7 +864,18 @@ class Decision:
                     span.attrs.update(
                         updated=len(update.unicast_routes_to_update),
                         deleted=len(update.unicast_routes_to_delete),
+                        identical=update.diff_identical,
+                        compared=update.diff_compared,
                     )
+            # identical / (identical + compared) is the share of the
+            # table the diff settled by object identity
+            registry = get_registry()
+            registry.counter_bump(
+                "decision.route_diff_identical", update.diff_identical
+            )
+            registry.counter_bump(
+                "decision.route_diff_compared", update.diff_compared
+            )
         else:
             update = value
         if trace is not None:

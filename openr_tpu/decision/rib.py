@@ -8,7 +8,8 @@ Behavioral parity with the reference ``openr/decision/RibEntry.h``,
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, FrozenSet, List, Optional, Set
+from operator import attrgetter
+from typing import Callable, Dict, FrozenSet, List, Optional, Set, Tuple
 
 from openr_tpu.analysis.annotations import thread_confined
 from openr_tpu.types import (
@@ -81,6 +82,60 @@ class RibMplsEntry:
         return MplsRoute(top_label=self.label, next_hops=tuple(self.nexthops))
 
 
+_PREFIX_OF = attrgetter("prefix")
+_LABEL_OF = attrgetter("label")
+
+
+def _diff_table(
+    installed: Dict, new: Dict, key_of: Callable
+) -> Tuple[List, List, int]:
+    """One table of ``DecisionRouteDb.calculate_update``: the entries of
+    ``new`` to install and the keys of ``installed`` to drop, both in
+    the order the plain two-loop diff gives them, then how many entries
+    of ``new`` were skipped as the installed object itself (the others
+    took the field-by-field test).
+
+    A route build hands back the object it returned last time for every
+    route it did not re-derive, and the installed table holds that very
+    object, so identity settles most of a large table without a call to
+    ``__eq__`` (an object equals itself, so the answer is the one
+    equality would give). The passes over the whole table run in C on
+    the hashes the dicts already store; only what the build re-derived
+    is hashed and compared in Python. Every writer files an entry
+    under its own key (``table[key_of(entry)] is entry``; ``update``
+    asserts it for the one key it is handed), so an object found in
+    both tables sits under the same key in both.
+
+    An entry of ``new`` that equals the installed one without being it
+    (re-derived to the same route, or built after the solver lost its
+    cache) is not in the delta, and ``installed`` takes it in place of
+    its equal twin: the builder will hand that object back from now on,
+    and left alone the pair would go to ``__eq__`` in every later diff.
+    """
+    held = set(map(id, installed.values()))
+    fresh = [e for e in new.values() if id(e) not in held]
+    identical = len(new) - len(fresh)
+    changed = []
+    kept = identical  # keys of installed that new has too
+    for entry in fresh:
+        key = key_of(entry)
+        old = installed.get(key)
+        if old is None:
+            changed.append(entry)
+            continue
+        kept += 1
+        if old != entry:
+            changed.append(entry)
+        else:
+            installed[key] = entry
+    gone: List = []
+    if kept < len(installed):
+        # the key objects that come back are installed's own
+        lost = set(map(id, set(installed).difference(new)))
+        gone = [k for k in installed if id(k) in lost]
+    return changed, gone, identical
+
+
 @dataclass
 class DecisionRouteUpdate:
     """Route delta published by Decision, consumed by Fib / PrefixManager.
@@ -96,6 +151,13 @@ class DecisionRouteUpdate:
     # in-process telemetry trace adopted from the triggering
     # publication (oldest-chain rule, same as perf_events)
     trace: Optional[object] = None
+    # how the full-db diff that made this update decided "unchanged":
+    # entries of the new db that ARE the installed object, and entries
+    # that went to the field-by-field test (adds included). Telemetry
+    # riders like the two above; 0 on a per-prefix delta, which no diff
+    # produced
+    diff_identical: int = field(default=0, compare=False)
+    diff_compared: int = field(default=0, compare=False)
 
     def empty(self) -> bool:
         return not (
@@ -146,29 +208,37 @@ class DecisionRouteDb:
         self.mpls_routes[entry.label] = entry
 
     def calculate_update(self, new_db: "DecisionRouteDb") -> DecisionRouteUpdate:
-        """Delta from self -> new_db (reference: Decision.cpp:112)."""
-        delta = DecisionRouteUpdate()
-        for prefix, entry in new_db.unicast_routes.items():
-            old = self.unicast_routes.get(prefix)
-            if old is None or old != entry:
-                delta.unicast_routes_to_update[prefix] = entry
-        for prefix in self.unicast_routes:
-            if prefix not in new_db.unicast_routes:
-                delta.unicast_routes_to_delete.append(prefix)
-        for label, entry in new_db.mpls_routes.items():
-            old = self.mpls_routes.get(label)
-            if old is None or old != entry:
-                delta.mpls_routes_to_update.append(entry)
-        for label in self.mpls_routes:
-            if label not in new_db.mpls_routes:
-                delta.mpls_routes_to_delete.append(label)
-        return delta
+        """Delta from self -> new_db (reference: Decision.cpp:112). The
+        one thing it changes in self: an entry that equals new_db's
+        without being the same object gives way to new_db's
+        (``_diff_table``). ``__eq__`` cannot tell the two apart, so no
+        later delta differs; ``best_area``, which ``__eq__`` leaves out
+        and nothing reads off the installed db, becomes the newer one."""
+        u_new, u_gone, u_same = _diff_table(
+            self.unicast_routes, new_db.unicast_routes, _PREFIX_OF
+        )
+        m_new, m_gone, m_same = _diff_table(
+            self.mpls_routes, new_db.mpls_routes, _LABEL_OF
+        )
+        identical = u_same + m_same
+        return DecisionRouteUpdate(
+            unicast_routes_to_update={e.prefix: e for e in u_new},
+            unicast_routes_to_delete=u_gone,
+            mpls_routes_to_update=m_new,
+            mpls_routes_to_delete=m_gone,
+            diff_identical=identical,
+            diff_compared=(
+                len(new_db.unicast_routes) + len(new_db.mpls_routes) - identical
+            ),
+        )
 
     def update(self, delta: DecisionRouteUpdate) -> None:
         """Apply a delta in place (reference: Decision.cpp:146)."""
         for prefix in delta.unicast_routes_to_delete:
             self.unicast_routes.pop(prefix, None)
         for prefix, entry in delta.unicast_routes_to_update.items():
+            # calculate_update's identity pass relies on it
+            assert entry.prefix == prefix, (prefix, entry.prefix)
             self.unicast_routes[prefix] = entry
         for label in delta.mpls_routes_to_delete:
             self.mpls_routes.pop(label, None)
