@@ -383,20 +383,21 @@ class Decision:
                         "telemetry.traces_no_route_impact"
                     )
         if self.pending.needs_route_update():
-            # overlap the device-side delta application with the
-            # debounce window: the band scatter for this publication's
-            # topology delta is enqueued asynchronously NOW, so by the
-            # time the debounced rebuild dispatches its fused solve the
-            # resident bands are already patched (and the previous
-            # event's RouteDatabase delta emission ran concurrently
-            # with the scatter instead of ahead of it)
+            # arm the window first (upstream's order: processPublication
+            # -> rebuildRoutesDebounced_), then patch inside it: the
+            # callback runs on this thread, so it cannot fire before
+            # prewarm has returned, and the band scatter for this
+            # publication's topology delta costs the window nothing as
+            # long as it is shorter than the policy wait. By the time
+            # the debounced rebuild dispatches its fused solve the
+            # resident bands are already current.
+            self._rebuild_debounced()
             if self._admission is None or self._admission.allow_prewarm(
                 self._kv_reader.size()
             ):
                 self.spf_solver.prewarm(
                     self.area_link_states, trace=self.pending.trace
                 )
-            self._rebuild_debounced()
             # debounce-terminal speculation: once the window's backoff
             # saturates, further publications can only JOIN the window,
             # never extend it — the fire time is final, and under

@@ -961,15 +961,15 @@ class SpfSolver:
         self, area_link_states: AreaLinkStates, trace=None
     ) -> None:
         """Publication-time overlap hook (called by the decision module
-        as publications land, BEFORE the debounced rebuild fires): push
+        as publications land, right AFTER it has armed the debounce
+        timer and so before the debounced rebuild can fire): push
         pending topology deltas into the device-resident ELL bands now,
-        so the band scatter overlaps the debounce window and the
-        previous event's RouteDatabase delta emission instead of
-        sitting on the rebuild's critical path. Touches only graphs
-        that ALREADY have resident state (never compiles a new one) and
-        swallows failures — this is an overlap optimization, not a
-        correctness step: the rebuild re-syncs and no-ops when the
-        bands are already current.
+        so the host patch and the band scatter run inside the policy
+        wait instead of sitting on the rebuild's critical path. Touches
+        only graphs that ALREADY have resident state (never compiles a
+        new one) and swallows failures — this is an overlap
+        optimization, not a correctness step: the rebuild re-syncs and
+        no-ops when the bands are already current.
 
         Safe to call once per publication in a burst: the EllState
         journal MERGES stacked patches (snapshot-keyed edge deltas, see
@@ -979,9 +979,11 @@ class SpfSolver:
         a forced cold seed.
 
         ``trace`` is the debounce window's: the patch runs on the
-        caller's thread before the timer is armed, so it is time inside
-        ``decision.debounce`` that is work, not policy, and gets a span
-        of its own there (none when there is nothing to patch)."""
+        caller's thread with the timer already armed, so it is the part
+        of ``decision.debounce`` that is work under the policy wait (it
+        lengthens the window only by what it runs past the deadline),
+        and gets a span of its own there (none when there is nothing
+        to patch)."""
         if self.backend != "device":
             return
         for ls in area_link_states.values():

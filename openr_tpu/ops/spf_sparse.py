@@ -823,6 +823,27 @@ def _first_hops_from_rows(d, srcs, w_sv, overloaded, n):
     return (transit_ok | direct_ok) & reachable[None, :]
 
 
+def _scatter_band_rows(src, w, ids, rows_src, rows_w):
+    """One band's row scatter, written once: _ell_reconverge traces it
+    in-program per band, _patch_band is the same expression as a
+    program of its own. ``ids`` is a pad_patch_rows bucket (padding
+    repeats a row — an idempotent scatter)."""
+    return src.at[ids, :].set(rows_src), w.at[ids, :].set(rows_w)
+
+
+@functools.partial(
+    jax.jit,
+    # the resident band tensors are dead after the call, as in
+    # _ell_reconverge: scatter in place instead of copying the band
+    donate_argnums=(0, 1),
+)
+def _patch_band(src, w, ids, rows_src, rows_w):
+    """The solve-free patch (EllState.apply_patch): ONE program per
+    band, keyed (band shape x bucket) — never fused across bands,
+    which would key on the cross product of their buckets."""
+    return _scatter_band_rows(src, w, ids, rows_src, rows_w)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("bands", "n"),
@@ -839,14 +860,12 @@ def _ell_reconverge(srcs_t, ws_t, patch_ids_t, patch_src_t, patch_w_t,
     (reset only the increase cone), pack distances + first hops.
     Only the O(rows x K) patch + O(|delta|) increase edges cross
     host->device; only the packed [2B, N] view crosses back."""
-    new_src = tuple(
-        s.at[ids, :].set(ps)
-        for s, ids, ps in zip(srcs_t, patch_ids_t, patch_src_t)
-    )
-    new_w = tuple(
-        w.at[ids, :].set(pw)
-        for w, ids, pw in zip(ws_t, patch_ids_t, patch_w_t)
-    )
+    new_src, new_w = zip(*(
+        _scatter_band_rows(s, w, ids, ps, pw)
+        for s, w, ids, ps, pw in zip(
+            srcs_t, ws_t, patch_ids_t, patch_src_t, patch_w_t
+        )
+    ))
     w_sv = _device_direct_metrics(new_src, new_w, srcs, bands)
     b = srcs.shape[0]
     unit = jnp.full((b, n), INF, dtype=jnp.int32)
@@ -1181,38 +1200,45 @@ def ell_masked_distances_resident(
     return np.asarray(d)
 
 
-def band_patch_inputs(resident_src, resident_w, patched: EllGraph):
-    """The ONE implementation of the band patch discipline shared by
-    every resident-band consumer (EllState.apply_patch/.reconverge and
-    the route engine's churn prep): per band, either a bucketed
-    row-scatter (pad_patch_rows shapes, a zeros(1) no-op when nothing
-    changed) or — for a WIDENED band, whose tensor SHAPE changed — a
-    wholesale re-upload with a no-op scatter. Returns
-    (in_src, in_w, patch_ids, patch_src, patch_w) as tuples of device
-    arrays: dispatch inputs plus the scatter triples."""
+def _band_patch_rows(patched: EllGraph):
+    """Host half of the band patch discipline, the ONE implementation
+    every resident-band consumer shares: per band, ``(widened, ids)``.
+    A WIDENED band changed tensor SHAPE and is re-uploaded wholesale
+    (ids None); otherwise ids is the bucketed changed-row vector
+    (pad_patch_rows shapes; every row once a change outgrows the
+    largest bucket), or None when nothing changed."""
     changed: Dict[int, np.ndarray] = patched.changed or {}
     widened = patched.widened or frozenset()
+    for bi, band in enumerate(patched.bands):
+        rows = None if bi in widened else changed.get(bi)
+        if rows is None or len(rows) == 0:
+            yield bi in widened, None
+            continue
+        padded = pad_patch_rows(np.asarray(rows, dtype=np.int32))
+        yield False, (
+            padded
+            if padded is not None
+            else np.arange(band.rows, dtype=np.int32)
+        )
+
+
+def band_patch_inputs(resident_src, resident_w, patched: EllGraph):
+    """The band patch as inputs of a FUSED dispatch, whose signature
+    carries a scatter triple for every band (EllState.reconverge and
+    the route engine's churn prep): _band_patch_rows' ids, a zeros(1)
+    no-op where a band has nothing to scatter, and a widened band's
+    re-upload in place of the resident tensor. Returns
+    (in_src, in_w, patch_ids, patch_src, patch_w) as tuples of device
+    arrays: dispatch inputs plus the scatter triples."""
     in_src = list(resident_src)
     in_w = list(resident_w)
     patch_ids, patch_src, patch_w = [], [], []
-    for bi, band in enumerate(patched.bands):
-        if bi in widened:
+    for bi, (widened, rows) in enumerate(_band_patch_rows(patched)):
+        if widened:
             in_src[bi] = jnp.asarray(patched.src[bi])
             in_w[bi] = jnp.asarray(patched.w[bi])
-            rows = np.zeros(1, dtype=np.int32)
-        else:
-            rows = changed.get(bi)
-            if rows is None or len(rows) == 0:
-                rows = np.zeros(1, dtype=np.int32)  # no-op scatter
-            else:
-                padded = pad_patch_rows(
-                    np.asarray(rows, dtype=np.int32)
-                )
-                rows = (
-                    padded
-                    if padded is not None
-                    else np.arange(band.rows, dtype=np.int32)
-                )
+        if rows is None:
+            rows = np.zeros(1, dtype=np.int32)  # no-op scatter
         patch_ids.append(jnp.asarray(rows))
         patch_src.append(jnp.asarray(patched.src[bi][rows]))
         patch_w.append(jnp.asarray(patched.w[bi][rows]))
@@ -1359,19 +1385,20 @@ class EllState:
         can still warm-start across the un-solved patch."""
         ov_changed = self._sync_overloaded(patched)
         self._note_patch(patched, ov_changed)
-        in_src, in_w, patch_ids, patch_src, patch_w = (
-            band_patch_inputs(self.src, self.w, patched)
-        )
-        # eager bucketed scatters (one compiled shape per bucket); the
-        # no-op rows rewrite identical values
-        self.src = tuple(
-            s.at[ids, :].set(vals)
-            for s, ids, vals in zip(in_src, patch_ids, patch_src)
-        )
-        self.w = tuple(
-            w.at[ids, :].set(vals)
-            for w, ids, vals in zip(in_w, patch_ids, patch_w)
-        )
+        # one jitted scatter per band that has changed rows (one
+        # compiled shape per band x bucket), fed the host row blocks
+        # directly; a band with nothing to scatter launches nothing
+        src, w = list(self.src), list(self.w)
+        for bi, (widened, rows) in enumerate(_band_patch_rows(patched)):
+            if widened:
+                src[bi] = jnp.asarray(patched.src[bi])
+                w[bi] = jnp.asarray(patched.w[bi])
+            elif rows is not None:
+                src[bi], w[bi] = _patch_band(
+                    src[bi], w[bi], rows,
+                    patched.src[bi][rows], patched.w[bi][rows],
+                )
+        self.src, self.w = tuple(src), tuple(w)
         self.graph = _replace(patched, changed=None)
 
     @solve_window

@@ -17,6 +17,7 @@ from __future__ import annotations
 from dataclasses import replace
 
 import numpy as np
+import pytest
 
 from openr_tpu.graph.linkstate import LinkState
 from openr_tpu.models import topologies
@@ -38,6 +39,33 @@ def load(topo):
 
 def _adj_other(ls, node, i):
     return ls.get_adjacency_databases()[node].adjacencies[i].other_node_name
+
+
+def _grow_in_degree(ls, node, peers):
+    """Point ``peers`` at ``node`` (both directions, metric 2), pushing
+    its row past its compiled slot class."""
+    db = ls.get_adjacency_databases()[node]
+    adjs = list(db.adjacencies)
+    for peer in peers:
+        pdb = ls.get_adjacency_databases()[peer]
+        ls.update_adjacency_database(replace(
+            pdb,
+            adjacencies=tuple(pdb.adjacencies) + (replace(
+                pdb.adjacencies[0],
+                other_node_name=node,
+                if_name=f"if_{peer}_{node}",
+                other_if_name=f"if_{node}_{peer}",
+                metric=2,
+            ),),
+        ))
+        adjs.append(replace(
+            db.adjacencies[0],
+            other_node_name=peer,
+            if_name=f"if_{node}_{peer}",
+            other_if_name=f"if_{peer}_{node}",
+            metric=2,
+        ))
+    ls.update_adjacency_database(replace(db, adjacencies=tuple(adjs)))
 
 
 class TestEllStateWarmParity:
@@ -205,40 +233,10 @@ class TestEllStateWarmParity:
 
         # grow node-5's in-degree past its compiled slot class by
         # pointing several new neighbors at it
-        db5 = ls.get_adjacency_databases()["node-5"]
-        affected = {"node-5"}
-        new_adjs = list(db5.adjacencies)
-        for peer in ("node-0", "node-3", "node-10", "node-12",
-                     "node-14", "node-15"):
-            pdb = ls.get_adjacency_databases()[peer]
-            ls.update_adjacency_database(
-                replace(
-                    pdb,
-                    adjacencies=tuple(pdb.adjacencies)
-                    + (
-                        replace(
-                            pdb.adjacencies[0],
-                            other_node_name="node-5",
-                            if_name=f"if_{peer}_node-5",
-                            other_if_name=f"if_node-5_{peer}",
-                            metric=2,
-                        ),
-                    ),
-                )
-            )
-            new_adjs.append(
-                replace(
-                    db5.adjacencies[0],
-                    other_node_name=peer,
-                    if_name=f"if_node-5_{peer}",
-                    other_if_name=f"if_{peer}_node-5",
-                    metric=2,
-                )
-            )
-            affected.add(peer)
-        ls.update_adjacency_database(
-            replace(db5, adjacencies=tuple(new_adjs))
-        )
+        peers = ("node-0", "node-3", "node-10", "node-12",
+                 "node-14", "node-15")
+        _grow_in_degree(ls, "node-5", peers)
+        affected = {"node-5", *peers}
         patched = spf_sparse.ell_patch(
             state.graph, ls, sorted(affected), widen=True
         )
@@ -278,6 +276,137 @@ class TestEllStateWarmParity:
                 else set()
             )
             assert got_nh == want_nh, (dst, got_nh, want_nh)
+
+
+class TestApplyPatchOneProgramPerBand:
+    """EllState.apply_patch — the solve-free sync behind the decision
+    module's publication-time prewarm and the KSP2 masked batches —
+    lands a patch with ONE jitted scatter per band that has changed
+    rows (spf_sparse._patch_band, the expression _ell_reconverge
+    traces in-program), nothing for an unchanged or a widened band.
+    Counts on the CPU backend; never times."""
+
+    ROOT = "rsw-0-0"
+    LEAF = "rsw-0-1"
+
+    def _view_by_name(self, graph, ls):
+        """dst -> (distance from ROOT, first-hop names): a widen
+        renumbers a fresh compile_ell, so views compare by name."""
+        srcs = spf_sparse.ell_source_batch(graph, ls, self.ROOT)
+        return srcs, lambda packed: {
+            dst: (
+                int(packed[0, did]),
+                frozenset(
+                    graph.node_names[srcs[i]]
+                    for i in np.nonzero(packed[len(srcs):, did])[0]
+                ),
+            )
+            for dst, did in graph.node_index.items()
+        }
+
+    @pytest.mark.parametrize(
+        "case",
+        ["one_band", "both_bands", "widened_band", "overload_flip",
+         "stacked_patches"],
+    )
+    def test_patch_parity_launches_and_compiles(self, case, monkeypatch):
+        ls = load(topologies.fat_tree(2))
+        state = spf_sparse.EllState(spf_sparse.compile_ell(ls))
+        assert len(state.graph.bands) == 2
+        srcs, _ = self._view_by_name(state.graph, ls)
+        state.reconverge(state.graph, srcs)  # resident distances
+
+        jitted = spf_sparse._patch_band
+        launches = []  # the band tensor shape of every launch
+
+        def counting(src, w, ids, rows_src, rows_w):
+            # host row blocks go straight in: no staging of its own
+            assert all(
+                isinstance(a, np.ndarray) for a in (ids, rows_src, rows_w)
+            )
+            launches.append(src.shape)
+            return jitted(src, w, ids, rows_src, rows_w)
+
+        monkeypatch.setattr(spf_sparse, "_patch_band", counting)
+
+        def patch(affected):
+            patched = spf_sparse.ell_patch(
+                state.graph, ls, sorted(affected), widen=True
+            )
+            assert patched is not None
+            before = len(launches)
+            state.apply_patch(patched)
+            mine = launches[before:]
+            assert len(mine) == len(set(mine))  # at most one per band
+            assert len(mine) == sum(
+                bi not in (patched.widened or ())
+                for bi in patched.changed
+            )
+            for bi in range(len(patched.bands)):
+                np.testing.assert_array_equal(
+                    np.asarray(state.src[bi]), patched.src[bi])
+                np.testing.assert_array_equal(
+                    np.asarray(state.w[bi]), patched.w[bi])
+            np.testing.assert_array_equal(
+                np.asarray(state.overloaded), patched.overloaded)
+            assert state.graph.changed is None
+            return patched, mine
+
+        spine = _adj_other(ls, self.LEAF, 0)
+        c0 = dict(spf_sparse.ELL_COUNTERS)
+        if case == "one_band":
+            # LEAF's metric toward its spine sits in the spine's row
+            _mutate_metric(ls, self.LEAF, 0, 7)
+            _, mine = patch({spine})
+            assert len(mine) == 1
+        elif case == "both_bands":
+            _mutate_metric(ls, self.LEAF, 0, 7)
+            _, mine = patch({self.LEAF, spine})
+            assert len(mine) == 2
+        elif case == "widened_band":
+            peers = [f"rsw-1-{i}" for i in range(5)]
+            shape = state.src[0].shape
+            _grow_in_degree(ls, self.LEAF, peers)
+            patched, mine = patch({self.LEAF, *peers})
+            # the widened band is re-uploaded wholesale, not scattered
+            assert patched.widened == {0} and mine == []
+            assert state.src[0].shape[1] > shape[1]
+        elif case == "overload_flip":
+            _set_overload(ls, spine, True)
+            patch({spine})
+        else:
+            # one edge moved twice with no solve in between: the
+            # journal keeps the solved-under snapshot (metric 1)
+            _mutate_metric(ls, self.LEAF, 0, 30)
+            patch({self.LEAF, spine})
+            _mutate_metric(ls, self.LEAF, 0, 3)
+            patch({self.LEAF, spine})
+
+        # the rebuild that follows: bands current, warm, equal to cold
+        srcs, by_name = self._view_by_name(state.graph, ls)
+        got = by_name(np.asarray(state.reconverge(state.graph, srcs)))
+        c1 = dict(spf_sparse.ELL_COUNTERS)
+        assert c1["ell_warm_solves"] == c0["ell_warm_solves"] + 1
+        assert c1["ell_cold_solves"] == c0["ell_cold_solves"]
+        if case == "overload_flip":
+            assert (c1["ell_structural_warm_solves"]
+                    == c0["ell_structural_warm_solves"] + 1)
+        if case == "stacked_patches":
+            assert c1["ell_patch_merges"] == c0["ell_patch_merges"] + 1
+        cold = spf_sparse.compile_ell(ls)
+        cold_srcs, cold_by_name = self._view_by_name(cold, ls)
+        want = cold_by_name(np.asarray(
+            spf_sparse.ell_view_batch_packed(cold, cold_srcs)))
+        assert got == want
+
+        # a second patch of a bucket already seen compiles nothing
+        _mutate_metric(ls, self.LEAF, 0, 11)
+        patch({self.LEAF, spine})
+        compiled = jitted._cache_size()
+        _mutate_metric(ls, self.LEAF, 0, 12)
+        _, mine = patch({self.LEAF, spine})
+        assert len(mine) == 2
+        assert jitted._cache_size() == compiled
 
 
 class TestSolverIncrementalParity:

@@ -334,8 +334,8 @@ class TestSpanTreeEndToEnd:
             by_name = {s.name: s for s in trace.spans}
             if formulation == "ell":
                 _assert_tree(trace, ELL_ADJ_EVENT_TREE)
-                # prewarm patched b and its two neighbours' rows ahead
-                # of the timer, so the rebuild finds the bands current
+                # prewarm patched b and its two neighbours' rows inside
+                # the window, so the rebuild finds the bands current
                 assert by_name["decision.prewarm"].attrs == {"rows": 3}
                 assert by_name["graph.view_sync"].attrs == {
                     "formulation": "ell", "rows": 0}
@@ -374,5 +374,87 @@ class TestSpanTreeEndToEnd:
                          "telemetry.traces_unclosed_spans",
                          "ops.host_dispatches"):
                 assert after_prefix[name] == before[name], name
+        finally:
+            h.stop()
+
+    def test_prewarm_is_entered_with_the_window_already_armed(
+        self, monkeypatch
+    ):
+        """``_on_publication`` arms the debounce timer BEFORE it calls
+        ``SpfSolver.prewarm``, so the publication-time band patch runs
+        inside the policy wait instead of ahead of it. The span tree is
+        what it was (``decision.prewarm`` inside ``decision.debounce``,
+        same ``rows``), and a publication with no route impact arms
+        nothing and patches nothing."""
+        reg, tracer = get_registry(), get_tracer()
+        monkeypatch.setattr(spf_solver, "SPARSE_NODE_THRESHOLD", 2)
+        h = PipelineHarness(solver_backend="device")
+        entered = []  # (window armed?, a resident band is stale?)
+        solver = h.decision.spf_solver
+        real_prewarm = solver.prewarm
+
+        def spy(area_link_states, trace=None):
+            stale = False
+            for ls in area_link_states.values():
+                entry = spf_solver._ELL_RESIDENT._cache.get(ls)
+                stale = stale or (
+                    entry is not None
+                    and entry[0] != ls.topology_version
+                )
+            entered.append(
+                (h.decision._rebuild_debounced.is_scheduled(), stale)
+            )
+            return real_prewarm(area_link_states, trace=trace)
+
+        monkeypatch.setattr(solver, "prewarm", spy)
+        try:
+            topo = line_topology()
+            for db in topo.adj_dbs.values():
+                h.publish_adj(db)
+            for pdb in topo.prefix_dbs.values():
+                h.publish_prefixes(pdb)
+            assert wait_until(lambda: len(h.fib.unicast_routes) >= 2)
+            time.sleep(0.4)  # the last debounce window of the load
+            assert not h.decision._rebuild_debounced.is_scheduled()
+            del entered[:]
+            prewarms = reg.counter_get("decision.ell_prewarms")
+            no_impact = reg.counter_get("telemetry.traces_no_route_impact")
+            newest = max(t.trace_id for t in tracer.traces())
+
+            # no route impact: nothing armed, prewarm never entered
+            h.store.set_key(
+                keyutil.fib_time_key("b"), b"12.5", version=1,
+                originator="b",
+            )
+            assert wait_until(
+                lambda: reg.counter_get("telemetry.traces_no_route_impact")
+                == no_impact + 1
+            )
+            assert not h.decision._rebuild_debounced.is_scheduled()
+            assert entered == []
+
+            # an adjacency event over resident ELL state: the patch has
+            # work to do, and finds the timer running when it starts
+            b = topo.adj_dbs["b"]
+            h.publish_adj(dataclasses.replace(b, adjacencies=tuple(
+                dataclasses.replace(adj, metric=adj.metric + 3)
+                for adj in b.adjacencies
+            )))
+
+            def finished():
+                return [
+                    t for t in tracer.traces()
+                    if t.trace_id > newest
+                    and t.spans[0].attrs.get("keys") == ["adj:b"]
+                ]
+            assert wait_until(lambda: bool(finished()))
+            assert entered == [(True, True)]
+            assert reg.counter_get("decision.ell_prewarms") == prewarms + 1
+            trace = finished()[-1]
+            _assert_tree(trace, ELL_ADJ_EVENT_TREE)
+            by_name = {s.name: s for s in trace.spans}
+            assert by_name["decision.prewarm"].attrs == {"rows": 3}
+            assert by_name["graph.view_sync"].attrs == {
+                "formulation": "ell", "rows": 0}
         finally:
             h.stop()
