@@ -174,13 +174,12 @@ def leg_pipeline(nodes: int, events: int = 20, rate: int = 4,
         "decision.ladder_walks",
     )
     before = _counter_snapshot(watched)
-    # the debounce window and emit staging OpenrConfig ships
+    # the debounce window OpenrConfig ships
     harness = SustainedLoadHarness(
         nodes=nodes,
         solver_backend="device",
         debounce_min_s=0.010,
         debounce_max_s=0.250,
-        pipelined_emit=False,
     )
     harness.start(initial_timeout_s=timeout_s)
     try:

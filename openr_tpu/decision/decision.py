@@ -25,7 +25,6 @@ from __future__ import annotations
 import base64
 import threading
 import time
-from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Callable, Dict, Optional, Set, Tuple
 
 from openr_tpu.decision.prefix_state import PrefixState
@@ -41,11 +40,7 @@ from openr_tpu.types import (
     PrefixDatabase,
     PrefixEntry,
 )
-from openr_tpu.analysis.annotations import (
-    fault_boundary,
-    solve_window,
-    thread_confined,
-)
+from openr_tpu.analysis.annotations import fault_boundary, solve_window
 from openr_tpu.faults.supervisor import DegradationSupervisor, HealthState
 from openr_tpu.integrity import get_auditor, quarantine_active
 from openr_tpu.load.admission import AdmissionControl
@@ -180,10 +175,10 @@ class DecisionPendingUpdates:
         self.release_trace()
 
 
-# route_db is single-owner by mode, not by lock: eager mode mutates it
-# on the event base; pipelined mode hands ownership to the emit worker,
-# and every rebuild joins the worker (_drain_emit) before touching it.
-@thread_confined("owner", "route_db")
+# route_db is mutated on the event base only (_emit_update, inline at
+# the end of rebuild_routes); other threads reach it through
+# evb.call_and_wait. It carries no @thread_confined exemption: the
+# shared-state rule infers the confinement and convicts a second writer.
 class Decision:
     def __init__(
         self,
@@ -202,10 +197,6 @@ class Decision:
         solver_backend: str = "device",
         enable_rib_policy: bool = True,
         admission: Optional[AdmissionControl] = None,
-        pipelined_emit: bool = False,
-        kvstore_reader_maxlen: Optional[int] = None,
-        world_batch: Optional[bool] = None,
-        view_cache_cap: Optional[int] = None,
         state_plane=None,
     ):
         # crash-safe state plane (openr_tpu.state.StatePlane): engine
@@ -236,8 +227,6 @@ class Decision:
             bgp_dry_run=bgp_dry_run,
             enable_best_route_selection=enable_best_route_selection,
             backend=solver_backend,
-            view_cache_cap=view_cache_cap,
-            world_batch=world_batch,
         )
         # degradation ladder for the rebuild path: warm device solve →
         # device-state reset + cold rebuild → non-device backend (see
@@ -256,10 +245,10 @@ class Decision:
         # ladder was fully warm and no engine sat in integrity
         # quarantine — the staleness gauge ages from it while degraded
         self._last_good_route_ts: Optional[float] = None
-        # the stamp is written by whichever role emits (event base or
-        # the emit worker) and read by the registry's gauge thread —
-        # a dedicated lock keeps the pair race-free without dragging
-        # the gauge into the emit path's wider critical sections
+        # the stamp is written on the event base (_emit_update) and
+        # read by the registry's gauge thread — a dedicated lock keeps
+        # the pair race-free without dragging the gauge into the emit
+        # path's wider critical sections
         self._emit_mu = threading.Lock()
         get_registry().gauge(
             "decision.route_staleness_ms", self._route_staleness_ms
@@ -301,20 +290,6 @@ class Decision:
             self._admission.bind_debounce(
                 self._rebuild_debounced, debounce_max_s
             )
-        # pipelined emit: the diff/apply/publish tail of a rebuild runs
-        # on a single-worker FIFO executor so event N+1's solve can
-        # dispatch while event N's routes are still being derived and
-        # programmed (PendingDelta double-buffering, one layer up). The
-        # worker is the sole owner of route_db once enabled.
-        self._pipelined_emit = pipelined_emit
-        self._emit_executor: Optional[ThreadPoolExecutor] = (
-            ThreadPoolExecutor(
-                max_workers=1, thread_name_prefix=f"decision-emit:{my_node_name}"
-            )
-            if pipelined_emit
-            else None
-        )
-        self._emit_future: Optional[Future] = None
         self._cold_start_until = (
             time.monotonic() + cold_start_s if cold_start_s > 0 else 0.0
         )
@@ -322,7 +297,7 @@ class Decision:
             self.evb.schedule_timeout(cold_start_s, self._on_cold_start_done)
 
         self._kv_reader = kvstore_updates_queue.get_reader(
-            f"decision:{my_node_name}", maxlen=kvstore_reader_maxlen
+            f"decision:{my_node_name}"
         )
         self.evb.add_queue_reader(self._kv_reader, self._on_publication)
         if static_routes_queue is not None:
@@ -340,14 +315,6 @@ class Decision:
     def stop(self) -> None:
         self.evb.stop()
         self.evb.join()
-        if self._emit_executor is not None:
-            if self._emit_future is not None:
-                try:
-                    self._emit_future.result(timeout=10.0)
-                except Exception:  # noqa: BLE001 - drained best-effort
-                    pass
-                self._emit_future = None
-            self._emit_executor.shutdown(wait=True)
 
     # -- queue handlers (run on the module thread) ------------------------
 
@@ -826,39 +793,21 @@ class Decision:
         self.pending.add_event("ROUTE_UPDATE")
         perf_events = self.pending.move_out_events()
         self.pending.reset()
-        if self._emit_executor is not None:
-            # double-buffered handoff: at most one emit in flight. The
-            # wait lands AFTER this event's solve, so emit N overlapped
-            # solve N+1; the single worker keeps route_db mutation and
-            # queue pushes strictly FIFO.
-            self._drain_emit()
-            self._emit_future = self._emit_executor.submit(
-                self._emit_update, payload, trace, rebuild_span, perf_events
-            )
-        else:
-            self._emit_update(payload, trace, rebuild_span, perf_events)
-
-    def _drain_emit(self) -> None:
-        if self._emit_future is not None:
-            try:
-                self._emit_future.result()
-            except Exception:  # noqa: BLE001 - counted, never kills evb
-                get_registry().counter_bump("decision.emit_errors")
-            self._emit_future = None
+        self._emit_update(payload, trace, rebuild_span, perf_events)
 
     def _emit_update(
         self, payload, trace, rebuild_span, perf_events
     ) -> None:
         """Emit stage of a rebuild: diff the solved db against the
-        installed one, apply, and publish. In pipelined mode this runs
-        on the single-worker emit executor (which then exclusively owns
-        route_db); in eager mode it runs inline on the module thread."""
+        installed one, apply, and publish. Runs inline on the module
+        thread, the only place route_db is mutated."""
         tracer = get_tracer()
         kind, value = payload
         if kind == "db":
-            # the diff runs HERE, not in the solve rung: route_db is
-            # mutated by this stage, so reading it from the (possibly
-            # concurrent) solve would race in pipelined mode
+            # the diff runs HERE, not in the solve rung: this stage
+            # is where the installed table is adopted (calculate_update
+            # heals identity on it), and the ladder's rungs stay free
+            # of route_db so a failed rung leaves nothing to undo
             with tracer.span("decision.route_diff", trace=trace) as span:
                 update = self.route_db.calculate_update(value)
                 if span is not None:
@@ -916,7 +865,7 @@ class Decision:
         full build (the emit stage diffs it against the installed db)
         or ``("delta", DecisionRouteUpdate)`` for the per-prefix
         incremental pass — so the rung itself never touches route_db
-        and can overlap the previous event's emit."""
+        and a failed rung leaves nothing to undo."""
         flipped = self.spf_solver.backend != backend
         if reset:
             self.spf_solver.reset_device_state()

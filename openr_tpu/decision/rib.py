@@ -189,10 +189,10 @@ class DecisionRouteUpdate:
         )
 
 
-# a passive container with a single owner at any moment: Decision
-# mutates it on whichever role currently drives emission (see
-# Decision.route_db's owner confinement) — it carries no lock of its
-# own by design
+# a passive container with a single owner at any moment: a solve
+# builds one on whatever thread it runs on, and the installed one is
+# mutated on Decision's event base only (see Decision.route_db's
+# confinement) — it carries no lock of its own by design
 @thread_confined("owner", "unicast_routes", "mpls_routes")
 @dataclass
 class DecisionRouteDb:

@@ -167,7 +167,7 @@ SPARSE_NODE_THRESHOLD = 4096
 # Since the telemetry spine landed this is a registry-backed shim: the
 # same `SPF_COUNTERS[k] += 1` / `dict(SPF_COUNTERS)` call sites, but
 # the store of record is openr_tpu.telemetry's process-wide Registry,
-# so OpenrCtrl.get_counters / breeze / bench artifacts see these names
+# so OpenrCtrl.get_counters / breeze / the benchmark see these names
 # without a per-module merge loop.
 from openr_tpu.telemetry import get_registry as _get_registry
 from openr_tpu.telemetry import get_tracer as _get_tracer
@@ -296,7 +296,7 @@ def _local_links_sig(ls: LinkState, node: str) -> tuple:
 def get_spf_counters() -> Dict[str, int]:
     out = dict(SPF_COUNTERS)
     # sharded-dispatch placement/readback counters: surfaced in the
-    # same snapshot so bench artifacts and the reshard-storm runbook
+    # same snapshot so the benchmark and the reshard-storm runbook
     # recipe read one merged view (0 when no mesh ever activated)
     _reg = _get_registry()
     for _k in (

@@ -1,9 +1,10 @@
 """Sustained-load harness: drive the real pipeline at a target rate.
 
 Assembles the actual KvStore → Decision → Fib module pipeline (same
-wiring as the daemon: ReplicateQueues between per-module event bases),
-pumps a seeded ``LoadGenerator`` stream at a target events/s, and
-measures:
+wiring as the daemon: ReplicateQueues between per-module event bases;
+``Decision`` is built as ``daemon.OpenrNode`` builds it, plus an
+``AdmissionControl`` — the one difference, ROADMAP D6), pumps a seeded
+``LoadGenerator`` stream at a target events/s, and measures:
 
 - p50/p95/p99 end-to-end convergence, sampled per retired trace through
   the tracer's finish-listener (the 256-deep export ring overflows in
@@ -19,7 +20,7 @@ whose p99 meets the SLO and whose backlog drains).
 Oracle parity: every published event is journaled; ``check_parity``
 replays the journal — unshedded, single-threaded — through a fresh
 Decision and compares canonical RouteDatabases bit-for-bit, proving
-shed-by-coalescing and pipelined emit never changed net effect.
+shed-by-coalescing never changed net effect.
 """
 
 from __future__ import annotations
@@ -119,7 +120,12 @@ class RateReport:
 
 
 class SustainedLoadHarness:
-    """Owns the pipeline + generator + journal for one load session."""
+    """Owns the pipeline + generator + journal for one load session.
+
+    ``Decision`` gets the keywords ``daemon.OpenrNode`` passes it (the
+    rest at their defaults) plus ``admission``. The defaults here are
+    the tests': ``OpenrConfig`` ships ``solver_backend="device"`` and a
+    250 ms debounce ceiling, which ``chip_smoke.py`` passes explicitly."""
 
     def __init__(
         self,
@@ -130,7 +136,6 @@ class SustainedLoadHarness:
         debounce_min_s: float = 0.010,
         debounce_max_s: float = 0.100,
         admission: Optional[AdmissionConfig] = None,
-        pipelined_emit: bool = True,
         area: str = DEFAULT_AREA,
     ):
         # real-module imports live here so importing openr_tpu.load (as
@@ -157,7 +162,6 @@ class SustainedLoadHarness:
             debounce_max_s=debounce_max_s,
             solver_backend=solver_backend,
             admission=AdmissionControl(admission or AdmissionConfig()),
-            pipelined_emit=pipelined_emit,
         )
         self.fib = Fib(
             self.my_node,
@@ -304,15 +308,16 @@ class SustainedLoadHarness:
 
     def drain(self, timeout_s: float = 60.0) -> bool:
         """Wait for the pipeline to go quiescent: empty Decision reader,
-        no pending debounce, emit stage flushed, Fib caught up."""
+        no pending debounce, no rebuild in flight, Fib caught up."""
         reader = self.decision._kv_reader
         debounce = self.decision._rebuild_debounced
         ok = self._wait_until(
             lambda: reader.size() == 0 and not debounce.is_scheduled(),
             timeout_s,
         )
-        # flush the pipelined emit stage and any queued evb callbacks
-        self.decision.evb.call_and_wait(self.decision._drain_emit)
+        # barrier on the event base: a rebuild that was running when
+        # the predicate turned true has emitted once this returns
+        self.decision.evb.call_and_wait(lambda: None)
         # Fib: its reader must drain too (route programming is the last
         # trace stage)
         reg = get_registry()
@@ -371,11 +376,10 @@ class SustainedLoadHarness:
     def live_route_db(self):
         """The pipeline Decision's installed DecisionRouteDb (call after
         ``drain()``)."""
-        self.decision.evb.call_and_wait(self.decision._drain_emit)
-        return self.decision.route_db
+        return self.decision.evb.call_and_wait(lambda: self.decision.route_db)
 
     def check_parity(self) -> bool:
-        """Shed-by-coalescing + pipelined emit vs the unshedded oracle:
+        """Shed-by-coalescing vs the unshedded oracle:
         the canonical RouteDatabase must match bit for bit. The live
         solve may have run on a different backend than the host oracle —
         cross-backend parity is the parity suite's own guarantee."""

@@ -67,10 +67,9 @@ class Autotuner:
         self._winners: Dict[str, str] = {}
 
     def record(self, kernel: str, shape_key: str, winner: str) -> None:
-        """Adopt an EXTERNALLY measured winner (e.g. bench.py's oracle-
-        gated probe, which times the real reconverge loop rather than a
-        synthetic contraction) — memoized exactly like a ``pick``
-        result for the rest of the process."""
+        """Adopt an EXTERNALLY measured winner (one that timed the real
+        reconverge loop rather than a synthetic contraction) — memoized
+        exactly like a ``pick`` result for the rest of the process."""
         assert kernel in _FAMILY_CANDIDATES, kernel
         assert winner in _FAMILY_CANDIDATES[kernel], (kernel, winner)
         platform = jax.devices()[0].platform

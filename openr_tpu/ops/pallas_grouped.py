@@ -28,9 +28,9 @@ are either multiples of (8, 128) or equal to the full array extent;
 leading block dims are unconstrained. Padding rows/cols are inert
 (weights pad with INF; min ignores them).
 
-Like the dense kernel, selection is BY MEASUREMENT: the scale bench
-times both impls at the segment shapes and runs the winner
-(spf_grouped.set_grouped_impl). ``interpret`` is always passed by the
+Like the dense kernel, selection is explicit
+(spf_grouped.set_grouped_impl; ``"auto"`` measures through
+ops.autotune). ``interpret`` is always passed by the
 caller — True in CPU tests, False on the chip, where ``chip_smoke.py``
 compiles both layouts at the 10k fabric's segment shapes and compares
 them with the jnp contraction bit for bit.

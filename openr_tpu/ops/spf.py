@@ -242,8 +242,8 @@ def source_batch(snap, sid: int):
 
     Returns (real_srcs, padded_device_ids); row i of the kernel output
     corresponds to real_srcs[i] for i < len(real_srcs). This is the one
-    place the batch layout is defined — the solver, the bench, and the
-    tests all share it.
+    place the batch layout is defined — the solver and the tests share
+    it.
     """
     nbrs = sorted({dl.dst_id for dl in snap.links_from[sid]})
     srcs = [sid] + nbrs

@@ -76,8 +76,7 @@ from openr_tpu.ops.spf_sparse import (
 # VMEM tiling); "auto" resolves to a MEASURED winner via ops.autotune
 # (coarse: one representative block shape per platform — the grouped
 # contraction's tiling is dominated by platform, not by the exact
-# segment dims). Like the dense path (ops.spf minplus), the bench also
-# probes all three ON REAL HARDWARE and can pin the winner explicitly.
+# segment dims). Nothing arms "auto" since PR 28 (ROADMAP D3).
 _GROUPED_IMPL = KernelImpl(os.environ.get("OPENR_GROUPED_IMPL", "jnp"))
 
 # representative [B, G, S, R] probe block for the "auto" measurement

@@ -1,10 +1,10 @@
 """Always-on device-time attribution with bounded overhead.
 
 The ROADMAP's open claim — ``host_overhead_ratio`` within 2x of
-``device_only_ms`` — was only checkable by bench-side arithmetic
-(``benchmarks/bench_scale.py:_chained_device_only_ms`` models a chained
-dispatch; nothing measures one). This module makes device time a
-*measured, always-on* output of the dispatch plane itself:
+``device_only_ms`` — was only checkable by arithmetic outside the
+program (a model of a chained dispatch; nothing measured one). This
+module makes device time a *measured, always-on* output of the
+dispatch plane itself:
 
 - every ``aot_call`` dispatch is wall-timed on the host
   (``ops.host_ms.<tag>``), and every ``sample_every``-th call per tag
@@ -21,7 +21,7 @@ dispatch; nothing measures one). This module makes device time a
 - ``dispatch_accounting.event_window`` reports every window's wall
   clock here, so ``ops.host_overhead_ratio`` is a live gauge of
   window-wall over attributed device time — the measured number that
-  replaces the bench-derived one.
+  replaces the modelled one.
 
 Overhead budget (<5% on the churn bench, gated by ``make obs-smoke``):
 the un-sampled path is one ``perf_counter`` pair, one histogram

@@ -6,7 +6,7 @@ gauge, and histogram. Modules keep their historical idioms:
 - legacy module-global counter dicts (``SPF_COUNTERS``,
   ``ELL_COUNTERS``) become ``CounterDict`` shims — same ``d[k] += 1``
   / ``dict(d)`` / ``.items()`` call sites, but the backing store is
-  the registry, so ``OpenrCtrl.get_counters`` and bench artifacts see
+  the registry, so ``OpenrCtrl.get_counters`` and the benchmark see
   them without per-module merge loops;
 - latency distributions are ``Histogram``s over a sliding window of
   the most recent observations, exported as streaming percentiles

@@ -1,7 +1,7 @@
 """Persistent XLA compilation cache, placed from outside the program.
 
-Every jax entry point (``chip_smoke.py``, ``bench.py``, the scale legs,
-the daemon, the test conftest) runs in a fresh process and would re-pay
+Every jax entry point (``chip_smoke.py``, ``chipbench.run``, the
+daemon, the test conftest) runs in a fresh process and would re-pay
 every jit compile; a cold engine build at 10k nodes is mostly compile.
 jax's persistent cache keys executables by computation, platform and
 version, so pointing every process at one directory lets the second one

@@ -1873,9 +1873,8 @@ def test_seeded_registry_unmutated_is_clean(tmp_path):
 
 
 def test_seeded_decision_emit_mu_deletion_trips(tmp_path):
-    # delete the _emit_mu guard on the emit-worker's staleness stamp:
-    # the emit-executor write races the registry gauge read again —
-    # the PR's original Decision._last_good_route_ts race
+    # delete the _emit_mu guard on the emit stage's staleness stamp:
+    # the event-base write races the registry gauge read again
     report = _lint_mutated(
         tmp_path,
         [DECISION_PY],
@@ -1896,10 +1895,62 @@ def test_seeded_decision_emit_mu_deletion_trips(tmp_path):
         for f in hits
         if "Decision._last_good_route_ts" in f.message
     )
-    # the first convicting pair is the eager-mode event-base write vs
-    # the emit-worker write; the gauge read pairs too, but one finding
-    # per attribute keeps the report readable
-    assert "evb" in msg and "ex:Decision._emit_executor" in msg, msg
+    # the convicting pair: the event-base write in _emit_update
+    # against the gauge thread's read in _route_staleness_ms
+    assert "role evb" in msg and "role registry.gauge" in msg, msg
+
+
+def test_seeded_decision_emit_worker_fork_trips(tmp_path):
+    # fork the emit stage by mode again (worker thread when the
+    # executor exists, inline otherwise): route_db then has a writer
+    # under two roles and no annotation exempts it, so the fork cannot
+    # come back unseen
+    def mutate(src):
+        src = src.replace(
+            "import threading\n",
+            "import threading\n"
+            "from concurrent.futures import ThreadPoolExecutor\n",
+            1,
+        )
+        src = src.replace(
+            "        self._emit_mu = threading.Lock()\n",
+            "        self._emit_mu = threading.Lock()\n"
+            "        self._emit_pool = ThreadPoolExecutor(max_workers=1)\n",
+            1,
+        )
+        return src.replace(
+            "        self._emit_update(payload, trace, rebuild_span, "
+            "perf_events)\n",
+            "        if self._emit_pool is not None:\n"
+            "            self._emit_pool.submit(\n"
+            "                self._emit_update, payload, trace,"
+            " rebuild_span, perf_events\n"
+            "            )\n"
+            "        else:\n"
+            "            self._emit_update(payload, trace, rebuild_span,"
+            " perf_events)\n",
+            1,
+        )
+
+    report = _lint_mutated(tmp_path, [DECISION_PY], "decision.py", mutate)
+    hits = rule_hits(report, "shared-state")
+    assert any("Decision.route_db" in f.message for f in hits), [
+        str(f) for f in hits
+    ]
+    msg = next(f.message for f in hits if "Decision.route_db" in f.message)
+    assert "ex:Decision._emit_pool" in msg and "evb" in msg, msg
+
+
+def test_decision_unmutated_is_clean(tmp_path):
+    report = _lint_mutated(
+        tmp_path,
+        [DECISION_PY],
+        "decision.py",
+        lambda src: src + "\n# trailing comment\n",
+    )
+    assert rule_hits(report, "shared-state") == [], [
+        str(f) for f in rule_hits(report, "shared-state")
+    ]
 
 
 def test_seeded_service_unmutated_is_clean(tmp_path):

@@ -368,3 +368,124 @@ class TestDecisionKsp2Engine:
             assert reuses > 0, "no routes reused through the daemon path"
         finally:
             h.stop()
+
+
+# ---------------------------------------------------------------------------
+# the constructor the daemon uses; route_db's one owner
+# ---------------------------------------------------------------------------
+
+# Decision keywords that daemon.OpenrNode does not pass, each with the
+# reason it may stay. Anything else needs a production caller.
+_NOT_FROM_THE_DAEMON = {
+    "admission": "load/harness.py passes it; whether the daemon wires "
+    "AdmissionControl is ROADMAP D6",
+    "state_plane": "durability plane (state/, recovery tests); ROADMAP "
+    "D8/R9",
+    "cold_start_s": "upstream's eor_time_s; passed by nobody today: wire "
+    "it from OpenrConfig or remove it, ROADMAP D5",
+}
+
+
+def _decision_keywords(module):
+    """What ``module`` passes to its first ``Decision(...)`` call (the
+    pipeline's), read off its source: (positional count, keyword
+    names)."""
+    import ast
+    import inspect
+
+    calls = [
+        n for n in ast.walk(ast.parse(inspect.getsource(module)))
+        if isinstance(n, ast.Call)
+        and isinstance(n.func, ast.Name)
+        and n.func.id == "Decision"
+    ]
+    call = min(calls, key=lambda n: n.lineno)
+    assert all(kw.arg is not None for kw in call.keywords), "no **kwargs"
+    return len(call.args), {kw.arg for kw in call.keywords}
+
+
+class TestDecisionConstructor:
+    def test_every_keyword_has_a_production_caller(self):
+        import inspect
+
+        from openr_tpu import daemon
+
+        params = list(inspect.signature(Decision.__init__).parameters)[1:]
+        n_positional, passed = _decision_keywords(daemon)
+        passed |= set(params[:n_positional])
+        assert passed <= set(params), passed - set(params)
+        unexplained = set(params) - passed - set(_NOT_FROM_THE_DAEMON)
+        assert not unexplained, (
+            f"Decision.__init__ takes {sorted(unexplained)}, which "
+            "daemon.OpenrNode never passes: an option needs a production "
+            "caller"
+        )
+        # the exemption list does not outlive its entries
+        assert set(_NOT_FROM_THE_DAEMON) <= set(params) - passed
+
+    def test_load_harness_builds_decision_as_the_daemon_plus_admission(self):
+        """What load/harness.py's docstring says, held: its pipeline's
+        Decision gets no keyword the daemon does not pass, admission
+        aside (ROADMAP D6)."""
+        from openr_tpu import daemon
+        from openr_tpu.load import harness as load_harness
+
+        _, from_daemon = _decision_keywords(daemon)
+        n_positional, from_harness = _decision_keywords(load_harness)
+        assert n_positional == 1  # my_node_name
+        assert from_harness - from_daemon == {"admission"}
+
+
+class TestRouteDbOwnership:
+    def test_route_db_is_touched_on_the_event_base_only(self, harness):
+        """Through the real queues, every diff against and every
+        mutation of the installed table runs on Decision's event-base
+        thread."""
+        import threading
+        from dataclasses import replace
+
+        seen = []
+        db = harness.decision.route_db
+        for name in ("update", "calculate_update"):
+            def wrapper(*a, _real=getattr(db, name), _name=name, **kw):
+                seen.append((_name, threading.current_thread().name))
+                return _real(*a, **kw)
+
+            setattr(db, name, wrapper)
+
+        topo = line_topology()
+        harness.publish_topology(topo)
+        assert harness.drain_updates()
+        b = topo.adj_dbs["b"]
+        for bump in (3, 5, 7):  # three debounced rebuilds, one by one
+            harness.publish_adj(replace(b, adjacencies=tuple(
+                replace(adj, metric=adj.metric + bump)
+                for adj in b.adjacencies
+            )))
+            assert harness.drain_updates(first_timeout=5.0)
+
+        assert sum(n == "update" for n, _ in seen) >= 4
+        assert sum(n == "calculate_update" for n, _ in seen) >= 3
+        assert {t for _, t in seen} == {"decision:a"}, seen
+
+    def test_stop_leaves_no_decision_thread(self):
+        import threading
+
+        before = set(threading.enumerate())
+        h = DecisionHarness("a")
+        started = {
+            t for t in set(threading.enumerate()) - before
+            if t.name.startswith("decision")
+        }
+        assert started, "the event base and its reader run as threads"
+        try:
+            h.publish_topology(line_topology())
+            assert h.drain_updates()
+            started |= {
+                t for t in set(threading.enumerate()) - before
+                if t.name.startswith("decision")
+            }
+        finally:
+            h.stop()
+        alive = [t.name for t in started if t.is_alive()]
+        assert alive == [], alive

@@ -41,9 +41,12 @@ def _engine_everywhere(monkeypatch):
     monkeypatch.setenv("OPENR_KSP2_FAST", "1")
 
 
-def _ksp2_network(kind: str, n: int):
+def _ksp2_network(
+    kind: str, n: int,
+    algorithm=PrefixForwardingAlgorithm.KSP2_ED_ECMP,
+):
     kwargs = dict(
-        forwarding_algorithm=PrefixForwardingAlgorithm.KSP2_ED_ECMP,
+        forwarding_algorithm=algorithm,
         forwarding_type=PrefixForwardingType.SR_MPLS,
     )
     topo = (
@@ -92,6 +95,75 @@ def _set_overload(ls, node, overloaded):
 def _set_label(ls, node, label):
     db = ls.get_adjacency_databases()[node]
     ls.update_adjacency_database(replace(db, node_label=label))
+
+
+def _ksp2_churn(nodes, events, ksp2_dst_count=0, sp_only=False):
+    """Fabric metric churn through the full SpfSolver (device backend)
+    from an rsw's vantage: one fsw adjacency cycling its metric, one
+    rebuild per event after a warm metric cycle. Returns counter deltas
+    over the ``events`` rebuilds.
+
+    ``ksp2_dst_count`` > 0 marks only that many (evenly sampled)
+    prefixes KSP2_ED_ECMP and leaves the rest SP_ECMP — KSP2 is a
+    per-prefix opt-in, and this is the shape that takes the engine past
+    4096 nodes: the all-pairs dispatch covers the whole graph while host
+    path tracing stays bounded by the KSP2 destination count.
+    ``sp_only`` keeps every prefix SP_ECMP (no engine state at all)."""
+    all_ksp2 = ksp2_dst_count <= 0 and not sp_only
+    topo, area_ls, ps = _ksp2_network(
+        "fabric",
+        nodes,
+        algorithm=(
+            PrefixForwardingAlgorithm.KSP2_ED_ECMP
+            if all_ksp2
+            else PrefixForwardingAlgorithm.SP_ECMP
+        ),
+    )
+    (ls,) = area_ls.values()
+    if ksp2_dst_count > 0:
+        names = sorted(topo.prefix_dbs)
+        stride = max(1, len(names) // ksp2_dst_count)
+        for name in names[::stride][:ksp2_dst_count]:
+            pdb = topo.prefix_dbs[name]
+            ps.update_prefix_database(replace(
+                pdb,
+                prefix_entries=tuple(
+                    replace(
+                        e,
+                        forwarding_algorithm=(
+                            PrefixForwardingAlgorithm.KSP2_ED_ECMP
+                        ),
+                    )
+                    for e in pdb.prefix_entries
+                ),
+            ))
+    rsw = next(k for k in sorted(topo.adj_dbs) if k.startswith("rsw"))
+    fsw = next(k for k in sorted(topo.adj_dbs) if k.startswith("fsw"))
+    solver = SpfSolver(rsw, backend="device")
+    solver.build_route_db(rsw, area_ls, ps)
+    # one full metric cycle first: the engine's cold build and every
+    # masked-batch bucket exist before the counted events
+    for step in range(5):
+        _mutate_metric(ls, fsw, 0, 2 + step % 5)
+        solver.build_route_db(rsw, area_ls, ps)
+    before = dict(SPF_COUNTERS)
+    applied = 0
+    for step in range(events):
+        _mutate_metric(ls, fsw, 0, 2 + step % 5)
+        applied += solver.build_route_db(rsw, area_ls, ps) is not None
+
+    def delta(name):
+        return SPF_COUNTERS[name] - before[name]
+
+    return {
+        "events": applied,
+        "ksp2_host_fallbacks": delta("decision.ksp2_host_fallbacks"),
+        "incremental_syncs": delta("decision.ksp2_incremental_syncs"),
+        "ksp2_device_batches": delta("decision.ksp2_device_batches"),
+        "sp_route_reuses_per_event": (
+            delta("decision.sp_route_reuses") / max(1, events)
+        ),
+    }
 
 
 class TestEngineChurnParity:
@@ -750,13 +822,30 @@ class TestEngineBeyondLegacyBound:
         opt-in, so destinations are a subset while the graph is big.
         (~15 s on CPU: each event is one [4224, 4224] all-pairs
         dispatch — single-digit ms on a real accelerator.)"""
-        from openr_tpu.decision import ksp2_engine
-        from benchmarks.bench_scale import ksp2_churn_bench
-
         assert ksp2_engine.ENGINE_MAX_NODES > 4096
-        result = ksp2_churn_bench(4200, 1, ksp2_dst_count=128)
+        result = _ksp2_churn(4200, 1, ksp2_dst_count=128)
         assert result["ksp2_host_fallbacks"] == 0
         assert result["incremental_syncs"] >= 1, result
+
+
+class TestKsp2ChurnLeg:
+    def test_ksp2_churn_smoke(self):
+        """All-KSP2 fabric churn runs end to end: engine churn rebuilds
+        with zero host fallbacks on a parallel-link-free fabric."""
+        out = _ksp2_churn(120, 3)
+        assert out["events"] == 3
+        assert out["ksp2_host_fallbacks"] == 0
+        assert out["incremental_syncs"] == 3
+
+    def test_sp_only_churn_smoke(self):
+        """Full-SPF reconvergence of one node's RouteDb, every prefix
+        SP_ECMP: no KSP2 engine state at all, host rebuild bounded by
+        the SP route reuse dirty test."""
+        out = _ksp2_churn(120, 3, sp_only=True)
+        assert out["events"] == 3
+        assert out["ksp2_device_batches"] == 0
+        assert out["incremental_syncs"] == 0  # no engine in play
+        assert out["sp_route_reuses_per_event"] > 50
 
 
 class TestBandWideningOnSolverPath:

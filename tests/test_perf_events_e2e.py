@@ -12,7 +12,7 @@ reports against:
   program),
 - with the device backend the trace is the whole span tree (queue
   waits, route build with the view's sync / dispatch / readback inside
-  it, diff, emit), well nested in eager and in pipelined-emit mode.
+  it, diff, emit), well nested in both solver formulations.
 """
 
 import dataclasses
@@ -46,9 +46,7 @@ class PipelineHarness:
     """KvStore -> Decision -> Fib wired through real queues (host
     solver: these tests assert accounting, not kernels)."""
 
-    def __init__(
-        self, my_node="a", solver_backend="host", pipelined_emit=False
-    ):
+    def __init__(self, my_node="a", solver_backend="host"):
         self.store = KvStoreWrapper(f"store:{my_node}")
         self.route_q = ReplicateQueue(name="routeUpdates")
         self.decision = Decision(
@@ -58,7 +56,6 @@ class PipelineHarness:
             debounce_min_s=0.05,
             debounce_max_s=0.25,
             solver_backend=solver_backend,
-            pipelined_emit=pipelined_emit,
         )
         self.agent = MockFibAgent()
         self.fib = Fib(
@@ -275,13 +272,9 @@ def _assert_tree(trace, expected):
 
 
 class TestSpanTreeEndToEnd:
-    @pytest.mark.parametrize(
-        "formulation,pipelined_emit",
-        [("dense", False), ("dense", True), ("ell", False)],
-        ids=["dense-eager", "dense-pipelined_emit", "ell-eager"],
-    )
+    @pytest.mark.parametrize("formulation", ["dense", "ell"])
     def test_device_pipeline_yields_the_whole_tree(
-        self, formulation, pipelined_emit, monkeypatch
+        self, formulation, monkeypatch
     ):
         reg, tracer = get_registry(), get_tracer()
         if formulation == "ell":
@@ -310,9 +303,7 @@ class TestSpanTreeEndToEnd:
                 t.to_dict() for t in tracer.traces()[-3:]]
             return find()[-1]
 
-        h = PipelineHarness(
-            solver_backend="device", pipelined_emit=pipelined_emit
-        )
+        h = PipelineHarness(solver_backend="device")
         try:
             topo = line_topology()
             for db in topo.adj_dbs.values():

@@ -1,8 +1,8 @@
 """Service-plane tests: queue backpressure instrumentation, the
 rate-adaptive debounce FSM, shed-by-coalescing admission (oracle
 parity: a seeded overload burst with shedding produces a RouteDatabase
-bit-identical to the unshedded replay), the pipelined Decision emit
-stage, the debounce-span reclaim path, the seedable load generator with
+bit-identical to the unshedded replay), Decision's emit stage, the
+debounce-span reclaim path, the seedable load generator with
 its ``load.generator`` fault seam, and a short end-to-end sustained run
 through the real KvStore→Decision→Fib pipeline."""
 
@@ -375,54 +375,22 @@ class TestAdmissionParity:
 
 
 # ---------------------------------------------------------------------------
-# pipelined emit
+# emit stage
 # ---------------------------------------------------------------------------
 
 
-class TestPipelinedEmit:
-    def test_pipelined_matches_eager_bit_identical(self):
-        topo = topologies.fat_tree_nodes(24)
-        node = next(n for n in sorted(topo.adj_dbs) if n.startswith("rsw"))
-
-        def run(pipelined):
-            gen = LoadGenerator(topo, seed=SEED + 2)
-            d = _decision(node, pipelined_emit=pipelined)
-            reader = d.route_updates_queue.get_reader("tst:collect")
-            d.process_publication(
-                Publication(
-                    key_vals=dict(gen.initial_key_vals()), area=topo.area
-                )
-            )
-            d.rebuild_routes("INIT")
-            for ev in gen.events(25):
-                d.process_publication(_event_pub(ev, topo.area))
-                d.rebuild_routes("STEP")
-            d._drain_emit()
-            pushed = []
-            while True:
-                item = reader.try_get()
-                if item is None:
-                    break
-                pushed.append(item)
-            return _route_db_bytes(d, node), len(pushed)
-
-        eager_db, eager_n = run(False)
-        piped_db, piped_n = run(True)
-        assert eager_db == piped_db
-        assert eager_n == piped_n
-
+class TestEmitStage:
     def test_emit_stage_closes_rebuild_span(self):
         topo = topologies.fat_tree_nodes(24)
         node = next(n for n in sorted(topo.adj_dbs) if n.startswith("rsw"))
         gen = LoadGenerator(topo, seed=SEED)
-        d = _decision(node, pipelined_emit=True)
+        d = _decision(node)
         d.process_publication(
             Publication(key_vals=dict(gen.initial_key_vals()), area=topo.area)
         )
         trace = get_tracer().start("kvstore.publish")
         d.pending.adopt_trace(trace)
         d.rebuild_routes("STEP")
-        d._drain_emit()
         assert all(s.closed for s in trace.spans)
         assert trace.well_formed()
 
@@ -580,7 +548,6 @@ class TestSustainedMiniRun:
             solver_backend="host",
             debounce_max_s=0.05,
             admission=AdmissionConfig(shed_depth=4, cap_s=0.4),
-            pipelined_emit=True,
         )
         h.start(initial_timeout_s=120.0)
         try:
