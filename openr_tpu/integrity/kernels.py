@@ -172,8 +172,7 @@ def world_cold_slot(src, w, overloaded, srcs):
     n = src.shape[0]
     unit = jnp.full((s, n), INF, dtype=jnp.int32)
     unit = unit.at[jnp.arange(s), srcs].set(0)
-    no_overload = jnp.zeros_like(overloaded)
-    d0 = spf_sparse._uniform_relax(unit, src, w, no_overload)
+    d0 = spf_sparse._uniform_relax(unit, src, w, None)
 
     def cond(state):
         _, changed, it = state

@@ -14,11 +14,17 @@ Dijkstra (openr/decision/LinkState.cpp:809 runSpf) in diameter steps
 inside a ``lax.while_loop``.
 
 Semantics parity with the dense kernels:
-- transit exclusion: out-edges of overloaded nodes are dropped from the
-  relaxation edge list; the *initial* rows are produced by one
-  relaxation over the FULL edge list from the unit init (diagonal 0),
-  which equals the sources' direct-edge rows — so an overloaded source
-  still originates (reference: LinkState.cpp:831-838).
+- transit exclusion: out-edges of overloaded nodes never extend a
+  path. The mask sits on the DISTANCES, not the edges: every relax
+  reads ``where(overloaded[None, :], INF, d)`` at the edges' tails
+  (_mask_transit_cols, the sparse twin of ops.spf._mask_transit_rows),
+  one select over [S, N] per pass. Masking the edge slots instead
+  (the weight of every edge whose tail is overloaded set to INF) is
+  the same term bit for bit but a scalar gather per edge per pass,
+  which was 81% of the device's time at 4992 nodes. The *initial* rows
+  are one relaxation with no mask at all from the unit init (diagonal
+  0), which equals the sources' direct-edge rows — so an overloaded
+  source still originates (reference: LinkState.cpp:831-838).
 - hop-count mode: all edge weights 1.
 - INF saturation: d + w clips at INF = 2**30 - 1 (int32-safe).
 
@@ -714,18 +720,34 @@ def direct_metrics(graph: EllGraph, src_id: int, node_ids) -> np.ndarray:
     return out
 
 
+def _mask_transit_cols(d, overloaded):
+    """Distance columns of overloaded nodes read as INF: the rows a relax
+    gathers for their out-edges then never extend a path. One select
+    over [S, N]; ``overloaded=None`` is the unmasked (origination) relax
+    and applies nothing, decided at trace time."""
+    if overloaded is None:
+        return d
+    return jnp.where(overloaded[None, :], INF, d)
+
+
 def _ell_relax(d, bands, srcs_t, ws_t, overloaded):
     """One masked relaxation over the class bands: [S, N] -> [S, N] as
     pure gather + reduce per band, writing contiguous output slices.
-    Edges originating at overloaded nodes never extend paths."""
+    Edges originating at overloaded nodes never extend paths: the mask
+    sits on the distance columns the gather reads (_mask_transit_cols),
+    not on the edge slots. min(INF + w, INF) and min(d + INF, INF) are
+    the same INF, and a mask looked up per edge slot is a scalar gather
+    per edge per pass: 7 ms of the 8.9 ms the chip was busy in a
+    4992-node solve. Each band's own columns take the min with the
+    UNMASKED d."""
+    d_t = _mask_transit_cols(d, overloaded)
     parts = []
     pos = 0
     for band, s_b, w_b in zip(bands, srcs_t, ws_t):
         assert band.start == pos, (band, pos)
-        w_eff = jnp.where(overloaded[s_b], INF, w_b)  # [rows, k]
-        gathered = d[:, s_b]  # [S, rows, k]
+        gathered = d_t[:, s_b]  # [S, rows, k]
         relaxed = jnp.min(
-            jnp.minimum(gathered + w_eff[None, :, :], INF), axis=2
+            jnp.minimum(gathered + w_b[None, :, :], INF), axis=2
         )
         parts.append(
             jnp.minimum(d[:, pos : pos + band.rows], relaxed.astype(jnp.int32))
@@ -783,8 +805,7 @@ def _ell_view_batch(srcs_t, ws_t, overloaded, srcs, w_sv, bands, n):
     unit = jnp.full((b, n), INF, dtype=jnp.int32)
     unit = unit.at[jnp.arange(b), srcs].set(0)
     # init rows: one UNMASKED relax (overloaded sources still originate)
-    no_overload = jnp.zeros_like(overloaded)
-    d0 = _ell_relax(unit, bands, srcs_t, ws_t, no_overload)
+    d0 = _ell_relax(unit, bands, srcs_t, ws_t, None)
 
     def cond(state):
         _, changed, it = state
@@ -870,8 +891,8 @@ def _ell_reconverge(srcs_t, ws_t, patch_ids_t, patch_src_t, patch_w_t,
     b = srcs.shape[0]
     unit = jnp.full((b, n), INF, dtype=jnp.int32)
     unit = unit.at[jnp.arange(b), srcs].set(0)
-    no_overload = jnp.zeros_like(overloaded)
-    d0 = _ell_relax(unit, bands, new_src, new_w, no_overload)
+    # init rows: one UNMASKED relax (overloaded sources still originate)
+    d0 = _ell_relax(unit, bands, new_src, new_w, None)
     seed = _warm_seed(d_prev, inc_tail, inc_head, inc_w, d0)
 
     def cond(state):
@@ -946,8 +967,7 @@ def _ell_fixed_point(srcs_t, ws_t, overloaded, src_ids, bands, n,
     s = src_ids.shape[0]
     unit = jnp.full((s, n), INF, dtype=jnp.int32)
     unit = unit.at[jnp.arange(s), src_ids].set(0)
-    no_overload = jnp.zeros_like(overloaded)
-    d0 = _ell_relax(unit, bands, srcs_t, ws_t, no_overload)
+    d0 = _ell_relax(unit, bands, srcs_t, ws_t, None)
     if warm is not None:
         d_prev, inc_tail, inc_head, inc_w = warm
         d0 = _warm_seed(d_prev, inc_tail, inc_head, inc_w, d0)
@@ -1039,14 +1059,15 @@ def ell_all_sources(graph: EllGraph, block: int = 2048) -> np.ndarray:
 def _ell_relax_masked(d, bands, srcs_t, ws_t, masks_t, overloaded):
     """One relaxation with a PER-BATCH edge mask: [B, N] -> [B, N].
     masks_t[bi] is [B, rows, k] bool — True == edge excluded for that
-    batch element (the KSP2 edge-disjoint second-path graphs)."""
+    batch element (the KSP2 edge-disjoint second-path graphs). The
+    overload mask sits on the distance columns, as in _ell_relax."""
+    d_t = _mask_transit_cols(d, overloaded)
     parts = []
     pos = 0
     for band, s_b, w_b, m_b in zip(bands, srcs_t, ws_t, masks_t):
         assert band.start == pos, (band, pos)
-        w_eff = jnp.where(overloaded[s_b], INF, w_b)  # [rows, k]
-        w_batched = jnp.where(m_b, INF, w_eff[None, :, :])  # [B, rows, k]
-        gathered = d[:, s_b]  # [B, rows, k]
+        w_batched = jnp.where(m_b, INF, w_b[None, :, :])  # [B, rows, k]
+        gathered = d_t[:, s_b]  # [B, rows, k]
         relaxed = jnp.min(
             jnp.minimum(gathered + w_batched, INF), axis=2
         )
@@ -1072,8 +1093,7 @@ def _ell_masked_fixed_point(srcs_t, ws_t, masks_t, overloaded, src_id,
     b = masks_t[0].shape[0]
     unit = jnp.full((b, n), INF, dtype=jnp.int32)
     unit = unit.at[:, src_id].set(0)
-    no_overload = jnp.zeros_like(overloaded)
-    d0 = _ell_relax_masked(unit, bands, srcs_t, ws_t, masks_t, no_overload)
+    d0 = _ell_relax_masked(unit, bands, srcs_t, ws_t, masks_t, None)
 
     def cond(state):
         _, changed, it = state
@@ -2140,10 +2160,9 @@ def _uniform_relax(d, src, w, overloaded):
     as one gather + K-reduce (the single-band special case of
     _ell_relax — identical algebra, so fixed points agree bit-for-bit).
     Edges originating at overloaded nodes never extend paths."""
-    w_eff = jnp.where(overloaded[src], INF, w)  # [n, k]
-    gathered = d[:, src]  # [S, n, k]
+    gathered = _mask_transit_cols(d, overloaded)[:, src]  # [S, n, k]
     relaxed = jnp.min(
-        jnp.minimum(gathered + w_eff[None, :, :], INF), axis=2
+        jnp.minimum(gathered + w[None, :, :], INF), axis=2
     )
     return jnp.minimum(d, relaxed.astype(jnp.int32))
 
@@ -2183,8 +2202,7 @@ def _tenant_view_solve(src, w, overloaded, srcs, p_rows, p_src, p_w,
     unit = jnp.full((s, n), INF, dtype=jnp.int32)
     unit = unit.at[jnp.arange(s), srcs].set(0)
     # init rows: one UNMASKED relax (overloaded sources still originate)
-    no_overload = jnp.zeros_like(overloaded)
-    d0 = _uniform_relax(unit, src, w, no_overload)
+    d0 = _uniform_relax(unit, src, w, None)
     seed = _warm_seed(d_prev, inc_t, inc_h, inc_w, d0)
 
     def cond(state):

@@ -505,3 +505,246 @@ class TestShardedMaskedBatch:
             graph, sid, masks, mesh
         )
         assert (sharded == single).all()
+
+
+# --- where the overload mask sits (PR 29) -----------------------------
+#
+# The three relaxes mask the DISTANCE columns of overloaded nodes
+# (spf_sparse._mask_transit_cols). The plain reference below masks the
+# EDGE slots instead, in numpy: where(overloaded[src], INF, w). Term by
+# term the two are the same int32, so every pass and the fixed point
+# must agree bit for bit.
+
+_MASK_BANDS = (
+    spf_sparse.EllBand(start=0, rows=24, k=4),
+    spf_sparse.EllBand(start=24, rows=10, k=8),
+)
+_MASK_N_PAD = 40  # 34 real rows + 6 padding columns
+_MASK_ISLAND = (30, 31, 32, 33)  # edges among themselves only
+_MASK_MAIN = [v for v in range(34) if v not in _MASK_ISLAND]
+_MASK_SOURCES = (0, 7, 25, 30)  # batch rows; 30 starts inside the island
+
+
+def _mask_case(scenario: str, seed: int):
+    """A seeded random banded ELL graph with the property the scenario
+    names. Returns (src bands, w bands, overloaded or None, extras)."""
+    rng = np.random.default_rng(seed)
+    main = np.array(_MASK_MAIN, dtype=np.int32)
+    src, w = [], []
+    for band in _MASK_BANDS:
+        ids = np.arange(band.start, band.start + band.rows, dtype=np.int32)
+        s = rng.choice(main, size=(band.rows, band.k)).astype(np.int32)
+        ww = rng.integers(1, 20, size=(band.rows, band.k)).astype(np.int32)
+        for r, v in enumerate(ids):
+            if v in _MASK_ISLAND:
+                s[r] = rng.choice(_MASK_ISLAND, size=band.k)
+        # padding slots: a self-loop at INF, at least one per row
+        pad = rng.random((band.rows, band.k)) < 0.25
+        pad[:, -1] = True
+        s = np.where(pad, ids[:, None], s)
+        ww = np.where(pad, INF, ww)
+        src.append(s)
+        w.append(ww)
+    overloaded = np.zeros(_MASK_N_PAD, dtype=bool)
+    extras = {}
+    if scenario == "transit":
+        # the node most edges leave from
+        tails = np.concatenate([s.ravel() for s in src])
+        hub = int(np.bincount(tails, minlength=34)[1:30].argmax()) + 1
+        overloaded[hub] = True
+        extras["hub"] = hub
+    elif scenario == "source":
+        overloaded[list(_MASK_SOURCES[:2])] = True
+    elif scenario == "fed_by_overloaded":
+        # node 12's only in-edges come from overloaded nodes 3 and 4,
+        # and it is overloaded itself
+        src[0][12, :3] = (3, 4, 3)
+        w[0][12, :3] = (2, 5, 9)
+        overloaded[[3, 4, 12]] = True
+    elif scenario == "padding":
+        # a row of nothing but padding, and overloaded nodes beside it
+        src[0][5] = 5
+        w[0][5] = INF
+        overloaded[[2, 26]] = True
+    elif scenario == "unreachable":
+        overloaded[[1, 31]] = True
+    else:
+        assert scenario == "no_mask", scenario
+        overloaded = None
+    return src, w, overloaded, extras
+
+
+def _edge_side_relax(d, src, w, overloaded, masks=None):
+    """The plain reference: mask the edge slots, one band at a time."""
+    parts, pos = [], 0
+    for bi, (s_b, w_b) in enumerate(zip(src, w)):
+        w_eff = w_b if overloaded is None else np.where(
+            overloaded[s_b], INF, w_b
+        )
+        w_eff = w_eff[None, :, :]
+        if masks is not None:
+            w_eff = np.where(masks[bi], INF, w_eff)
+        cand = np.minimum(d[:, s_b].astype(np.int64) + w_eff, INF)
+        rows = s_b.shape[0]
+        parts.append(np.minimum(d[:, pos : pos + rows], cand.min(axis=2)))
+        pos += rows
+    parts.append(d[:, pos:])
+    return np.concatenate(parts, axis=1).astype(np.int32)
+
+
+def _as_uniform(src, w):
+    """The bands as one [n_pad, k_max] block, self-loop/INF padded."""
+    k = max(s.shape[1] for s in src)
+    u_src = np.tile(np.arange(_MASK_N_PAD, dtype=np.int32)[:, None], (1, k))
+    u_w = np.full((_MASK_N_PAD, k), INF, dtype=np.int32)
+    pos = 0
+    for s_b, w_b in zip(src, w):
+        rows, kb = s_b.shape
+        u_src[pos : pos + rows, :kb] = s_b
+        u_w[pos : pos + rows, :kb] = w_b
+        pos += rows
+    return u_src, u_w
+
+
+class TestOverloadMaskSitsOnDistances:
+    @pytest.mark.parametrize("seed", [11, 2900000029])
+    @pytest.mark.parametrize("scenario", [
+        "transit", "source", "fed_by_overloaded", "padding",
+        "unreachable", "no_mask",
+    ])
+    @pytest.mark.parametrize("kind", ["ell", "masked", "uniform"])
+    def test_bit_identical_to_edge_side_reference(self, kind, scenario, seed):
+        src, w, overloaded, extras = _mask_case(scenario, seed)
+        rng = np.random.default_rng(seed + 1)
+        srcs_t = tuple(jnp.asarray(s) for s in src)
+        ws_t = tuple(jnp.asarray(x) for x in w)
+        ov_dev = None if overloaded is None else jnp.asarray(overloaded)
+        b = len(_MASK_SOURCES)
+        masks = None
+        if kind == "masked":
+            masks = [
+                rng.random((b,) + s.shape) < 0.15 for s in src
+            ]
+            masks_t = tuple(jnp.asarray(m) for m in masks)
+
+            def relax(d, ov):
+                return spf_sparse._ell_relax_masked(
+                    d, _MASK_BANDS, srcs_t, ws_t, masks_t, ov
+                )
+        elif kind == "uniform":
+            u_src, u_w = (jnp.asarray(x) for x in _as_uniform(src, w))
+
+            def relax(d, ov):
+                return spf_sparse._uniform_relax(d, u_src, u_w, ov)
+        else:
+            def relax(d, ov):
+                return spf_sparse._ell_relax(
+                    d, _MASK_BANDS, srcs_t, ws_t, ov
+                )
+
+        # one pass from arbitrary rows (INF among them)
+        d_any = rng.integers(0, 60, size=(b, _MASK_N_PAD)).astype(np.int32)
+        d_any[rng.random(d_any.shape) < 0.3] = INF
+        got = np.asarray(relax(jnp.asarray(d_any), ov_dev))
+        want = _edge_side_relax(d_any, src, w, overloaded, masks)
+        assert got.dtype == np.int32
+        assert (got == want).all()
+
+        # to the fixed point: the unmasked origination pass, then masked
+        # passes until nothing moves — every pass compared
+        unit = np.full((b, _MASK_N_PAD), INF, dtype=np.int32)
+        unit[np.arange(b), list(_MASK_SOURCES)] = 0
+        d_got = np.asarray(relax(jnp.asarray(unit), None))
+        d_want = _edge_side_relax(unit, src, w, None, masks)
+        assert (d_got == d_want).all()
+        for _ in range(_MASK_N_PAD):
+            nxt_got = np.asarray(relax(jnp.asarray(d_got), ov_dev))
+            nxt_want = _edge_side_relax(d_want, src, w, overloaded, masks)
+            assert (nxt_got == nxt_want).all()
+            if (nxt_want == d_want).all():
+                break
+            d_got, d_want = nxt_got, nxt_want
+        else:
+            raise AssertionError("no fixed point in n passes")
+        d = d_got
+
+        # what each scenario is there for
+        assert (d[:, 34:] == INF).all()  # padding columns never move
+        assert (d[:3][:, list(_MASK_ISLAND)] == INF).all()
+        assert (d[3, _MASK_MAIN] == INF).all()
+        direct = _edge_side_relax(unit, src, w, None, masks)
+        free = direct
+        for _ in range(_MASK_N_PAD):  # the same graph, nobody overloaded
+            free = _edge_side_relax(free, src, w, None, masks)
+        assert (d >= free).all()
+        if scenario == "no_mask":
+            assert (d == free).all()
+        if scenario == "transit":
+            # paths through the hub are gone, paths to it are not
+            assert (d > free).any()
+            assert (d[:, extras["hub"]] == free[:, extras["hub"]]).all()
+        if scenario == "source":
+            # an overloaded source still originates
+            assert (direct[:2] < INF).sum() > 2
+            assert (d[:2] <= direct[:2]).all()
+        if scenario == "fed_by_overloaded":
+            # reached only as a direct neighbour of a source, and no
+            # batch source is 3 or 4
+            assert (d[:, 12] == INF).all()
+            assert (free[:3, 12] < INF).any()
+        if scenario == "padding":
+            assert (d[:, 5] == INF).all()
+
+    @pytest.mark.parametrize("seed", [5, 2900000031])
+    def test_fixed_point_entry_points_agree_with_reference(self, seed):
+        """The jitted loops (which pass no mask for the origination
+        pass) reach the edge-side reference's fixed point."""
+        src, w, overloaded, _ = _mask_case("source", seed)
+        overloaded[[9, 27]] = True
+        srcs_t = tuple(jnp.asarray(s) for s in src)
+        ws_t = tuple(jnp.asarray(x) for x in w)
+        ov = jnp.asarray(overloaded)
+        b = len(_MASK_SOURCES)
+        unit = np.full((b, _MASK_N_PAD), INF, dtype=np.int32)
+        unit[np.arange(b), list(_MASK_SOURCES)] = 0
+
+        def fixed_point(masks=None, rows=unit):
+            d = _edge_side_relax(rows, src, w, None, masks)
+            while True:
+                nxt = _edge_side_relax(d, src, w, overloaded, masks)
+                if (nxt == d).all():
+                    return d
+                d = nxt
+
+        want = fixed_point()
+        got = np.asarray(spf_sparse._ell_from_sources(
+            srcs_t, ws_t, ov, jnp.asarray(_MASK_SOURCES, dtype=jnp.int32),
+            _MASK_BANDS, _MASK_N_PAD,
+        ))
+        assert (got == want).all()
+
+        rng = np.random.default_rng(seed)
+        masks = [rng.random((b,) + s.shape) < 0.15 for s in src]
+        one = np.full((b, _MASK_N_PAD), INF, dtype=np.int32)
+        one[:, 0] = 0
+        got_m = np.asarray(spf_sparse._ell_masked_source_batch(
+            srcs_t, ws_t, tuple(jnp.asarray(m) for m in masks), ov,
+            jnp.int32(0), _MASK_BANDS, _MASK_N_PAD,
+        ))
+        assert (got_m == fixed_point(masks, one)).all()
+
+        u_src, u_w = _as_uniform(src, w)
+        n, k = u_src.shape
+        reset = spf_sparse._FORCE_RESET_EDGE
+        _, got_u, _, _ = spf_sparse._tenant_view_solve(
+            jnp.asarray(u_src), jnp.asarray(u_w), ov,
+            jnp.asarray(_MASK_SOURCES, dtype=jnp.int32),
+            jnp.full((1,), n, dtype=jnp.int32),
+            jnp.zeros((1, k), dtype=jnp.int32),
+            jnp.zeros((1, k), dtype=jnp.int32),
+            jnp.asarray([reset[0]], dtype=jnp.int32),
+            jnp.asarray([reset[1]], dtype=jnp.int32),
+            jnp.asarray([reset[2]], dtype=jnp.int32),
+            jnp.zeros((b, n), dtype=jnp.int32),
+        )
+        assert (np.asarray(got_u) == want).all()
