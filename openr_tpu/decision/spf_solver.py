@@ -225,7 +225,10 @@ KSP2_DEVICE_MIN_DSTS = 32
 # fabrics (fat-tree: 4-6 hops) one dispatch replaces N host Dijkstras
 # (measured 5.4x at 1k nodes), but on a 31x31 grid (60 hops) the
 # iteration count hands the win back to host Dijkstra — gate on the
-# root's hop eccentricity from the unit-metric SPF
+# root's hop eccentricity from the unit-metric SPF. The SP_ECMP view
+# solve pays the same per-hop iteration and has no such gate: the cell
+# grid-10000.drain-churn (198 hops) is where it shows, as
+# relax_passes_per_solve
 KSP2_DEVICE_MAX_HOPS = 16
 # mask-memory budget per dispatch (bool slots); the chunk adapts so
 # small graphs take ONE dispatch and one readback while 10k+-node
@@ -707,9 +710,15 @@ class _EllResidentCache:
         SPF_COUNTERS["decision.device_solves"] += 1
         packed_dev = state.reconverge(graph, srcs)
         # reconverge's own span (ops.ell_reconverge) ends when the
-        # dispatch returns; the host waits for the device here
-        with tracer.span("ops.solve_readback", bytes=packed_dev.nbytes):
-            packed = np.asarray(packed_dev)
+        # dispatch returns; the host waits for the device here, and the
+        # solve's pass count and reset rows arrive in the same read
+        with tracer.span(
+            "ops.solve_readback", bytes=packed_dev.nbytes
+        ) as span:
+            packed, passes, reset_rows = state.fetch_view(packed_dev)
+            if span is not None:
+                span.attrs["passes"] = passes
+                span.attrs["reset_rows"] = reset_rows
         self._cache[ls] = (ls.topology_version, state)
         return state.graph, srcs, packed
 

@@ -83,6 +83,7 @@ ELL_COUNTERS = _get_registry().counter_dict(
         "ell_widen_events",       # widen-on-overflow band re-uploads
         "ell_patch_merges",       # stacked patches coalesced warm
         "ell_structural_warm_solves",  # overload/link flips kept warm
+        "ell_reset_solves",       # solves that restarted >= 1 row from d0
     ],
     prefix="decision.",
 )
@@ -759,7 +760,8 @@ def _ell_relax(d, bands, srcs_t, ws_t, overloaded):
 
 def _warm_seed(d_prev, inc_tail, inc_head, inc_w, d0):
     """Seed the relaxation fixed point from the previous distance rows,
-    resetting only rows in the increase-affected cone.
+    resetting WHOLE every batch row that has one tight increased edge.
+    Returns (seed [S, N], reset [S] bool: the rows that restarted).
 
     Soundness: the masked min-relax closure of any seed S with
     d* <= S <= d0 equals d* (monotone closure squeezed between the
@@ -767,7 +769,12 @@ def _warm_seed(d_prev, inc_tail, inc_head, inc_w, d0):
     previous row d_prev[s] >= d*_new[s] unless some increased edge lay
     on an old shortest path from s — exactly when the edge was TIGHT
     under the old distances: d_prev[s, head] == d_prev[s, tail] + w_old.
-    Tight rows restart from the cold init d0; everything else seeds
+    A tight row restarts from the cold init d0 in every column, not
+    only in the cone behind the increased edge, so its closure takes
+    the source's hop eccentricity in passes whatever the edge was
+    (4-6 on a fat-tree; 198 from a corner of the 100x100 grid, where
+    every link pointing away from the corner is tight — the cell
+    grid-10000.drain-churn pays exactly this). Every other row seeds
     min(d_prev, d0) (the min keeps the unmasked-origination first-hop
     floor that d_prev already carries and d0 re-derives). Raw (unmasked)
     old weights make the test conservative under overload masks; mask
@@ -779,7 +786,18 @@ def _warm_seed(d_prev, inc_tail, inc_head, inc_w, d0):
         == d_prev[:, inc_head]
     ) & (inc_w[None, :] < INF)
     reset = jnp.any(tight, axis=1)
-    return jnp.where(reset[:, None], d0, jnp.minimum(d_prev, d0))
+    return jnp.where(reset[:, None], d0, jnp.minimum(d_prev, d0)), reset
+
+
+def _solve_stats(passes, reset_rows):
+    """The two scalars a solve carries out beside its packed view, as
+    one int32[2]: the relax passes its ``while_loop`` ran (the pass that
+    builds the cold init is not one of them) and the batch rows that
+    restarted from the cold init."""
+    return jnp.stack([
+        jnp.asarray(passes, dtype=jnp.int32),
+        jnp.asarray(reset_rows, dtype=jnp.int32),
+    ])
 
 
 def _device_direct_metrics(srcs_t, ws_t, srcs, bands):
@@ -800,7 +818,8 @@ def _device_direct_metrics(srcs_t, ws_t, srcs, bands):
 def _ell_view_batch(srcs_t, ws_t, overloaded, srcs, w_sv, bands, n):
     """Batched {src} + neighbors distances + packed first hops over the
     sliced-ELL graph — the sparse mirror of ops.spf._spf_view_batch.
-    w_sv: [B] host-computed direct metric source -> batch node."""
+    w_sv: [B] host-computed direct metric source -> batch node.
+    Returns (packed [2B, N], _solve_stats: every row starts cold)."""
     b = srcs.shape[0]
     unit = jnp.full((b, n), INF, dtype=jnp.int32)
     unit = unit.at[jnp.arange(b), srcs].set(0)
@@ -816,9 +835,10 @@ def _ell_view_batch(srcs_t, ws_t, overloaded, srcs, w_sv, bands, n):
         nxt = _ell_relax(d, bands, srcs_t, ws_t, overloaded)
         return nxt, jnp.any(nxt < d), it + 1
 
-    d, _, _ = jax.lax.while_loop(cond, body, (d0, jnp.bool_(True), 0))
+    d, _, it = jax.lax.while_loop(cond, body, (d0, jnp.bool_(True), 0))
     fh = _first_hops_from_rows(d, srcs, w_sv, overloaded, n)
-    return jnp.concatenate([d, fh.astype(jnp.int32)], axis=0)
+    packed = jnp.concatenate([d, fh.astype(jnp.int32)], axis=0)
+    return packed, _solve_stats(it, b)
 
 
 def _first_hops_from_rows(d, srcs, w_sv, overloaded, n):
@@ -878,9 +898,11 @@ def _ell_reconverge(srcs_t, ws_t, patch_ids_t, patch_src_t, patch_w_t,
                     srcs, bands, n):
     """Fused churn executable: scatter the patched rows, derive the
     direct metrics on device, warm-seed the fixed point from d_prev
-    (reset only the increase cone), pack distances + first hops.
+    (rows an increase is tight in restart from the cold init), pack
+    distances + first hops.
     Only the O(rows x K) patch + O(|delta|) increase edges cross
-    host->device; only the packed [2B, N] view crosses back."""
+    host->device; only the packed [2B, N] view and the solve's two
+    scalars (_solve_stats) cross back."""
     new_src, new_w = zip(*(
         _scatter_band_rows(s, w, ids, ps, pw)
         for s, w, ids, ps, pw in zip(
@@ -893,7 +915,7 @@ def _ell_reconverge(srcs_t, ws_t, patch_ids_t, patch_src_t, patch_w_t,
     unit = unit.at[jnp.arange(b), srcs].set(0)
     # init rows: one UNMASKED relax (overloaded sources still originate)
     d0 = _ell_relax(unit, bands, new_src, new_w, None)
-    seed = _warm_seed(d_prev, inc_tail, inc_head, inc_w, d0)
+    seed, reset = _warm_seed(d_prev, inc_tail, inc_head, inc_w, d0)
 
     def cond(state):
         _, changed, it = state
@@ -904,10 +926,10 @@ def _ell_reconverge(srcs_t, ws_t, patch_ids_t, patch_src_t, patch_w_t,
         nxt = _ell_relax(d, bands, new_src, new_w, overloaded)
         return nxt, jnp.any(nxt < d), it + 1
 
-    d, _, _ = jax.lax.while_loop(cond, body, (seed, jnp.bool_(True), 0))
+    d, _, it = jax.lax.while_loop(cond, body, (seed, jnp.bool_(True), 0))
     fh = _first_hops_from_rows(d, srcs, w_sv, overloaded, n)
     packed = jnp.concatenate([d, fh.astype(jnp.int32)], axis=0)
-    return new_src, new_w, packed, d
+    return new_src, new_w, packed, d, _solve_stats(it, jnp.sum(reset))
 
 
 def _batch_args(graph: EllGraph, srcs):
@@ -927,7 +949,7 @@ def ell_view_batch_packed(graph: EllGraph, srcs):
         tuple(jnp.asarray(w) for w in graph.w),
         jnp.asarray(graph.overloaded),
         srcs_dev, w_sv, graph.bands, graph.n_pad,
-    )
+    )[0]
 
 
 def ell_source_batch(graph: EllGraph, ls, src_name: str):
@@ -970,7 +992,7 @@ def _ell_fixed_point(srcs_t, ws_t, overloaded, src_ids, bands, n,
     d0 = _ell_relax(unit, bands, srcs_t, ws_t, None)
     if warm is not None:
         d_prev, inc_tail, inc_head, inc_w = warm
-        d0 = _warm_seed(d_prev, inc_tail, inc_head, inc_w, d0)
+        d0, _ = _warm_seed(d_prev, inc_tail, inc_head, inc_w, d0)
 
     def cond(state):
         _, changed, it = state
@@ -1300,6 +1322,9 @@ class EllState:
         # delta and an undrain as a plain decrease — no forced cold
         # seed on either.
         self._d_dev = None
+        # the last solve's _solve_stats, still on the device: fetch_view
+        # brings it to the host in the read that brings the packed view
+        self._stats_dev = None
         self._warm_key: Optional[Tuple[int, ...]] = None
         self._pending_edges: Dict[
             Tuple[int, int], Tuple[int, int]
@@ -1482,7 +1507,7 @@ class EllState:
         # openr-lint: disable=sharding-spec -- single-chip resident
         # reconvergence (mesh callers go through the sharded_ell_*
         # shard_map wrappers): no mesh axis to spec
-        self.src, self.w, packed, d = _ell_reconverge(
+        self.src, self.w, packed, d, self._stats_dev = _ell_reconverge(
             in_src, in_w, patch_ids, patch_src, patch_w,
             jnp.asarray(inc_t), jnp.asarray(inc_h), jnp.asarray(inc_w),
             self.overloaded, d_prev, srcs_dev,
@@ -1511,6 +1536,23 @@ class EllState:
             host_overhead_ms=round(_total_ms - _dispatch_ms, 4),
         )
         return packed
+
+    def fetch_view(self, packed):
+        """The packed view ``reconverge`` just returned, on the host,
+        with that solve's relax passes and reset rows: outputs of one
+        program, ready together and brought over by ONE ``device_get``,
+        so the scalars cost no program and no sync of their own. They
+        are only known here, so this is where the registry learns them:
+        the observation ``ops.ell.relax_passes`` once per solve, and
+        ``decision.ell_reset_solves`` for a solve that restarted at
+        least one row from the cold init. Returns (packed host array,
+        passes, reset_rows)."""
+        packed_host, stats = jax.device_get((packed, self._stats_dev))
+        passes, reset_rows = int(stats[0]), int(stats[1])
+        _get_registry().observe("ops.ell.relax_passes", passes)
+        if reset_rows:
+            ELL_COUNTERS["ell_reset_solves"] += 1
+        return packed_host, passes, reset_rows
 
 
 def ell_reconverge_step(state: EllState, patched: EllGraph, srcs):
@@ -2203,7 +2245,7 @@ def _tenant_view_solve(src, w, overloaded, srcs, p_rows, p_src, p_w,
     unit = unit.at[jnp.arange(s), srcs].set(0)
     # init rows: one UNMASKED relax (overloaded sources still originate)
     d0 = _uniform_relax(unit, src, w, None)
-    seed = _warm_seed(d_prev, inc_t, inc_h, inc_w, d0)
+    seed, _ = _warm_seed(d_prev, inc_t, inc_h, inc_w, d0)
 
     def cond(state):
         _, changed, it = state

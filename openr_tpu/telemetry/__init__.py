@@ -6,7 +6,7 @@ Three pieces, one export surface:
 - ``registry.py``: a thread-safe fb303-style metric registry. Modules
   register dotted-name counters/gauges/histograms; ``snapshot()``
   flattens everything (histograms expand to ``.p50/.p95/.p99/.max/
-  .avg/.count``) into the dict served by ``OpenrCtrl.get_counters``
+  .avg/.sum/.count``) into the dict served by ``OpenrCtrl.get_counters``
   and ``breeze monitor counters``.
 - ``trace.py``: structured spans over the PerfEvents chain. A trace is
   born at KvStore publication, rides the Publication/RouteUpdate

@@ -74,7 +74,9 @@ class Histogram:
             return self._count
 
     def stats(self) -> Dict[str, float]:
-        """Flattened ``<name>.p50/.p95/.p99/.max/.avg/.count`` dict."""
+        """Flattened ``<name>.p50/.p95/.p99/.max/.avg/.sum/.count`` dict
+        (``.sum`` and ``.count`` are lifetime totals: a reader that
+        takes both twice has the mean of what fell between)."""
         with self._lock:
             count, filled = self._count, self._filled
             ring = self._ring[:filled]
@@ -90,6 +92,7 @@ class Histogram:
             out[self.name + suffix] = round(window[idx], 4)
         out[self.name + ".max"] = round(hmax, 4)
         out[self.name + ".avg"] = round(hsum / count, 4)
+        out[self.name + ".sum"] = round(hsum, 4)
         return out
 
     def percentile(self, q: float) -> float:
