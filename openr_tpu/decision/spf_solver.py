@@ -226,8 +226,9 @@ KSP2_DEVICE_MIN_DSTS = 32
 # (measured 5.4x at 1k nodes), but on a 31x31 grid (60 hops) the
 # iteration count hands the win back to host Dijkstra — gate on the
 # root's hop eccentricity from the unit-metric SPF. The SP_ECMP view
-# solve pays the same per-hop iteration and has no such gate: the cell
-# grid-10000.drain-churn (198 hops) is where it shows, as
+# solve pays the same per-hop iteration only when it starts cold: its
+# warm solves (spf_sparse._cone_seed) pay the depth of what changed,
+# which the cell grid-10000.drain-churn (198 hops) reads as
 # relax_passes_per_solve
 KSP2_DEVICE_MAX_HOPS = 16
 # mask-memory budget per dispatch (bool slots); the chunk adapts so
@@ -711,7 +712,11 @@ class _EllResidentCache:
         packed_dev = state.reconverge(graph, srcs)
         # reconverge's own span (ops.ell_reconverge) ends when the
         # dispatch returns; the host waits for the device here, and the
-        # solve's pass count and reset rows arrive in the same read
+        # solve's two scalars arrive in the same read: ``passes`` = the
+        # passes over the bands of both its loops (the cone seed's
+        # support passes, then the relax passes), ``reset_rows`` = the
+        # batch rows a tight increased edge flagged (their cone was
+        # computed, or, cold, they restarted whole)
         with tracer.span(
             "ops.solve_readback", bytes=packed_dev.nbytes
         ) as span:

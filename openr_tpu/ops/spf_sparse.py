@@ -731,6 +731,22 @@ def _mask_transit_cols(d, overloaded):
     return jnp.where(overloaded[None, :], INF, d)
 
 
+def _ell_band_relaxed(d_t, bands, srcs_t, ws_t):
+    """The gather + add + K-reduce of one pass, band by band: yields
+    (first column, rows, relaxed [S, rows]) where relaxed[s, v] is the
+    least min(d_t[s, u] + w, INF) over v's in-slots (u, w). ``d_t`` is
+    read as given: the caller has masked what must not extend a path."""
+    pos = 0
+    for band, s_b, w_b in zip(bands, srcs_t, ws_t):
+        assert band.start == pos, (band, pos)
+        gathered = d_t[:, s_b]  # [S, rows, k]
+        relaxed = jnp.min(
+            jnp.minimum(gathered + w_b[None, :, :], INF), axis=2
+        )
+        yield pos, band.rows, relaxed.astype(jnp.int32)
+        pos += band.rows
+
+
 def _ell_relax(d, bands, srcs_t, ws_t, overloaded):
     """One masked relaxation over the class bands: [S, N] -> [S, N] as
     pure gather + reduce per band, writing contiguous output slices.
@@ -743,19 +759,39 @@ def _ell_relax(d, bands, srcs_t, ws_t, overloaded):
     UNMASKED d."""
     d_t = _mask_transit_cols(d, overloaded)
     parts = []
-    pos = 0
-    for band, s_b, w_b in zip(bands, srcs_t, ws_t):
-        assert band.start == pos, (band, pos)
-        gathered = d_t[:, s_b]  # [S, rows, k]
-        relaxed = jnp.min(
-            jnp.minimum(gathered + w_b[None, :, :], INF), axis=2
-        )
-        parts.append(
-            jnp.minimum(d[:, pos : pos + band.rows], relaxed.astype(jnp.int32))
-        )
-        pos += band.rows
-    parts.append(d[:, pos:])  # padding columns: unchanged
+    end = 0
+    for pos, rows, relaxed in _ell_band_relaxed(d_t, bands, srcs_t, ws_t):
+        end = pos + rows
+        parts.append(jnp.minimum(d[:, pos:end], relaxed))
+    parts.append(d[:, end:])  # padding columns: unchanged
     return jnp.concatenate(parts, axis=1)
+
+
+def _ell_relax_raw(d, bands, srcs_t, ws_t, overloaded):
+    """_ell_relax without its final min with ``d``: what each column's
+    in-slots OFFER it under ``d``, [S, N], INF in the padding columns
+    (they have no in-slot). The same pass over the same slots, so it
+    costs what a relax pass costs; _cone_seed asks it which columns
+    their in-edges still support."""
+    d_t = _mask_transit_cols(d, overloaded)
+    parts = [
+        relaxed
+        for _, _, relaxed in _ell_band_relaxed(d_t, bands, srcs_t, ws_t)
+    ]
+    real = sum(band.rows for band in bands)
+    s, n = d.shape
+    parts.append(jnp.full((s, n - real), INF, dtype=jnp.int32))
+    return jnp.concatenate(parts, axis=1)
+
+
+def _tight_increases(d_prev, inc_tail, inc_head, inc_w):
+    """[S, E] bool: increased edge e lay on an old shortest path of
+    batch row s, d_prev[s, head] == d_prev[s, tail] + w_old. Padding
+    entries (w_old = INF, pad_increase_edges) are never tight."""
+    return (
+        jnp.minimum(d_prev[:, inc_tail] + inc_w[None, :], INF)
+        == d_prev[:, inc_head]
+    ) & (inc_w[None, :] < INF)
 
 
 def _warm_seed(d_prev, inc_tail, inc_head, inc_w, d0):
@@ -768,32 +804,104 @@ def _warm_seed(d_prev, inc_tail, inc_head, inc_w, d0):
     fixed point and the cold init's closure). d0 >= d* always; a
     previous row d_prev[s] >= d*_new[s] unless some increased edge lay
     on an old shortest path from s — exactly when the edge was TIGHT
-    under the old distances: d_prev[s, head] == d_prev[s, tail] + w_old.
-    A tight row restarts from the cold init d0 in every column, not
-    only in the cone behind the increased edge, so its closure takes
-    the source's hop eccentricity in passes whatever the edge was
-    (4-6 on a fat-tree; 198 from a corner of the 100x100 grid, where
-    every link pointing away from the corner is tight — the cell
-    grid-10000.drain-churn pays exactly this). Every other row seeds
-    min(d_prev, d0) (the min keeps the unmasked-origination first-hop
-    floor that d_prev already carries and d0 re-derives). Raw (unmasked)
-    old weights make the test conservative under overload masks; mask
-    CHANGES must be forced to a full reset by the caller (the
+    under the old distances (_tight_increases). A tight row restarts
+    from the cold init d0 in every column, not only in the cone behind
+    the increased edge, so its closure takes the source's hop
+    eccentricity in passes whatever the edge was (4-6 on a fat-tree;
+    198 from a corner of the 100x100 grid, where every link pointing
+    away from the corner is tight). That is the price the callers of
+    _ell_fixed_point(warm=...) and _tenant_view_solve still pay, whose
+    loops vote across a mesh or a tenant axis; the served single-chip
+    program, _ell_reconverge, takes this function's ``reset`` and
+    narrows it with _cone_seed. Every other row seeds min(d_prev, d0)
+    (the min keeps the unmasked-origination first-hop floor that d_prev
+    already carries and d0 re-derives). Raw (unmasked) old weights make
+    the test conservative under overload masks; mask CHANGES must reach
+    it as increases (EllState's journal emits a drained node's
+    out-edges) or be forced to a full reset by the caller (the
     _FORCE_RESET_EDGE sentinel). Bit-identical to a cold solve: int32
     min-relaxation has a unique fixed point, no float reassociation."""
-    tight = (
-        jnp.minimum(d_prev[:, inc_tail] + inc_w[None, :], INF)
-        == d_prev[:, inc_head]
-    ) & (inc_w[None, :] < INF)
-    reset = jnp.any(tight, axis=1)
+    reset = jnp.any(
+        _tight_increases(d_prev, inc_tail, inc_head, inc_w), axis=1
+    )
     return jnp.where(reset[:, None], d0, jnp.minimum(d_prev, d0)), reset
+
+
+def _cone_seed(reset, whole, d0, d_prev, relax_raw):
+    """_warm_seed narrowed from the row to the cone: inside a row that
+    the tight test flags (``reset`` [S]) keep d_prev[s, v] in every
+    column that an in-edge still SUPPORTS, and fall back to d0[s, v]
+    only in the rest. Returns (seed [S, N], support passes run).
+
+    ``relax_raw(d)`` is one pass over the PATCHED bands under the
+    CURRENT overload mask without the final min with d (_ell_relax_raw
+    closed over them). Column v of a flagged row is supported when
+      - base: d0[s, v] <= d_prev[s, v] (the source, its neighbours at an
+        unchanged or lower metric) or d_prev[s, v] == INF, or
+      - step: some in-slot (u, v, w) has min(mask(d_prev)[s, u] + w,
+        INF) <= d_prev[s, v] with u supported (an overloaded u reads
+        INF and supports nothing).
+    Computed as the least fixed point of its complement: nothing is
+    invalid to begin with; a pass marks every non-base column whose
+    offer under where(invalid, INF, d_prev) is above d_prev; the loop
+    ends at the first pass that marks nothing. A cone of depth c takes
+    c + 1 passes, each at the cost of a relax pass, and the loop runs
+    ZERO times when no row is flagged (or all flagged rows are
+    ``whole``). The relax loop then re-derives the cone from its rim,
+    c + 1 passes more, instead of the row from its source.
+
+    Soundness (the squeeze of _warm_seed wants d* <= seed <= d0).
+    seed <= d0 by construction. A supported non-base v has a supported
+    parent u with d_prev[u] + w <= d_prev[v]; with every weight >= 1
+    that is d_prev[u] < d_prev[v], so the chain of supports descends
+    strictly and ends at a base column, where d* <= d0 <= d_prev;
+    walking back, d*[v] <= d*[u] + w <= d_prev[u] + w <= d_prev[v].
+    Nothing is asked of d_prev but to be the rows this batch was last
+    solved for (EllState's _warm_key), and no increase list is needed
+    for soundness: ``reset`` only says where looking is worth a pass.
+
+    The one precondition is weights >= 1: two columns joined by
+    zero-metric links could support each other after both lost their
+    real parent. ``whole`` [S] marks the flagged rows for which the
+    caller cannot rule that out; they restart in every column, as
+    _warm_seed has it, with no support pass. _ell_reconverge sets it
+    for a row with a tight increased edge of old weight 0, and for
+    every flagged row when a real slot of the patched bands carries 0.
+    The first is also exactly _FORCE_RESET_EDGE = (0, 0, 0), so a cold
+    or re-keyed solve (d_prev zeros, or another batch's rows) needs no
+    case of its own."""
+    whole = reset & whole
+    cand = (reset & ~whole)[:, None] & (d0 > d_prev)
+
+    def cond(state):
+        _, grew, _ = state
+        return grew
+
+    def body(state):
+        invalid, _, it = state
+        offer = relax_raw(jnp.where(invalid, INF, d_prev))
+        nxt = invalid | (cand & (offer > d_prev))
+        return nxt, jnp.any(nxt != invalid), it + 1
+
+    # every pass but the last adds a column to a finite set: no bound
+    # on ``it`` is needed, and one that cut the loop short would leave
+    # an unsupported column in the seed
+    invalid, _, passes = jax.lax.while_loop(
+        cond,
+        body,
+        (jnp.broadcast_to(whole[:, None], d_prev.shape), jnp.any(cand), 0),
+    )
+    return jnp.where(invalid, d0, jnp.minimum(d_prev, d0)), passes
 
 
 def _solve_stats(passes, reset_rows):
     """The two scalars a solve carries out beside its packed view, as
-    one int32[2]: the relax passes its ``while_loop`` ran (the pass that
-    builds the cold init is not one of them) and the batch rows that
-    restarted from the cold init."""
+    one int32[2]: the passes over the bands its ``while_loop``s ran
+    (relax passes, and in _ell_reconverge the support passes of
+    _cone_seed before them: each streams the same slots; the pass that
+    builds the cold init is not one of them) and the batch rows that a
+    tight increased edge flagged (_ell_reconverge: the rows whose cone
+    was computed or that restarted whole; the cold program: all)."""
     return jnp.stack([
         jnp.asarray(passes, dtype=jnp.int32),
         jnp.asarray(reset_rows, dtype=jnp.int32),
@@ -885,6 +993,34 @@ def _patch_band(src, w, ids, rows_src, rows_w):
     return _scatter_band_rows(src, w, ids, rows_src, rows_w)
 
 
+def _reconverge_seed(srcs_t, ws_t, inc_tail, inc_head, inc_w, overloaded,
+                     d_prev, srcs, bands, n):
+    """The seed _ell_reconverge relaxes from, over the PATCHED bands:
+    (seed [B, N], cold init d0 [B, N], support passes run, reset [B]
+    bool). Traced into that one program; a function of its own so a
+    test can hold the seed itself, and not only the fixed point it
+    closes to, to d* <= seed <= d0."""
+    b = srcs.shape[0]
+    unit = jnp.full((b, n), INF, dtype=jnp.int32)
+    unit = unit.at[jnp.arange(b), srcs].set(0)
+    # init rows: one UNMASKED relax (overloaded sources still originate)
+    d0 = _ell_relax(unit, bands, srcs_t, ws_t, None)
+    tight = _tight_increases(d_prev, inc_tail, inc_head, inc_w)
+    reset = jnp.any(tight, axis=1)
+    # what _cone_seed's induction cannot stand: a weight below 1
+    # (padding slots carry INF)
+    zero_slot = functools.reduce(
+        jnp.logical_or, [jnp.any(w_b < 1) for w_b in ws_t]
+    )
+    whole = jnp.any(tight & (inc_w[None, :] < 1), axis=1) | zero_slot
+    with jax.named_scope("ell.cone_seed"):
+        seed, support_passes = _cone_seed(
+            reset, whole, d0, d_prev,
+            lambda x: _ell_relax_raw(x, bands, srcs_t, ws_t, overloaded),
+        )
+    return seed, d0, support_passes, reset
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("bands", "n"),
@@ -898,11 +1034,14 @@ def _ell_reconverge(srcs_t, ws_t, patch_ids_t, patch_src_t, patch_w_t,
                     srcs, bands, n):
     """Fused churn executable: scatter the patched rows, derive the
     direct metrics on device, warm-seed the fixed point from d_prev
-    (rows an increase is tight in restart from the cold init), pack
-    distances + first hops.
+    (in a row an increase is tight in, the columns no in-edge supports
+    any more restart from the cold init: _cone_seed), pack distances +
+    first hops.
     Only the O(rows x K) patch + O(|delta|) increase edges cross
     host->device; only the packed [2B, N] view and the solve's two
-    scalars (_solve_stats) cross back."""
+    scalars (_solve_stats) cross back. Warm, cold (_FORCE_RESET_EDGE)
+    and zero-metric inputs are one executable: which rows take the
+    cone and which restart whole is decided from the arrays."""
     new_src, new_w = zip(*(
         _scatter_band_rows(s, w, ids, ps, pw)
         for s, w, ids, ps, pw in zip(
@@ -910,12 +1049,10 @@ def _ell_reconverge(srcs_t, ws_t, patch_ids_t, patch_src_t, patch_w_t,
         )
     ))
     w_sv = _device_direct_metrics(new_src, new_w, srcs, bands)
-    b = srcs.shape[0]
-    unit = jnp.full((b, n), INF, dtype=jnp.int32)
-    unit = unit.at[jnp.arange(b), srcs].set(0)
-    # init rows: one UNMASKED relax (overloaded sources still originate)
-    d0 = _ell_relax(unit, bands, new_src, new_w, None)
-    seed, reset = _warm_seed(d_prev, inc_tail, inc_head, inc_w, d0)
+    seed, _, support_passes, reset = _reconverge_seed(
+        new_src, new_w, inc_tail, inc_head, inc_w, overloaded, d_prev,
+        srcs, bands, n,
+    )
 
     def cond(state):
         _, changed, it = state
@@ -926,10 +1063,15 @@ def _ell_reconverge(srcs_t, ws_t, patch_ids_t, patch_src_t, patch_w_t,
         nxt = _ell_relax(d, bands, new_src, new_w, overloaded)
         return nxt, jnp.any(nxt < d), it + 1
 
-    d, _, it = jax.lax.while_loop(cond, body, (seed, jnp.bool_(True), 0))
+    with jax.named_scope("ell.relax"):
+        d, _, it = jax.lax.while_loop(
+            cond, body, (seed, jnp.bool_(True), 0)
+        )
     fh = _first_hops_from_rows(d, srcs, w_sv, overloaded, n)
     packed = jnp.concatenate([d, fh.astype(jnp.int32)], axis=0)
-    return new_src, new_w, packed, d, _solve_stats(it, jnp.sum(reset))
+    return new_src, new_w, packed, d, _solve_stats(
+        support_passes + it, jnp.sum(reset)
+    )
 
 
 def _batch_args(graph: EllGraph, srcs):
@@ -1450,7 +1592,8 @@ class EllState:
     def reconverge(self, patched: EllGraph, srcs):
         """Fused churn step: scatter the patched rows into the resident
         bands, solve the batched view warm-started from the previous
-        solve's distances (bit-identical to cold — see _warm_seed),
+        solve's distances (bit-identical to cold — see _warm_seed and
+        _cone_seed),
         O(rows x K_class + |delta|) transfer in, O(B x N) out. Widened
         bands (shape changed) are re-uploaded wholesale as the dispatch
         inputs with a no-op scatter — same discipline as apply_patch;
@@ -1539,14 +1682,17 @@ class EllState:
 
     def fetch_view(self, packed):
         """The packed view ``reconverge`` just returned, on the host,
-        with that solve's relax passes and reset rows: outputs of one
+        with that solve's two scalars (_solve_stats): outputs of one
         program, ready together and brought over by ONE ``device_get``,
         so the scalars cost no program and no sync of their own. They
         are only known here, so this is where the registry learns them:
-        the observation ``ops.ell.relax_passes`` once per solve, and
-        ``decision.ell_reset_solves`` for a solve that restarted at
-        least one row from the cold init. Returns (packed host array,
-        passes, reset_rows)."""
+        the observation ``ops.ell.relax_passes`` once per solve (every
+        pass over the bands the solve ran: _cone_seed's support passes
+        and the relax passes after them, so it is what the mechanism
+        achieved), and ``decision.ell_reset_solves`` for a solve in
+        which a tight increased edge flagged at least one row (how
+        often the mechanism engaged; a cold solve flags every row).
+        Returns (packed host array, passes, reset_rows)."""
         packed_host, stats = jax.device_get((packed, self._stats_dev))
         passes, reset_rows = int(stats[0]), int(stats[1])
         _get_registry().observe("ops.ell.relax_passes", passes)

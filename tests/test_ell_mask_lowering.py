@@ -9,6 +9,11 @@ distance columns, so the compiled program has none. This is a count
 from a compile, not a time; it is what stops the gather coming back
 through a refactor.
 
+Since PR 31 the same compile also holds the shape of the cone seed: two
+``while`` loops under their own name scopes (``ell.cone_seed`` before
+``ell.relax``), and, run on the CPU at a small size, the counts that say
+when the first one runs at all.
+
 The helpers are ``tests/chipbench/test_tpu_lowering.py``'s; its fixture
 describes the topology only once a test of this file has started, and
 skips where it cannot be described.
@@ -19,6 +24,7 @@ from __future__ import annotations
 import re
 
 import numpy as np
+import pytest
 
 from tests.chipbench.test_tpu_lowering import (  # noqa: F401 - fixture
     _config,
@@ -43,7 +49,12 @@ def _edge_shaped_pred_gathers(text: str, bands) -> list:
     return found
 
 
-def test_ell_reconverge_gathers_no_mask_per_edge(one_chip):
+@pytest.fixture(scope="module")
+def fabric_5000_text(one_chip):
+    """``_ell_reconverge`` compiled for the described chip at
+    ``fabric-5000``'s shapes: (bands, optimised HLO text, output
+    shapes)."""
+    import jax
     import jax.numpy as jnp
 
     from openr_tpu.graph import snapshot
@@ -61,7 +72,7 @@ def test_ell_reconverge_gathers_no_mask_per_edge(one_chip):
     def per_band(shape_of):
         return tuple(_shape(one_chip, shape_of(b), i32) for b in graph.bands)
 
-    text = spf_sparse._ell_reconverge.lower(
+    compiled = spf_sparse._ell_reconverge.lower(
         per_band(lambda b: (b.rows, b.k)),
         per_band(lambda b: (b.rows, b.k)),
         per_band(lambda b: (rows,)),
@@ -74,10 +85,34 @@ def test_ell_reconverge_gathers_no_mask_per_edge(one_chip):
         _shape(one_chip, (batch, graph.n_pad), i32),
         _shape(one_chip, (batch,), i32),
         bands=graph.bands, n=graph.n_pad,
-    ).compile().as_text()
+    ).compile()
+    out = [
+        tuple(o.shape) for o in jax.tree_util.tree_leaves(compiled.out_info)
+    ]
+    return graph.bands, compiled.as_text(), out, (batch, graph.n_pad)
+
+
+def test_ell_reconverge_gathers_no_mask_per_edge(fabric_5000_text):
+    bands, text, _, _ = fabric_5000_text
     # the reading is of this text: the distance gathers must be in it
     assert re.search(r"= s32\[\d+,16\]\S* fusion\(.*while/body/gather", text)
-    assert _edge_shaped_pred_gathers(text, graph.bands) == []
+    assert _edge_shaped_pred_gathers(text, bands) == []
+
+
+def test_ell_reconverge_is_one_program_with_two_named_loops(fabric_5000_text):
+    """Warm, cold and zero-metric inputs share this one executable: the
+    support loop and the relax loop are both in it, each under its own
+    name scope, and what comes out is what the benchmark's own lowering
+    tests pin: the bands, the packed view, the distance rows and
+    ``_solve_stats`` of shape (2,)."""
+    bands, text, out, (batch, n_pad) = fabric_5000_text
+    assert out == [(b.rows, b.k) for b in bands] * 2 + [
+        (2 * batch, n_pad), (batch, n_pad), (2,)]
+    # both loops gather distances under their own scope
+    assert re.search(r"ell\.cone_seed/while/body/.*gather", text)
+    assert re.search(r"ell\.relax/while/body/.*gather", text)
+    # and no mask is looked up per edge in either
+    assert not re.search(r"pred\[[0-9,]*\]\S* gather\(.*ell\.cone_seed", text)
 
 
 def test_the_count_sees_what_it_is_for():
@@ -96,3 +131,96 @@ def test_the_count_sees_what_it_is_for():
         "  %gather.2 = s32[960,128,16]{2,1,0} gather(%p, %q)\n"
     )
     assert len(_edge_shaped_pred_gathers(parent, bands)) == 2
+
+
+# -- when the support loop runs, as counts on the CPU -------------------------
+
+
+def _small_state():
+    from openr_tpu.models import topologies
+    from openr_tpu.ops import spf_sparse
+    from tests.test_incremental_parity import load
+
+    ls = load(topologies.grid(6))
+    return ls, spf_sparse.EllState(spf_sparse.compile_ell(ls))
+
+
+def _relax_loop_count(seed, graph, state):
+    """What ``_ell_reconverge``'s relax loop counts from ``seed``: passes
+    until one changes nothing, that one included."""
+    import jax.numpy as jnp
+
+    from openr_tpu.ops import spf_sparse
+
+    d, passes = jnp.asarray(seed), 0
+    while True:
+        nxt = spf_sparse._ell_relax(
+            d, graph.bands, state.src, state.w, state.overloaded)
+        passes += 1
+        if not bool((nxt < d).any()):
+            return passes
+        d = nxt
+
+
+@pytest.mark.parametrize("case", ["forced-reset", "pure-decrease", "raise"])
+def test_support_passes_run_only_where_a_row_takes_the_cone(case, monkeypatch):
+    """``stats[0]`` is support passes + relax passes. The forced reset
+    (a first solve: ``_FORCE_RESET_EDGE``) restarts every row whole with
+    no support pass; a solve that flags no row runs none either; a raise
+    that is tight runs at least one. In each case the rest of
+    ``stats[0]`` is the relax loop's own count from that seed."""
+    import jax
+    import jax.numpy as jnp
+
+    from openr_tpu.ops import spf_sparse
+    from tests.test_cone_seed import _set_metric
+
+    ls, state = _small_state()
+    kept = {}
+    real = spf_sparse._ell_reconverge
+
+    def keeping(*args, **kwargs):
+        kept["args"] = [np.array(a) for a in args[5:11]]
+        out = real(*args, **kwargs)
+        kept["leaves"] = jax.tree_util.tree_leaves(out)
+        return out
+
+    monkeypatch.setattr(spf_sparse, "_ell_reconverge", keeping)
+
+    def solve(affected):
+        graph = state.graph
+        if affected:
+            graph = spf_sparse.ell_patch(
+                graph, ls, sorted(affected), widen=True)
+        srcs = spf_sparse.ell_source_batch(graph, ls, "node-0")
+        _, passes, reset_rows = state.fetch_view(state.reconverge(graph, srcs))
+        seed, _, support, _ = spf_sparse._reconverge_seed(
+            state.src, state.w, *(jnp.asarray(a) for a in kept["args"]),
+            graph.bands, graph.n_pad)
+        return graph, len(srcs), passes, reset_rows, int(support), seed
+
+    def recost(metric):
+        # node-14 = (2, 2) of the 6 x 6 grid, an interior node: two of
+        # its four links point away from the corner
+        touched = set()
+        for i in range(4):
+            touched |= _set_metric(ls, "node-14", i, metric)
+        return solve(touched)
+
+    got = solve([])
+    if case != "forced-reset":
+        recost(5)
+        got = recost(1)
+    if case == "raise":
+        got = recost(3)
+    graph, batch, passes, reset_rows, support, seed = got
+    assert [tuple(x.shape) for x in kept["leaves"]] == (
+        [(b.rows, b.k) for b in graph.bands] * 2
+        + [(2 * batch, graph.n_pad), (batch, graph.n_pad), (2,)])
+    if case == "forced-reset":
+        assert reset_rows == batch and support == 0
+    elif case == "pure-decrease":
+        assert reset_rows == 0 and support == 0
+    else:
+        assert reset_rows >= 1 and support >= 1
+    assert passes == support + _relax_loop_count(seed, graph, state)

@@ -8,9 +8,12 @@ burst of ``grid-10000.drain-churn``'s events (a node re-costs all its
 links, a link flaps) the device backend's ``RouteDatabase`` equals the
 plain per-source Dijkstra of ``chipbench/reference.py`` and is
 bit-identical to ``solver_backend=host``. And the two scalars a solve now
-carries out beside its packed view say what the solve did: the relax
-passes its ``while_loop`` ran and the batch rows ``_warm_seed`` restarted
-from the cold init. Counts, never times: this is the CPU.
+carries out beside its packed view say what the solve did: the passes
+over the bands its two ``while_loop``s ran (``_cone_seed``'s support
+passes, then the relax passes) and the batch rows a tight increased edge
+flagged. Since PR 31 a flagged row restarts only the columns no in-edge
+supports any more, so what a solve pays is the depth of what changed and
+not the corner's 128 hops. Counts, never times: this is the CPU.
 """
 
 from __future__ import annotations
@@ -109,11 +112,19 @@ def test_routes_equal_reference_and_host_after_every_burst(grid, seed):
     assert resets == sum(s.attrs["reset_rows"] > 0 for s in spans)
     # from a corner nearly every node-metric raises a tight edge
     assert resets >= len(BURSTS) // 2
+    ecc = 2 * (SIDE - 1)
     for s in spans:
-        assert 1 <= s.attrs["passes"] < SIDE * SIDE
+        # at worst a whole row is cone: support passes, then relax passes
+        assert 1 <= s.attrs["passes"] <= 2 * ecc
         assert 0 <= s.attrs["reset_rows"] <= 8
         if s.attrs["reset_rows"]:
-            assert s.attrs["passes"] >= 2 * (SIDE - 1) - 1
+            # one support pass at least, and the relax pass that confirms
+            assert s.attrs["passes"] >= 2
+    # a flagged solve no longer costs the eccentricity: the typical one
+    # finds every raised link's head a second parent and closes at once
+    flagged = sorted(
+        s.attrs["passes"] for s in spans if s.attrs["reset_rows"])
+    assert flagged[len(flagged) // 2] <= 6, flagged
 
 
 # -- the two scalars, at the ops level ---------------------------------------
@@ -158,6 +169,20 @@ class _Resident:
             a.other_node_name
             for a in self.ls.get_adjacency_databases()[node].adjacencies})
 
+    def withdraw(self, node: str, other: str):
+        """One side of a link stops announcing it, as a flap does."""
+        self._before = self.ls.get_adjacency_databases()[node]
+        self.ls.update_adjacency_database(replace(
+            self._before, adjacencies=tuple(
+                a for a in self._before.adjacencies
+                if a.other_node_name != other)))
+        return self.solve({node, other})
+
+    def restore(self, node: str):
+        self.ls.update_adjacency_database(self._before)
+        return self.solve({node} | {
+            a.other_node_name for a in self._before.adjacencies})
+
 
 @pytest.fixture()
 def resident(grid):
@@ -178,24 +203,83 @@ def test_a_forced_reset_runs_the_corners_eccentricity_in_passes(resident):
     assert got["cold_stats"] == (got["ecc"], got["batch"])
 
 
-@pytest.mark.parametrize("node,tight", [
-    ("node-1", True),               # the vantage's own neighbour
-    (f"node-{32 * SIDE + 32}", True),   # the middle of the grid
-    (f"node-{SIDE * SIDE - 2}", True),  # next to the far corner
-    (FAR, False),    # the far corner: no link of it points away
+@pytest.mark.parametrize("node,flagged,passes", [
+    # the vantage's own neighbour is a source of the batch: every
+    # distance of its own row rises, that row is all cone, and the two
+    # loops each walk it (127 + 1 support passes, 127 relax passes)
+    ("node-1", True, 2 * 2 * (SIDE - 1) - 1),
+    # the middle of the grid, and next to the far corner: each raised
+    # link's head has a second parent at the same distance, so one
+    # support pass marks nothing and one relax pass changes nothing
+    (f"node-{32 * SIDE + 32}", True, 2),
+    (f"node-{SIDE * SIDE - 2}", True, 2),
+    (FAR, False, 1),    # the far corner: no link of it points away
 ])
 def test_a_node_that_raises_its_links_restarts_rows_unless_it_is_the_far_corner(
-        resident, node, tight):
+        resident, node, flagged, passes):
     """Every link that points away from the corner lies on a shortest
     path, and every node but the far corner has one: raising all of a
-    node's links is tight in ``_warm_seed`` and restarts whole rows,
-    which then take the eccentricity in passes wherever the node is."""
+    node's links is tight and flags rows. What the solve then pays is
+    the depth of the cone behind the raised links, not the
+    eccentricity: nothing for an interior node, the whole row only for
+    a source of the batch itself."""
     got = resident.recost(node, 2)
-    if tight:
+    assert got["passes"] == passes
+    if flagged:
         assert got["reset_rows"] >= 1
-        assert got["passes"] >= got["ecc"] - 1
+        assert got["passes"] <= 4 or node == "node-1"
     else:
-        assert got["reset_rows"] == 0 and got["passes"] == 1
+        assert got["reset_rows"] == 0
+
+
+def _cone_passes(depth: int) -> int:
+    """A chain of ``depth`` columns behind a raised edge: the support
+    loop marks one a pass and one more pass marks nothing; the relax
+    loop settles one a pass and one more pass changes nothing."""
+    return 2 * (depth + 1)
+
+
+@pytest.mark.parametrize("node,depth", [
+    # row 0 beyond column 2: on a line through the batch, not in it
+    ("node-2", SIDE - 3),
+    # column 0 below rows 5, 10, 60
+    (f"node-{5 * SIDE}", SIDE - 6),
+    (f"node-{10 * SIDE}", SIDE - 11),
+    (f"node-{60 * SIDE}", SIDE - 61),
+])
+def test_a_raise_on_a_line_through_the_vantage_pays_the_line_behind_it(
+        resident, node, depth):
+    """On column 0 (and row 0) a node's only shortest parent is the one
+    before it on the line, so a raise there moves the whole line behind
+    it farther: the cone is that line, and the passes are its depth and
+    the two confirming ones, twice over. Under the eccentricity, which
+    is what any flagged row cost before."""
+    got = resident.recost(node, 2)
+    assert got["reset_rows"] >= 1
+    assert got["passes"] == _cone_passes(depth) < got["ecc"]
+
+
+@pytest.mark.parametrize("a,b,support,relax", [
+    # an interior link: the head keeps its other parent
+    (32 * SIDE + 32, 32 * SIDE + 33, 1, 1),
+    # a link of row 0: the head's only parent was the tail, so row 0
+    # beyond it is the cone, 54 columns that the support loop marks one
+    # a pass; every one of them then takes its new distance from its
+    # neighbour on row 1, which never moved, in ONE relax pass
+    (10, 11, SIDE - 11 + 1, 2),
+])
+def test_a_withdrawn_link_pays_its_cone_and_nothing_when_a_second_parent_holds(
+        resident, a, b, support, relax):
+    """A flap's withdrawal reads as w -> INF on both directions of the
+    link; the one that pointed away from the corner was tight."""
+    got = resident.withdraw(f"node-{a}", f"node-{b}")
+    assert got["reset_rows"] >= 1
+    assert got["passes"] == support + relax < got["ecc"]
+    # the restore only decreases: nothing is flagged, no support pass,
+    # and the news travels down the row it shortens
+    back = resident.restore(f"node-{a}")
+    assert back["reset_rows"] == 0
+    assert back["passes"] == (1 if support == 1 else support)
 
 
 @pytest.mark.parametrize("node", [FAR, f"node-{SIDE * SIDE - 2}",

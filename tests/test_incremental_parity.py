@@ -2,10 +2,12 @@
 stay bit-identical to a cold rebuild.
 
 The fused churn dispatch (EllState.reconverge) seeds the fixed point
-with the previous solve's distance rows and resets only rows whose old
-shortest paths were TIGHT through an increase-affected edge
-(spf_sparse._warm_seed); every other row keeps its previous distances
-as valid upper bounds of the min-relaxation. These tests drive mixed
+with the previous solve's distance rows and resets only in rows whose
+old shortest paths were TIGHT through an increase-affected edge
+(spf_sparse._warm_seed; since PR 31 only the columns of such a row that
+no in-edge supports any more, spf_sparse._cone_seed, whose seed
+tests/test_cone_seed.py holds to its bounds); every other row keeps its
+previous distances as valid upper bounds of the min-relaxation. These tests drive mixed
 churn — metric increases, decreases, both at once, link down/restore,
 overload flips, stacked patches — and require byte equality with a
 from-scratch compile+solve at every step, plus counter assertions
