@@ -129,10 +129,10 @@ pipeline-smoke: native
 # pins the device count): pipelined==eager bit-identity across a
 # shard-boundary event, zero reshards / zero implicit transfers under
 # jax.transfer_guard across a 5-event churn run, and the KSP2
-# speculative fast path dispatching mesh-wide (typed fallback counter
-# when it can't). Same contracts a real multi-chip run must hold.
+# engine's warm dispatches staying on the mesh. Same contracts a real
+# multi-chip run must hold.
 multichip-smoke: native
-	env JAX_PLATFORMS=cpu OPENR_KSP2_FAST=1 python -m pytest \
+	env JAX_PLATFORMS=cpu python -m pytest \
 	  tests/test_route_engine_delta.py::TestMeshPipelining \
 	  tests/test_route_engine_delta.py::TestShardedNoReshard \
 	  tests/test_ksp2_engine.py::TestMeshShardedEngine \

@@ -22,11 +22,15 @@
 #include <cstring>
 #include <queue>
 #include <thread>
+#include <utility>
 #include <vector>
 
 namespace {
 
 constexpr int32_t kInf = (1 << 30) - 1;
+constexpr int32_t kDepthMask = (1 << 14) - 1;
+constexpr int32_t kRanOut = 1 << 14;
+constexpr int32_t kInLast = 1 << 15;
 
 struct Csr {
   int32_t n;
@@ -200,6 +204,17 @@ void spf_first_hops(int32_t n, int32_t n_edges, const int32_t* edge_src,
 // as no exclusions exist), else [n_dsts, n] row-major. Excluded link
 // ids per destination: excl_off[d]..excl_off[d+1) of excl_ids.
 //
+// reach (may be null): [n_dsts, n] int32 the caller set to -1. For
+// every node whose candidate list a destination's searches consulted
+// it comes back with what they did there, kept apart by how the search
+// ended. Searches that FOUND a path (their dead ends included): in the
+// low 14 bits 1 + the position (within the node's candidates,
+// canonical order) of the last one examined, and kRanOut where one ran
+// the list out. The last search, which found none: kInLast on every
+// node it reached — the set no way leads on from, once the paths
+// before it are taken. The paths are a function of those prefixes, and
+// the count of that set, which is what lets the engine prove a
+// destination's paths unmoved without tracing again.
 // Output, per destination: n_paths, then per path: len, link ids in
 // src->dst order. Returns the total int32 count written, or -1 when
 // out_cap would be exceeded (caller grows the buffer and retries).
@@ -209,16 +224,43 @@ int32_t ksp2_trace_batch(
     const int32_t* cand_w, int32_t src, const uint8_t* transit_blocked,
     int32_t n_dsts, const int32_t* dst_ids, const int32_t* rows,
     int32_t shared_row, const int32_t* excl_off,
-    const int32_t* excl_ids, int32_t* out, int32_t out_cap) {
+    const int32_t* excl_ids, int32_t* out, int32_t out_cap,
+    int32_t* reach) {
   // epoch-stamped scratch: visited/excluded links, per-node pred lists
   std::vector<int32_t> vis(n_links, -1);
   std::vector<int32_t> exc(n_links, -1);
   int32_t total_cands = cand_off[n];
   std::vector<int32_t> pred_link(total_cands);
   std::vector<int32_t> pred_uid(total_cands);
+  std::vector<int32_t> pred_pos(total_cands);
   std::vector<int32_t> pred_cnt(n, 0);
   std::vector<int32_t> pred_epoch(n, -1);
+  // dead[v] == d: every predecessor link of v is spent for destination
+  // d, so no later trace of d gets from v to src (spent links only
+  // grow); arriving there again is a dead end known in advance
+  std::vector<int32_t> dead(n, -1);
   bool share_preds = shared_row && excl_off[n_dsts] == 0;
+  // src's links as they leave it: the weight of each from src's side,
+  // which the far node's list holds (a node's candidates carry the
+  // weight INTO the node)
+  std::vector<int32_t> src_out_w;
+  for (int32_t c = cand_off[src]; c < cand_off[src + 1]; ++c) {
+    int32_t w = kInf;
+    int32_t nb = cand_uid[c];
+    if (nb >= 0) {
+      for (int32_t k = cand_off[nb]; k < cand_off[nb + 1]; ++k) {
+        if (cand_link[k] == cand_link[c] && cand_uid[k] == src) {
+          w = cand_w[k];
+          break;
+        }
+      }
+    }
+    src_out_w.push_back(w);
+  }
+
+  // what one search did where: (node, 1 + place examined), or
+  // (node, -1) where it ran the node's list out
+  std::vector<std::pair<int32_t, int32_t>> marks;
 
   struct Frame {
     int32_t v;
@@ -273,14 +315,35 @@ int32_t ksp2_trace_batch(
         }
         pred_link[cand_off[v] + cnt] = l;
         pred_uid[cand_off[v] + cnt] = uid;
+        pred_pos[cand_off[v] + cnt] = c - cand_off[v];
         ++cnt;
       }
       pred_cnt[v] = cnt;
     };
+    // every path leaves src over a link of its own, and over one
+    // that is the first hop of a shortest path (its weight is its far
+    // end's distance), so src's links of that kind that this
+    // destination does not exclude bound the count: once that many
+    // are found, the trace that would fail (it has to exhaust the
+    // whole DAG to say so, the costly one where second paths wind
+    // through every pod, or where one of src's links is dearer than
+    // the rest and no first path takes it) need not run
+    int32_t src_links = 0;
+    for (int32_t c = cand_off[src]; c < cand_off[src + 1]; ++c) {
+      int32_t nb = cand_uid[c];
+      if (nb >= 0 && exc[cand_link[c]] != d &&
+          src_out_w[c - cand_off[src]] == row[nb]) {
+        ++src_links;
+      }
+    }
     // enumerate link-disjoint paths until a trace fails
     for (;;) {
+      if (out[npaths_slot] >= src_links) {
+        break;
+      }
       frames.clear();
       frames.push_back({dst, 0, -1});
+      marks.clear();
       bool found = false;
       while (!frames.empty()) {
         Frame& f = frames.back();
@@ -293,16 +356,40 @@ int32_t ksp2_trace_batch(
         while (f.idx < pred_cnt[f.v]) {
           int32_t c = cand_off[f.v] + f.idx++;
           int32_t l = pred_link[c];
+          if (reach != nullptr) {
+            marks.emplace_back(f.v, pred_pos[c] + 1);
+          }
           if (vis[l] == d) {
             continue;
           }
           vis[l] = d;  // visited stays set even if this branch dies
+          if (dead[pred_uid[c]] == d) {
+            continue;  // the branch that would die, not walked again
+          }
           frames.push_back({pred_uid[c], 0, l});
           advanced = true;
           break;
         }
         if (!advanced) {
+          dead[f.v] = d;
+          if (reach != nullptr) {
+            marks.emplace_back(f.v, -1);
+          }
           frames.pop_back();
+        }
+      }
+      if (reach != nullptr) {
+        int32_t* row = reach + static_cast<int64_t>(d) * n;
+        for (const auto& m : marks) {
+          int32_t cur = row[m.first] < 0 ? 0 : row[m.first];
+          if (!found) {
+            cur |= kInLast;
+          } else if (m.second < 0) {
+            cur |= kRanOut;
+          } else if (m.second > (cur & kDepthMask)) {
+            cur = (cur & ~kDepthMask) | std::min(m.second, kDepthMask);
+          }
+          row[m.first] = cur;
         }
       }
       if (!found) {

@@ -29,7 +29,11 @@ from typing import Callable, Dict, Optional, Set, Tuple
 
 from openr_tpu.decision.prefix_state import PrefixState
 from openr_tpu.decision.rib import DecisionRouteDb, DecisionRouteUpdate
-from openr_tpu.decision.spf_solver import SpfSolver, get_spf_counters
+from openr_tpu.decision.spf_solver import (
+    SPF_COUNTERS,
+    SpfSolver,
+    get_spf_counters,
+)
 from openr_tpu.graph.linkstate import LinkState, LinkStateChange
 from openr_tpu.messaging.queue import ReplicateQueue
 from openr_tpu.types import (
@@ -50,6 +54,7 @@ from openr_tpu.telemetry import (
     get_tracer,
     install_default_triggers,
     install_gc_hook,
+    settle_heap,
 )
 from openr_tpu.utils import keys as keyutil
 from openr_tpu.utils import wire
@@ -710,6 +715,7 @@ class Decision:
             return
         self.pending.add_event(event)
         self.counters["decision.route_build_runs"] += 1
+        cold_builds = SPF_COUNTERS["decision.ksp2_cold_builds"]
         if self.pending.count > 1:
             # a debounce window folded several publications into THIS
             # one rebuild: downstream, the device churn path pays one
@@ -794,6 +800,11 @@ class Decision:
         perf_events = self.pending.move_out_events()
         self.pending.reset()
         self._emit_update(payload, trace, rebuild_span, perf_events)
+        if SPF_COUNTERS["decision.ksp2_cold_builds"] != cold_builds:
+            # a KSP2 engine rebuilt whole: what it left on the heap
+            # lives until its next cold build. After the update is on
+            # its way to Fib, not before
+            settle_heap()
 
     def _emit_update(
         self, payload, trace, rebuild_span, perf_events

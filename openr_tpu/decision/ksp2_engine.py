@@ -39,7 +39,9 @@ a sound distance-algebra test:
 The distances come from a device-resident all-pairs matrix over the
 sliced-ELL bands (ops/spf_sparse.py): at KSP2 scale (n_pad <= 4096, the
 engine's activation bound) a full all-sources solve is ONE source block
-(~1-2 ms on-device), so every churn event recomputes it, swaps it with
+(dispatch to readback it is the span ops.ksp2_all_pairs, which the cell
+fabric-1000-ksp2.adj-churn reads as ksp2_all_pairs_ms beside the
+device's own time), so every churn event recomputes it, swaps it with
 the previous event's matrix (kept resident — no transfer), and reads
 back one fused packet: the SPF view batch (served to SpfView, saving
 its separate dispatch) plus old/new distance rows for the changed-edge
@@ -49,7 +51,7 @@ device round trip and O(changed) host work.
 
 from __future__ import annotations
 
-from typing import Dict, FrozenSet, List, Optional, Set, Tuple
+from typing import Dict, List, Optional, Set, Tuple
 
 import numpy as np
 
@@ -61,6 +63,7 @@ from openr_tpu.analysis.annotations import (
 from openr_tpu.graph.linkstate import Link, LinkState
 from openr_tpu.ops import dispatch_accounting as _da
 from openr_tpu.ops.spf import INF
+from openr_tpu.telemetry import get_tracer
 
 # Engine activation bound: the event loop keeps TWO device-resident
 # [n_pad, n_pad] int32 matrices (current + previous all-pairs) — at the
@@ -76,12 +79,6 @@ ENGINE_MAX_NODES = 12288
 # (set_engine_mesh), the all-pairs fixed point and the masked batches
 # run SHARDED over the mesh — per-device footprint n^2/ndev — and the
 # activation bound scales with sqrt(ndev) (~100k on a 64-way mesh).
-# The speculative resident-masks fast path runs mesh-wide too: the
-# destination batch pads to a mesh multiple and the mask stack / dm
-# residents stripe over the batch axis (ShardingPlan.batch3/rows).
-# When the fast path CANNOT engage on-mesh (mask budget, empty batch)
-# the drop is typed — decision.ksp2.spec_mesh_fallbacks plus a trace
-# stamp — never silent.
 _ENGINE_MESH = None
 
 
@@ -108,34 +105,11 @@ def engine_max_nodes() -> int:
     return int(ENGINE_MAX_NODES * math.sqrt(_ENGINE_MESH.devices.size))
 
 
-# churn larger than this falls back to a full (cold) rebuild
+# churn larger than this falls back to a full (cold) rebuild; the
+# fused dispatch pads its endpoint rows and its increase list to these,
+# so every sync of an engine runs one compiled shape
 ENGINE_MAX_CHANGED_PAIRS = 64
 ENGINE_MAX_ENDPOINTS = 32
-# if more than this fraction of destinations is affected, a cold
-# rebuild is cheaper than the incremental machinery
-ENGINE_FULL_REBUILD_FRACTION = 3  # affected * N > dsts  -> cold
-# fast path: how many changed masked rows the fused dispatch reads back
-# inline; more than this forces one extra full-matrix readback
-ENGINE_ROW_BUDGET = 64
-
-
-def _fast_path_enabled() -> bool:
-    """The resident-mask speculative solve trades extra device compute
-    (a masked re-solve of EVERY destination per event) for one fewer
-    host<->device round trip. On the CPU backend round trips are free
-    and the speculation is pure overhead (8x slower at fabric-1008 on
-    the host clock), so it only engages on real accelerators; whether
-    the trade pays on a chip the host is attached to is not measured
-    (ROADMAP D5). OPENR_KSP2_FAST=1/0 overrides (tests force it on
-    under the CPU mesh)."""
-    import os
-
-    override = os.environ.get("OPENR_KSP2_FAST")
-    if override is not None:
-        return override == "1"
-    import jax
-
-    return jax.devices()[0].platform != "cpu"
 
 
 def _counters():
@@ -254,58 +228,108 @@ def make_cands_of(ls: LinkState, node_index: Dict[str, int]):
     return cands_of
 
 
+# one instance per engine, built and patched inside that engine's
+# sync: the engine's own "owner" confinement covers it
+@thread_confined(
+    "owner", "_rows", "blocked", "link", "off", "uid", "w",
+)
 class _TraceArrays:
-    """Int-encoded view of one build's candidate structure for the
-    native batch tracer (native/spfcore.cpp ksp2_trace_batch): a
-    candidate CSR in the same canonical order make_cands_of yields,
-    a link table for id<->object mapping, and the transit-blocked
-    bitmap. Built once per churn event and shared by every trace
-    site; the Python tracer remains the fallback and the semantic
-    reference."""
+    """Int-encoded view of the candidate structure for the native batch
+    tracer (native/spfcore.cpp ksp2_trace_batch): a candidate CSR in
+    the same canonical order make_cands_of yields, a link table for
+    id<->object mapping, and the transit-blocked bitmap. Built whole
+    once per engine epoch and PATCHED per churn event: only the rows of
+    the nodes the LinkState journals name are re-derived (building it
+    whole sorts every node's links, O(E log E) of Python per event,
+    which at 1016 nodes was the largest single item of an incremental
+    sync). Shared by every trace site of an event; the Python tracer
+    remains the fallback and the semantic reference."""
 
     __slots__ = (
         "off", "link", "uid", "w", "links", "lid_of", "blocked",
-        "n_pad",
+        "n_pad", "index", "_rows",
     )
 
     def __init__(self, graph, cands_of, transit_blocked):
-        index = graph.node_index
-        names = graph.node_names
-        n_pad = graph.n_pad
-        off = np.zeros(n_pad + 1, np.int32)
-        link_l: List[int] = []
-        uid_l: List[int] = []
-        w_l: List[int] = []
-        links: List[Link] = []
+        self.index = graph.node_index
+        self.n_pad = graph.n_pad
+        self.links: List[Link] = []
         # keyed by the Link VALUE (its hash is cached), not id(): the
         # Python tracer excludes via `link not in excluded` — a link
         # that flapped down and back up is a fresh-but-EQUAL object,
         # and an identity key would silently drop its exclusion
-        lid_of: Dict[Link, int] = {}
-        for i, v in enumerate(names):
-            for lnk, _u, uuid, w in cands_of(v):
-                lid = lid_of.get(lnk)
-                if lid is None:
-                    lid = lid_of[lnk] = len(links)
-                    links.append(lnk)
-                link_l.append(lid)
-                uid_l.append(-1 if uuid is None else int(uuid))
-                w_l.append(int(w))
-            off[i + 1] = len(link_l)
-        off[len(names) + 1 :] = len(link_l)
+        self.lid_of: Dict[Link, int] = {}
+        # per node: (link ids, origin ids, weights), in canonical order
+        self._rows: List[Tuple[np.ndarray, np.ndarray, np.ndarray]] = [
+            self._row(cands_of(v)) for v in graph.node_names
+        ]
+        self._flatten()
+        self.set_blocked(transit_blocked)
+
+    def _row(self, cands):
+        link_l: List[int] = []
+        uid_l: List[int] = []
+        w_l: List[int] = []
+        links, lid_of = self.links, self.lid_of
+        for lnk, _u, uuid, w in cands:
+            lid = lid_of.get(lnk)
+            if lid is None:
+                lid = lid_of[lnk] = len(links)
+                links.append(lnk)
+            else:
+                # an equal link re-created by a flap: paths must carry
+                # the LIVE object, whose metrics are the current ones
+                links[lid] = lnk
+            link_l.append(lid)
+            uid_l.append(-1 if uuid is None else int(uuid))
+            w_l.append(int(w))
+        return (
+            np.asarray(link_l, np.int32), np.asarray(uid_l, np.int32),
+            np.asarray(w_l, np.int32),
+        )
+
+    def _flatten(self) -> None:
+        off = np.zeros(self.n_pad + 1, np.int32)
+        counts = [len(r[0]) for r in self._rows]
+        off[1 : len(counts) + 1] = np.cumsum(counts)
+        off[len(counts) + 1 :] = off[len(counts)]
         self.off = off
-        self.link = np.asarray(link_l, np.int32)
-        self.uid = np.asarray(uid_l, np.int32)
-        self.w = np.asarray(w_l, np.int32)
-        self.links = links
-        self.lid_of = lid_of
-        blocked = np.zeros(n_pad, np.uint8)
+        for x, name in enumerate(("link", "uid", "w")):
+            setattr(self, name, np.concatenate(
+                [r[x] for r in self._rows]
+            ))
+
+    def rows_of(self, node_id: int):
+        """(link ids, origin ids, weights) of a node's candidate
+        in-links, in canonical order."""
+        return self._rows[node_id]
+
+    def patch(self, cands_of, dirty, transit_blocked) -> List[Tuple]:
+        """Re-derive the rows of the ``dirty`` nodes (names) — a changed
+        adjacency moves the candidate rows of its two ends only.
+        Returns (node id, what moved) for the nodes whose candidates
+        are not, place for place, the origins they were: ``(place, +1)``
+        where one link came in at ``place``, ``(place, -1)`` where the
+        one at ``place`` went, None where more than that changed."""
+        reordered = []
+        for name in dirty:
+            i = self.index.get(name)
+            if i is not None:
+                row = self._row(cands_of(name))
+                if not np.array_equal(row[1], self._rows[i][1]):
+                    reordered.append((i, _one_moved(self._rows[i][1], row[1])))
+                self._rows[i] = row
+        self._flatten()
+        self.set_blocked(transit_blocked)
+        return reordered
+
+    def set_blocked(self, transit_blocked) -> None:
+        blocked = np.zeros(self.n_pad, np.uint8)
         for nm in transit_blocked:
-            bi = index.get(nm)
+            bi = self.index.get(nm)
             if bi is not None:
                 blocked[bi] = 1
         self.blocked = blocked
-        self.n_pad = n_pad
 
     def _excl_arrays(self, excls):
         """Per-dst exclusion ranges; a link absent from the current
@@ -320,10 +344,14 @@ class _TraceArrays:
             off[i + 1] = len(ids)
         return off, np.asarray(ids, np.int32)
 
-    def trace(self, src_id, dst_ids, rows, shared_row, excls):
+    def trace(self, src_id, dst_ids, rows, shared_row, excls,
+              reach=None):
         """Batch-enumerate via the native core; None when it is
         unavailable. Paths come back as Link-object lists, identical
-        in content and order to trace_paths_from_row."""
+        in content and order to trace_paths_from_row. ``reach``: an
+        int32 [len(dst_ids), n_pad] of -1 that comes back, for every
+        node a destination's traces consulted, with how far down the
+        node's candidate list they looked (native_spf.trace_batch)."""
         from openr_tpu.graph import native_spf
 
         excl_off, excl_ids = self._excl_arrays(excls)
@@ -332,7 +360,7 @@ class _TraceArrays:
             self.uid, self.w, src_id, self.blocked,
             np.ascontiguousarray(dst_ids, np.int32),
             np.ascontiguousarray(rows, np.int32),
-            shared_row, excl_off, excl_ids,
+            shared_row, excl_off, excl_ids, reach,
         )
         if got is None:
             return None
@@ -352,6 +380,75 @@ def _path_nodes(src: str, path: List[Link]) -> List[str]:
     return out
 
 
+# reach2, per destination and node (native/spfcore.cpp): -1 where the
+# searches never came; else, of the searches that found a path, 1 + the
+# place of the last candidate they examined (low 14 bits) and _RAN_OUT
+# where one ran the list out; and _IN_LAST where the last search, the
+# one that found none, reached the node
+_DEPTH = (1 << 14) - 1
+_RAN_OUT = 1 << 14
+_IN_LAST = 1 << 15
+# "read to its end, and to any length": what is said where nobody knows
+# (not _IN_LAST: as an edge's tail, unknown must not pass for stuck)
+_ALL_CONSULTED = _RAN_OUT | _DEPTH
+
+
+def _one_moved(was: np.ndarray, now: np.ndarray):
+    """``now`` is ``was`` with one element put in -> (place, +1), or
+    with one taken out -> (place, -1); anything else -> None."""
+    if len(now) == len(was) + 1:
+        longer, shorter, step = now, was, 1
+    elif len(was) == len(now) + 1:
+        longer, shorter, step = was, now, -1
+    else:
+        return None
+    differ = np.flatnonzero(longer[:-1] != shorter)
+    place = int(differ[0]) if len(differ) else len(shorter)
+    if not np.array_equal(longer[place + 1 :], shorter[place:]):
+        return None
+    return place, step
+
+
+def _hop_eccentricity(graph, sid: int) -> int:
+    """Links on the longest fewest-link path from node ``sid``: what
+    ``LinkState.get_max_hops_to_node`` reads off a unit-metric Dijkstra
+    (up links only, no transit through an overloaded node but the
+    root), here as a breadth-first sweep over the in-edge bands the
+    engine already holds on the host — a handful of numpy gathers
+    where the Dijkstra is tens of milliseconds of Python per link
+    flap at a thousand nodes."""
+    reached = np.zeros(graph.n_pad, dtype=bool)
+    reached[sid] = True
+    frontier = reached.copy()
+    forwards = ~np.asarray(graph.overloaded, dtype=bool)
+    forwards[sid] = True
+    hops = 0
+    while True:
+        carry = frontier & forwards
+        nxt = np.zeros(graph.n_pad, dtype=bool)
+        for band, s_b, w_b in zip(graph.bands, graph.src, graph.w):
+            nxt[band.start : band.start + band.rows] = (
+                carry[s_b] & (w_b < INF)
+            ).any(axis=1)
+        nxt &= ~reached
+        if not nxt.any():
+            return hops
+        hops += 1
+        reached |= nxt
+        frontier = nxt
+
+
+def _masked_buckets(chunk: int) -> Tuple[int, ...]:
+    """Batch sizes the masked solve is compiled for: a sixteenth and a
+    half of the chunk, and the chunk (64 / 512 / 1024 rows on a graph
+    whose masks fit the budget whole). A batch of 8 rows and one of 64
+    cost the device about the same, so finer buckets bought compiles,
+    not time: the size of an affected set is the data's, and a bucket
+    first reached inside a churn window stalls the rebuild while it
+    compiles."""
+    return tuple(sorted({max(8, chunk // 16), max(8, chunk // 2), chunk}))
+
+
 def _pad_ids(ids: List[int], bucket_min: int = 8) -> np.ndarray:
     """Pad an id list to a power-of-two bucket by repeating the first id
     (inert for row gathers) so jit shapes stay bounded."""
@@ -367,10 +464,8 @@ def _pad_ids(ids: List[int], bucket_min: int = 8) -> np.ndarray:
     d_prev_dev="rebuilt by _cold_build from the resident EllState "
                "distance cache (engine invalidates to valid=False and "
                "re-seeds on the next sync)",
-    dm_dev="rebuilt by _cold_build from the traced host-side dm rows",
-    masks_t="re-derived by _cold_build from the band tensor shapes",
 )
-@resident_buffers("d_prev_dev", "dm_dev", "masks_t")
+@resident_buffers("d_prev_dev")
 # externally serialized, never internally locked: every engine is
 # created and driven by exactly one plane — Decision's under evb, a
 # ctrl handler's under SolverCtrlHandler._lock, the twin's on its one
@@ -379,9 +474,9 @@ def _pad_ids(ids: List[int], bucket_min: int = 8) -> np.ndarray:
 # hence "owner" confinement (same contract as WorldManager).
 @thread_confined(
     "owner",
+    "_masked_warm",
     "_mesh",
     "_mesh_knob",
-    "_slot_maps",
     "_tarrays",
     "attr_sig",
     "aversion",
@@ -389,16 +484,15 @@ def _pad_ids(ids: List[int], bucket_min: int = 8) -> np.ndarray:
     "d_base",
     "d_prev_dev",
     "dm",
-    "dm_dev",
     "dst_pos",
     "dsts",
     "ecc_hops",
     "eff_w",
     "excl",
+    "excl_users",
     "first_paths",
     "host_dsts",
     "last_affected",
-    "masks_t",
     "node_label",
     "node_users",
     "ov",
@@ -407,6 +501,7 @@ def _pad_ids(ids: List[int], bucket_min: int = 8) -> np.ndarray:
     "sid",
     "state",
     "valid",
+    "reach2",
     "version",
 )
 class Ksp2Engine:
@@ -424,6 +519,11 @@ class Ksp2Engine:
         # which case the single-chip dispatch runs instead).
         self._mesh_knob = _ENGINE_MESH
         self._mesh = None
+        # (band shapes, n_pad, root) the masked buckets are compiled for
+        self._masked_warm = None
+        # ((topology, attributes) versions, _TraceArrays) of the last
+        # trace; None until one ran on the native core
+        self._tarrays = None
 
     # -- public entry ------------------------------------------------------
 
@@ -438,10 +538,21 @@ class Ksp2Engine:
         every device readback must ride the committed chain
         (``aot_call`` + async kick, reaped via ``reap_read``), and the
         ``ops.host_touches.ksp2_window`` observation is the gate."""
-        with _da.event_window("ksp2_window"):
-            return self._sync_window(ls, dsts)
+        with _da.event_window("ksp2_window"), get_tracer().span(
+            "decision.ksp2_sync", changed_pairs=0
+        ) as span:
+            affected = self._sync_window(ls, dsts, span)
+            if span is not None:
+                # a cold build re-derives every destination
+                span.attrs["cold"] = affected is None
+                span.attrs["affected"] = (
+                    len(dsts) if affected is None else len(affected)
+                )
+            return affected
 
-    def _sync_window(self, ls: LinkState, dsts: List[str]) -> Optional[Set[str]]:
+    def _sync_window(
+        self, ls: LinkState, dsts: List[str], span=None
+    ) -> Optional[Set[str]]:
         self.last_affected = None
         from openr_tpu.decision import spf_solver as _ss
 
@@ -452,9 +563,8 @@ class Ksp2Engine:
             or dsts != self.dsts
             or self.sid != state.graph.node_index.get(self.src_name)
             # a widened band (ell_patch grew a slot class in place)
-            # changed the band tensor shapes the resident masks were
-            # built for: the masked fast path would shape-mismatch,
-            # so re-seed everything from the new shapes
+            # changed the band tensor shapes the masked buckets were
+            # compiled for: re-seed everything from the new shapes
             or tuple(state.graph.bands) != getattr(
                 self, "band_shapes", None
             )
@@ -479,6 +589,8 @@ class Ksp2Engine:
             return None
         affected_nodes = set(affected_nodes) | set(attr_nodes)
         changed = self._diff_pairs(ls, affected_nodes)
+        if span is not None and changed is not None:
+            span.attrs["changed_pairs"] = len(changed)
         if changed is None or len(changed) > ENGINE_MAX_CHANGED_PAIRS:
             self._cold_build(ls, state, dsts)
             return None
@@ -520,15 +632,14 @@ class Ksp2Engine:
             ep = [self.sid]
 
         # one fused dispatch: all-pairs + view + old/new endpoint rows
-        # (+ on the fast path: speculative masked re-solve of every
-        # destination against the RESIDENT masks, row-diffed on device)
         from openr_tpu.ops import spf_sparse
 
         view_srcs = spf_sparse.ell_source_batch(graph, ls, self.src_name)
         srcs_dev, w_sv = spf_sparse._batch_args(graph, view_srcs)
-        ep_ids = _pad_ids(ep)
-        use_fast = getattr(self, "masks_t", None) is not None
-        dm_new_dev = None
+        # padded to the limits checked above, as the cold build pads
+        # its own: every sync of an engine runs ONE compiled shape of
+        # the fused program, whatever the window carried
+        ep_ids = _pad_ids(ep, ENGINE_MAX_ENDPOINTS)
         # increase-edge delta for the warm-started fixed point: pairs
         # whose collapsed min weight went UP since d_prev_dev's epoch.
         # An overload flip changes effective weights without touching
@@ -543,58 +654,37 @@ class Ksp2Engine:
             # both the single-chip and the sharded dispatches thread
             # the delta into the warm-seeded fixed point now
             _counters()["decision.ksp2_warm_dispatches"] += 1
-        if self._mesh is not None and use_fast:
-            # mesh twin of the fused speculative dispatch; nothing is
-            # donated (residents keep their NamedSharding placement),
-            # the rebind below is a plain replace
-            d_all_dev, dm_new_dev, packed = (
-                spf_sparse.sharded_ell_all_view_rows_masked(
+        # dispatch to readback of the one fused program: the all-pairs
+        # fixed point, the view and the endpoint rows
+        with get_tracer().span(
+            "ops.ksp2_all_pairs", rows=graph.n_pad, batches=1,
+        ):
+            if self._mesh is not None:
+                # nothing is donated on the mesh (residents keep their
+                # NamedSharding placement): the rebind is a plain replace
+                d_all_dev, packed = spf_sparse.sharded_ell_all_view_rows(
                     state, srcs_dev, w_sv, ep_ids, self.d_prev_dev,
-                    self.masks_t, self.dm_dev, self.sid,
-                    ENGINE_ROW_BUDGET, len(self.dsts), self._mesh,
-                    inc=inc,
+                    self._mesh, inc=inc,
+                    inc_bucket=ENGINE_MAX_CHANGED_PAIRS,
                 )
-            )
-        elif self._mesh is not None:
-            if _fast_path_enabled():
-                # fast path requested but no resident masks on-mesh
-                # (budget refusal at cold build): typed, not silent
-                self._note_mesh_fallback("no_resident_masks")
-            d_all_dev, packed = spf_sparse.sharded_ell_all_view_rows(
-                state, srcs_dev, w_sv, ep_ids, self.d_prev_dev,
-                self._mesh, inc=inc,
-            )
-        elif use_fast:
-            # openr-lint: disable=donation-hazard -- intentional: the
-            # dispatch consumes the previous epoch's resident
-            # d_prev_dev/dm_dev (dead after this call, no retry path)
-            # and both are rebound to the fresh outputs right below
-            d_all_dev, dm_new_dev, packed = spf_sparse.ell_all_view_rows_masked(
-                state, srcs_dev, w_sv, ep_ids, self.d_prev_dev,
-                self.masks_t, self.dm_dev, self.sid, ENGINE_ROW_BUDGET,
-                inc=inc, defer=True,
-            )
-        else:
-            # openr-lint: disable=donation-hazard -- intentional: same
-            # consume-and-rebind discipline as the fast path above
-            d_all_dev, packed = spf_sparse.ell_all_view_rows(
-                state, srcs_dev, w_sv, ep_ids, self.d_prev_dev, inc=inc,
-                defer=True,
-            )
-        # the single-chip dispatches DONATE d_prev_dev (and dm_dev on
-        # the fast path): adopt the outputs NOW, before any fallback
-        # below can hand the dead buffers to _cold_build (which reuses
-        # d_prev_dev as its placeholder). The sharded dispatches donate
-        # nothing, so for them this is a plain rebind.
-        self.d_prev_dev = d_all_dev
-        if dm_new_dev is not None:
-            self.dm_dev = dm_new_dev
-        if not isinstance(packed, np.ndarray):
-            # single-chip deferred dispatch: the packed readback was
-            # kicked copy_to_host_async inside the wrapper — reap it
-            # AFTER the residents adopted the donated outputs so a
-            # reap failure can never hand dead buffers to _cold_build
-            packed = _da.reap_read(packed, kicked=True)
+                self.d_prev_dev = d_all_dev
+            else:
+                # openr-lint: disable=donation-hazard -- intentional: the
+                # dispatch consumes the previous epoch's resident
+                # d_prev_dev (dead after this call, no retry path), which
+                # is rebound to the fresh output right below
+                d_all_dev, packed = spf_sparse.ell_all_view_rows(
+                    state, srcs_dev, w_sv, ep_ids, self.d_prev_dev, inc=inc,
+                    inc_bucket=ENGINE_MAX_CHANGED_PAIRS, defer=True,
+                )
+                # adopt the output NOW, before any fallback below can
+                # hand the dead buffer to _cold_build (which reuses
+                # d_prev_dev as its placeholder), and reap the packed
+                # readback (kicked copy_to_host_async inside the
+                # wrapper) only AFTER that, so a reap failure can never
+                # hand it one either
+                self.d_prev_dev = d_all_dev
+                packed = _da.reap_read(packed, kicked=True)
         b = len(view_srcs)
         p = len(ep_ids)
         view_packed = packed[: 2 * b]
@@ -605,43 +695,11 @@ class Ksp2Engine:
         self._preload_view(ls, graph, view_srcs, view_packed)
         d_new_src = view_packed[0].astype(np.int64)
 
-        aff1, aff2 = self._affected_dsts(
-            ls, graph, changed, d_new_src, rows_new, rows_old
+        aff1, aff2, row_stands, rows_proven = self._affected_dsts(
+            ls, graph, changed, d_new_src, rows_new, rows_old,
+            exact=not ov_flips,
         )
         dst_set = set(self.dst_pos)
-        # slot-map drift: a band patch that changes a node's in-edge
-        # SET re-packs that row's slot assignments, silently re-aiming
-        # every resident mask bit stored for those slots (soak repro
-        # seed 40018: a dropped link shifted two slots and a
-        # destination's masked solve excluded the wrong edges,
-        # yielding a metric-15 second path where the truth was 8).
-        # Metric-only patches keep the slot map stable. Destinations
-        # whose stored paths touch a re-slotted node join aff1 — the
-        # stale-mask bucket, re-solved with FRESH masks.
-        # only the fast path holds RESIDENT masks; the slow path
-        # rebuilds masks fresh from the current slot_of every event,
-        # so there is nothing to go stale there
-        if (
-            graph.slot_of is not None
-            and getattr(self, "masks_t", None) is not None
-        ):
-            for nm in affected_nodes:
-                nid = graph.node_index.get(nm)
-                if nid is None:
-                    continue
-                new_map = graph.slot_of.get(nid, {})
-                old_map = self._slot_maps.get(nid)
-                if old_map is not None and old_map != new_map:
-                    if nm == self.src_name:
-                        # every destination's mask holds its first-hop
-                        # bits in the ROOT's row (build_edge_masks
-                        # sets both endpoint rows), and node_users
-                        # never indexes the root — a re-slotted root
-                        # stales every mask
-                        aff1 |= set(self.dst_pos)
-                    else:
-                        aff1 |= self.node_users.get(nm, set())
-                self._slot_maps[nid] = new_map
         aff1 &= dst_set
         aff2 &= dst_set
         # label/overload materialization extras: paths are unchanged
@@ -655,74 +713,24 @@ class Ksp2Engine:
         route_extra &= dst_set
         affected = aff1 | aff2 | route_extra | (self.host_dsts & dst_set)
 
-        if len(affected) * ENGINE_FULL_REBUILD_FRACTION > len(dsts):
-            self._cold_build(ls, state, dsts)
-            return None
-
-        if use_fast:
-            # parse the on-device row diff: meta row carries the top-K
-            # changed row ids and the total count
-            meta = packed[2 * b + 2 * p]
-            ids = meta[:ENGINE_ROW_BUDGET]
-            count = int(meta[ENGINE_ROW_BUDGET])
-            changed_rows = packed[2 * b + 2 * p + 1 :]
-            # the speculative matrix was adopted right after the
-            # dispatch, so dispatch-2 corrections scatter into the
-            # CURRENT resident state
-            row_map = {}
-            if count <= ENGINE_ROW_BUDGET:
-                for x, i in enumerate(ids):
-                    if int(i) >= 0:
-                        row_map[self.dsts[int(i)]] = changed_rows[x]
-            else:
-                # budget overflow: one extra readback of the full
-                # matrix (rare — means a large fraction of rows moved);
-                # under the mesh the batch carries pad rows — drop them
-                dm_full = np.asarray(
-                    _da.reap_read(dm_new_dev)
-                )[: len(self.dsts)]
-                moved = np.flatnonzero((dm_full != self.dm).any(axis=1))
-                row_map = {self.dsts[int(i)]: dm_full[int(i)] for i in moved}
-            # host-fallback dsts: adopt moved speculative rows into the
-            # host mirror (keeps the overflow diff and future row
-            # budgets quiet) but never re-trace from them
-            for dst in self.host_dsts & set(row_map):
-                self.dm[self.dst_pos[dst]] = row_map[dst]
-            a_retrace = (
-                (aff2 | set(row_map)) - aff1 - self.host_dsts
-            ) & dst_set
-            ok = True
-            if aff1:
-                # first paths changed: masks are stale for these — the
-                # speculative rows are garbage by construction; re-solve
-                # with fresh masks (dispatch 2) and scatter corrections
-                ok = self._recompute(ls, state, sorted(aff1), d_new_src)
-            if not ok:
-                self._cold_build(ls, state, dsts)
-                return None
-            if a_retrace:
-                unrealized = self._retrace_only(
-                    ls, graph, sorted(a_retrace), row_map
-                )
-                if unrealized:
-                    # masks drifted for these: full per-dst repair
-                    if not self._recompute(
-                        ls, state, sorted(unrealized), d_new_src
-                    ):
-                        self._cold_build(ls, state, dsts)
-                        return None
-            # a moved speculative row means the destination's second
-            # paths may have changed even when no membership test
-            # fired — its routes must not be served from the reuse
-            # cache (the soak's stale-route half of the same finding)
-            affected |= set(row_map) & dst_set
-        else:
-            recompute = sorted(aff1 | aff2)
-            if recompute:
-                ok = self._recompute(ls, state, recompute, d_new_src)
-                if not ok:
-                    self._cold_build(ls, state, dsts)
-                    return None
+        # however many the tests name, the incremental machinery is the
+        # cheaper way: it re-derives what moved, where a cold build
+        # re-derives every path and every route besides (an event on
+        # the root's own pod names nine destinations in ten and moves
+        # 0 to all of them)
+        if aff1 or aff2:
+            moved = self._recompute(
+                ls, state, aff1, aff2, d_new_src, changed,
+                row_stands, rows_proven,
+            )
+            # of the destinations the tests named, those whose paths
+            # came back as they were keep their routes
+            affected = moved | route_extra | (self.host_dsts & dst_set)
+        if not rows_proven:
+            named = aff1 | aff2
+            self._refresh_rows(
+                state, [d for d in self.dsts if d not in named]
+            )
         self._prime_all(ls)
 
         # commit snapshots
@@ -746,7 +754,7 @@ class Ksp2Engine:
             w_old >= INF or w_new >= INF
             for (w_old, w_new, _so, _sn) in changed.values()
         ):
-            self.ecc_hops = ls.get_max_hops_to_node(self.src_name)
+            self.ecc_hops = _hop_eccentricity(graph, self.sid)
         self.d_base = d_new_src.astype(np.int32)
         self.version = ls.topology_version
         self.aversion = ls.attributes_version
@@ -756,20 +764,6 @@ class Ksp2Engine:
         return affected
 
     # -- cold build --------------------------------------------------------
-
-    def _note_mesh_fallback(self, reason: str) -> None:
-        """The speculative fast path could not run mesh-wide: bump the
-        typed counter AND stamp the active trace span — the drop
-        forfeits the warm-dispatch win exactly when sharding activates,
-        so it must never be silent (issue 7 satellite)."""
-        _counters()["decision.ksp2.spec_mesh_fallbacks"] += 1
-        from openr_tpu.telemetry import get_tracer
-
-        tracer = get_tracer()
-        span = tracer.span_active(
-            "decision.ksp2.spec_mesh_fallback", reason=reason
-        )
-        tracer.end_span_active(span, reason=reason)
 
     def _cold_build(self, ls: LinkState, state, dsts: List[str]) -> None:
         from openr_tpu.decision import spf_solver as _ss
@@ -782,12 +776,6 @@ class Ksp2Engine:
         self.state = state
         self.dsts = list(dsts)
         self.band_shapes = tuple(graph.bands)
-        # per-node slot-map snapshot for drift detection (see sync):
-        # inner dicts are immutable-in-practice (ell_patch replaces a
-        # node's map wholesale), so references compare by content later
-        self._slot_maps = (
-            dict(graph.slot_of) if graph.slot_of is not None else {}
-        )
         self._mesh_knob = _ENGINE_MESH
         self._mesh = (
             _ENGINE_MESH
@@ -823,24 +811,29 @@ class Ksp2Engine:
                 )()
             else:
                 placeholder = jnp.zeros((n, n), dtype=jnp.int32)
-        if self._mesh is not None:
-            d_all_dev, packed = spf_sparse.sharded_ell_all_view_rows(
-                state, srcs_dev, w_sv,
-                np.asarray([self.sid], np.int32),
-                placeholder, self._mesh,
-            )
-        else:
-            # the dispatch DONATES the placeholder (which may be the
-            # previous d_prev_dev): drop our reference first so a
-            # failed dispatch can't leave a dead buffer behind for the
-            # next cold build to reuse
-            self.d_prev_dev = None
-            d_all_dev, packed = spf_sparse.ell_all_view_rows(
-                state, srcs_dev, w_sv,
-                np.asarray([self.sid], np.int32),
-                placeholder, defer=True,
-            )
-            packed = _da.reap_read(packed, kicked=True)
+        with get_tracer().span(
+            "ops.ksp2_all_pairs", rows=n, batches=1,
+        ):
+            if self._mesh is not None:
+                d_all_dev, packed = spf_sparse.sharded_ell_all_view_rows(
+                    state, srcs_dev, w_sv,
+                    _pad_ids([self.sid], ENGINE_MAX_ENDPOINTS),
+                    placeholder, self._mesh,
+                    inc_bucket=ENGINE_MAX_CHANGED_PAIRS,
+                )
+            else:
+                # the dispatch DONATES the placeholder (which may be the
+                # previous d_prev_dev): drop our reference first so a
+                # failed dispatch can't leave a dead buffer behind for the
+                # next cold build to reuse
+                self.d_prev_dev = None
+                d_all_dev, packed = spf_sparse.ell_all_view_rows(
+                    state, srcs_dev, w_sv,
+                    _pad_ids([self.sid], ENGINE_MAX_ENDPOINTS),
+                    placeholder, inc_bucket=ENGINE_MAX_CHANGED_PAIRS,
+                    defer=True,
+                )
+                packed = _da.reap_read(packed, kicked=True)
         b = len(view_srcs)
         self._preload_view(ls, graph, view_srcs, packed[: 2 * b])
         self.d_base = packed[0].astype(np.int32)
@@ -849,74 +842,41 @@ class Ksp2Engine:
         # first paths traced from the device base row (identical to the
         # host get_kth_paths(.., 1) trace — same canonical order)
         cands_of = make_cands_of(ls, graph.node_index)
-        transit_blocked = {
-            name
-            for name in graph.node_names
-            if ls.is_node_overloaded(name) and name != self.src_name
-        }
+        transit_blocked = self._transit_blocked(ls, graph)
         self.first_paths: Dict[str, List[List[Link]]] = {}
         self.second_paths: Dict[str, List[List[Link]]] = {}
         self.excl: Dict[str, Set[Link]] = {}
+        self.excl_users: Dict[Link, Set[int]] = {}
         self.node_users: Dict[str, Set[str]] = {}
+        # per destination and node, how far down the node's candidate
+        # list the second-path traces looked (-1: never there);
+        # everything, until a trace has said otherwise
+        self.reach2 = np.full(
+            (len(dsts), n), _ALL_CONSULTED, dtype=np.int32
+        )
         traced = self._trace_many(
             ls, graph, cands_of, transit_blocked, dsts, self.d_base,
             True, [set()] * len(dsts),
         )
         for dst, paths in zip(dsts, traced):
-            self.first_paths[dst] = paths
-            self.excl[dst] = {l for p in paths for l in p}
+            self._set_first_paths(dst, paths)
 
         # masked rows for every destination, chunked like the original
         # prefetch; second paths traced from them
         self.dm = np.full((len(dsts), n), INF, dtype=np.int32)
         self.host_dsts: Set[str] = set()
-        self.masks_t = None  # set below; must be None while the
-        self.dm_dev = None  # chunked solves run (no resident scatter)
         self._solve_masked_batches(
             ls, state, dsts, cands_of, transit_blocked
         )
         self._prime_all(ls)
-
-        # fast path (1 device round trip per metric-churn event): keep
-        # every destination's edge masks and masked rows RESIDENT so
-        # the next event's fused dispatch can speculatively re-solve
-        # and row-diff them on device. Gated on the same mask-memory
-        # budget as the chunked dispatch.
-        slots = sum(band.rows * band.k for band in graph.bands)
-        ndev = self._mesh.devices.size if self._mesh is not None else 1
-        # under the mesh the destination batch pads to a device
-        # multiple so the mask stack / dm residents stripe evenly over
-        # the batch axis (ShardingPlan.batch3 / rows); the budget is
-        # charged for the PADDED batch — what the device actually holds
-        b_pad = -(-len(dsts) // ndev) * ndev
-        if (
-            _fast_path_enabled()
-            and dsts
-            and b_pad * 2 * max(1, slots) <= _ss.KSP2_DEVICE_MASK_BUDGET
-        ):
-            excl_sets = [self.excl[d] for d in dsts]
-            # pad rows carry empty exclusion sets: their (unmasked)
-            # speculative solves are diff-masked out by d_real in the
-            # sharded dispatch, so their churn never reads back
-            excl_sets += [set()] * (b_pad - len(dsts))
-            masks_all, _ok = spf_sparse.build_edge_masks(graph, excl_sets)
-            if self._mesh is not None:
-                from openr_tpu.parallel.mesh import ShardingPlan
-
-                plan = ShardingPlan(self._mesh)
-                self.masks_t = tuple(
-                    plan.place(m, plan.batch3) for m in masks_all
+        warm_key = (self.band_shapes, n, self.sid)
+        if self._mesh is None and self._masked_warm != warm_key:
+            # the buckets this build's own batches did not reach
+            for bucket in _masked_buckets(_ss._ksp2_chunk(graph)):
+                spf_sparse.warm_masked_distances_resident(
+                    state, self.sid, bucket
                 )
-                dm_pad = np.full((b_pad, n), INF, dtype=np.int32)
-                dm_pad[: len(dsts)] = self.dm
-                self.dm_dev = plan.place(dm_pad, plan.rows)
-            else:
-                self.masks_t = tuple(jnp.asarray(m) for m in masks_all)
-                self.dm_dev = jnp.asarray(self.dm)
-        elif _fast_path_enabled() and self._mesh is not None and dsts:
-            # speculative path requested but the padded mask stack
-            # exceeds the device budget: typed drop, never silent
-            self._note_mesh_fallback("mask_budget")
+            self._masked_warm = warm_key
 
         # graph-attribute snapshots for churn diffing
         self.eff_w, self.attr_sig = {}, {}
@@ -940,7 +900,7 @@ class Ksp2Engine:
             name: db.node_label
             for name, db in ls.get_adjacency_databases().items()
         }
-        self.ecc_hops = ls.get_max_hops_to_node(self.src_name)
+        self.ecc_hops = _hop_eccentricity(graph, self.sid)
         self.version = ls.topology_version
         self.aversion = ls.attributes_version
         self.valid = True
@@ -949,24 +909,30 @@ class Ksp2Engine:
     # -- diffing -----------------------------------------------------------
 
     @staticmethod
-    def _node_sigs(ls: LinkState, a: str) -> Dict[str, Tuple]:
+    def _node_sigs(
+        ls: LinkState, a: str, far_side: bool = False
+    ) -> Dict[str, Tuple]:
         """Materialization-relevant attributes of every (a, other) link
         direction in ONE pass over a's ordered links: next-hop
         addresses, interfaces, adj labels, and canonical link identity
         (identity changes can reorder the deterministic trace's
         candidate list). One pass matters: per-pair scans made diffing
-        a single churn event O(degree^2) on high-degree spines."""
+        a single churn event O(degree^2) on high-degree spines. With
+        ``far_side`` the same links read from their other end: the
+        (other, a) directions, keyed by ``other`` as well."""
         sigs: Dict[str, List[Tuple]] = {}
         for link in ls.ordered_links_from_node(a):
             if not link.is_up():
                 continue
-            sigs.setdefault(link.other_node(a), []).append(
+            other = link.other_node(a)
+            end = other if far_side else a
+            sigs.setdefault(other, []).append(
                 (
-                    link.iface_from(a),
-                    link.nh_v4_from(a).addr,
-                    link.nh_v6_from(a).addr,
-                    link.adj_label_from(a),
-                    link.metric_from(a),
+                    link.iface_from(end),
+                    link.nh_v4_from(end).addr,
+                    link.nh_v6_from(end).addr,
+                    link.adj_label_from(end),
+                    link.metric_from(end),
                 )
             )
         return {other: tuple(s) for other, s in sigs.items()}
@@ -996,16 +962,6 @@ class Ksp2Engine:
         changed: Dict[Tuple[str, str], Tuple] = {}
         graph_index = self.state.graph.node_index
         seen_pairs: Set[Tuple[str, str]] = set()
-        # one links pass per origin node, not per pair
-        sig_cache: Dict[str, Dict[str, Tuple]] = {}
-        w_cache: Dict[str, Dict[str, int]] = {}
-
-        def node_view(a: str):
-            if a not in sig_cache:
-                sig_cache[a] = self._node_sigs(ls, a)
-                w_cache[a] = self._min_weights(sig_cache[a])
-            return sig_cache[a], w_cache[a]
-
         for x in affected_nodes:
             if x not in graph_index:
                 return None  # node set changed
@@ -1027,15 +983,25 @@ class Ksp2Engine:
                         self.eff_w.get((u, v), INF), INF, None, None,
                     )
                     seen_pairs.add((u, v))
+            # both directions of every pair at x off ONE pass over x's
+            # own links (a link carries both ends' attributes, and the
+            # canonical order of two parallel links is the same seen
+            # from either end): walking each neighbour's links for its
+            # side cost a spine's whole degree per neighbour of an FSW
+            out_sigs = self._node_sigs(ls, x)
+            in_sigs = self._node_sigs(ls, x, far_side=True)
+            out_ws = self._min_weights(out_sigs)
+            in_ws = self._min_weights(in_sigs)
             for other in neighbors:
-                for pair in ((x, other), (other, x)):
+                for pair, sigs, ws in (
+                    ((x, other), out_sigs, out_ws),
+                    ((other, x), in_sigs, in_ws),
+                ):
                     if pair in seen_pairs:
                         continue
                     seen_pairs.add(pair)
-                    a, bnode = pair
-                    sigs_a, ws_a = node_view(a)
-                    w_new = ws_a.get(bnode, INF)
-                    sig_new = sigs_a.get(bnode, ())
+                    w_new = ws.get(other, INF)
+                    sig_new = sigs.get(other, ())
                     w_old = self.eff_w.get(pair, INF)
                     sig_old = self.attr_sig.get(pair, ())
                     if w_old != w_new or sig_old != sig_new:
@@ -1069,11 +1035,21 @@ class Ksp2Engine:
         d_new_src: np.ndarray,
         rows_new: Dict[int, np.ndarray],
         rows_old: Dict[int, np.ndarray],
-    ) -> Tuple[Set[str], Set[str]]:
-        """Returns (first-path affected, masked/second-path affected) —
-        split because the former invalidates the destination's MASKS
-        (forcing a fresh masked solve) while the latter only needs the
-        second paths re-derived."""
+        exact: bool = False,
+    ) -> Tuple[Set[str], Set[str], Set[str], bool]:
+        """Returns (first-path affected, masked/second-path affected,
+        row_stands, rows_proven). The first two are split because the
+        former invalidates the destination's MASKS (forcing a fresh
+        masked solve) while the latter only needs the second paths
+        re-derived. ``exact``: narrow the second set with
+        _second_paths_may_move where the change is one link's.
+        ``row_stands``: of the second set, the destinations whose
+        masked row provably stands as long as their first paths, and
+        so their masks, do; _recompute re-traces them off the row it
+        has. ``rows_proven``: whether every row the sync does
+        not re-solve is proven to stand; where not (several links at
+        once, a drain flip), the sync re-solves them all to keep the
+        rows exact."""
         index = graph.node_index
         dst_ids = np.asarray(
             [index[d] for d in self.dsts], dtype=np.int64
@@ -1084,9 +1060,13 @@ class Ksp2Engine:
 
         aff = d_new[dst_ids] != d_old_src[dst_ids]
         aff2_vec = np.zeros(len(self.dsts), dtype=bool)
+        row_stands: Set[str] = set()
+        rows_proven = False
 
-        dm = self.dm.astype(np.int64, copy=False)
-        dm_total = dm[np.arange(len(self.dsts)), dst_ids]
+        # int32 as held: widened a column at a time where sums are
+        # taken (the whole matrix is 8 MB a sync to widen)
+        dm = self.dm
+        dm_total = dm[np.arange(len(self.dsts)), dst_ids].astype(np.int64)
 
         def eff(w, origin, ov_map):
             if w >= INF:
@@ -1098,6 +1078,12 @@ class Ksp2Engine:
         ov_new = {
             x: ls.is_node_overloaded(x) for x in graph.node_names
         }
+        # links (either direction) usable now that were not before
+        appeared = len({
+            frozenset((u, v))
+            for (u, v), (w_old, w_new, _so, _sn) in changed.items()
+            if eff(w_old, u, self.ov) >= inf and eff(w_new, u, ov_new) < inf
+        })
         for (u, v), (w_old, w_new, _so, _sn) in changed.items():
             uid, vid = index[u], index[v]
             r_old_v = rows_old[vid].astype(np.int64, copy=False)
@@ -1125,10 +1111,11 @@ class Ksp2Engine:
             # fires for every disconnected row and the engine
             # degenerates to cold rebuilds.
             reachable_m = dm_total < inf
+            dm_u = dm[:, uid].astype(np.int64)
             if wo < inf:
-                lhs = dm[:, uid] + wo + r_old_v[dst_ids]
+                lhs = dm_u + wo + r_old_v[dst_ids]
                 valid = (
-                    (dm[:, uid] < inf)
+                    (dm_u < inf)
                     & (r_old_v[dst_ids] < inf)
                     & reachable_m
                 )
@@ -1145,181 +1132,518 @@ class Ksp2Engine:
                 # edge usable where it was not (link appeared, or its
                 # origin was undrained — hence EFFECTIVE weights, not
                 # raw: overload flips are injected with equal raw w):
-                # disconnected masked rows may reconnect
-                aff2_vec |= ~reachable_m
+                # disconnected masked rows may reconnect. Through ONE
+                # new link only those whose masked graph already
+                # reaches the edge's tail (a destination whose first
+                # paths take every link of the root reaches nothing,
+                # and a link elsewhere cannot change that); with
+                # several, one may carry the reach to the next
+                if appeared > 1:
+                    aff2_vec |= ~reachable_m
+                else:
+                    aff2_vec |= ~reachable_m & (dm_u < inf)
+        if exact:
+            verdict = self._second_paths_may_move(
+                ls, graph, changed, dm, ov_new, eff
+            )
+            if verdict is not None:
+                # a destination whose first paths move gets fresh masks
+                # and a fresh solve whatever this says; one whose row
+                # may move is re-solved even where the bound above sees
+                # no shortest second path through the edge, because the
+                # verdict reads the rows as exact (a row left stale at
+                # a node that mattered to nothing is a wrong verdict
+                # the day it matters)
+                may_move, row_moves, mended = verdict
+                for col, at, values in mended:
+                    self.dm[at, col] = values
+                aff2_vec = (aff2_vec & may_move) | row_moves
+                row_stands = {
+                    self.dsts[i]
+                    for i in np.flatnonzero(aff2_vec & ~row_moves)
+                }
+                rows_proven = True
         aff1 = {self.dsts[i] for i in np.flatnonzero(aff)}
         aff2 = {self.dsts[i] for i in np.flatnonzero(aff2_vec)}
-        return aff1, aff2
+        return aff1, aff2, row_stands, rows_proven
+
+    def _second_paths_may_move(
+        self, ls, graph, changed, dm, ov_new, eff
+    ) -> Optional[Tuple[np.ndarray, np.ndarray, List[Tuple]]]:
+        """Two [D] bools: the destinations whose second-path trace can
+        come out differently after the change of ONE link, first paths
+        and masks unchanged; and, of those, the ones whose masked row
+        may have moved (the rest need a trace, not a solve). Third,
+        the rows that did move, in one column only and where no walk
+        read it, as ``(column, row positions, new values)``: the
+        caller writes them into ``dm`` and they are in neither vector
+        (_one_column_moves). None where the question is not this
+        simple (several links in one window, parallel links, no native
+        tracer): the caller keeps its DAG-membership bound.
+
+        The trace of destination i is a function of the prefixes it
+        read of the predecessor lists of the nodes it consults
+        (``reach2[i]``, as the native tracer reported them: a walk
+        takes the first unspent predecessor and looks no further), and
+        a node's list is a function of its candidate in-links, of i's
+        exclusions and of i's masked distances. So i's second paths
+        stand when (a) at the node the changed link points INTO no
+        walk looked as far down as that link (and, for an edge that
+        only got dearer, no path runs over it), and (b) its masked row
+        is provably what it was: a raised or removed edge u->v moves
+        the row only where it was tight and v has no other tight,
+        unexcluded predecessor (with one, v keeps its distance and so
+        does everything behind it); a lowered or new one only where
+        dm[u] + w undercuts dm[v]. The DAG-membership bound asks
+        whether the edge lies on SOME shortest second path, which on a
+        fat-tree is true of every spine link for some 300
+        destinations; this asks whether the walk ever looked."""
+        pairs = list(changed.items())
+        if not 1 <= len(pairs) <= 2 or (
+            len(pairs) == 2 and pairs[0][0] != pairs[1][0][::-1]
+        ):
+            return None
+        index = graph.node_index
+        # the candidate rows as the last sync's traces saw them, before
+        # this event's patch lands on them
+        cached = self._tarrays
+        if cached is None or cached[0] != (self.version, self.aversion):
+            return None
+        was = {index[v]: cached[1].rows_of(index[v]) for (_u, v), _ in pairs}
+        arrays = self._trace_arrays(
+            ls, graph, make_cands_of(ls, graph.node_index),
+            self._transit_blocked(ls, graph),
+        )
+        if arrays is None:
+            return None
+        inf = np.int64(INF)
+        may = np.zeros(len(self.dsts), dtype=bool)
+        row_moves = np.zeros(len(self.dsts), dtype=bool)
+        mended: List[Tuple] = []
+        for (u, v), (w_old, w_new, _so, _sn) in pairs:
+            uid, vid = index[u], index[v]
+            lids, uids, ws = arrays.rows_of(vid)
+            at_new = np.flatnonzero(uids == uid)
+            at_old = np.flatnonzero(was[vid][1] == uid)
+            if len(at_new) > 1 or len(at_old) > 1 or len(uids) >= _DEPTH:
+                return None  # parallel links: which one changed?
+            if not len(at_new) and not len(at_old):
+                continue
+            # where the link sits among v's candidates: the same place
+            # before and after, or, gone or new, the place it left or
+            # took, with every candidate before it where it was
+            at = int(at_new[0] if len(at_new) else at_old[0])
+            wo, wn = eff(w_old, u, self.ov), eff(w_new, u, ov_new)
+            dm_u = dm[:, uid].astype(np.int64)
+            dm_v = dm[:, vid].astype(np.int64)
+            # (a): v's list changes where the edge is, or was, on it —
+            # or keeps it with other attributes (the route's to carry)
+            # — and a walk saw that only if it looked that far down
+            if wn > wo:
+                # an edge that only got dearer, or went, takes nothing
+                # from a walk but itself: a branch that died dies
+                # sooner, a search that failed still fails, and a
+                # predecessor tried before the one taken is skipped
+                # now as it failed then. Only a walk THROUGH it moves
+                consulted = self._second_paths_over(u, v)
+            else:
+                # one that appeared or got cheaper (or changed what a
+                # route carries of it) can turn up in any list a walk
+                # read as far as its place, or read to the end
+                looked = self.reach2[:, vid]
+                consulted = (looked >= 0) & (
+                    ((looked & _DEPTH) > at) | ((looked & _RAN_OUT) != 0)
+                )
+                # ... or give the LAST search, the one that found no
+                # further path, a way on: it reached v, and the edge
+                # helps it only from a tail it had not reached as well
+                # (what it reached is the set nothing leads on from
+                # once the paths are taken; an edge inside the set
+                # leaves it the set). Only the last search may be
+                # argued so: a list that ran out earlier may have run
+                # out after a path took its last way on, and the new
+                # edge can change which path comes first
+                stuck = (looked >= 0) & ((looked & _IN_LAST) != 0)
+                if uid != self.sid:
+                    tail = self.reach2[:, uid]
+                    stuck &= ~((tail >= 0) & ((tail & _IN_LAST) != 0))
+                consulted |= stuck
+            if wn != wo:
+                consulted &= (dm_u < inf) & (
+                    (dm_u + wo == dm_v) | (dm_u + wn == dm_v)
+                )
+            may |= consulted
+            moves = np.zeros(len(self.dsts), dtype=bool)
+            if wn < wo:
+                moves = (dm_u < inf) & (dm_u + wn < dm_v)
+            elif wn > wo:
+                other = np.zeros(len(self.dsts), dtype=bool)
+                for lid, cu, cw in zip(
+                    lids.tolist(), uids.tolist(), ws.tolist()
+                ):
+                    if cu < 0 or cu == uid or (
+                        cu != self.sid and arrays.blocked[cu]
+                    ):
+                        continue
+                    dm_c = dm[:, cu].astype(np.int64)
+                    tight = (dm_c < inf) & (dm_c + cw == dm_v)
+                    users = self.excl_users.get(arrays.links[lid])
+                    if users:
+                        tight[list(users)] = False
+                    other |= tight
+                moves = (dm_u < inf) & (dm_u + wo == dm_v) & ~other
+            if moves.any():
+                at, values = self._one_column_moves(
+                    ls, arrays, dm, u, v, uid, vid, wo, wn,
+                    np.flatnonzero(moves & ~consulted),
+                )
+                if len(at):
+                    mended.append((vid, at, values))
+                    moves[at] = False
+            row_moves |= moves
+        if len(mended) == 2:
+            # a link's two directions each moved a column of one row:
+            # the two proofs read each other's column as it was
+            both = np.intersect1d(mended[0][1], mended[1][1])
+            if len(both):
+                row_moves[both] = True
+                mended = [
+                    (col, at[keep], values[keep])
+                    for col, at, values in mended
+                    for keep in [~np.isin(at, both)]
+                ]
+        return may | row_moves, row_moves, mended
+
+    def _one_column_moves(
+        self, ls, arrays, dm, u, v, uid, vid, wo, wn, rows
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """Of ``rows`` (positions of destinations whose masked row the
+        change of edge u->v moves, their walks not through it), those
+        whose row moves in column v ALONE, where no walk of theirs
+        read that column: ``(positions, new dm[:, v])``. For these the
+        row is mended here and nothing else is done: no masks, no
+        solve, no trace.
+
+        The column alone: a raised or removed edge leaves every other
+        node its distance where each node v leads on to over a tight
+        edge has another tight, unexcluded predecessor (it keeps its
+        distance, and so does everything behind it); a lowered or new
+        one where v's new distance undercuts no node v leads on to.
+        v's new distance is the least over its usable predecessors, as
+        the masked relax computes it. Nobody read it: the tracer reads
+        a row at the nodes a walk consulted and at every candidate
+        predecessor of those (``ensure_preds`` in native/spfcore.cpp
+        tests each for tightness), and at the destination; so not
+        where reach2 shows the walks at neither v nor any neighbour of
+        v, now or before the change. On a fat-tree whose root has one
+        uplink its first paths leave free, every masked graph hangs
+        off that uplink and a change at a leaf of its plane moves one
+        column of six hundred rows that matter to one route."""
+        none = np.zeros(0, dtype=np.int64)
+        if not len(rows):
+            return none, none
+        inf = np.int64(INF)
+        index = arrays.index
+        # v's neighbours, now and as the last traces saw them
+        out_w: Dict[int, int] = {}
+        for link in ls.links_from_node(v):
+            if link.is_up():
+                sid = index.get(link.other_node(v))
+                if sid is not None:
+                    w = min(int(link.metric_from(v)), INF - 1)
+                    out_w[sid] = min(out_w.get(sid, w), w)
+        near = set(out_w)
+        for a, b in self.pairs_by_node.get(v, ()):
+            near.add(index[b if a == v else a])
+        near.discard(vid)
+        dst_ids = np.asarray(
+            [index[self.dsts[i]] for i in rows], dtype=np.int64
+        )
+        unread = (dst_ids != vid) & (self.reach2[rows, vid] < 0)
+        for sid in near:
+            unread &= self.reach2[rows, sid] < 0
+        rows = rows[unread]
+        if not len(rows):
+            return none, none
+        sub = dm[rows].astype(np.int64)
+        place = {int(pos): k for k, pos in enumerate(rows)}
+        blocked = arrays.blocked
+
+        def reached_over(nid, but=-1, weight_of=None) -> np.ndarray:
+            """[rows, candidates] int64: each row's distance to node
+            ``nid`` over each of its usable candidate in-links (INF
+            where the tail is out of reach, drained, ``but``, or the
+            row excludes the link)."""
+            lids, uids, ws = arrays.rows_of(nid)
+            keep = (uids >= 0) & (uids != but)
+            keep[keep] &= (uids[keep] == self.sid) | (
+                blocked[uids[keep]] == 0
+            )
+            lids, uids = lids[keep], uids[keep]
+            ws = np.minimum(ws[keep].astype(np.int64), inf - 1)
+            if weight_of is not None:
+                ws[uids == weight_of[0]] = weight_of[1]
+            tails = sub[:, uids]
+            over = np.where(
+                (tails < inf) & (ws[None, :] < inf), tails + ws[None, :], inf
+            )
+            for j, lid in enumerate(lids.tolist()):
+                users = self.excl_users.get(arrays.links[lid])
+                if users:
+                    hit = [place[p] for p in users if p in place]
+                    if hit:
+                        over[hit, j] = inf
+            return over
+
+        # v's new distance: the least over the predecessors it has now
+        # (the changed edge among them at its new weight, if it stays)
+        over_v = reached_over(vid, weight_of=(uid, wn))
+        new_v = (
+            over_v.min(axis=1) if over_v.shape[1]
+            else np.full(len(rows), inf, dtype=np.int64)
+        )
+        alone = np.ones(len(rows), dtype=bool)
+        old_v = sub[:, vid]
+        for sid, w in out_w.items():
+            if wn < wo:
+                alone &= new_v + w >= sub[:, sid]
+                continue
+            tight = (old_v < inf) & (old_v + w == sub[:, sid])
+            if tight.any():
+                others = reached_over(sid, but=vid)
+                alone &= ~tight | (
+                    others == sub[:, sid][:, None]
+                ).any(axis=1)
+        return rows[alone], new_v[alone].astype(np.int32)
 
     # -- recompute ---------------------------------------------------------
 
-    def _retrace_only(
-        self, ls: LinkState, graph, dsts: List[str],
-        row_map: Dict[str, np.ndarray],
-    ) -> Set[str]:
-        """Fast-path update for destinations whose MASKS are unchanged:
-        adopt the speculative masked row (when it moved) and re-trace
-        second paths with the current weights. First paths and
-        exclusion sets stay as cached.
+    def _second_paths_over(self, u: str, v: str) -> np.ndarray:
+        """[D] bool: destinations with a second path that crosses a
+        link from ``u`` to ``v``."""
+        over = np.zeros(len(self.dsts), dtype=bool)
+        users = self.node_users.get(v, set())
+        if u != self.src_name:
+            users = users & self.node_users.get(u, set())
+        for dst in users:
+            for path in self.second_paths.get(dst, ()):
+                cur = self.src_name
+                for link in path:
+                    nxt = link.other_node(cur)
+                    if cur == u and nxt == v:
+                        over[self.dst_pos[dst]] = True
+                    cur = nxt
+        return over
 
-        Returns the destinations whose row could NOT be realized by a
-        trace (a finite masked total with no path walking to it): that
-        means the resident masks drifted from the destination's true
-        exclusion set, so the speculative row is bogus — the caller
-        must _recompute them from scratch (fresh first paths + masks).
-        The mixed-churn soak caught exactly this as a silently dropped
-        second path (seed 9013: stale masks yielded total 6 where the
-        true masked distance was 8, the trace found nothing, and the
-        destination was never invalidated)."""
-        cands_of = make_cands_of(ls, graph.node_index)
-        transit_blocked = {
+    def _transit_blocked(self, ls: LinkState, graph) -> Set[str]:
+        """Drained nodes: reachable, but no path runs through one (the
+        root, drained or not, still originates)."""
+        return {
             name
             for name in graph.node_names
             if ls.is_node_overloaded(name) and name != self.src_name
         }
-        for dst in dsts:
-            row = row_map.get(dst)
-            if row is not None:
-                self.dm[self.dst_pos[dst]] = row
-            for path in self.second_paths.get(dst, []):
+
+    def _set_first_paths(self, dst: str, paths: List[List[Link]]) -> None:
+        """First paths, the exclusion set the masked solve takes from
+        them, and the index of which destinations exclude a link."""
+        pos = self.dst_pos[dst]
+        for link in self.excl.get(dst, ()):
+            self.excl_users[link].discard(pos)
+        self.first_paths[dst] = paths
+        excl = self.excl[dst] = {l for p in paths for l in p}
+        for link in excl:
+            self.excl_users.setdefault(link, set()).add(pos)
+
+    def _recompute(
+        self, ls: LinkState, state, aff1: Set[str], aff2: Set[str],
+        d_new_src: np.ndarray, changed,
+        row_stands: Set[str], rows_proven: bool,
+    ) -> Set[str]:
+        """Re-derive the paths of the destinations the membership tests
+        named (``aff1``: first paths, ``aff2``: second) and return
+        those whose paths MOVED: the tests name every destination
+        whose shortest-path DAG holds a changed edge, but the canonical
+        trace walks one branch of that DAG, so most come back link for
+        link as they were (a metric raised on one of 36 equal spines
+        moves the destinations traced through that spine, not the 300
+        whose DAG merely contains it; a link of the root's own FSW
+        lies on the DAG of every destination outside the pod). A
+        destination whose two path sets are unchanged and cross no
+        changed pair keeps its cached routes.
+
+        In three steps, each doing less than the one before it was
+        asked to. First paths are re-traced for all of ``aff1``. One
+        whose first paths come back as they were keeps its exclusion
+        set, hence its masks, and is then no different from a
+        destination ``aff1`` never named: where the window's rows are
+        proven (``rows_proven``) it is re-solved only if ``aff2``
+        names it, and re-traced off the row the engine holds, no mask
+        built and nothing sent to the device, if ``row_stands`` has it
+        (_second_paths_may_move). Where they are not proven, every
+        named destination is re-solved as it always was."""
+        graph = state.graph
+        cands_of = make_cands_of(ls, graph.node_index)
+        transit_blocked = self._transit_blocked(ls, graph)
+        named = sorted(aff1 | aff2)
+        before = {
+            dst: (self.first_paths.get(dst), self.second_paths.get(dst))
+            for dst in named
+        }
+        first = sorted(aff1)
+        traced = self._trace_many(
+            ls, graph, cands_of, transit_blocked, first,
+            d_new_src.astype(np.int32), True, [set()] * len(first),
+        )
+        fresh_masks: Set[str] = set()
+        for dst, paths in zip(first, traced):
+            if paths != self.first_paths.get(dst):
+                self._set_first_paths(dst, paths)
+                fresh_masks.add(dst)
+        if rows_proven:
+            solve = [
+                dst for dst in named
+                if dst in fresh_masks
+                or dst in self.host_dsts
+                or (dst in aff2 and dst not in row_stands)
+            ]
+            solved = set(solve)
+            retrace = [
+                dst for dst in named
+                if dst in aff2 and dst not in solved
+            ]
+        else:
+            solve, retrace = named, []
+
+        self.host_dsts -= set(solve)
+        if solve:
+            self._solve_masked_batches(
+                ls, state, solve, cands_of, transit_blocked,
+                index_users=False,
+            )
+        if retrace:
+            at = [self.dst_pos[dst] for dst in retrace]
+            reach = np.full((len(retrace), graph.n_pad), -1, dtype=np.int32)
+            traced = self._trace_many(
+                ls, graph, cands_of, transit_blocked, retrace,
+                np.ascontiguousarray(self.dm[at]), False,
+                [self.excl[dst] for dst in retrace], reach,
+            )
+            for dst, paths in zip(retrace, traced):
+                self.second_paths[dst] = paths
+            reach[:, self.sid] = _ALL_CONSULTED
+            self.reach2[at] = reach
+        # links whose weight or materialization attributes changed: a
+        # path across one keeps its links and still changes its route
+        touched: Set[Link] = set()
+        for u, v in changed:
+            touched.update(
+                link for link in ls.links_from_node(u)
+                if link.other_node(u) == v
+            )
+        moved: Set[str] = set()
+        for dst in named:
+            first, second = before[dst]
+            now = (self.first_paths[dst], self.second_paths.get(dst))
+            if (
+                dst not in self.host_dsts
+                and now == (first, second)
+                and touched.isdisjoint(self.excl[dst])
+                and not any(
+                    link in touched for path in now[1] or () for link in path
+                )
+            ):
+                # the fresh lists are equal to the cached ones; keep
+                # the cached objects so every holder sees one list
+                self.first_paths[dst] = first
+                if second is not None:
+                    self.second_paths[dst] = second
+                continue
+            moved.add(dst)
+            # reverse index: drop the old paths' nodes, add the new
+            for path in (first or []) + (second or []):
                 for x in _path_nodes(self.src_name, path):
                     users = self.node_users.get(x)
                     if users is not None:
                         users.discard(dst)
-        traced = self._trace_many(
-            ls, graph, cands_of, transit_blocked, dsts,
-            np.ascontiguousarray(
-                self.dm[[self.dst_pos[d] for d in dsts]]
-            ),
-            False, [self.excl[d] for d in dsts],
-        )
-        unrealized: Set[str] = set()
-        for dst, paths in zip(dsts, traced):
-            if not paths:
-                # empty trace: either the row is finite but unwalkable
-                # (masks drifted toward extra paths) or INF where the
-                # true masked graph has a path (masks drifted toward
-                # extra exclusions) — indistinguishable without fresh
-                # masks, and a genuinely second-path-less destination
-                # just re-confirms cheaply. Recompute all of them.
-                unrealized.add(dst)
+            if dst in self.host_dsts:
                 continue
-            self.second_paths[dst] = paths
-            for path in paths:
+            for path in now[0] + (now[1] or []):
                 for x in _path_nodes(self.src_name, path):
                     self.node_users.setdefault(x, set()).add(dst)
-        return unrealized
+        return moved
 
-    def _recompute(
-        self, ls: LinkState, state, affected: List[str],
-        d_new_src: np.ndarray,
-    ) -> bool:
+    def _masked_dispatch(self, state, batch: List[str]):
+        """Masks of one chunk's destinations, padded to one of the
+        chunk's three buckets (all compiled by the cold build, so no
+        affected-set size compiles later), and their masked solve
+        dispatched. Returns ``(ok, drows_dev, drows)``: which masks
+        the slots could carry, and the rows either on the device with
+        their readback kicked (one chip: the caller reaps them) or on
+        the host (the mesh's sharded solve returns them so)."""
         from openr_tpu.decision import spf_solver as _ss
         from openr_tpu.ops import spf_sparse
 
         graph = state.graph
-        cands_of = make_cands_of(ls, graph.node_index)
-        transit_blocked = {
-            name
-            for name in graph.node_names
-            if ls.is_node_overloaded(name) and name != self.src_name
-        }
-        for dst in affected:
-            # drop stale reverse-index entries
-            for path in self.first_paths.get(dst, []) + self.second_paths.get(
-                dst, []
-            ):
-                for x in _path_nodes(self.src_name, path):
-                    users = self.node_users.get(x)
-                    if users is not None:
-                        users.discard(dst)
-        traced = self._trace_many(
-            ls, graph, cands_of, transit_blocked, affected,
-            d_new_src.astype(np.int32), True,
-            [set()] * len(affected),
+        bucket = next(
+            b for b in _masked_buckets(_ss._ksp2_chunk(graph))
+            if b >= len(batch)
         )
-        for dst, paths in zip(affected, traced):
-            self.first_paths[dst] = paths
-            self.excl[dst] = {l for p in paths for l in p}
+        if self._mesh is not None:
+            # sharded batches divide destinations over the mesh
+            ndev = self._mesh.devices.size
+            bucket = -(-max(bucket, ndev) // ndev) * ndev
+        masks, ok = spf_sparse.build_edge_masks(
+            graph,
+            [self.excl[d] for d in batch] + [set()] * (bucket - len(batch)),
+        )
+        _counters()["decision.ksp2_device_batches"] += 1
+        if self._mesh is not None:
+            return ok, None, spf_sparse.sharded_ell_masked_distances_resident(
+                state, self.sid, masks, self._mesh
+            )
+        # committed chain: the masked rows are kicked
+        # copy_to_host_async and the host copy is reaped once
+        return ok, spf_sparse.ell_masked_distances_resident(
+            state, self.sid, masks, defer=True
+        ), None
 
-        self.host_dsts -= set(affected)
-        self._solve_masked_batches(
-            ls, state, affected, cands_of, transit_blocked
-        )
-        return True
+    def _refresh_rows(self, state, dsts: List[str]) -> None:
+        """Masked rows of ``dsts`` solved again and kept, nothing
+        traced: what keeps ``dm`` exact through a window whose changes
+        _second_paths_may_move does not answer for."""
+        from openr_tpu.decision import spf_solver as _ss
+
+        dsts = [d for d in dsts if d not in self.host_dsts]
+        if not dsts:
+            return
+        chunk = _ss._ksp2_chunk(state.graph)
+        with get_tracer().span(
+            "ops.ksp2_masked_solve", rows=len(dsts),
+            batches=-(-len(dsts) // chunk), refresh=True,
+        ):
+            for start in range(0, len(dsts), chunk):
+                batch = dsts[start : start + chunk]
+                ok, drows_dev, drows = self._masked_dispatch(state, batch)
+                if drows is None:
+                    drows = _da.reap_read(drows_dev, kicked=True)
+                keep = np.flatnonzero(ok[: len(batch)])
+                self.dm[[self.dst_pos[batch[i]] for i in keep]] = (
+                    drows[keep]
+                )
 
     def _solve_masked_batches(
-        self, ls, state, dsts, cands_of, transit_blocked
+        self, ls, state, dsts, cands_of, transit_blocked,
+        index_users: bool = True,
     ) -> None:
         """Masked-SPF rows + second-path traces + dm/node_users updates
         for a destination subset (shared by cold build and incremental
         recompute; the two loops MUST stay identical — fallback
         accounting drifting between them was a review finding)."""
         from openr_tpu.decision import spf_solver as _ss
-        from openr_tpu.ops import spf_sparse
 
         graph = state.graph
         chunk = _ss._ksp2_chunk(graph)
-
-        def _submit(batch):
-            """Stage 1 of the chunk pipeline: mask build + (async)
-            masked solve + resident masks/dm scatter, all chained on
-            the device stream. Returns the in-flight context
-            ``(batch, ok, drows_dev, drows)`` — exactly one of the
-            last two is set, depending on the mesh path."""
-            # pad to a power-of-two bucket (capped at the chunk) so the
-            # masked kernel compiles a handful of shapes, not one per
-            # distinct affected-set size
-            bucket = 8
-            while bucket < len(batch):
-                bucket *= 2
-            bucket = min(bucket, chunk)
-            if self._mesh is not None:
-                # sharded batches divide destinations over the mesh
-                ndev = self._mesh.devices.size
-                bucket = max(bucket, ndev)
-                bucket = ((bucket + ndev - 1) // ndev) * ndev
-            excl_sets = [self.excl[d] for d in batch]
-            pad = bucket - len(batch)
-            masks, ok = spf_sparse.build_edge_masks(
-                graph, excl_sets + [set()] * pad
-            )
-            drows_dev = None
-            if self._mesh is not None:
-                drows = spf_sparse.sharded_ell_masked_distances_resident(
-                    state, self.sid, masks, self._mesh
-                )
-            else:
-                # committed chain: the masked rows are kicked
-                # copy_to_host_async; the resident scatter below chains
-                # off the DEVICE rows, and the host copy is reaped once
-                drows_dev = spf_sparse.ell_masked_distances_resident(
-                    state, self.sid, masks, defer=True
-                )
-                drows = None
-            _counters()["decision.ksp2_device_batches"] += 1
-            if getattr(self, "masks_t", None) is not None:
-                # fast path: keep the RESIDENT masks and masked-row
-                # matrix in sync so the next event's speculative solve
-                # uses current exclusions
-                import jax.numpy as jnp
-
-                ids = jnp.asarray(
-                    np.asarray(
-                        [self.dst_pos[d] for d in batch], np.int32
-                    )
-                )
-                self.masks_t = tuple(
-                    m_res.at[ids].set(jnp.asarray(m_new[: len(batch)]))
-                    for m_res, m_new in zip(self.masks_t, masks)
-                )
-                rows_src = (
-                    drows_dev[: len(batch)]
-                    if drows_dev is not None
-                    else jnp.asarray(drows[: len(batch)])
-                )
-                self.dm_dev = self.dm_dev.at[ids].set(rows_src)
-            return batch, ok, drows_dev, drows
 
         def _settle(batch, ok, drows_dev, drows):
             """Stage 2: reap the masked rows, settle dm + fallback
@@ -1329,27 +1653,35 @@ class Ksp2Engine:
                 drows = _da.reap_read(drows_dev, kicked=True)
             traceable: List[int] = []
             for i, dst in enumerate(batch):
+                self.dm[self.dst_pos[dst]] = drows[i]
                 if not ok[i]:
                     _counters()["decision.ksp2_host_fallbacks"] += 1
                     self.host_dsts.add(dst)
                     self.second_paths.pop(dst, None)
-                    # keep the (unrepresentable-mask) solve row anyway:
-                    # it is deterministic, so the fast path's on-device
-                    # row diff stays quiet for this destination instead
-                    # of burning a gather slot every event; host_dsts
-                    # membership keeps it out of every cache read
-                    self.dm[self.dst_pos[dst]] = drows[i]
+                    self.reach2[self.dst_pos[dst]] = _ALL_CONSULTED
+                    # host_dsts membership keeps its row out of every
+                    # cache read
                     continue
-                self.dm[self.dst_pos[dst]] = drows[i]
                 traceable.append(i)
+            reach = np.full(
+                (len(traceable), graph.n_pad), -1, dtype=np.int32
+            )
             traced = self._trace_many(
                 ls, graph, cands_of, transit_blocked,
                 [batch[i] for i in traceable],
                 np.ascontiguousarray(np.asarray(drows)[traceable]),
                 False, [self.excl[batch[i]] for i in traceable],
+                reach,
             )
             for i, paths in zip(traceable, traced):
                 self.second_paths[batch[i]] = paths
+            if traceable:
+                # a path ends at the root over a link of the root's:
+                # what changes there can change the count of paths
+                reach[:, self.sid] = _ALL_CONSULTED
+                self.reach2[
+                    [self.dst_pos[batch[i]] for i in traceable]
+                ] = reach
 
         # ONE-DEEP chunk pipeline: chunk i+1's masked solve is
         # submitted before chunk i's rows are reaped, so the device
@@ -1359,17 +1691,27 @@ class Ksp2Engine:
         # stage touches only host mirrors. The mesh path degrades to
         # eager per-chunk order — the sharded solve already returns
         # host rows, so there is nothing in flight to overlap.
-        inflight = None
-        for start in range(0, len(dsts), chunk):
-            staged = _submit(dsts[start : start + chunk])
+        # the span's self time is mask build, dispatch and readback:
+        # the second-path traces of _settle nest their own span in it
+        with get_tracer().span(
+            "ops.ksp2_masked_solve", rows=len(dsts),
+            batches=-(-len(dsts) // chunk),
+        ):
+            inflight = None
+            for start in range(0, len(dsts), chunk):
+                # stage 1: mask build + (async) masked solve
+                batch = dsts[start : start + chunk]
+                staged = (batch, *self._masked_dispatch(state, batch))
+                if inflight is not None:
+                    if staged[2] is not None:
+                        _da.note_pipelined_dispatch(2)
+                        _da.note_overlapped_reap()
+                    _settle(*inflight)
+                inflight = staged
             if inflight is not None:
-                if staged[2] is not None:
-                    _da.note_pipelined_dispatch(2)
-                    _da.note_overlapped_reap()
                 _settle(*inflight)
-            inflight = staged
-        if inflight is not None:
-            _settle(*inflight)
+        if not index_users:
+            return  # _recompute indexes the destinations that moved
         for dst in dsts:
             if dst in self.host_dsts:
                 continue
@@ -1380,62 +1722,111 @@ class Ksp2Engine:
                     self.node_users.setdefault(x, set()).add(dst)
 
     def _trace_arrays(self, ls, graph, cands_of, transit_blocked):
-        """Per-event cache of the native tracer's int-encoded candidate
-        structure. One build serves every trace site of the event (cold
-        build first paths, recompute, retrace, masked second paths);
-        None when the native core is unavailable (callers fall back to
-        the Python tracer)."""
+        """The native tracer's int-encoded candidate structure, current
+        for ``ls``. One build serves every trace site of an event (cold
+        build first paths, recompute, retrace, masked second paths) and
+        the next event patches it from the LinkState journals; None
+        when the native core is unavailable (callers fall back to the
+        Python tracer)."""
         from openr_tpu.graph import native_spf
 
         if not native_spf.is_available():
             return None
         key = (ls.topology_version, ls.attributes_version)
-        cached = getattr(self, "_tarrays", None)
-        if (
-            cached is not None
-            and cached[0] == key
-            and cached[1] is graph
+        cached = self._tarrays
+        # (an engine serves one LinkState for life, so the journals
+        # read below are the ones the cached versions came from)
+        if cached is not None and (
+            cached[1].index is not graph.node_index
+            or cached[1].n_pad != graph.n_pad
         ):
-            return cached[2]
-        arrays = _TraceArrays(graph, cands_of, transit_blocked)
-        self._tarrays = (key, graph, arrays)
+            cached = None
+        if cached is not None and cached[0] == key:
+            return cached[1]
+        dirty = None
+        if cached is not None:
+            moved = ls.affected_since(cached[0][0])
+            attrs = ls.attr_affected_since(cached[0][1])
+            if moved is not None and attrs is not None:
+                dirty = set(moved) | set(attrs)
+        reach2 = getattr(self, "reach2", None)
+        if dirty is None:
+            arrays = _TraceArrays(graph, cands_of, transit_blocked)
+            if reach2 is not None:
+                reach2[reach2 >= 0] = _ALL_CONSULTED
+        else:
+            arrays = cached[1]
+            for nid, moved in arrays.patch(
+                cands_of, dirty, transit_blocked
+            ):
+                # reach2 holds places in the list as it was. Where one
+                # link came or went, what was read past its place
+                # shifts by one (a walk that read as far as the link
+                # that went is re-traced anyway: the tests name it);
+                # where more changed, the places say nothing any more
+                # and every walk that read some of the list read it all
+                if reach2 is None:
+                    continue
+                col = reach2[:, nid]
+                if moved is None:
+                    col[col >= 0] = _ALL_CONSULTED
+                else:
+                    place, step = moved
+                    shift = (col >= 0) & ((col & _DEPTH) > place) & (
+                        (col & _DEPTH) != _DEPTH
+                    )
+                    col[shift] += step
+        self._tarrays = (key, arrays)
         return arrays
 
     def _trace_many(
         self, ls, graph, cands_of, transit_blocked, dsts, rows,
-        shared_row, excls,
+        shared_row, excls, reach=None,
     ) -> List[List[List[Link]]]:
         """THE trace front-end for every per-event path enumeration:
         native batch when the core is available, else the Python tracer
         per destination — one site to keep the two byte-identical.
         ``rows``: one [n_pad] row (shared_row) or [len(dsts), n_pad];
-        ``excls``: per-dst exclusion sets (empty for first paths)."""
-        arrays = self._trace_arrays(ls, graph, cands_of, transit_blocked)
-        if arrays is not None:
-            got = arrays.trace(
-                self.sid,
-                np.asarray(
-                    [graph.node_index[d] for d in dsts], np.int32
-                ),
-                rows, shared_row, excls,
+        ``excls``: per-dst exclusion sets (empty for first paths);
+        ``reach``: as _TraceArrays.trace's."""
+        with get_tracer().span(
+            "decision.ksp2_trace", dsts=len(dsts),
+            rank=1 if shared_row else 2,
+        ) as span:
+            arrays = self._trace_arrays(
+                ls, graph, cands_of, transit_blocked
             )
-            if got is not None:
-                return got
-        shared_preds: Optional[Dict[str, list]] = (
-            {} if shared_row else None
-        )
-        row_list = rows.tolist() if shared_row else None
-        return [
-            trace_paths_from_row(
-                self.src_name, dst, graph.node_index,
-                row_list if shared_row else rows[i].tolist(),
-                excls[i], cands_of, transit_blocked,
-                preds_cache=(
-                    shared_preds if not excls[i] else None
-                ),
+            if arrays is not None:
+                got = arrays.trace(
+                    self.sid,
+                    np.asarray(
+                        [graph.node_index[d] for d in dsts], np.int32
+                    ),
+                    rows, shared_row, excls, reach,
+                )
+                if got is not None:
+                    return got
+            if span is not None:
+                span.attrs["python"] = True
+            if reach is not None:
+                # the Python tracer does not say which lists it
+                # consulted: all of them to the end, for all we know
+                reach[:] = _ALL_CONSULTED
+            shared_preds: Optional[Dict[str, list]] = (
+                {} if shared_row else None
             )
-            for i, dst in enumerate(dsts)
-        ]
+            row_list = rows.tolist() if shared_row else None
+            return [
+                trace_paths_from_row(
+                    self.src_name, dst, graph.node_index,
+                    row_list if shared_row else rows[i].tolist(),
+                    excls[i], cands_of, transit_blocked,
+                    preds_cache=(
+                        shared_preds if not excls[i] else None
+                    ),
+                )
+                for i, dst in enumerate(dsts)
+            ]
 
     # -- priming / view preload -------------------------------------------
 

@@ -19,6 +19,7 @@ predecessor links (paths are short; the SPF runs behind them are memoized).
 
 from __future__ import annotations
 
+import contextlib
 import os
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Set, Tuple
@@ -182,10 +183,6 @@ SPF_COUNTERS = _get_registry().counter_dict(
         "decision.ksp2_cold_builds",
         "decision.ksp2_incremental_syncs",
         "decision.ksp2_warm_dispatches",
-        # speculative fast path could not run mesh-wide (mask budget,
-        # empty batch, ...): typed so dashboards and the runbook can
-        # alert on silent single-chip drops under sharding
-        "decision.ksp2.spec_mesh_fallbacks",
         "decision.ksp2_affected_dsts",
         "decision.ksp2_route_reuses",
         "decision.sp_route_reuses",
@@ -223,9 +220,11 @@ FAULT_SPF_SOLVE = register_fault_site("decision.spf_solve")
 KSP2_DEVICE_MIN_DSTS = 32
 # the masked kernel iterates one relaxation per hop: on low-diameter
 # fabrics (fat-tree: 4-6 hops) one dispatch replaces N host Dijkstras
-# (measured 5.4x at 1k nodes), but on a 31x31 grid (60 hops) the
-# iteration count hands the win back to host Dijkstra — gate on the
-# root's hop eccentricity from the unit-metric SPF. The SP_ECMP view
+# (the cell fabric-1000-ksp2.adj-churn reads it as ksp2_masked_solve_ms
+# and ksp2_masked_roofline), but on a 31x31 grid (60 hops) the
+# iteration count is expected to hand the win back to host Dijkstra,
+# which no cell has measured — gate on the root's hop eccentricity
+# (the engine reads it off the bands it holds). The SP_ECMP view
 # solve pays the same per-hop iteration only when it starts cold: its
 # warm solves (spf_sparse._cone_seed) pay the depth of what changed,
 # which the cell grid-10000.drain-churn (198 hops) reads as
@@ -1035,7 +1034,12 @@ class SpfSolver:
         or while any chaos fault is armed: every fault seam belongs to
         the committed path's degradation ladder, and a speculative
         solve consuming a charge would let a fault escape the rung
-        that owns it."""
+        that owns it. And for an area whose KSP2 engine is live: the
+        rebuild's view there comes out of the engine's own fused
+        dispatch (``Ksp2Engine._preload_view``), so a view staged now
+        is solved for nobody, by the dense or ELL view path that area
+        otherwise never runs (in ``fabric-1000-ksp2.adj-churn`` its
+        first use compiled a snapshot patch inside the window)."""
         from openr_tpu.faults.injector import get_injector
 
         reg = _get_registry()
@@ -1057,6 +1061,14 @@ class SpfSolver:
             if prev is not None:
                 # an earlier stage for this graph died unconsumed
                 reg.counter_bump("ops.spec_cancels")
+            engine = self._ksp2_engines.get(ls)
+            if (
+                engine is not None
+                and engine.valid
+                and engine.src_name == my_node_name
+            ):
+                reg.counter_bump("ops.spec_skips")
+                continue
             per_ls = self._views.get(ls)
             if per_ls is not None and key in per_ls:
                 continue  # already current: nothing to speculate
@@ -1499,48 +1511,64 @@ class SpfSolver:
             SPF_COUNTERS["decision.sp_route_reuses"] += len(new_cache)
             iter_prefixes = must
 
-        for prefix in iter_prefixes:
-            if adv_map is not None and prefix in self._route_cache:
-                advertisers, has_ksp2 = adv_map[prefix]
-                # a cached route is reusable when every input that
-                # could change it is provably unchanged:
-                # - non-KSP2 prefix + every advertiser clean under the
-                #   SP dirty test (column-wise vs the previous build)
-                # - OR every advertiser is tracked by the KSP2 engine
-                #   and outside its affected set. An advertiser covered
-                #   by neither detector forces a re-derive.
-                ok = (
-                    not has_ksp2
-                    and reuse_sp is not None
-                    and advertisers.isdisjoint(reuse_sp)
-                )
-                if ok:
-                    SPF_COUNTERS["decision.sp_route_reuses"] += 1
-                elif (
-                    reuse is not None
-                    and advertisers <= self._ksp2_tracked
-                    and advertisers.isdisjoint(reuse)
-                ):
-                    ok = True
-                    SPF_COUNTERS["decision.ksp2_route_reuses"] += 1
-                if ok:
-                    entry, best = self._route_cache[prefix]
-                    if best is not None:
-                        self.best_routes_cache[prefix] = best
-                    if entry is not None:
-                        route_db.add_unicast_route(entry)
-                    new_cache[prefix] = (entry, best)
-                    continue
-            entry = self.create_route_for_prefix(
-                my_node_name, area_link_states, prefix_state, prefix
+        # where the KSP2 engine ran, the loop below is the KSP2 share
+        # of route derivation: label stacks re-derived for the
+        # destinations the engine named, the rest served from the cache
+        # counted here and booked once: a counter bump per prefix is a
+        # registry round trip per prefix
+        ksp2_reused = 0
+        with (
+            _get_tracer().span(
+                "decision.ksp2_routes", prefixes=len(iter_prefixes)
             )
-            if entry is not None:
-                route_db.add_unicast_route(entry)
-            if populate:
-                new_cache[prefix] = (
-                    entry,
-                    self.best_routes_cache.get(prefix),
+            if affected is not None
+            else contextlib.nullcontext()
+        ) as ksp2_span:
+            for prefix in iter_prefixes:
+                if adv_map is not None and prefix in self._route_cache:
+                    advertisers, has_ksp2 = adv_map[prefix]
+                    # a cached route is reusable when every input that
+                    # could change it is provably unchanged:
+                    # - non-KSP2 prefix + every advertiser clean under the
+                    #   SP dirty test (column-wise vs the previous build)
+                    # - OR every advertiser is tracked by the KSP2 engine
+                    #   and outside its affected set. An advertiser covered
+                    #   by neither detector forces a re-derive.
+                    ok = (
+                        not has_ksp2
+                        and reuse_sp is not None
+                        and advertisers.isdisjoint(reuse_sp)
+                    )
+                    if ok:
+                        SPF_COUNTERS["decision.sp_route_reuses"] += 1
+                    elif (
+                        reuse is not None
+                        and advertisers <= self._ksp2_tracked
+                        and advertisers.isdisjoint(reuse)
+                    ):
+                        ok = True
+                        ksp2_reused += 1
+                    if ok:
+                        entry, best = self._route_cache[prefix]
+                        if best is not None:
+                            self.best_routes_cache[prefix] = best
+                        if entry is not None:
+                            route_db.add_unicast_route(entry)
+                        new_cache[prefix] = (entry, best)
+                        continue
+                entry = self.create_route_for_prefix(
+                    my_node_name, area_link_states, prefix_state, prefix
                 )
+                if entry is not None:
+                    route_db.add_unicast_route(entry)
+                if populate:
+                    new_cache[prefix] = (
+                        entry,
+                        self.best_routes_cache.get(prefix),
+                    )
+            SPF_COUNTERS["decision.ksp2_route_reuses"] += ksp2_reused
+            if ksp2_span is not None:
+                ksp2_span.attrs["reused"] = ksp2_reused
         self._route_cache = new_cache
         if populate:
             # the bulk path's starting point next build: previous
@@ -2300,13 +2328,18 @@ class SpfSolver:
                     paths.append((area, path))
 
             first_count = len(paths)
+            # one advertiser in one area: its second paths were solved
+            # with its first paths' links removed, so none contains one
+            anycast = (
+                len(best.all_node_areas) > 1 or len(area_link_states) > 1
+            )
             for node, best_area in sorted(best.all_node_areas):
                 if area != best_area:
                     continue
                 for sec_path in ls.get_kth_paths(my_node_name, node, 2):
                     # avoid double-spray: drop second paths that contain a
                     # first path (anycast in meshes)
-                    if any(
+                    if anycast and any(
                         LinkState.path_a_in_path_b(paths[i][1], sec_path)
                         for i in range(first_count)
                     ):

@@ -126,7 +126,7 @@ def _load() -> Optional[ctypes.CDLL]:
         lib.ksp2_trace_batch.argtypes = [
             ctypes.c_int32, ctypes.c_int32, i32p, i32p, i32p, i32p,
             ctypes.c_int32, u8p, ctypes.c_int32, i32p, i32p,
-            ctypes.c_int32, i32p, i32p, i32p, ctypes.c_int32,
+            ctypes.c_int32, i32p, i32p, i32p, ctypes.c_int32, i32p,
         ]
         lib.ksp2_trace_batch.restype = ctypes.c_int32
         _lib = lib
@@ -214,12 +214,20 @@ def trace_batch(
     shared_row: bool,
     excl_off: np.ndarray,
     excl_ids: np.ndarray,
+    reach: Optional[np.ndarray] = None,
 ) -> Optional[list]:
     """Batched KSP2 link-disjoint path enumeration via the native core
     (spfcore.cpp ksp2_trace_batch) — byte-identical path content and
     order to ksp2_engine.trace_paths_from_row. Returns a list (one per
     destination) of lists of link-id paths, or None when the native
-    library is unavailable. The int32 output buffer grows on overflow."""
+    library is unavailable. The int32 output buffer grows on overflow.
+    ``reach``: a C-contiguous int32 [len(dst_ids), n] set to -1; for
+    every node a destination's traces consulted the core leaves how
+    far down its candidate list the searches that found a path
+    looked (low 14 bits: 1 + the position of the last candidate
+    examined; bit 14 where one ran the list out) and bit 15 where the
+    last search, which found none, reached the node (spfcore.cpp's
+    comment has why they are kept apart)."""
     lib = _load()
     if lib is None:
         return None
@@ -233,18 +241,24 @@ def trace_batch(
             _as_u8p(transit_blocked), n_dsts, _as_i32p(dst_ids),
             _as_i32p(rows), 1 if shared_row else 0,
             _as_i32p(excl_off), _as_i32p(excl_ids), _as_i32p(out), cap,
+            None if reach is None else _as_i32p(reach),
         )
         if wrote >= 0:
             break
         cap *= 4
+    # one bulk conversion: indexing a numpy array an element at a time
+    # costs more than the trace itself at a few thousand paths
+    flat = out[:wrote].tolist()
     result = []
     pos = 0
     for _ in range(n_dsts):
-        n_paths = int(out[pos]); pos += 1
+        n_paths = flat[pos]
+        pos += 1
         paths = []
         for _p in range(n_paths):
-            ln = int(out[pos]); pos += 1
-            paths.append(out[pos : pos + ln].tolist())
+            ln = flat[pos]
+            pos += 1
+            paths.append(flat[pos : pos + ln])
             pos += ln
         result.append(paths)
     return result

@@ -689,13 +689,13 @@ _FORCE_RESET_EDGE = (0, 0, 0)
 
 
 def pad_increase_edges(
-    inc: List[Tuple[int, int, int]]
+    inc: List[Tuple[int, int, int]], bucket_min: int = 4
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Pack an increase-edge delta into pow-of-two bucketed arrays
     (tails, heads, old weights). Padding entries carry w = INF, which
     the tight test masks out, so every bucket size is one compiled
     shape."""
-    bucket = 4
+    bucket = bucket_min
     while bucket < len(inc):
         bucket *= 2
     tails = np.zeros(bucket, dtype=np.int32)
@@ -1384,6 +1384,30 @@ def ell_masked_distances_resident(
     return np.asarray(d)
 
 
+def warm_masked_distances_resident(
+    state: "EllState", src_id: int, rows: int
+) -> bool:
+    """Compile (or find compiled) ``ell_masked_distances_resident``'s
+    executable for a batch of ``rows`` masked graphs, without running
+    it: the same key the dispatch looks up."""
+    from openr_tpu.ops.aot_cache import get_aot_cache
+
+    return get_aot_cache().warm(
+        "ksp2_masked_resident", _ell_masked_source_batch,
+        (
+            state.src,
+            state.w,
+            tuple(
+                jnp.zeros((rows, band.rows, band.k), dtype=jnp.bool_)
+                for band in state.graph.bands
+            ),
+            state.overloaded,
+            src_id,
+        ),
+        dict(bands=state.graph.bands, n=state.graph.n_pad),
+    )
+
+
 def _band_patch_rows(patched: EllGraph):
     """Host half of the band patch discipline, the ONE implementation
     every resident-band consumer shares: per band, ``(widened, ids)``.
@@ -1755,135 +1779,35 @@ def _ell_all_view_rows(
     return d_all, packed
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("bands", "n", "k_budget"),
-    donate_argnums=(6, 11),  # d_prev, dm_old: dead after the call
-)
-def _ell_all_view_rows_masked(
-    srcs_t, ws_t, overloaded, view_srcs, w_sv, ep_ids, d_prev,
-    inc_tail, inc_head, inc_w, masks_t, dm_old, src_id, bands, n,
-    k_budget,
-):
-    """The 1-round-trip incremental-KSP2 dispatch: everything
-    _ell_all_view_rows computes PLUS a speculative masked re-solve of
-    every destination's second-path graph against the RESIDENT masks,
-    diffed on-device against the previous masked rows so the readback
-    carries only the rows that actually moved:
-
-      - dm_new [D, n]: single-source solve over D edge-masked graphs
-        (the KSP2 second-path product, ops semantics of
-        _ell_masked_source_batch)
-      - changed row ids (top k_budget, -1 padded) + their rows
-      - count of changed rows (callers fall back to a full dm readback
-        when it exceeds the budget)
-
-    Destinations whose masks are stale this event (first paths changed)
-    get garbage dm_new rows by construction — the engine re-solves
-    exactly those in a follow-up dispatch and scatters the corrections
-    into the resident matrix. For every other destination the
-    speculative row is exact, which is what turns the common
-    metric-churn event into ONE device round trip. The all-sources
-    fixed point is warm-seeded from ``d_prev`` (cold when the caller
-    passes the _FORCE_RESET_EDGE sentinel); the masked second-path
-    solve stays cold — its masks change shape with the first paths, so
-    a previous dm row is not a sound upper bound."""
-    d_all = _ell_fixed_point(
-        srcs_t, ws_t, overloaded,
-        jnp.arange(n, dtype=jnp.int32), bands, n,
-        warm=(d_prev, inc_tail, inc_head, inc_w),
-    )
-    d = d_all[view_srcs]
-    fh = _first_hops_from_rows(d, view_srcs, w_sv, overloaded, n)
-
-    b = masks_t[0].shape[0]
-    dm_new = _ell_masked_fixed_point(
-        srcs_t, ws_t, masks_t, overloaded, src_id, bands, n
-    )
-
-    row_changed = jnp.any(dm_new != dm_old, axis=1)  # [D]
-    changed_ids = jnp.nonzero(
-        row_changed, size=k_budget, fill_value=-1
-    )[0].astype(jnp.int32)
-    count = jnp.sum(row_changed.astype(jnp.int32))
-    # ids + count packed into one int32 row of width n (n > k_budget)
-    meta = jnp.full((n,), -1, dtype=jnp.int32)
-    meta = meta.at[:k_budget].set(changed_ids)
-    meta = meta.at[k_budget].set(count)
-    changed_rows = dm_new[jnp.clip(changed_ids, 0, b - 1)]  # [K, n]
-
-    packed = jnp.concatenate(
-        [
-            d,
-            fh.astype(jnp.int32),
-            d_all[ep_ids],
-            d_prev[ep_ids],
-            meta[None, :],
-            changed_rows,
-        ],
-        axis=0,
-    )
-    return d_all, dm_new, packed
-
-
-def _inc_args(inc):
+def _inc_args(inc, bucket: int):
     """Device increase-edge triple for the warm-seeded dispatches:
     ``inc=None`` means cold semantics (the reset sentinel flags every
-    row); an (possibly empty) increase list warm-starts."""
+    row); an (possibly empty) increase list warm-starts. ``bucket``:
+    the length every list is padded to, which is the caller's bound on
+    a list (ksp2_engine.ENGINE_MAX_CHANGED_PAIRS), so that an engine
+    runs one compiled shape of the fused dispatch and not one per power
+    of two a window of events happens to reach."""
     inc_t, inc_h, inc_w = pad_increase_edges(
-        [_FORCE_RESET_EDGE] if inc is None else list(inc)
+        [_FORCE_RESET_EDGE] if inc is None else list(inc),
+        bucket_min=bucket,
     )
     return jnp.asarray(inc_t), jnp.asarray(inc_h), jnp.asarray(inc_w)
 
 
-@donates("d_prev", "dm_old")
-def ell_all_view_rows_masked(
-    state: EllState, view_srcs, w_sv, ep_ids, d_prev,
-    masks_t, dm_old, src_id: int, k_budget: int, inc=None,
-    defer: bool = False,
-):
-    """Run the fused 1-RTT dispatch on the resident bands. Returns
-    (d_all_dev, dm_new_dev, packed_host). ``inc`` is the increase-edge
-    delta [(tail, head, old_w)] for warm seeding — None forces the
-    cold seed; d_prev and dm_old are DONATED (invalid after the
-    call). Rides the committed AOT executable cache
-    (``ksp2_view_rows_masked``); ``defer=True`` keeps ``packed`` on
-    device with its readback kicked async — the caller reaps via
-    ``dispatch_accounting.reap_read(packed, kicked=True)`` inside its
-    event window, folding the device round trip into the chain."""
-    inc_t, inc_h, inc_w = _inc_args(inc)
-    d_all, dm_new, packed = _aot_call(
-        "ksp2_view_rows_masked", _ell_all_view_rows_masked,
-        (
-            state.src, state.w, state.overloaded,
-            _as_device_ids(view_srcs),
-            w_sv if isinstance(w_sv, jax.Array) else jnp.asarray(
-                np.asarray(w_sv, dtype=np.int32)
-            ),
-            _as_device_ids(ep_ids),
-            d_prev, inc_t, inc_h, inc_w, masks_t, dm_old, src_id,
-        ),
-        dict(
-            bands=state.graph.bands, n=state.graph.n_pad,
-            k_budget=k_budget,
-        ),
-    )
-    if defer:
-        _da.kick_async(packed)
-        return d_all, dm_new, packed
-    return d_all, dm_new, np.asarray(packed)
-
-
 @donates("d_prev")
 def ell_all_view_rows(state: EllState, view_srcs, w_sv, ep_ids, d_prev,
-                      inc=None, defer: bool = False):
+                      inc=None, inc_bucket: int = 4, defer: bool = False):
     """Run the fused all-sources + view + invalidation-rows dispatch on
-    the resident bands. Returns (d_all_dev, packed_host). ``inc`` as in
-    ell_all_view_rows_masked; d_prev is DONATED. Rides the committed
-    AOT executable cache (``ksp2_view_rows``); ``defer=True`` as in
-    ell_all_view_rows_masked (device ``packed``, readback kicked,
-    caller reaps)."""
-    inc_t, inc_h, inc_w = _inc_args(inc)
+    the resident bands. Returns (d_all_dev, packed_host). ``inc`` is
+    the increase-edge delta [(tail, head, old_w)] for warm seeding,
+    padded to ``inc_bucket`` (None forces the cold seed); d_prev is
+    DONATED (invalid after the call). Rides the committed AOT
+    executable cache (``ksp2_view_rows``); ``defer=True`` keeps
+    ``packed`` on device with its readback kicked async: the caller
+    reaps via ``dispatch_accounting.reap_read(packed, kicked=True)``
+    inside its event window, folding the device round trip into the
+    chain."""
+    inc_t, inc_h, inc_w = _inc_args(inc, inc_bucket)
     d_all, packed = _aot_call(
         "ksp2_view_rows", _ell_all_view_rows,
         (
@@ -2126,7 +2050,7 @@ def _sharded_ell_all_view_rows(
 
 def sharded_ell_all_view_rows(
     state: "EllState", view_srcs, w_sv, ep_ids, d_prev, mesh: Mesh,
-    inc=None,
+    inc=None, inc_bucket: int = 4,
 ):
     """Run the sharded all-sources + view + invalidation-rows dispatch
     on the resident bands. Returns (d_all_dev SHARDED, packed_host).
@@ -2137,7 +2061,7 @@ def sharded_ell_all_view_rows(
     assert state.graph.n_pad % mesh.devices.size == 0, (
         state.graph.n_pad, mesh.devices.size,
     )
-    inc_t, inc_h, inc_w = _inc_args(inc)
+    inc_t, inc_h, inc_w = _inc_args(inc, inc_bucket)
     d_all, packed = _sharded_ell_all_view_rows(
         state.src, state.w, state.overloaded,
         _as_device_ids(view_srcs),
@@ -2149,118 +2073,6 @@ def sharded_ell_all_view_rows(
         state.graph.bands, state.graph.n_pad, mesh,
     )
     return d_all, jax.device_get(packed)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("bands", "n", "k_budget", "mesh")
-)
-def _sharded_ell_all_view_rows_masked(
-    srcs_t, ws_t, overloaded, view_srcs, w_sv, ep_ids, d_prev,
-    inc_tail, inc_head, inc_w, masks_t, dm_old, d_real, src_id,
-    bands, n, k_budget, mesh,
-):
-    """Mesh-sharded twin of _ell_all_view_rows_masked — the 1-RTT
-    speculative KSP2 dispatch on-mesh. Three pieces:
-
-      - the warm-seeded all-pairs fixed point, source rows sharded
-        (see _sharded_warm_all_pairs);
-      - the speculative masked second-path solve, DESTINATION batch
-        sharded (each device owns D_pad/ndev masked solves over the
-        replicated bands — the _sharded_ell_masked layout);
-      - the row diff / budget meta / changed-row gather assembled as
-        global-view ops on the sharded dm_new.
-
-    The destination batch is padded to a mesh multiple by the caller;
-    pad rows are unmasked solves whose rows move every event, so the
-    diff is masked to the first ``d_real`` real rows (a device scalar:
-    the pad width is a compile-time shape, the real count is not).
-    Nothing is donated — matching the plain sharded dispatch (see
-    _sharded_warm_all_pairs on why)."""
-    nb = len(srcs_t)
-    d_all = _sharded_warm_all_pairs(
-        srcs_t, ws_t, overloaded, d_prev, inc_tail, inc_head, inc_w,
-        bands, n, mesh,
-    )
-    d = d_all[view_srcs]
-    fh = _first_hops_from_rows(d, view_srcs, w_sv, overloaded, n)
-
-    def masked_fn(*args):
-        masks_blk = args[:nb]
-        srcs_r = args[nb : 2 * nb]
-        ws_r = args[2 * nb : 3 * nb]
-        ov_r = args[-1]
-        return _ell_masked_fixed_point(
-            srcs_r, ws_r, masks_blk, ov_r, src_id, bands, n,
-            vote=lambda bit: jax.lax.psum(bit, SOURCES_AXIS),
-        )
-
-    b = masks_t[0].shape[0]
-    dm_new = shard_map(
-        masked_fn,
-        mesh=mesh,
-        in_specs=tuple(
-            [P(SOURCES_AXIS, None, None)] * nb  # masks: batch-sharded
-            + [P(None, None)] * (2 * nb)  # bands replicated
-            + [P(None)]
-        ),
-        out_specs=P(SOURCES_AXIS, None),
-    )(*masks_t, *srcs_t, *ws_t, overloaded)
-
-    valid = jnp.arange(b, dtype=jnp.int32) < d_real
-    row_changed = valid & jnp.any(dm_new != dm_old, axis=1)  # [D_pad]
-    changed_ids = jnp.nonzero(
-        row_changed, size=k_budget, fill_value=-1
-    )[0].astype(jnp.int32)
-    count = jnp.sum(row_changed.astype(jnp.int32))
-    meta = jnp.full((n,), -1, dtype=jnp.int32)
-    meta = meta.at[:k_budget].set(changed_ids)
-    meta = meta.at[k_budget].set(count)
-    changed_rows = dm_new[jnp.clip(changed_ids, 0, b - 1)]  # [K, n]
-
-    packed = jnp.concatenate(
-        [
-            d,
-            fh.astype(jnp.int32),
-            d_all[ep_ids],
-            d_prev[ep_ids],
-            meta[None, :],
-            changed_rows,
-        ],
-        axis=0,
-    )
-    return d_all, dm_new, packed
-
-
-def sharded_ell_all_view_rows_masked(
-    state: "EllState", view_srcs, w_sv, ep_ids, d_prev,
-    masks_t, dm_old, src_id: int, k_budget: int, d_real: int,
-    mesh: Mesh, inc=None,
-):
-    """Run the fused speculative dispatch on-mesh. Returns
-    (d_all_dev SHARDED, dm_new_dev SHARDED, packed_host).
-    ``d_real`` is the count of REAL destination rows in the padded
-    masks batch (pad rows are excluded from the changed-row diff);
-    ``inc`` as in ell_all_view_rows_masked. Unlike the single-chip
-    twin nothing is donated."""
-    assert state.graph.n_pad % mesh.devices.size == 0, (
-        state.graph.n_pad, mesh.devices.size,
-    )
-    assert masks_t[0].shape[0] % mesh.devices.size == 0, (
-        masks_t[0].shape[0], mesh.devices.size,
-    )
-    inc_t, inc_h, inc_w = _inc_args(inc)
-    d_all, dm_new, packed = _sharded_ell_all_view_rows_masked(
-        state.src, state.w, state.overloaded,
-        _as_device_ids(view_srcs),
-        w_sv if isinstance(w_sv, jax.Array) else jnp.asarray(
-            np.asarray(w_sv, dtype=np.int32)
-        ),
-        _as_device_ids(ep_ids),
-        d_prev, inc_t, inc_h, inc_w, masks_t, dm_old,
-        jnp.int32(d_real), src_id,
-        state.graph.bands, state.graph.n_pad, k_budget, mesh,
-    )
-    return d_all, dm_new, jax.device_get(packed)
 
 
 def sharded_ell_masked_distances_resident(

@@ -37,7 +37,10 @@ from openr_tpu.telemetry.trace import (  # noqa: F401
     Tracer,
     get_tracer,
 )
-from openr_tpu.telemetry.gc_pauses import install_gc_hook  # noqa: F401
+from openr_tpu.telemetry.gc_pauses import (  # noqa: F401
+    install_gc_hook,
+    settle_heap,
+)
 from openr_tpu.telemetry.profiler import (  # noqa: F401
     Profiler,
     get_profiler,
@@ -79,4 +82,5 @@ __all__ = [
     "load_bundle",
     "reset_flight_recorder",
     "reset_profiler",
+    "settle_heap",
 ]

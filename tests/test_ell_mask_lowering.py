@@ -224,3 +224,107 @@ def test_support_passes_run_only_where_a_row_takes_the_cone(case, monkeypatch):
     else:
         assert reset_rows >= 1 and support >= 1
     assert passes == support + _relax_loop_count(seed, graph, state)
+
+
+# -- the KSP2 programs at fabric-1000's band shapes ---------------------------
+
+
+@pytest.fixture(scope="module")
+def fabric_1000_ksp2(one_chip):
+    """What the KSP2 engine hands its two programs on the 1016-node
+    fabric: the bands, and shapes for the resident tensors."""
+    import jax.numpy as jnp
+
+    from openr_tpu.decision import ksp2_engine, spf_solver
+    from openr_tpu.ops import spf_sparse
+
+    config = _config("fabric-1000-ksp2")
+    ls = _link_state(config)
+    graph = spf_sparse.compile_ell(ls)
+    assert [(b.rows, b.k) for b in graph.bands] == [
+        (624, 8), (288, 16), (104, 128)]
+    assert graph.n_pad == 1024
+    # a cold build solves every destination in one masked batch
+    assert spf_solver._ksp2_chunk(graph) == 1024
+    i32, n = jnp.int32, graph.n_pad
+
+    def per_band(shape_of, dtype=i32):
+        return tuple(
+            _shape(one_chip, shape_of(b), dtype) for b in graph.bands)
+
+    view = len(spf_sparse.ell_source_batch(graph, ls, config["vantage"]))
+    # the one shape an engine's fused dispatch has: both lists padded
+    # to the engine's bounds on them
+    inc = spf_sparse.pad_increase_edges(
+        [(0, 1, 1)], ksp2_engine.ENGINE_MAX_CHANGED_PAIRS)[0].shape[0]
+    ep = ksp2_engine._pad_ids([0], ksp2_engine.ENGINE_MAX_ENDPOINTS)
+    assert (inc, ep.shape[0]) == (64, 32)
+    return {
+        "graph": graph,
+        "bands": (per_band(lambda b: (b.rows, b.k)),) * 2,
+        "masks": lambda rows: per_band(
+            lambda b: (rows, b.rows, b.k), jnp.bool_),
+        "overloaded": _shape(one_chip, (n,), jnp.bool_),
+        "src_id": _shape(one_chip, (), i32),
+        # _ell_all_view_rows's arguments after the bands
+        "view": [
+            _shape(one_chip, (view,), i32), _shape(one_chip, (view,), i32),
+            _shape(one_chip, ep.shape, i32),
+            _shape(one_chip, (n, n), i32),
+        ] + [_shape(one_chip, (inc,), i32)] * 3,
+    }
+
+
+@pytest.mark.parametrize("rows", [64, 512, 1024])
+def test_masked_batch_lowers_and_gathers_no_mask_per_edge(
+        fabric_1000_ksp2, rows):
+    """``jit__ell_masked_source_batch`` at the three buckets the engine
+    compiles on this graph: the two an incremental sync pads its
+    destinations to, and the cold build's one batch of them all. The
+    per-destination edge mask is an operand of the relax, selected
+    against the weights; what PR 29 took out of ``_ell_relax`` — a
+    ``pred`` gathered per edge — is in neither."""
+    from openr_tpu.ops import spf_sparse
+
+    from openr_tpu.decision import ksp2_engine, spf_solver
+
+    k = fabric_1000_ksp2
+    graph = k["graph"]
+    assert rows in ksp2_engine._masked_buckets(spf_solver._ksp2_chunk(graph))
+    compiled = spf_sparse._ell_masked_source_batch.lower(
+        *k["bands"], k["masks"](rows), k["overloaded"], k["src_id"],
+        bands=graph.bands, n=graph.n_pad,
+    ).compile()
+    assert compiled.memory_analysis().output_size_in_bytes \
+        == rows * graph.n_pad * 4
+    text = compiled.as_text()
+    assert re.search(r"while/body/.*gather", text)
+    assert _edge_shaped_pred_gathers(text, graph.bands) == []
+    assert not re.search(r"= pred\[[0-9,]*\]\S* gather\(", text)
+
+
+def test_all_pairs_program_lowers_at_fabric_1000(fabric_1000_ksp2):
+    """The fused program of a KSP2 sync, at the one shape the engine
+    runs it in: the all-pairs fixed point from all 1024 rows, the view
+    and the old and new rows of 32 endpoints."""
+    import jax
+
+    from openr_tpu.ops import spf_sparse
+
+    k = fabric_1000_ksp2
+    graph, n = k["graph"], k["graph"].n_pad
+    compiled = spf_sparse._ell_all_view_rows.lower(
+        *k["bands"], k["overloaded"], *k["view"],
+        bands=graph.bands, n=n,
+    ).compile()
+    out = [
+        tuple(o.shape) for o in jax.tree_util.tree_leaves(compiled.out_info)
+    ]
+    view, ep = k["view"][0].shape[0], k["view"][2].shape[0]
+    assert out == [(n, n), (2 * view + 2 * ep, n)]
+    text = compiled.as_text()
+    # (the view's first hops look ``overloaded`` up per source row,
+    # pred[16]: per row, not per edge)
+    assert _edge_shaped_pred_gathers(text, graph.bands) == []
+    # the two resident matrices fit the chip many times over
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30

@@ -35,10 +35,6 @@ def _engine_everywhere(monkeypatch):
     from openr_tpu.decision import spf_solver as ss
 
     monkeypatch.setattr(ss, "KSP2_DEVICE_MIN_DSTS", 1)
-    # force the accelerator-only fast path on under the CPU test mesh
-    # (the slow 2-dispatch path keeps coverage via the parity-ring
-    # churn suite, which does not set the override)
-    monkeypatch.setenv("OPENR_KSP2_FAST", "1")
 
 
 def _ksp2_network(
@@ -305,11 +301,12 @@ class TestEngineChurnParity:
             [lambda ls: _set_label(ls, fsws[0], 60000)],
         )
 
-    def test_fast_path_dispatch_economy(self):
-        """Steady-state metric churn with unchanged first paths must
-        not issue the follow-up masked dispatch: the speculative
-        resident-mask solve inside the fused dispatch covers it (the
-        1-round-trip property)."""
+    def test_quiet_churn_dispatch_economy(self):
+        """Steady-state metric churn that moves no first path and no
+        masked row must not issue the follow-up masked dispatch: the
+        fused all-pairs dispatch is the event's one round trip, and
+        what the walks read is re-traced off the rows the engine
+        holds."""
         topo, area_d, ps = _ksp2_network("fabric", 120)
         (ls,) = area_d.values()
         rsw = next(
@@ -320,10 +317,6 @@ class TestEngineChurnParity:
         )
         dev = SpfSolver(rsw, backend="device")
         dev.build_route_db(rsw, area_d, ps)
-        from openr_tpu.decision import spf_solver as ss
-
-        engine = next(iter(dev._ksp2_engines.values()))
-        assert engine.masks_t is not None  # fast path active
         # warm one full metric cycle (covers cold/tie transitions)
         for step in range(5):
             _mutate_metric(ls, fsw, 0, 2 + step % 5)
@@ -347,7 +340,7 @@ class TestEngineChurnParity:
             assert syncs == 1, "event did not run incrementally"
             if batches == 0:
                 quiet += 1
-        assert quiet == 2, "fast path issued masked dispatches"
+        assert quiet == 2, "a quiet event issued masked dispatches"
 
     def test_route_reuse_counts(self):
         """Steady-state no-op rebuild reuses every cached route."""
@@ -542,28 +535,27 @@ class TestEngineChurnParity:
         )
 
     def test_soak_seed_9013_stale_mask_regression(self):
-        """Soak-found regression: under compound churn (overload flips
-        + link drops), a destination's resident masks drifted, the
-        speculative masked row went bogus (total 6 vs true 8), the
-        re-trace silently dropped its second path, and the destination
-        never entered the affected set — stale reused routes diverged
-        from the host 12 steps later. The fix recomputes
-        unrealizable-row destinations and invalidates every
-        moved-row destination."""
+        """Soak-found regression, kept as a parity stream: under
+        compound churn (overload flips + link drops) the masks the
+        speculative path kept resident drifted, a masked row went
+        bogus (total 6 vs true 8), the re-trace silently dropped a
+        second path, and stale reused routes diverged from the host 12
+        steps later. That path left the tree (PR 32); the engine
+        builds masks fresh from the current slot map for every solve,
+        and the stream holds it to the host."""
         from tools.soak_ksp2 import soak_one
 
         out = soak_one(9013, "fabric", 120, 60)
         assert out["parity"] == "ok", out
 
     def test_soak_seed_40018_slot_map_drift_regression(self):
-        """The root cause behind both soak breaks: a band patch that
-        changes a node's in-edge SET re-packs its slot assignments,
-        re-aiming every resident mask bit for that row — a dropped
+        """The root cause behind both soak breaks, kept as a parity
+        stream: a band patch that changes a node's in-edge SET
+        re-packs its slot assignments, which re-aimed every mask bit
+        the speculative path kept resident for that row — a dropped
         link shifted two slots and the masked solve excluded the
         wrong edges (metric-15 second path where the truth was 8).
-        The engine now snapshots per-node slot maps and sends
-        re-slotted nodes' path users through the fresh-mask aff1
-        bucket."""
+        Masks built fresh per solve read the current ``slot_of``."""
         from tools.soak_ksp2 import soak_one
 
         out = soak_one(40018, "grid", 5, 60)
@@ -853,8 +845,8 @@ class TestBandWideningOnSolverPath:
     exactly its slot-class capacity gaining a NEW adjacency widens the
     resident band in place (no full recompile of the graph), the
     reconverge dispatch re-uploads the widened band wholesale, and the
-    KSP2 engine — whose resident masks were shaped for the old band —
-    re-seeds cleanly instead of shape-mismatching."""
+    KSP2 engine — whose masked buckets were compiled for the old band
+    — re-seeds cleanly instead of shape-mismatching."""
 
     def test_new_adjacency_widens_and_stays_correct(self):
         from openr_tpu.types import Adjacency
@@ -941,10 +933,8 @@ class TestMeshShardedEngine:
     """The engine's all-pairs residency sharded over the device mesh
     (set_engine_mesh): per-device footprint n^2/ndev, activation bound
     scaled by sqrt(ndev) — the path past the single-chip 12k ceiling.
-    The speculative resident-masks fast path runs mesh-wide too: the
-    destination batch pads to a device multiple and the mask stack /
-    dm residents stripe over the batch axis; when it cannot engage the
-    drop is typed (decision.ksp2.spec_mesh_fallbacks), never silent."""
+    The masked batches run mesh-wide too: each pads to a device
+    multiple and stripes its destinations over the batch axis."""
 
     @pytest.fixture()
     def engine_mesh(self):
@@ -1002,12 +992,12 @@ class TestMeshShardedEngine:
             == before["decision.ksp2_host_fallbacks"]
         )
 
-    def test_mesh_fast_path_engages(self, engine_mesh):
-        """The speculative resident-masks fast path must run ON the
-        mesh: mask/dm residents padded to a device multiple and
-        batch-striped, warm dispatches counted, zero typed fallbacks —
-        and routes stay host-exact through churn (no silent drop to
-        the plain dispatch, let alone single-chip)."""
+    def test_mesh_warm_dispatches_stay_on_mesh(self, engine_mesh):
+        """Metric churn under the mesh warm-starts the SHARDED fused
+        dispatch: the resident all-pairs matrix stays striped over
+        every device from one event to the next, warm dispatches are
+        counted, and routes stay host-exact (no silent drop to the
+        single-chip dispatch)."""
         topo, area_d, ps = _ksp2_network("fabric", 120)
         _t2, area_h, ps_h = _ksp2_network("fabric", 120)
         (ls_d,) = area_d.values()
@@ -1024,31 +1014,26 @@ class TestMeshShardedEngine:
         assert d.to_route_db(rsw) == h.to_route_db(rsw), "cold"
         engine = next(iter(dev._ksp2_engines.values()))
         assert engine._mesh is not None
-        assert engine.masks_t is not None, (
-            "speculative fast path must engage on-mesh"
-        )
         ndev = engine_mesh.devices.size
-        assert engine.masks_t[0].shape[0] % ndev == 0, "padded batch"
-        assert engine.dm_dev.shape[0] == engine.masks_t[0].shape[0]
         for step in range(4):
             _mutate_metric(ls_d, fsw, 0, 2 + step % 3)
             _mutate_metric(ls_h, fsw, 0, 2 + step % 3)
             d = dev.build_route_db(rsw, area_d, ps)
             h = host.build_route_db(rsw, area_h, ps_h)
             assert d.to_route_db(rsw) == h.to_route_db(rsw), step
+            assert len(
+                {s.device for s in engine.d_prev_dev.addressable_shards}
+            ) == ndev, "resident all-pairs matrix left the mesh"
         assert (
             SPF_COUNTERS["decision.ksp2_warm_dispatches"]
-            > before["decision.ksp2_warm_dispatches"]
-        ), "sharded metric churn must count warm speculative dispatches"
-        assert (
-            SPF_COUNTERS["decision.ksp2.spec_mesh_fallbacks"]
-            == before["decision.ksp2.spec_mesh_fallbacks"]
-        ), "the fast path engaged: no fallback may be recorded"
+            - before["decision.ksp2_warm_dispatches"]
+        ) == 4, "sharded metric churn must count warm dispatches"
 
-    def test_mesh_fallback_is_typed(self, engine_mesh, monkeypatch):
-        """When the padded mask stack exceeds the device budget the
-        mesh fast path refuses LOUDLY — typed counter bumped — while
-        the plain sharded dispatch keeps routes host-exact."""
+    def test_mesh_mask_budget_chunks_the_batches(self, engine_mesh,
+                                                  monkeypatch):
+        """A mask budget that admits one masked graph a dispatch
+        splits the sharded masked solve into device-multiple batches;
+        routes stay host-exact."""
         from openr_tpu.decision import spf_solver as ss
 
         monkeypatch.setattr(ss, "KSP2_DEVICE_MASK_BUDGET", 1)
@@ -1066,12 +1051,10 @@ class TestMeshShardedEngine:
         d = dev.build_route_db(rsw, area_d, ps)
         h = host.build_route_db(rsw, area_h, ps_h)
         assert d.to_route_db(rsw) == h.to_route_db(rsw), "cold"
-        engine = next(iter(dev._ksp2_engines.values()))
-        assert engine.masks_t is None
         assert (
-            SPF_COUNTERS["decision.ksp2.spec_mesh_fallbacks"]
-            > before["decision.ksp2.spec_mesh_fallbacks"]
-        ), "budget refusal must be typed, not silent"
+            SPF_COUNTERS["decision.ksp2_device_batches"]
+            - before["decision.ksp2_device_batches"]
+        ) >= len(topo.adj_dbs) - 1, "one batch a destination"
         _mutate_metric(ls_d, fsw, 0, 7)
         _mutate_metric(ls_h, fsw, 0, 7)
         d = dev.build_route_db(rsw, area_d, ps)

@@ -1,0 +1,354 @@
+"""``KSP2_ED_ECMP`` over ``SR_MPLS`` through the normal path, on the CPU
+at a size the KSP2 engine takes: a 3-pod fabric of 56 nodes, every
+prefix KSP2, solved from ``rsw-0-0`` (55 destinations,
+``KSP2_DEVICE_MIN_DSTS`` = 32).
+
+After every burst of ``fabric-1000-ksp2.adj-churn``'s events (a metric
+change, a link flap) the device backend's ``RouteDatabase`` — unicast
+next hops with their label stacks, and the node-label MPLS routes —
+equals the plain reference of ``chipbench/reference_ksp2.py`` and is
+bit-identical to ``solver_backend=host``. Both of the engine's branches
+are taken (an incremental sync, and the cold build a window of many
+events overflows into), and a rebuild's trace holds the spans the
+per-layer metrics read, nested and closed. Counts, never times: this is
+the CPU.
+"""
+
+from __future__ import annotations
+
+import pytest
+
+from chipbench import reference_ksp2, topology, traffic
+from openr_tpu.decision import ksp2_engine
+from openr_tpu.decision.decision import Decision
+from openr_tpu.decision.spf_solver import KSP2_DEVICE_MIN_DSTS, SPF_COUNTERS
+from openr_tpu.messaging.queue import ReplicateQueue
+from openr_tpu.telemetry import get_tracer
+from openr_tpu.types import Publication
+from openr_tpu.utils import wire
+
+VANTAGE = "rsw-0-0"
+KSP2 = {"algorithm": "KSP2_ED_ECMP", "type": "SR_MPLS"}
+MIX = {"kinds": {"metric": 0.8, "flap": 0.2}}
+# 60 events in the bursts one rebuild window carries; the window of 40
+# touches more endpoints than ENGINE_MAX_ENDPOINTS and goes cold
+BURSTS = (1, 1, 2, 1, 3, 1, 40, 1, 2, 1, 4, 1, 2)
+# (a sync whose tests name no destination solves and traces nothing)
+ENGINE_SPANS = (
+    "decision.ksp2_sync", "ops.ksp2_all_pairs", "decision.ksp2_routes",
+)
+
+
+@pytest.fixture(scope="module")
+def fabric():
+    topo = topology.build({
+        "kind": "fat_tree", "pods": 3, "ssw_per_plane": 2,
+        "fsw_per_pod": 4, "rsw_per_pod": 12}, KSP2)
+    assert len(topo.adj_dbs) - 1 >= KSP2_DEVICE_MIN_DSTS + 1
+    return topo
+
+
+def _decision(backend: str):
+    kv_q = ReplicateQueue(name=f"{backend}:kvstore")
+    return kv_q, Decision(
+        VANTAGE,
+        kvstore_updates_queue=kv_q,
+        route_updates_queue=ReplicateQueue(name=f"{backend}:routes"),
+        solver_backend=backend,
+    )
+
+
+def _inside(inner, outer) -> bool:
+    return (outer.ts_ms <= inner.ts_ms
+            and inner.ts_ms + inner.dur_ms <= outer.ts_ms + outer.dur_ms + 0.5)
+
+
+@pytest.mark.parametrize("seed", [7, 4294967311])
+def test_routes_equal_reference_and_host_after_every_burst(fabric, seed):
+    assert sum(BURSTS) == 60
+    gen = traffic.Generator(fabric, seed, MIX, VANTAGE)
+    initial = gen.initial_key_vals()
+    queues, sides = zip(*(_decision(b) for b in ("device", "host")))
+    tracer = get_tracer()
+    traces, kinds = [], set()
+    try:
+        for d in sides:
+            d.process_publication(Publication(key_vals=dict(initial), area="0"))
+            d.rebuild_routes("LOAD")
+        before = dict(SPF_COUNTERS)
+        for burst in BURSTS:
+            events = [gen.draw() for _ in range(burst)]
+            kinds |= {ev.kind for ev in events}
+            for d in sides:
+                for ev in events:
+                    d.process_publication(Publication(
+                        key_vals={ev.key: ev.value}, area="0"))
+            # the trace a publication would carry out of KvStore
+            trace = tracer.start()
+            sides[0].pending.adopt_trace(trace)
+            for d in sides:
+                d.rebuild_routes("BURST")
+            tracer.finish(trace)
+            traces.append(trace)
+            live, host = (d.route_db.to_route_db(VANTAGE) for d in sides)
+            assert reference_ksp2.routes_of(live) == reference_ksp2.routes(
+                gen.adj_dbs, gen.prefix_dbs, VANTAGE)
+            assert reference_ksp2.mpls_routes_of(live) \
+                == reference_ksp2.mpls_routes(gen.adj_dbs, VANTAGE)
+            assert wire.dumps(live) == wire.dumps(host)
+            # the hop gate reads the bands, not a Dijkstra in Python
+            ls = sides[0].area_link_states["0"]
+            engine = sides[0].spf_solver._ksp2_engines[ls]
+            assert engine.ecc_hops == ls.get_max_hops_to_node(VANTAGE)
+        assert kinds == {"metric", "flap"}
+    finally:
+        for q in queues:
+            q.close()
+
+    moved = {k: SPF_COUNTERS[k] - before.get(k, 0) for k in SPF_COUNTERS}
+    # both branches: a window syncs incrementally unless it touches more
+    # endpoints than ENGINE_MAX_ENDPOINTS (the window of 40), and then
+    # rebuilds cold; no destination went to the host
+    assert moved["decision.ksp2_incremental_syncs"] >= len(BURSTS) - 3
+    assert moved["decision.ksp2_cold_builds"] >= 1
+    assert moved["decision.ksp2_incremental_syncs"] \
+        + moved["decision.ksp2_cold_builds"] == len(BURSTS)
+    assert moved["decision.ksp2_host_fallbacks"] == 0
+    assert moved["decision.ksp2_device_batches"] >= 1
+    assert 0 < moved["decision.ksp2_affected_dsts"] \
+        < 55 * moved["decision.ksp2_incremental_syncs"]
+
+    cold = 0
+    for trace, burst in zip(traces, BURSTS):
+        assert trace.well_formed()
+        by_name = {}
+        for s in trace.spans:
+            assert s.closed
+            by_name.setdefault(s.name, []).append(s)
+        for name in ENGINE_SPANS:
+            assert name in by_name, (name, burst)
+        (build,) = by_name["decision.route_build"]
+        (sync,) = by_name["decision.ksp2_sync"]
+        (routes,) = by_name["decision.ksp2_routes"]
+        assert _inside(sync, build) and _inside(routes, build)
+        assert routes.ts_ms >= sync.ts_ms + sync.dur_ms - 0.5
+        assert set(sync.attrs) >= {"changed_pairs", "affected", "cold"}
+        assert 0 <= sync.attrs["affected"] <= 55
+        if sync.attrs["cold"]:
+            cold += 1
+            assert sync.attrs["affected"] == 55
+        else:
+            assert 1 <= sync.attrs["changed_pairs"] \
+                <= ksp2_engine.ENGINE_MAX_CHANGED_PAIRS
+        # one fused dispatch; a sync that names too many destinations
+        # only after it falls to the cold build, which dispatches again
+        assert len(by_name["ops.ksp2_all_pairs"]) <= 1 + sync.attrs["cold"]
+        for s in by_name["ops.ksp2_all_pairs"]:
+            assert _inside(s, sync)
+            assert s.attrs["rows"] == 128  # 56 nodes, padded
+            assert s.attrs["batches"] == 1
+        for s in by_name.get("decision.ksp2_trace", ()):
+            assert _inside(s, sync)
+            assert s.attrs["rank"] in (1, 2) and s.attrs["dsts"] >= 0
+        for s in by_name.get("ops.ksp2_masked_solve", ()):
+            assert _inside(s, sync)
+            assert 1 <= s.attrs["rows"] <= 55 and s.attrs["batches"] >= 1
+            # the second-rank traces of the batch nest in it, unless it
+            # only keeps the rows exact (a window of several links)
+            assert s.attrs.get("refresh") or any(
+                _inside(t, s) and t.attrs["rank"] == 2
+                for t in by_name["decision.ksp2_trace"])
+        assert routes.attrs["prefixes"] >= 55
+        assert 0 <= routes.attrs["reused"] <= routes.attrs["prefixes"]
+        if not sync.attrs["cold"]:
+            # what the engine did not name is served from the cache
+            assert routes.attrs["reused"] >= 55 - sync.attrs["affected"]
+    assert cold == moved["decision.ksp2_cold_builds"]
+    # an event that moves first paths re-solves them with fresh masks
+    # and re-traces both ranks
+    assert any(
+        s.name == "ops.ksp2_masked_solve" for t in traces for s in t.spans)
+    assert {s.attrs["rank"] for t in traces for s in t.spans
+            if s.name == "decision.ksp2_trace"} == {1, 2}
+
+
+def test_hop_eccentricity_off_the_bands_is_the_unit_metric_dijkstras(fabric):
+    """The engine's hop gate, across what moves it: a drained FSW (no
+    transit but reachable), a drained root (it still originates), links
+    withdrawn until an RSW hangs off one uplink, and a node cut off."""
+    from dataclasses import replace
+
+    from openr_tpu.graph.linkstate import LinkState
+    from openr_tpu.ops import spf_sparse
+
+    ls = LinkState(area="0")
+    for name in sorted(fabric.adj_dbs):
+        ls.update_adjacency_database(fabric.adj_dbs[name])
+
+    def check() -> int:
+        graph = spf_sparse.compile_ell(ls)
+        hops = ksp2_engine._hop_eccentricity(
+            graph, graph.node_index[VANTAGE])
+        assert hops == ls.get_max_hops_to_node(VANTAGE)
+        return hops
+
+    assert check() == 4
+    for name in ("fsw-1-0", VANTAGE):
+        ls.update_adjacency_database(
+            replace(fabric.adj_dbs[name], is_overloaded=True))
+        check()
+    # every FSW of pod 1 drained: its RSWs are out of reach
+    for k in range(1, 4):
+        ls.update_adjacency_database(
+            replace(fabric.adj_dbs[f"fsw-1-{k}"], is_overloaded=True))
+    check()
+    for k in range(4):
+        ls.update_adjacency_database(fabric.adj_dbs[f"fsw-1-{k}"])
+    # rsw-2-0 keeps one uplink, and that FSW loses its spines but one
+    db = fabric.adj_dbs["rsw-2-0"]
+    ls.update_adjacency_database(replace(db, adjacencies=db.adjacencies[:1]))
+    up = db.adjacencies[0].other_node_name
+    db = fabric.adj_dbs[up]
+    spines = [a for a in db.adjacencies if a.other_node_name.startswith("ssw")]
+    ls.update_adjacency_database(replace(db, adjacencies=tuple(
+        a for a in db.adjacencies if a not in spines[1:])))
+    check()
+    # ... and then the last: the pod's share of that plane goes dark
+    ls.update_adjacency_database(replace(db, adjacencies=tuple(
+        a for a in db.adjacencies if a not in spines)))
+    assert check() >= 4
+
+
+@pytest.mark.parametrize("seed, pods, rsws, windows", [
+    (51, 3, 12, 240), (53, 3, 12, 240), (72, 4, 8, 400)])
+def test_one_event_at_a_time_the_routes_stay_the_references(
+        seed, pods, rsws, windows):
+    """The engine's narrowest path: one link changes, the DAG tests name
+    some destinations, ``_second_paths_may_move`` keeps those whose
+    walks read the changed place of a candidate list, and only what
+    moved is re-derived. Windows of 1 to 4 events; after each the
+    routes are the plain reference's. (Seed 51 caught places going
+    stale when a link joined a list ahead of them; seed 72, on four
+    pods, a masked row left stale at a node that mattered to no route
+    until, 300 events on, it did.)"""
+    from tools.soak_ksp2 import soak_cell
+
+    out = soak_cell(seed, pods, rsws, windows)
+    assert out["parity"] == "ok", out
+    moved, dsts = out["moved"], out["dsts"]
+    syncs = moved["decision.ksp2_incremental_syncs"]
+    assert syncs >= windows - 10
+    assert moved["decision.ksp2_host_fallbacks"] == 0
+    # most windows move a handful of the destinations or none
+    assert moved["decision.ksp2_affected_dsts"] < dsts * syncs // 6
+    # ... and a good share of them solve nothing on the device again
+    assert moved["decision.ksp2_device_batches"] < syncs
+
+
+def test_settle_heap_reclaims_what_an_earlier_call_froze():
+    """``gc.freeze`` alone keeps cyclic garbage for ever; thawing first
+    is what lets the next call collect what has died since."""
+    import gc
+    import weakref
+
+    from openr_tpu.telemetry import settle_heap
+
+    class Node:
+        pass
+
+    a, b = Node(), Node()
+    a.other, b.other = b, a  # a cycle: only the collector frees it
+    gone = weakref.ref(a)
+    try:
+        settle_heap()
+        assert gc.get_freeze_count() > 0 and gone() is not None
+        del a, b
+        gc.collect()
+        assert gone() is not None, "frozen: out of the collector's reach"
+        settle_heap()
+        assert gone() is None
+    finally:
+        gc.unfreeze()
+
+
+def test_repeated_cold_builds_do_not_pile_up_in_the_permanent_generation(
+        fabric):
+    """Decision settles the heap after a rebuild in which the engine
+    built cold, and only then. Window after window of 80 events (more
+    endpoints than ENGINE_MAX_ENDPOINTS: every one a cold build), what
+    is frozen stays what is alive: each build's path lists replace the
+    last build's, which the next settling collects."""
+    import gc
+
+    gen = traffic.Generator(fabric, 11, MIX, VANTAGE)
+    kv_q, decision = _decision("device")
+    frozen = []
+    try:
+        decision.process_publication(Publication(
+            key_vals=dict(gen.initial_key_vals()), area="0"))
+        decision.rebuild_routes("LOAD")
+        assert gc.get_freeze_count() > 0
+        before = dict(SPF_COUNTERS)
+        # an incremental sync leaves the collector's policy alone
+        gc.unfreeze()
+        ev = gen.draw()
+        decision.process_publication(Publication(
+            key_vals={ev.key: ev.value}, area="0"))
+        decision.rebuild_routes("EVENT")
+        assert gc.get_freeze_count() == 0
+        for _ in range(6):
+            for _ in range(80):
+                ev = gen.draw()
+                decision.process_publication(Publication(
+                    key_vals={ev.key: ev.value}, area="0"))
+            decision.rebuild_routes("BURST")
+            frozen.append(gc.get_freeze_count())
+        assert SPF_COUNTERS["decision.ksp2_cold_builds"] \
+            - before["decision.ksp2_cold_builds"] == 6
+        assert SPF_COUNTERS["decision.ksp2_incremental_syncs"] \
+            - before["decision.ksp2_incremental_syncs"] == 1
+    finally:
+        kv_q.close()
+        gc.unfreeze()
+    # what is frozen follows what is alive (the LSDB's own churn moves
+    # it by a percent), not the number of builds; that a later call
+    # does collect what an earlier one froze is the test above's
+    assert max(frozen[1:]) - frozen[0] < frozen[0] // 20, frozen
+
+
+def test_no_view_is_staged_where_the_engine_serves_the_view(fabric):
+    """A saturated debounce window stages the root's view for the
+    rebuild to find (``speculate_views``). Where a KSP2 engine is live
+    the rebuild takes its view from the engine's fused dispatch, and a
+    staged one would be solved for nobody, on the view path that area
+    never runs otherwise: it stands down, counted."""
+    from openr_tpu.telemetry import get_registry
+
+    gen = traffic.Generator(fabric, 13, MIX, VANTAGE)
+    kv_q, decision = _decision("device")
+    reg = get_registry()
+    try:
+        decision.process_publication(Publication(
+            key_vals=dict(gen.initial_key_vals()), area="0"))
+        decision.rebuild_routes("LOAD")
+        solver = decision.spf_solver
+        (engine,) = solver._ksp2_engines.values()
+        assert engine.valid and engine.src_name == VANTAGE
+        for _ in range(3):
+            ev = gen.draw()
+            decision.process_publication(Publication(
+                key_vals={ev.key: ev.value}, area="0"))
+            before = (dict(SPF_COUNTERS), reg.counter_get("ops.spec_skips"),
+                      reg.counter_get("ops.spec_dispatches"))
+            assert solver.speculate_views(
+                VANTAGE, decision.area_link_states) == 0
+            assert reg.counter_get("ops.spec_skips") == before[1] + 1
+            assert reg.counter_get("ops.spec_dispatches") == before[2]
+            assert SPF_COUNTERS["decision.device_solves"] \
+                == before[0]["decision.device_solves"]
+            decision.rebuild_routes("EVENT")
+        # another root's view is nobody's to serve but the view path's
+        other = "rsw-1-0"
+        assert solver.speculate_views(other, decision.area_link_states) == 1
+    finally:
+        kv_q.close()

@@ -399,7 +399,6 @@ class TestKsp2CommittedChain:
         signatures."""
         from openr_tpu.decision import ksp2_engine
 
-        monkeypatch.setenv("OPENR_KSP2_FAST", "1")
         ls = load(topologies.grid(4))
         names = sorted(ls.get_adjacency_databases())
         root, dsts = names[0], names[1:]

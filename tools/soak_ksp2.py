@@ -1,14 +1,25 @@
 """Soak the incremental KSP2 engine: long randomized mutation streams,
-device (engine + fast path) vs fresh host solver, byte-exact
-RouteDatabase parity at every step.
+checked after every step.
 
 All prefixes are KSP2_ED_ECMP, so every event exercises the engine's
 invalidation algebra (first/second path membership tests, masked
-re-solve, speculative fast path) plus the label/overload
-materialization extras. Churn classes: metric wiggles, overload flips,
-node-label changes, link drop/restore.
+re-solve, the walk-reach proof) plus the label/overload
+materialization extras. Two streams:
+
+- ``soak_one``: metric wiggles, overload flips, node-label changes,
+  link drop/restore through ``SpfSolver``, device (engine) against a
+  fresh host solver, byte-exact RouteDatabase parity at every step;
+- ``soak_cell``: the events of the cell fabric-1000-ksp2.adj-churn
+  (chipbench's generator: 80% metric change, 20% link flap) through
+  ``Decision``, one to four a rebuild window, and after every window
+  the RouteDatabase (unicast next hops with label stacks, node-label
+  MPLS routes) against the plain reference chipbench/reference_ksp2.py.
+  This is the stream that found the three holes in
+  ``Ksp2Engine._second_paths_may_move`` (PERF.md section 6, PR 32);
+  tests/test_ksp2_pipeline.py runs its seeds.
 
 Run:  python -m tools.soak_ksp2 [--seeds 12] [--steps 40]
+      python -m tools.soak_ksp2 --cell [--seeds 12] [--steps 500]
 Prints one JSON line per seed; exits non-zero on the first break.
 """
 
@@ -129,23 +140,114 @@ def soak_one(seed: int, kind: str, n: int, steps: int) -> dict:
     }
 
 
+CELL_VANTAGE = "rsw-0-0"
+# (pods, RSWs a pod): 56 and 50 nodes, both above KSP2_DEVICE_MIN_DSTS
+CELL_WORLDS = ((3, 12), (4, 8))
+
+
+def _stale_rows(engine) -> list:
+    """Destinations whose masked row, as the engine holds it, is not
+    what a solve of its masked graph gives now. The engine's proofs
+    read the rows as exact, so a stale one is a fault the day it is
+    made, routes right or not."""
+    from openr_tpu.ops import spf_sparse
+
+    dsts = [d for d in engine.dsts if d not in engine.host_dsts]
+    masks, ok = spf_sparse.build_edge_masks(
+        engine.state.graph, [engine.excl[d] for d in dsts]
+    )
+    rows = spf_sparse.ell_masked_distances_resident(
+        engine.state, engine.sid, masks
+    )
+    return [
+        d for i, d in enumerate(dsts)
+        if ok[i] and (rows[i] != engine.dm[engine.dst_pos[d]]).any()
+    ]
+
+
+def soak_cell(seed: int, pods: int, rsws: int, windows: int) -> dict:
+    """``windows`` rebuild windows of the cell's events on a fabric of
+    ``pods`` pods (2 SSW a plane, 4 FSW and ``rsws`` RSW a pod), 85%
+    of them one event and the rest two to four. Returns the counters
+    the stream moved, or where the routes left the reference or a
+    masked row of the engine's went stale."""
+    from chipbench import reference_ksp2, topology, traffic
+    from openr_tpu.decision.decision import Decision
+    from openr_tpu.messaging.queue import ReplicateQueue
+    from openr_tpu.types import Publication
+
+    fabric = topology.build(
+        {"kind": "fat_tree", "pods": pods, "ssw_per_plane": 2,
+         "fsw_per_pod": 4, "rsw_per_pod": rsws},
+        {"algorithm": "KSP2_ED_ECMP", "type": "SR_MPLS"},
+    )
+    gen = traffic.Generator(
+        fabric, seed, {"kinds": {"metric": 0.8, "flap": 0.2}}, CELL_VANTAGE
+    )
+    kv_q = ReplicateQueue(name="soak:kvstore")
+    decision = Decision(
+        CELL_VANTAGE,
+        kvstore_updates_queue=kv_q,
+        route_updates_queue=ReplicateQueue(name="soak:routes"),
+        solver_backend="device",
+    )
+    rng = random.Random(seed)
+    before = dict(SPF_COUNTERS)
+    out = {"seed": seed, "pods": pods, "rsws": rsws, "windows": windows,
+           "dsts": len(fabric.adj_dbs) - 1}
+    t0 = time.time()
+    try:
+        decision.process_publication(Publication(
+            key_vals=dict(gen.initial_key_vals()), area="0"))
+        decision.rebuild_routes("LOAD")
+        for window in range(windows):
+            for _ in range(1 if rng.random() < 0.85 else rng.randint(2, 4)):
+                ev = gen.draw()
+                decision.process_publication(Publication(
+                    key_vals={ev.key: ev.value}, area="0"))
+            decision.rebuild_routes("EVENT")
+            live = decision.route_db.to_route_db(CELL_VANTAGE)
+            if reference_ksp2.routes_of(live) != reference_ksp2.routes(
+                gen.adj_dbs, gen.prefix_dbs, CELL_VANTAGE
+            ) or reference_ksp2.mpls_routes_of(
+                live
+            ) != reference_ksp2.mpls_routes(gen.adj_dbs, CELL_VANTAGE):
+                return {**out, "window": window, "parity": "BROKEN"}
+            (engine,) = decision.spf_solver._ksp2_engines.values()
+            stale = _stale_rows(engine)
+            if stale:
+                return {**out, "window": window, "parity": "BROKEN",
+                        "stale_rows": stale[:8]}
+    finally:
+        kv_q.close()
+    out["parity"] = "ok"
+    out["moved"] = {
+        k: SPF_COUNTERS[k] - before.get(k, 0)
+        for k in SPF_COUNTERS if k.startswith("decision.ksp2_")
+    }
+    out["wall_s"] = round(time.time() - t0, 1)
+    return out
+
+
 def main() -> int:
     p = argparse.ArgumentParser()
     p.add_argument("--seeds", type=int, default=12)
     p.add_argument("--steps", type=int, default=40)
-    p.add_argument("--fast-path", action="store_true", default=True)
+    p.add_argument("--cell", action="store_true",
+                   help="the cell's events against the plain reference")
     args = p.parse_args()
-    # engine active regardless of destination count; fast path on
-    # (covers the speculative resident-masks dispatch off-TPU too)
+    # engine active regardless of destination count
     _ss.KSP2_DEVICE_MIN_DSTS = 1
-    import os
-
-    os.environ.setdefault("OPENR_KSP2_FAST", "1")
     worlds = [("grid", 5), ("fabric", 120)]
     rc = 0
     for seed in range(args.seeds):
-        kind, n = worlds[seed % len(worlds)]
-        out = soak_one(seed, kind, n, args.steps)
+        if args.cell:
+            out = soak_cell(
+                seed, *CELL_WORLDS[seed % len(CELL_WORLDS)], args.steps
+            )
+        else:
+            kind, n = worlds[seed % len(worlds)]
+            out = soak_one(seed, kind, n, args.steps)
         print(json.dumps(out), flush=True)
         if out.get("parity") != "ok":
             rc = 1
