@@ -283,9 +283,9 @@ class Decision:
         self._rebuild_debounced = AsyncDebounce(
             self.evb, debounce_min_s, debounce_max_s, self._on_debounce_fire
         )
-        # debounce-terminal speculation latch: at most ONE speculative
-        # view solve per debounce window (armed when the window
-        # saturates, reset when the rebuild fires)
+        # speculation latch: at most ONE speculative view solve per
+        # debounce window (set where it is staged, reset when the
+        # rebuild fires)
         self._spec_fired_this_window = False
         # admission/backpressure path (service plane): the controller
         # adapts the debounce ceiling to the reader backlog, and the
@@ -364,28 +364,60 @@ class Decision:
             # the debounced rebuild dispatches its fused solve the
             # resident bands are already current.
             self._rebuild_debounced()
-            if self._admission is None or self._admission.allow_prewarm(
-                self._kv_reader.size()
-            ):
+            overlap = self._admission is None or (
+                self._admission.allow_prewarm(self._kv_reader.size())
+            )
+            if overlap:
                 self.spf_solver.prewarm(
                     self.area_link_states, trace=self.pending.trace
                 )
-            # debounce-terminal speculation: once the window's backoff
-            # saturates, further publications can only JOIN the window,
-            # never extend it — the fire time is final, and under
-            # latest-wins the current coalesced backlog is the most
-            # likely rebuild composition. Stage its view solve now
-            # (once per window) so the rebuild lands on a warm cache
-            # hit; a later join supersedes the stage, counted
-            # ops.spec_cancels, and the rebuild re-solves bit-identical.
+            # the first publication of a window that finds nothing
+            # queued behind it (at one publication a window, every rate
+            # under the knee: the one that opens it) also stages the
+            # root's view solve under the same policy wait: the LSDB as
+            # it stands now is what the rebuild will compute for, so the
+            # rebuild lands on a solved view (ops.spec_hits). Once per
+            # window (the latch: a storm cannot multiply device work),
+            # only for a window that will solve a view at all (a
+            # prefix-only one runs the per-prefix pass; a host backend
+            # has nothing to overlap), and never with a publication
+            # already queued: the composition is then known to be
+            # stale. A publication that joins later moves the version:
+            # the stage is discarded by version (ops.spec_cancels) and
+            # the rebuild re-solves, bit-identical.
             if (
                 not self._spec_fired_this_window
-                and self._rebuild_debounced.at_max_backoff()
+                and overlap
+                and self.pending.needs_full_rebuild()
+                and self.spf_solver.backend == "device"
+                and self._kv_reader.size() == 0
             ):
                 self._spec_fired_this_window = True
-                self.spf_solver.speculate_views(
+                self._speculate_views()
+
+    def _speculate_views(self) -> None:
+        """Stage the root's views for the window's rebuild, traced as
+        the rebuild's own solve would be: ``decision.speculate`` inside
+        ``decision.debounce``, with the window's trace active on this
+        thread so that the view's sync / dispatch / readback spans nest
+        beneath it, and an event window of its own for the host-touch
+        accounting."""
+        trace = self.pending.trace
+        tracer = get_tracer()
+        if trace is not None:
+            tracer.activate(trace)
+        try:
+            with tracer.span(
+                "decision.speculate", trace=trace
+            ) as span, da.event_window("decision.speculate"):
+                staged = self.spf_solver.speculate_views(
                     self.my_node_name, self.area_link_states
                 )
+                if span is not None:
+                    span.attrs["staged"] = staged
+        finally:
+            if trace is not None:
+                tracer.deactivate()
 
     def _on_static_routes(self, delta) -> None:
         """Static MPLS routes pushed by the platform/plugin layer

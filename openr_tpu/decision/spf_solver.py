@@ -863,10 +863,11 @@ class SpfSolver:
         # area graph must release its engine (resident [n, n] device
         # matrix + path caches) instead of pinning it until eviction
         self._ksp2_engines = _weakref.WeakKeyDictionary()
-        # debounce-terminal speculation ledger: ls -> (version, root)
-        # staged by speculate_views and not yet consumed by a rebuild.
-        # Weakly keyed like _ksp2_engines; the staged view itself lives
-        # in _views (it IS the rebuild's cache entry on a hit)
+        # speculation ledger: ls -> (version, root) staged by
+        # speculate_views (once per debounce window, under the policy
+        # wait) and not yet consumed by a rebuild. Weakly
+        # keyed like _ksp2_engines; the staged view itself lives in
+        # _views (it IS the rebuild's cache entry on a hit)
         self._spec_staged = _weakref.WeakKeyDictionary()
         # per-prefix route reuse across churn (driven by the engine's
         # affected set): prefix -> (RibUnicastEntry | None, best result)
@@ -1019,27 +1020,34 @@ class SpfSolver:
         my_node_name: str,
         area_link_states: AreaLinkStates,
     ) -> int:
-        """Debounce-terminal speculation hook (the decision module
-        calls this once per saturated debounce window, while the timer
-        runs out): under latest-wins, the most likely composition of
-        the pending rebuild is the CURRENT coalesced backlog, so solve
-        the root's view for it NOW and let the rebuild's ``_view``
-        land on a cache hit instead of paying the solve inside the
-        route-build critical path. Counted, never silent:
-        ``ops.spec_dispatches`` on stage, ``ops.spec_hits`` when the
-        rebuild consumes the staged view, ``ops.spec_cancels`` when a
-        later publication supersedes it (the committed rebuild then
-        re-solves — bit-identical, the view is pure in
-        (version, root)). Stands down (``ops.spec_skips``) off-device
-        or while any chaos fault is armed: every fault seam belongs to
-        the committed path's degradation ladder, and a speculative
-        solve consuming a charge would let a fault escape the rung
-        that owns it. And for an area whose KSP2 engine is live: the
-        rebuild's view there comes out of the engine's own fused
-        dispatch (``Ksp2Engine._preload_view``), so a view staged now
-        is solved for nobody, by the dense or ELL view path that area
-        otherwise never runs (in ``fabric-1000-ksp2.adj-churn`` its
-        first use compiled a snapshot patch inside the window)."""
+        """Window-opening speculation hook (the decision module calls
+        this once per debounce window, from the first publication that
+        finds no other queued behind it, which at one publication a
+        window is the one that opens it, right AFTER it has armed the
+        timer and ``prewarm`` has returned): the LSDB as it stands is
+        then what the rebuild will compute for, so solve the root's
+        view for it NOW, under the policy wait, through the committed
+        path's own ``_view`` (blocking; the timer's callback runs on
+        the same thread and so cannot fire before this returns), and
+        let the rebuild's ``_view`` land on a cache hit instead of
+        paying the solve inside the route-build critical path. Counted,
+        never silent: ``ops.spec_dispatches`` on stage, ``ops.spec_hits``
+        when the rebuild consumes the staged view, ``ops.spec_cancels``
+        when a later publication supersedes it (the committed rebuild
+        then re-solves, bit-identical: the view is pure in
+        (version, root)). A graph whose version has not moved (a
+        prefix-only window) has its view cached already: nothing is
+        dispatched and no counter moves. Stands down
+        (``ops.spec_skips``) while any chaos fault is armed: every
+        fault seam belongs to the committed path's degradation ladder,
+        and a speculative solve consuming a charge would let a fault
+        escape the rung that owns it. And for an area whose KSP2 engine
+        is live: the rebuild's view there comes out of the engine's own
+        fused dispatch (``Ksp2Engine._preload_view``), so a view staged
+        now is solved for nobody, by the dense or ELL view path that
+        area otherwise never runs (in ``fabric-1000-ksp2.adj-churn``
+        its first use compiled a snapshot patch inside the window).
+        Host and native backends return at once."""
         from openr_tpu.faults.injector import get_injector
 
         reg = _get_registry()
@@ -1054,10 +1062,13 @@ class SpfSolver:
             if not ls.has_node(my_node_name):
                 continue
             key = (ls.topology_version, my_node_name)
-            prev = self._spec_staged.pop(ls, None)
-            if prev == key:
-                self._spec_staged[ls] = prev
+            per_ls = self._views.get(ls)
+            if per_ls is not None and key in per_ls:
+                # the version has not moved since the view was solved
+                # (first, and cheap: a window of prefix or attribute
+                # changes only ends here with no counter moved)
                 continue
+            prev = self._spec_staged.pop(ls, None)
             if prev is not None:
                 # an earlier stage for this graph died unconsumed
                 reg.counter_bump("ops.spec_cancels")
@@ -1069,9 +1080,6 @@ class SpfSolver:
             ):
                 reg.counter_bump("ops.spec_skips")
                 continue
-            per_ls = self._views.get(ls)
-            if per_ls is not None and key in per_ls:
-                continue  # already current: nothing to speculate
             try:
                 self._view(area, ls, my_node_name)
             except Exception:

@@ -60,6 +60,10 @@ class RQueue(Generic[T]):
         self._maxlen = maxlen
         self._hwm = 0
         self._overflows = 0
+        # items a forwarder has popped for a consumer that has not
+        # taken them yet (get(hand_off=True) .. delivered()): still
+        # the consumer's backlog, so size() counts them
+        self._handed_off = 0
         self._leaf = _metric_leaf(name)
         if name:
             reg = get_registry()
@@ -101,18 +105,31 @@ class RQueue(Generic[T]):
             self._closed = True
             self._cv.notify_all()
 
-    def get(self, timeout: Optional[float] = None) -> T:
+    def get(
+        self, timeout: Optional[float] = None, hand_off: bool = False
+    ) -> T:
         """Block until a message is available. Raises QueueClosedError when
         the queue is closed and fully drained; QueueTimeoutError on
-        timeout."""
+        timeout. ``hand_off``: the caller only carries the message to
+        its consumer (an event base's reader thread, which pops at once
+        and queues a callback on the loop): the message stays counted
+        in ``size()`` until the consumer calls ``delivered()``, so the
+        depth the consumer reads is what waits behind the message it is
+        handling, not what the carrier has yet to pop."""
         with self._cv:
             if not self._cv.wait_for(
                 lambda: self._items or self._closed, timeout=timeout
             ):
                 raise QueueTimeoutError(self.name)
             if self._items:
+                self._handed_off += hand_off
                 return self._items.popleft()[1]
             raise QueueClosedError(self.name)
+
+    def delivered(self) -> None:
+        """The consumer has taken one handed-off message."""
+        with self._lock:
+            self._handed_off -= 1
 
     def try_get(self) -> Optional[T]:
         with self._cv:
@@ -124,7 +141,7 @@ class RQueue(Generic[T]):
 
     def size(self) -> int:
         with self._lock:
-            return len(self._items)
+            return len(self._items) + self._handed_off
 
     def oldest_age_ms(self) -> float:
         """Age of the head-of-line item — the time the slowest consumer

@@ -665,10 +665,12 @@ def install_default_triggers() -> FlightRecorder:
     fr.add_trigger(P99BreachTrigger("p99_breach", "convergence.e2e_ms"))
     fr.add_trigger(CompileAfterWarmupTrigger())
     fr.add_trigger(CounterDeltaTrigger("reshard", "ops.reshard_events"))
-    # a handful of speculation cancels per window is the normal
-    # latest-wins tax; a burst of them means every speculative
+    # a handful of speculation cancels between checks is the normal
+    # latest-wins tax (the Decision layer stages at most once per
+    # debounce window, so it can add at most one per window); a burst
+    # of them means every speculative
     # dispatch is being thrown away (composition churning faster than
-    # the debounce terminal) — capture the window for the runbook's
+    # the windows close) — capture the window for the runbook's
     # speculation-miss-storm recipe
     fr.add_trigger(CounterDeltaTrigger(
         "spec_cancel_storm", "ops.spec_cancels", min_delta=8,

@@ -210,15 +210,21 @@ class OpenrEventBase:
         """Deliver every message from rqueue as a callback on the loop
         thread (reference: fiber reading loops like Decision.cpp:1433)."""
 
+        def deliver(item: object) -> None:
+            rqueue.delivered()
+            callback(item)
+
         def forward() -> None:
             while not self._stop_requested.is_set():
                 try:
-                    item = rqueue.get(timeout=0.2)
+                    # handed off, not consumed: until the loop takes
+                    # it, the item is still the reader's backlog
+                    item = rqueue.get(timeout=0.2, hand_off=True)
                 except QueueClosedError:
                     return
                 except Exception:
                     continue
-                self.run_in_event_base(lambda item=item: callback(item))
+                self.run_in_event_base(lambda item=item: deliver(item))
 
         t = threading.Thread(
             target=forward, name=f"{self.name}::reader", daemon=True
@@ -398,15 +404,6 @@ class AsyncDebounce:
 
     def is_scheduled(self) -> bool:
         return self._handle is not None and not self._handle.cancelled
-
-    def at_max_backoff(self) -> bool:
-        """True once the extension ceiling is saturated: further
-        invocations no longer push the deadline out, so a pending fire
-        time is FINAL. This is the debounce *terminal* — the window
-        where speculating on the current coalesced backlog is sound
-        under latest-wins (nothing can reopen the window, only join
-        it)."""
-        return self._backoff.at_max_backoff()
 
     @property
     def max_backoff_s(self) -> float:

@@ -317,11 +317,12 @@ def test_repeated_cold_builds_do_not_pile_up_in_the_permanent_generation(
 
 
 def test_no_view_is_staged_where_the_engine_serves_the_view(fabric):
-    """A saturated debounce window stages the root's view for the
-    rebuild to find (``speculate_views``). Where a KSP2 engine is live
-    the rebuild takes its view from the engine's fused dispatch, and a
-    staged one would be solved for nobody, on the view path that area
-    never runs otherwise: it stands down, counted."""
+    """The publication that opens a debounce window stages the root's
+    view for the rebuild to find (``Decision._on_publication`` ->
+    ``speculate_views``). Where a KSP2 engine is live the rebuild takes
+    its view from the engine's fused dispatch, and a staged one would
+    be solved for nobody, on the view path that area never runs
+    otherwise: it stands down, counted."""
     from openr_tpu.telemetry import get_registry
 
     gen = traffic.Generator(fabric, 13, MIX, VANTAGE)
@@ -336,17 +337,17 @@ def test_no_view_is_staged_where_the_engine_serves_the_view(fabric):
         assert engine.valid and engine.src_name == VANTAGE
         for _ in range(3):
             ev = gen.draw()
-            decision.process_publication(Publication(
-                key_vals={ev.key: ev.value}, area="0"))
             before = (dict(SPF_COUNTERS), reg.counter_get("ops.spec_skips"),
                       reg.counter_get("ops.spec_dispatches"))
-            assert solver.speculate_views(
-                VANTAGE, decision.area_link_states) == 0
+            # the window's opener, through Decision's own trigger
+            decision._on_publication(Publication(
+                key_vals={ev.key: ev.value}, area="0"))
+            assert decision._rebuild_debounced.is_scheduled()
             assert reg.counter_get("ops.spec_skips") == before[1] + 1
             assert reg.counter_get("ops.spec_dispatches") == before[2]
             assert SPF_COUNTERS["decision.device_solves"] \
                 == before[0]["decision.device_solves"]
-            decision.rebuild_routes("EVENT")
+            decision._on_debounce_fire()
         # another root's view is nobody's to serve but the view path's
         other = "rsw-1-0"
         assert solver.speculate_views(other, decision.area_link_states) == 1

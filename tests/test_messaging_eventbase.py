@@ -136,6 +136,59 @@ class TestEventBase:
         evb.join()
         assert got == [("hello", "t6")]
 
+    def test_a_reader_behind_a_loop_still_counts_what_the_loop_has_not_taken(
+        self,
+    ):
+        """The event base's reader thread pops a message at once and
+        queues a callback on the loop. The message stays in the
+        reader's ``size()`` until the loop takes it, so a callback
+        reads what waits behind the message it is handling (Decision's
+        gates on its backlog), and a drained reader reads 0."""
+        evb = OpenrEventBase("t7")
+        evb.run_in_thread()
+        q = ReplicateQueue()
+        r = q.get_reader()
+        entered, release = threading.Event(), threading.Event()
+        seen = []
+
+        def on_message(m):
+            if m == "first":
+                entered.set()
+                release.wait(5.0)
+            seen.append((m, r.size()))
+
+        evb.add_queue_reader(r, on_message)
+        q.push("first")
+        assert entered.wait(5.0)
+        q.push("second")
+        q.push("third")
+        deadline = time.time() + 5.0
+        while r.size() != 2 and time.time() < deadline:
+            time.sleep(0.005)
+        # both are behind "first", whether or not the reader thread
+        # has popped them yet
+        assert r.size() == 2
+        release.set()
+        deadline = time.time() + 5.0
+        while len(seen) < 3 and time.time() < deadline:
+            time.sleep(0.005)
+        evb.stop()
+        evb.join()
+        assert seen == [("first", 2), ("second", 1), ("third", 0)]
+        assert r.size() == 0
+
+    def test_hand_off_keeps_a_popped_message_in_the_depth(self):
+        q = ReplicateQueue()
+        r = q.get_reader()
+        q.push("a")
+        q.push("b")
+        assert r.get(hand_off=True) == "a"
+        assert r.size() == 2
+        r.delivered()
+        assert r.size() == 1
+        assert r.get() == "b"  # a plain get is consumed at once
+        assert r.size() == 0
+
 
 class TestBackoffPrimitives:
     def test_exponential_backoff_doubles(self):

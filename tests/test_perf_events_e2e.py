@@ -16,6 +16,7 @@ reports against:
 """
 
 import dataclasses
+import threading
 import time
 
 import pytest
@@ -46,15 +47,16 @@ class PipelineHarness:
     """KvStore -> Decision -> Fib wired through real queues (host
     solver: these tests assert accounting, not kernels)."""
 
-    def __init__(self, my_node="a", solver_backend="host"):
+    def __init__(self, my_node="a", solver_backend="host",
+                 debounce_min_s=0.05, debounce_max_s=0.25):
         self.store = KvStoreWrapper(f"store:{my_node}")
         self.route_q = ReplicateQueue(name="routeUpdates")
         self.decision = Decision(
             my_node,
             kvstore_updates_queue=self.store.store.updates_queue,
             route_updates_queue=self.route_q,
-            debounce_min_s=0.05,
-            debounce_max_s=0.25,
+            debounce_min_s=debounce_min_s,
+            debounce_max_s=debounce_max_s,
             solver_backend=solver_backend,
         )
         self.agent = MockFibAgent()
@@ -204,31 +206,43 @@ class TestPerfEventsEndToEnd:
 
 
 # the span tree of one adjacency event through the dense device path,
-# in the order the spans open, with their depths
+# in the order the spans open, with their depths: the publication that
+# opens the window stages the view solve inside the debounce span, and
+# the rebuild lands on the solved view (nothing of the solver's under
+# the route build)
 ADJ_EVENT_TREE = [
     ("kvstore.publish", 0),
     ("decision.queue_wait", 0),
     ("decision.debounce", 0),
-    ("decision.rebuild", 0),
-    ("decision.route_build", 1),
+    ("decision.speculate", 1),
     ("graph.view_sync", 2),
     ("ops.spf_view_batch", 2),
     ("ops.solve_readback", 2),
+    ("decision.rebuild", 0),
+    ("decision.route_build", 1),
     ("decision.route_diff", 1),
     ("decision.emit", 0),
     ("fib.queue_wait", 0),
     ("fib.program", 0),
 ]
-# the same event over the resident sliced-ELL bands: the patch runs at
-# publication time inside the debounce span, and the solve is the
-# fused reconverge
+# the same event over the resident sliced-ELL bands: the patch runs
+# first inside the debounce span, and the staged solve is the fused
+# reconverge
 ELL_ADJ_EVENT_TREE = (
     ADJ_EVENT_TREE[:3]
     + [("decision.prewarm", 1)]
-    + ADJ_EVENT_TREE[3:6]
+    + ADJ_EVENT_TREE[3:5]
     + [("ops.ell_reconverge", 2)]
-    + ADJ_EVENT_TREE[7:]
+    + ADJ_EVENT_TREE[6:]
 )
+# a window whose stage was not there for the rebuild (none made, or
+# superseded): the rebuild solves for itself, inside the route build
+SERIAL_VIEW_SPANS = {
+    "dense": [("graph.view_sync", 2), ("ops.spf_view_batch", 2),
+              ("ops.solve_readback", 2)],
+    "ell": [("graph.view_sync", 2), ("ops.ell_reconverge", 2),
+            ("ops.solve_readback", 2)],
+}
 PREFIX_EVENT_TREE = [
     ("kvstore.publish", 0),
     ("decision.queue_wait", 0),
@@ -448,4 +462,234 @@ class TestSpanTreeEndToEnd:
             assert by_name["graph.view_sync"].attrs == {
                 "formulation": "ell", "rows": 0}
         finally:
+            h.stop()
+
+
+SPEC_COUNTERS = (
+    "ops.spec_dispatches", "ops.spec_hits", "ops.spec_cancels",
+    "ops.spec_skips", "decision.device_solves",
+)
+SPECULATION_CASES = (
+    "one_publication", "two_in_one_window", "prefix_only",
+    "backlog_behind_the_opener", "prefix_opens_adjacency_joins",
+    "armed_fault", "span_tree",
+)
+
+
+def _reweighted(adj_db, by):
+    return dataclasses.replace(adj_db, adjacencies=tuple(
+        dataclasses.replace(adj, metric=adj.metric + by)
+        for adj in adj_db.adjacencies
+    ))
+
+
+def _host_routes(decision):
+    """What the host backend builds from the LSDB Decision holds."""
+    solver = spf_solver.SpfSolver(decision.my_node_name, backend="host")
+    return solver.build_route_db(
+        decision.my_node_name, decision.area_link_states,
+        decision.prefix_state,
+    ).unicast_routes
+
+
+class TestSpeculationAtWindowOpening:
+    """The first route-affecting publication of a debounce window that
+    finds nothing queued behind it (alone in its window: the one that
+    opens it) stages the root's view solve under the policy wait,
+    after the timer is armed and the patch has run; the rebuild lands
+    on the solved view. Counts, not times, in both solver
+    formulations."""
+
+    @pytest.mark.parametrize("case", SPECULATION_CASES)
+    @pytest.mark.parametrize("formulation", ["dense", "ell"])
+    def test_the_opening_publication_stages_the_view(
+        self, formulation, case, monkeypatch
+    ):
+        from openr_tpu.faults.injector import FaultSchedule, get_injector
+
+        reg, tracer = get_registry(), get_tracer()
+        if formulation == "ell":
+            monkeypatch.setattr(spf_solver, "SPARSE_NODE_THRESHOLD", 2)
+        # a window long enough for a second publication to join it
+        h = PipelineHarness(
+            solver_backend="device", debounce_min_s=0.2, debounce_max_s=0.4
+        )
+        decision, solver = h.decision, h.decision.spf_solver
+        entered = []  # (timer armed?, reader backlog) at each stage
+        real = solver.speculate_views
+
+        def spy(my_node_name, area_link_states):
+            entered.append((
+                decision._rebuild_debounced.is_scheduled(),
+                decision._kv_reader.size(),
+            ))
+            return real(my_node_name, area_link_states)
+
+        monkeypatch.setattr(solver, "speculate_views", spy)
+
+        def counters():
+            out = {n: reg.counter_get(n) for n in SPEC_COUNTERS}
+            out["rebuilds"] = decision.counters["decision.route_build_runs"]
+            return out
+
+        def moved(before):
+            after = counters()
+            return {k: after[k] - before[k] for k in after
+                    if after[k] != before[k]}
+
+        def quiet():
+            return (
+                not decision._rebuild_debounced.is_scheduled()
+                and decision._kv_reader.size() == 0
+            )
+
+        def window_trace(key, newer_than):
+            def find():
+                return [
+                    t for t in tracer.traces()
+                    if t.trace_id > newer_than
+                    and t.spans[0].attrs.get("keys") == [key]
+                ]
+            assert wait_until(lambda: bool(find())), [
+                t.to_dict() for t in tracer.traces()[-3:]]
+            assert wait_until(quiet)
+            return find()[-1]
+
+        def newest():
+            return max(t.trace_id for t in tracer.traces())
+
+        try:
+            topo = line_topology()
+            for db in topo.adj_dbs.values():
+                h.publish_adj(db)
+            for pdb in topo.prefix_dbs.values():
+                h.publish_prefixes(pdb)
+            assert wait_until(lambda: len(h.fib.unicast_routes) >= 2)
+            assert wait_until(quiet)
+            # one adjacency window to compile the patch programs, so
+            # that no case's stage outlasts its window compiling
+            mark = newest()
+            h.publish_adj(_reweighted(topo.adj_dbs["b"], 1))
+            window_trace("adj:b", mark)
+            del entered[:]
+            before, mark = counters(), newest()
+            nesting = {n: reg.counter_get(n) for n in (
+                "telemetry.traces_bad_nesting",
+                "telemetry.traces_unclosed_spans", "ops.host_dispatches")}
+            names = None
+
+            if case in ("one_publication", "span_tree"):
+                h.publish_adj(_reweighted(topo.adj_dbs["b"], 3))
+                trace = window_trace("adj:b", mark)
+                # entered with the window armed and nothing queued;
+                # one solve, and it is the staged one
+                assert entered == [(True, 0)]
+                assert moved(before) == {
+                    "ops.spec_dispatches": 1, "ops.spec_hits": 1,
+                    "decision.device_solves": 1, "rebuilds": 1,
+                }
+                if case == "span_tree":
+                    _assert_tree(trace, ELL_ADJ_EVENT_TREE
+                                 if formulation == "ell"
+                                 else ADJ_EVENT_TREE)
+                    by_name = {s.name: s for s in trace.spans}
+                    assert by_name["decision.speculate"].attrs == {
+                        "staged": 1}
+                    stage = by_name["decision.speculate"]
+                    window = by_name["decision.debounce"]
+                    build = by_name["decision.route_build"]
+                    for name, _ in SERIAL_VIEW_SPANS[formulation]:
+                        span = by_name[name]
+                        assert stage.ts_ms - SLACK_MS <= span.ts_ms
+                        assert _end(span) <= _end(stage) + SLACK_MS
+                        assert _end(span) <= _end(window) + SLACK_MS
+                        assert _end(span) <= build.ts_ms + SLACK_MS
+            elif case == "two_in_one_window":
+                h.publish_adj(_reweighted(topo.adj_dbs["b"], 3))
+                assert wait_until(
+                    lambda: reg.counter_get("ops.spec_dispatches")
+                    == before["ops.spec_dispatches"] + 1, step=0.001)
+                assert decision._rebuild_debounced.is_scheduled()
+                h.publish_adj(_reweighted(topo.adj_dbs["c"], 2))
+                trace = window_trace("adj:b", mark)
+                # one stage only; the joining publication moved the
+                # version, so the rebuild threw it away and re-solved
+                assert entered == [(True, 0)]
+                assert moved(before) == {
+                    "ops.spec_dispatches": 1, "ops.spec_cancels": 1,
+                    "decision.device_solves": 2, "rebuilds": 1,
+                }
+                names = [(s.name, s.depth) for s in trace.spans]
+                view = SERIAL_VIEW_SPANS[formulation]
+                at = names.index(("decision.route_build", 1))
+                assert names[at + 1:at + 1 + len(view)] == view
+                assert names.count(("decision.speculate", 1)) == 1
+            elif case == "prefix_only":
+                h.publish_prefixes(dataclasses.replace(
+                    topo.prefix_dbs["c"], prefix_entries=()))
+                trace = window_trace("prefix:c", mark)
+                assert entered == []
+                assert moved(before) == {"rebuilds": 1}
+                _assert_tree(trace, PREFIX_EVENT_TREE)
+            elif case == "backlog_behind_the_opener":
+                # hold Decision's thread while two publications queue
+                held, release = threading.Event(), threading.Event()
+                decision.evb.run_in_event_base(
+                    lambda: (held.set(), release.wait(10.0)))
+                assert held.wait(5.0)
+                h.publish_adj(_reweighted(topo.adj_dbs["b"], 3))
+                h.publish_adj(_reweighted(topo.adj_dbs["c"], 2))
+                assert wait_until(lambda: decision._kv_reader.size() == 2)
+                release.set()
+                trace = window_trace("adj:b", mark)
+                # the opener saw one behind it and staged nothing; the
+                # one that emptied the queue staged, once, for the
+                # composition the rebuild then computed
+                assert entered == [(True, 0)]
+                assert moved(before) == {
+                    "ops.spec_dispatches": 1, "ops.spec_hits": 1,
+                    "decision.device_solves": 1, "rebuilds": 1,
+                }
+                names = [(s.name, s.depth) for s in trace.spans]
+                assert names.count(("decision.speculate", 1)) == 1
+                at = names.index(("decision.route_build", 1))
+                assert names[at + 1] == ("decision.route_diff", 1)
+            elif case == "prefix_opens_adjacency_joins":
+                # a window opened by a publication that moves no
+                # topology (seen on the chip: 1 window of 300): the
+                # adjacency publication that joins it still stages
+                h.publish_prefixes(dataclasses.replace(
+                    topo.prefix_dbs["c"], prefix_entries=()))
+                assert wait_until(
+                    decision._rebuild_debounced.is_scheduled, step=0.001)
+                assert entered == []
+                h.publish_adj(_reweighted(topo.adj_dbs["b"], 3))
+                trace = window_trace("prefix:c", mark)
+                assert entered == [(True, 0)]
+                assert moved(before) == {
+                    "ops.spec_dispatches": 1, "ops.spec_hits": 1,
+                    "decision.device_solves": 1, "rebuilds": 1,
+                }
+            elif case == "armed_fault":
+                # any armed charge: the stage stands down, counted,
+                # and the committed rebuild solves for itself
+                get_injector().arm(
+                    "route_engine.dispatch", FaultSchedule.fail_once())
+                h.publish_adj(_reweighted(topo.adj_dbs["b"], 3))
+                trace = window_trace("adj:b", mark)
+                assert entered == [(True, 0)]
+                assert moved(before) == {
+                    "ops.spec_skips": 1,
+                    "decision.device_solves": 1, "rebuilds": 1,
+                }
+                assert get_injector().any_armed
+                by_name = {s.name: s for s in trace.spans}
+                assert by_name["decision.speculate"].attrs == {"staged": 0}
+            assert trace.complete and trace.well_formed(), trace.to_dict()
+            assert (
+                decision.route_db.unicast_routes == _host_routes(decision)
+            ), names
+            assert {n: reg.counter_get(n) for n in nesting} == nesting
+        finally:
+            get_injector().reset()
             h.stop()
