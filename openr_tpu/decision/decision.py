@@ -58,7 +58,11 @@ from openr_tpu.telemetry import (
 )
 from openr_tpu.utils import keys as keyutil
 from openr_tpu.utils import wire
-from openr_tpu.utils.eventbase import AsyncDebounce, OpenrEventBase
+from openr_tpu.utils.eventbase import (
+    AsyncDebounce,
+    FiredWindow,
+    OpenrEventBase,
+)
 
 
 class DecisionPendingUpdates:
@@ -76,6 +80,8 @@ class DecisionPendingUpdates:
         # later traces in the window are counted as merged and dropped.
         self.trace = None
         self._debounce_span = None
+        # the event loop's busy seconds when that span opened
+        self._busy_at_open: Optional[float] = None
 
     def needs_full_rebuild(self) -> bool:
         return self._needs_full_rebuild
@@ -139,22 +145,58 @@ class DecisionPendingUpdates:
         self.perf_events = None
         return events
 
-    def adopt_trace(self, trace) -> None:
+    def adopt_trace(self, trace, evb: Optional[OpenrEventBase] = None) -> None:
+        """``evb``: the loop this window waits on, for the account of
+        the wait that ``move_out_trace`` closes the span with."""
         if trace is None:
             return
         if self.trace is None:
             self.trace = trace
             self._debounce_span = trace.begin_span("decision.debounce")
+            self._busy_at_open = (
+                evb.busy_seconds() if evb is not None else None
+            )
         else:
             get_registry().counter_bump("telemetry.traces_merged")
 
-    def move_out_trace(self):
-        """End the debounce span and hand the trace to the rebuild."""
+    def move_out_trace(self, fired: Optional[FiredWindow] = None):
+        """End the debounce span and hand the trace to the rebuild.
+
+        ``fired`` (the debounce timer's terms, when this is its fire)
+        makes the span say what it waited for, in ms: ``policy_ms``
+        (deadline - first arm: what the policy asked the timer for),
+        ``busy_ms`` (the loop's busy time from the span's start to the
+        fire: the rest of the opening callback and every callback that
+        ran inside the window), ``slack_ms`` (deadline - end of the last
+        callback before the fire; negative = the work outlasted the
+        wait) and ``timer_late_ms`` (fire - the later of the two: the
+        timer's own lateness, the overrun taken out). The idle stretch
+        itself, last callback's end -> fire, is the closed span
+        ``decision.policy_idle`` inside it."""
         trace, span = self.trace, self._debounce_span
+        busy_at_open = self._busy_at_open
         self.trace = None
         self._debounce_span = None
+        self._busy_at_open = None
         if trace is not None and span is not None:
-            trace.end_span(span, merged_updates=self.count)
+            attrs = {}
+            if fired is not None and busy_at_open is not None:
+                fire = fired.deadline + fired.late_s
+                idle_from = fired.idle_since
+                trace.closed_span(
+                    "decision.policy_idle",
+                    span.mark_at(idle_from),
+                    (fire - idle_from) * 1e3,
+                )
+                attrs = {
+                    "policy_ms": (fired.deadline - fired.armed_at) * 1e3,
+                    "busy_ms": (fired.busy_s - busy_at_open) * 1e3,
+                    "slack_ms": (fired.deadline - idle_from) * 1e3,
+                    "timer_late_ms": (
+                        fire - max(fired.deadline, idle_from)
+                    ) * 1e3,
+                }
+            trace.end_span(span, merged_updates=self.count, **attrs)
             get_registry().observe(
                 "decision.debounce_ms", span.dur_ms or 0.0
             )
@@ -168,6 +210,7 @@ class DecisionPendingUpdates:
         trace, span = self.trace, self._debounce_span
         self.trace = None
         self._debounce_span = None
+        self._busy_at_open = None
         if trace is not None and span is not None:
             trace.end_span(span, aborted=True)
             get_registry().counter_bump("decision.debounce_spans_reclaimed")
@@ -345,7 +388,7 @@ class Decision:
             # arrival order: the first (oldest) trace wins the window,
             # later ones are counted merged — same rule as perf_events
             for trace in traces:
-                self.pending.adopt_trace(trace)
+                self.pending.adopt_trace(trace, self.evb)
         else:
             for trace in traces:
                 if trace is not None:
@@ -764,7 +807,7 @@ class Decision:
         # close the debounce span, open the rebuild span, and activate
         # the trace on this thread so deep call sites (the ELL
         # reconverge in ops.spf_sparse) can nest their own spans
-        trace = self.pending.move_out_trace()
+        trace = self.pending.move_out_trace(self._rebuild_debounced.fired)
         tracer = get_tracer()
         rebuild_span = None
         full = self.pending.needs_full_rebuild()

@@ -8,6 +8,12 @@ names it:
 - ``process.gc_gen2_collections``: generation-2 collections finished;
 - ``process.gc_gen2_pause_ms``: their summed duration.
 
+Each pause is also handed to the tracer (``Tracer.note_pause``: where
+it began on the tracer's two clocks, and how long it lasted), which
+puts it as a closed ``process.gc_pause`` span on every trace it fell
+into and counts those traces (``telemetry.traces_paused``): the sample
+then says which collection stopped it.
+
 One ``gc.callbacks`` hook. The collector calls it on whichever thread
 tripped the threshold, never re-entrantly, so ``start`` and ``stop``
 of one collection arrive in order on one thread. Collections of the
@@ -26,29 +32,36 @@ from __future__ import annotations
 import gc
 import threading
 import time
-from typing import Optional
+from typing import Optional, Tuple
 
 from openr_tpu.telemetry.registry import get_registry
+from openr_tpu.telemetry.trace import get_tracer
 
 COLLECTIONS = "process.gc_gen2_collections"
 PAUSE_MS = "process.gc_gen2_pause_ms"
+TRACES_PAUSED = "telemetry.traces_paused"
 
 
 class _Gen2Pauses:
     def __init__(self) -> None:
-        self._t0: Optional[float] = None
+        # (wall clock in ms, perf_counter): a span's start pair
+        self._start: Optional[Tuple[float, float]] = None
+        # bound at install: nothing here may take a lock the collecting
+        # thread could already hold (get_tracer's, on first use)
+        self.tracer = None
 
     def __call__(self, phase: str, info: dict) -> None:
         if info["generation"] != 2:
             return
         if phase == "start":
-            self._t0 = time.perf_counter()
-        elif self._t0 is not None:
-            pause_ms = (time.perf_counter() - self._t0) * 1000.0
-            self._t0 = None
+            self._start = (time.time() * 1000.0, time.perf_counter())
+        elif self._start is not None:
+            start, self._start = self._start, None
+            pause_ms = (time.perf_counter() - start[1]) * 1000.0
             reg = get_registry()
             reg.counter_bump(COLLECTIONS)
             reg.counter_bump(PAUSE_MS, pause_ms)
+            self.tracer.note_pause(start, pause_ms, 2)
 
 
 def settle_heap() -> None:
@@ -72,11 +85,13 @@ _INSTALL_LOCK = threading.Lock()
 
 def install_gc_hook() -> None:
     """Idempotent: count this process's generation-2 collections from
-    now on. Both counters exist (at 0) from the first call."""
+    now on. The counters exist (at 0) from the first call."""
     with _INSTALL_LOCK:
         if _HOOK in gc.callbacks:
             return
         reg = get_registry()
         reg.counter_bump(COLLECTIONS, 0)
         reg.counter_bump(PAUSE_MS, 0)
+        reg.counter_bump(TRACES_PAUSED, 0)
+        _HOOK.tracer = get_tracer()
         gc.callbacks.append(_HOOK)

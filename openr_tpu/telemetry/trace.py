@@ -28,6 +28,13 @@ Design points:
 - A hand-off between threads is never an open span: the receiving
   side records it, closed, with ``Trace.gap_span()`` (from the end of
   the trace's last span to now).
+- A stretch that is only known once it is over is recorded after
+  the fact, closed, with ``Trace.closed_span()``: the idle end of a
+  debounce window (``decision.policy_idle``, from the event loop's own
+  account), and a full garbage collection that stopped the process
+  while the trace was in flight (``process.gc_pause``, which
+  ``finish()`` adds from the pauses the collector's hook handed to
+  ``note_pause()``).
 - ``finish()`` validates that every span is closed and properly
   nested; violations bump ``telemetry.traces_unclosed_spans`` /
   ``telemetry.traces_bad_nesting`` instead of raising, and the trace
@@ -52,6 +59,9 @@ from openr_tpu.analysis.annotations import thread_confined
 from openr_tpu.telemetry.registry import get_registry
 
 _trace_ids = itertools.count(1)
+# full collections remembered for the traces still in flight: at the
+# cells' rates one arrives every few seconds and a trace lives ~20 ms
+_PAUSE_RING = 16
 
 
 class Span:
@@ -91,6 +101,15 @@ class Span:
         return (
             self.ts_ms + self.dur_ms,
             self._t0 + self.dur_ms / 1000.0,
+        )
+
+    def mark_at(self, perf_counter: float) -> Tuple[float, float]:
+        """The instant ``perf_counter`` on both clocks, by this span's
+        own start: for what was timed on the one clock alone (an event
+        loop's account) and belongs inside this span."""
+        return (
+            self.ts_ms + (perf_counter - self._t0) * 1000.0,
+            perf_counter,
         )
 
     def to_dict(self) -> Dict[str, Any]:
@@ -164,6 +183,20 @@ class Trace:
         span = Span(name, depth=len(self._stack), start=self._last_end)
         self.spans.append(span)
         return self.end_span(span, **attrs)
+
+    def closed_span(
+        self, name: str, start: Tuple[float, float], dur_ms: float,
+        **attrs: Any
+    ) -> Span:
+        """A span recorded after the fact, closed: ``start`` on both
+        clocks (``Span.mark_at``, ``end_mark``) and its length. It nests
+        in whatever is open; it is not a hand-off, so the next
+        ``gap_span`` still starts where the last live span closed."""
+        span = Span(name, depth=len(self._stack), start=start)
+        span.dur_ms = max(0.0, dur_ms)
+        span.attrs.update(attrs)
+        self.spans.append(span)
+        return span
 
     @property
     def e2e_ms(self) -> Optional[float]:
@@ -249,6 +282,12 @@ class Tracer:
         # retired trace through these instead of polling the ring (the
         # 256-deep ring overflows in ~1s at 200+ events/s)
         self._finish_listeners: List[Any] = []
+        # the last full garbage collections, handed over by the
+        # collector's hook (telemetry/gc_pauses.py) on whichever thread
+        # tripped one: a fixed ring and a count, written with no lock
+        # (that thread may hold any) and read by finish()
+        self._pauses: List[Optional[tuple]] = [None] * _PAUSE_RING
+        self._pauses_noted = 0
 
     # -- lifecycle --------------------------------------------------
     def start(self, origin: str = "kvstore.publish", **attrs: Any) -> Trace:
@@ -267,6 +306,8 @@ class Tracer:
             reg.counter_bump("telemetry.traces_unclosed_spans", unclosed)
         elif not trace.well_formed():
             reg.counter_bump("telemetry.traces_bad_nesting")
+        if self._pauses_noted:
+            self._attach_pauses(trace)
         trace.complete = ok and unclosed == 0
         reg.counter_bump("telemetry.traces_finished")
         e2e = trace.e2e_ms
@@ -297,6 +338,41 @@ class Tracer:
                 fn(trace, ok)
             except Exception:  # noqa: BLE001 - observers never poison Fib
                 reg.counter_bump("telemetry.finish_listener_errors")
+
+    def note_pause(
+        self, start: Tuple[float, float], dur_ms: float, generation: int
+    ) -> None:
+        """A collection that stopped every thread of the process from
+        ``start`` (both clocks) for ``dur_ms``."""
+        self._pauses[self._pauses_noted % _PAUSE_RING] = (
+            start, dur_ms, generation
+        )
+        self._pauses_noted += 1
+
+    def _attach_pauses(self, trace: Trace) -> None:
+        """Put each noted pause that fell inside ``trace``'s extent on
+        it as a closed ``process.gc_pause`` span: the sample says which
+        collection it was stopped by. Every mark of a span is taken by
+        running Python, which a collection holds up on all threads, so a
+        pause lies inside the extent whole or not at all."""
+        closed = [s for s in trace.spans if s.closed]
+        if not closed:
+            return
+        t0 = closed[0]._t0
+        t1 = max(s.end_mark()[1] for s in closed)
+        paused = False
+        for pause in self._pauses:
+            if pause is None:
+                continue
+            start, dur_ms, generation = pause
+            if t0 <= start[1] < t1:
+                trace.closed_span(
+                    "process.gc_pause", start, dur_ms,
+                    generation=generation,
+                )
+                paused = True
+        if paused:
+            get_registry().counter_bump("telemetry.traces_paused")
 
     def add_finish_listener(self, fn) -> None:
         """Register ``fn(trace, ok)`` called after every finish(). Runs

@@ -22,25 +22,34 @@ import queue as _queue
 import random
 import threading
 import time
-from typing import Callable, List, Optional, Tuple
+from typing import Callable, List, NamedTuple, Optional, Tuple
 
 from openr_tpu.analysis.annotations import thread_confined
 from openr_tpu.messaging.queue import QueueClosedError, RQueue
+from openr_tpu.telemetry.registry import get_registry
 
 # upper bound on the event loop's idle wait so last_loop_ts stays fresh
 # for the Watchdog even on a completely quiet event base; small enough
 # that it stays well under any plausible watchdog threshold
 _WATCHDOG_TICK_S = 0.1
+# timer latenesses kept between two flushes of a loop's account; a loop
+# that never finds its queue empty drops the rest, its counters do not
+_LATE_BUFFER = 1024
 
 
 class TimerHandle:
-    __slots__ = ("deadline", "seq", "fn", "cancelled")
+    """``deadline`` is on ``time.perf_counter``, the clock of the loop's
+    own account and of the tracer's spans; ``late_s`` is how long after
+    it the loop got to fire (set just before ``fn`` runs)."""
+
+    __slots__ = ("deadline", "seq", "fn", "cancelled", "late_s")
 
     def __init__(self, deadline: float, seq: int, fn: Callable[[], None]):
         self.deadline = deadline
         self.seq = seq
         self.fn = fn
         self.cancelled = False
+        self.late_s = 0.0
 
     def cancel(self) -> None:
         self.cancelled = True
@@ -64,12 +73,37 @@ class OpenrEventBase:
         self._reader_threads: List[threading.Thread] = []
         # liveness for the watchdog (reference: Watchdog.h monitors evbs)
         self.last_loop_ts: float = time.monotonic()
+        # the loop's own account (folly's EventBaseObserver gives an
+        # operator the same: busy / idle per loop), on perf_counter,
+        # written by the loop thread alone: a clock read before and one
+        # after each callback and each timer function. Everything
+        # between two callbacks is idle (blocked in get(), the loop's
+        # own few lines), so busy + idle tile the time since run().
+        self.busy_s = 0.0  # callbacks and timer functions that returned
+        self.idle_s = 0.0
+        self.callbacks_run = 0
+        # when the callback now running began (None between callbacks)
+        # and when the last one ended: the instant the loop went idle
+        self._running_since: Optional[float] = None
+        self.idle_since: float = time.perf_counter()
+        # exported as evb.<module>.busy_ms / idle_ms / callbacks and
+        # the observation evb.<module>.timer_late_ms; <module> is the
+        # loop's name up to the colon (decision:node-7 -> decision).
+        # Flushed when the loop is about to block, never per callback.
+        module = "evb." + name.split(":", 1)[0]
+        self._metrics = tuple(
+            module + suffix for suffix in
+            (".busy_ms", ".idle_ms", ".callbacks", ".timer_late_ms")
+        )
+        self._flushed = (0.0, 0.0, 0)
+        self._late_ms: List[float] = []
 
     # -- lifecycle --------------------------------------------------------
 
     def run(self) -> None:
         """Run the loop on the calling thread until stop()."""
         self._running.set()
+        self.idle_since = time.perf_counter()
         try:
             while not self._stop_requested.is_set():
                 self.last_loop_ts = time.monotonic()
@@ -82,10 +116,13 @@ class OpenrEventBase:
                 # returns here and still trips the watchdog.
                 if timeout is None or timeout > _WATCHDOG_TICK_S:
                     timeout = _WATCHDOG_TICK_S
+                if self._callbacks.empty():
+                    self._flush_account()
                 try:
                     cb = self._callbacks.get(timeout=timeout)
                 except _queue.Empty:
                     continue
+                self._enter(time.perf_counter())
                 try:
                     cb()
                 except Exception:  # noqa: BLE001
@@ -95,8 +132,52 @@ class OpenrEventBase:
                     logging.getLogger(__name__).exception(
                         "%s: unhandled exception in event callback", self.name
                     )
+                self._leave()
         finally:
             self._running.clear()
+            self._flush_account()
+
+    # -- the loop's own account -------------------------------------------
+
+    def _enter(self, now: float) -> None:
+        self.idle_s += now - self.idle_since
+        self._running_since = now
+
+    def _leave(self) -> float:
+        now = time.perf_counter()
+        self.busy_s += now - self._running_since
+        self._running_since = None
+        self.callbacks_run += 1
+        self.idle_since = now
+        return now
+
+    def busy_seconds(self) -> float:
+        """Busy time so far, the part of the running callback that has
+        already passed included. For the loop's own thread: a window's
+        owner takes it when the window opens and subtracts it from
+        ``busy_s`` as its timer fires (a timer function's own time is
+        added only when it returns)."""
+        since = self._running_since
+        if since is None:
+            return self.busy_s
+        return self.busy_s + (time.perf_counter() - since)
+
+    def _flush_account(self) -> None:
+        """The account's deltas since the last flush, to the registry."""
+        busy, idle, ran = self.busy_s, self.idle_s, self.callbacks_run
+        busy0, idle0, ran0 = self._flushed
+        self._flushed = (busy, idle, ran)
+        busy_ms, idle_ms, callbacks, timer_late_ms = self._metrics
+        reg = get_registry()
+        if ran != ran0:
+            reg.counter_bump(busy_ms, (busy - busy0) * 1e3)
+            reg.counter_bump(callbacks, ran - ran0)
+        if idle != idle0:
+            reg.counter_bump(idle_ms, (idle - idle0) * 1e3)
+        if self._late_ms:
+            late, self._late_ms = self._late_ms, []
+            for ms in late:
+                reg.observe(timer_late_ms, ms)
 
     def run_in_thread(self) -> None:
         assert self._thread is None
@@ -167,7 +248,7 @@ class OpenrEventBase:
         self, delay_s: float, fn: Callable[[], None]
     ) -> TimerHandle:
         handle = TimerHandle(
-            time.monotonic() + max(0.0, delay_s), next(self._seq), fn
+            time.perf_counter() + max(0.0, delay_s), next(self._seq), fn
         )
         with self._timer_lock:
             heapq.heappush(self._timers, handle)
@@ -182,25 +263,33 @@ class OpenrEventBase:
 
     def _run_due_timers(self) -> Optional[float]:
         """Fire expired timers; return seconds until the next one."""
+        now = time.perf_counter()
         while True:
             with self._timer_lock:
                 while self._timers and self._timers[0].cancelled:
                     heapq.heappop(self._timers)
                 if not self._timers:
                     return None
-                now = time.monotonic()
                 if self._timers[0].deadline > now:
                     return self._timers[0].deadline - now
                 handle = heapq.heappop(self._timers)
-            if not handle.cancelled:
-                try:
-                    handle.fn()
-                except Exception:  # noqa: BLE001
-                    import logging
+            if handle.cancelled:
+                continue
+            # how late the loop is for it: a callback that outlasted the
+            # deadline, or the wake-up out of get() (both, for the timer)
+            handle.late_s = now - handle.deadline
+            if len(self._late_ms) < _LATE_BUFFER:
+                self._late_ms.append(handle.late_s * 1e3)
+            self._enter(now)
+            try:
+                handle.fn()
+            except Exception:  # noqa: BLE001
+                import logging
 
-                    logging.getLogger(__name__).exception(
-                        "%s: unhandled exception in timer", self.name
-                    )
+                logging.getLogger(__name__).exception(
+                    "%s: unhandled exception in timer", self.name
+                )
+            now = self._leave()
 
     # -- queue reader tasks (the "fibers") --------------------------------
 
@@ -369,11 +458,25 @@ class AsyncThrottle:
             self._handle = None
 
 
+class FiredWindow(NamedTuple):
+    """The terms of the debounce window whose timer is firing, all on
+    ``time.perf_counter``; ``deadline + late_s`` is the fire itself."""
+
+    armed_at: float  # the FIRST arm of the window
+    deadline: float  # the deadline it fired for (the last arm's)
+    late_s: float  # fire - deadline
+    idle_since: float  # end of the last callback the loop ran before it
+    busy_s: float  # the loop's busy_s at the fire
+
+
 class AsyncDebounce:
     """Debounce with exponential extension: every invocation while pending
     pushes the deadline out (doubling from min toward max); once the
     backoff is saturated further invocations no longer delay the fire.
-    reference: common/AsyncDebounce.h:27-62."""
+    reference: common/AsyncDebounce.h:27-62.
+
+    While the callback runs, ``fired`` holds the window's terms
+    (``FiredWindow``) for the owner to read; None at any other time."""
 
     def __init__(
         self,
@@ -386,6 +489,8 @@ class AsyncDebounce:
         self._backoff = ExponentialBackoff(min_backoff_s, max_backoff_s)
         self._callback = callback
         self._handle: Optional[TimerHandle] = None
+        self._armed_at: Optional[float] = None
+        self.fired: Optional[FiredWindow] = None
 
     def __call__(self) -> None:
         if not self._backoff.at_max_backoff():
@@ -395,12 +500,27 @@ class AsyncDebounce:
             self._handle = self._evb.schedule_timeout(
                 self._backoff.get_current_backoff(), self._fire
             )
+            if self._armed_at is None:
+                self._armed_at = self._handle.deadline - (
+                    self._backoff.get_current_backoff()
+                )
         assert self._handle is not None and not self._handle.cancelled
 
     def _fire(self) -> None:
+        handle, armed_at = self._handle, self._armed_at
         self._handle = None
+        self._armed_at = None
         self._backoff.report_success()
-        self._callback()
+        evb = self._evb
+        if handle is not None and armed_at is not None:
+            self.fired = FiredWindow(
+                armed_at, handle.deadline, handle.late_s, evb.idle_since,
+                evb.busy_s,
+            )
+        try:
+            self._callback()
+        finally:
+            self.fired = None
 
     def is_scheduled(self) -> bool:
         return self._handle is not None and not self._handle.cancelled

@@ -207,7 +207,8 @@ class TestPerfEventsEndToEnd:
 
 # the span tree of one adjacency event through the dense device path,
 # in the order the spans open, with their depths: the publication that
-# opens the window stages the view solve inside the debounce span, and
+# opens the window stages the view solve inside the debounce span, the
+# rest of the wait is recorded at the fire as decision.policy_idle, and
 # the rebuild lands on the solved view (nothing of the solver's under
 # the route build)
 ADJ_EVENT_TREE = [
@@ -218,6 +219,7 @@ ADJ_EVENT_TREE = [
     ("graph.view_sync", 2),
     ("ops.spf_view_batch", 2),
     ("ops.solve_readback", 2),
+    ("decision.policy_idle", 1),
     ("decision.rebuild", 0),
     ("decision.route_build", 1),
     ("decision.route_diff", 1),
@@ -247,6 +249,7 @@ PREFIX_EVENT_TREE = [
     ("kvstore.publish", 0),
     ("decision.queue_wait", 0),
     ("decision.debounce", 0),
+    ("decision.policy_idle", 1),
     ("decision.rebuild", 0),
     ("decision.route_build", 1),
     ("decision.emit", 0),

@@ -232,7 +232,13 @@ class TestWatchdogStallCounters:
             assert wait_until(
                 lambda: time.monotonic() - victim.last_loop_ts > 0.1
             )
-            healthy.run_in_event_base(lambda: None)  # keep it fresh
+            # keep it fresh: an idle loop turns only every 0.1 s, twice
+            # the timeout, so without work of its own it reads as stalled
+            # whenever this thread is late by a few tens of milliseconds
+            healthy.schedule_periodic(0.005, lambda: None)
+            assert wait_until(
+                lambda: time.monotonic() - healthy.last_loop_ts < 0.02
+            )
             wd._check()
             assert reg.counter_get("watchdog.stalls.victim") == before + 1
             assert reg.counter_get("watchdog.stalls.healthy") == 0
