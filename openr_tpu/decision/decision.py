@@ -330,6 +330,10 @@ class Decision:
         # debounce window (set where it is staged, reset when the
         # rebuild fires)
         self._spec_fired_this_window = False
+        # a KSP2 engine built cold inside a stage, outside the bracket
+        # rebuild_routes keeps for settle_heap: the window's rebuild
+        # settles for it
+        self._staged_cold_build = False
         # admission/backpressure path (service plane): the controller
         # adapts the debounce ceiling to the reader backlog, and the
         # consume path sheds-by-coalescing once the backlog is deep
@@ -439,26 +443,33 @@ class Decision:
                 self._speculate_views()
 
     def _speculate_views(self) -> None:
-        """Stage the root's views for the window's rebuild, traced as
-        the rebuild's own solve would be: ``decision.speculate`` inside
+        """Stage the root's views (where a KSP2 engine serves the view:
+        the engine's sync) for the window's rebuild, traced as the
+        rebuild's own solve would be: ``decision.speculate`` inside
         ``decision.debounce``, with the window's trace active on this
-        thread so that the view's sync / dispatch / readback spans nest
-        beneath it, and an event window of its own for the host-touch
+        thread so that the view's sync / dispatch / readback spans (the
+        engine's ``decision.ksp2_sync`` and its children) nest beneath
+        it, and an event window of its own for the host-touch
         accounting."""
         trace = self.pending.trace
         tracer = get_tracer()
         if trace is not None:
             tracer.activate(trace)
+        cold_builds = SPF_COUNTERS["decision.ksp2_cold_builds"]
         try:
             with tracer.span(
                 "decision.speculate", trace=trace
             ) as span, da.event_window("decision.speculate"):
                 staged = self.spf_solver.speculate_views(
-                    self.my_node_name, self.area_link_states
+                    self.my_node_name,
+                    self.area_link_states,
+                    self.prefix_state,
                 )
                 if span is not None:
                     span.attrs["staged"] = staged
         finally:
+            if SPF_COUNTERS["decision.ksp2_cold_builds"] != cold_builds:
+                self._staged_cold_build = True
             if trace is not None:
                 tracer.deactivate()
 
@@ -875,10 +886,15 @@ class Decision:
         perf_events = self.pending.move_out_events()
         self.pending.reset()
         self._emit_update(payload, trace, rebuild_span, perf_events)
-        if SPF_COUNTERS["decision.ksp2_cold_builds"] != cold_builds:
-            # a KSP2 engine rebuilt whole: what it left on the heap
-            # lives until its next cold build. After the update is on
-            # its way to Fib, not before
+        if (
+            self._staged_cold_build
+            or SPF_COUNTERS["decision.ksp2_cold_builds"] != cold_builds
+        ):
+            # a KSP2 engine rebuilt whole (here, or in the window's
+            # stage): what it left on the heap lives until its next
+            # cold build. After the update is on its way to Fib, not
+            # before
+            self._staged_cold_build = False
             settle_heap()
 
     def _emit_update(

@@ -474,6 +474,7 @@ def _pad_ids(ids: List[int], bucket_min: int = 8) -> np.ndarray:
 # hence "owner" confinement (same contract as WorldManager).
 @thread_confined(
     "owner",
+    "_carried",
     "_masked_warm",
     "_mesh",
     "_mesh_knob",
@@ -492,26 +493,42 @@ def _pad_ids(ids: List[int], bucket_min: int = 8) -> np.ndarray:
     "excl_users",
     "first_paths",
     "host_dsts",
-    "last_affected",
     "node_label",
     "node_users",
     "ov",
     "pairs_by_node",
     "second_paths",
     "sid",
+    "staged",
     "state",
+    "syncs_worked",
     "valid",
     "reach2",
     "version",
 )
 class Ksp2Engine:
     """Per-(LinkState, root) incremental KSP2 state. Invalid until the
-    first successful cold build."""
+    first successful cold build.
+
+    A ``sync`` may run before the ``build_route_db`` that uses it (the
+    solver stages one under the debounce's policy wait), so what a sync
+    found moved is CARRIED until a build takes it (``take_affected``).
+    The invariant: the set handed to a ``build_route_db`` covers every
+    destination whose paths or routes moved since the previous
+    ``build_route_db`` took one."""
 
     def __init__(self, src_name: str) -> None:
         self.src_name = src_name
         self.valid = False
-        self.last_affected: Optional[Set[str]] = None
+        # the carry: the union of the affected sets of every sync since
+        # the last take; None (a cold build: every destination) absorbs
+        self._carried: Optional[Set[str]] = None
+        # a sync ran ahead of its build (SpfSolver.speculate_views sets
+        # it, the build that takes the carry clears it)
+        self.staged = False
+        # syncs that found work to do (a cold build or an incremental
+        # step: the ones that open a decision.ksp2_sync span)
+        self.syncs_worked = 0
         # _mesh_knob: the module knob as of the last (re)build — the
         # change-detection identity. _mesh: the mesh the resident
         # arrays are ACTUALLY sharded over (None when the knob is off
@@ -530,58 +547,93 @@ class Ksp2Engine:
     def sync(self, ls: LinkState, dsts: List[str]) -> Optional[Set[str]]:
         """Bring the cache to ls.topology_version, prime the LinkState
         kth-path cache for every destination, and return the set of
-        destination names whose paths may have changed (for route
-        reuse). Returns None when the engine had to cold-rebuild (no
-        reuse this build) or cannot run (caller falls back).
+        destination names whose paths may have changed since the
+        engine's own version. Returns None when the engine had to
+        cold-rebuild or cannot run (caller falls back). What a build
+        may reuse is not this return but the carry (``take_affected``):
+        a sync is not pure in the version, and the one that did the
+        work may have run before the build that asks.
+
+        A sync at the version the engine is already at does no work: it
+        returns the empty set, opens no ``decision.ksp2_sync`` span and
+        moves no counter (the kth-path cache was not invalidated, so
+        priming is in place).
 
         The whole device round trip runs inside one accounting window:
         every device readback must ride the committed chain
         (``aot_call`` + async kick, reaped via ``reap_read``), and the
         ``ops.host_touches.ksp2_window`` observation is the gate."""
-        with _da.event_window("ksp2_window"), get_tracer().span(
-            "decision.ksp2_sync", changed_pairs=0
-        ) as span:
-            affected = self._sync_window(ls, dsts, span)
-            if span is not None:
-                # a cold build re-derives every destination
-                span.attrs["cold"] = affected is None
-                span.attrs["affected"] = (
-                    len(dsts) if affected is None else len(affected)
-                )
-            return affected
-
-    def _sync_window(
-        self, ls: LinkState, dsts: List[str], span=None
-    ) -> Optional[Set[str]]:
-        self.last_affected = None
         from openr_tpu.decision import spf_solver as _ss
 
-        state = _ss._ELL_RESIDENT.state_for(ls)
-        if (
-            not self.valid
-            or state is not getattr(self, "state", None)
-            or dsts != self.dsts
-            or self.sid != state.graph.node_index.get(self.src_name)
+        with _da.event_window("ksp2_window"):
+            try:
+                state = _ss._ELL_RESIDENT.state_for(ls)
+                fits = self._fits(state, dsts)
+                if (
+                    fits
+                    and ls.topology_version == self.version
+                    and ls.attributes_version == self.aversion
+                ):
+                    return set()
+                self.syncs_worked += 1
+                with get_tracer().span(
+                    "decision.ksp2_sync", changed_pairs=0
+                ) as span:
+                    affected = None
+                    if fits:
+                        affected = self._sync_window(ls, state, dsts, span)
+                    else:
+                        self._cold_build(ls, state, dsts)
+                    if span is not None:
+                        # a cold build re-derives every destination
+                        span.attrs["cold"] = affected is None
+                        span.attrs["affected"] = (
+                            len(dsts) if affected is None else len(affected)
+                        )
+                    return affected
+            except BaseException:
+                # torn between two versions: the next sync (the
+                # rebuild's own, where a stage raised) builds cold
+                self.invalidate()
+                raise
+
+    def take_affected(self) -> Optional[Set[str]]:
+        """Hand a ``build_route_db`` the carry and start a new one: the
+        destinations whose paths or routes moved in any sync since the
+        previous take, None for all of them (a cold build in between,
+        or an engine that is not valid)."""
+        carried, self._carried = self._carried, set()
+        self.staged = False
+        return carried
+
+    def invalidate(self) -> None:
+        """Make the next sync a cold build (and the next take "all")."""
+        self.valid = False
+        self._carried = None
+        self.staged = False
+        # a dispatch that raised may have consumed the donated buffer
+        self.d_prev_dev = None
+
+    def _fits(self, state, dsts: List[str]) -> bool:
+        """The engine was built for this resident state, root and
+        destination list: an incremental sync (or none) will do."""
+        return (
+            self.valid
+            and state is self.state
+            and dsts == self.dsts
+            and self.sid == state.graph.node_index.get(self.src_name)
             # a widened band (ell_patch grew a slot class in place)
             # changed the band tensor shapes the masked buckets were
             # compiled for: re-seed everything from the new shapes
-            or tuple(state.graph.bands) != getattr(
-                self, "band_shapes", None
-            )
+            and tuple(state.graph.bands) == self.band_shapes
             # the engine-mesh knob changed: resident arrays carry the
             # old sharding — re-seed under the new one
-            or self._mesh_knob is not _ENGINE_MESH
-        ):
-            self._cold_build(ls, state, dsts)
-            return None
-        if (
-            ls.topology_version == self.version
-            and ls.attributes_version == self.aversion
-        ):
-            # nothing changed since the last build; the kth-path cache
-            # was not invalidated, so priming is already in place
-            self.last_affected = set()
-            return set()
+            and self._mesh_knob is _ENGINE_MESH
+        )
+
+    def _sync_window(
+        self, ls: LinkState, state, dsts: List[str], span=None
+    ) -> Optional[Set[str]]:
         affected_nodes = ls.affected_since(self.version)
         attr_nodes = ls.attr_affected_since(self.aversion)
         if affected_nodes is None or attr_nodes is None:
@@ -760,7 +812,8 @@ class Ksp2Engine:
         self.aversion = ls.attributes_version
         _counters()["decision.ksp2_incremental_syncs"] += 1
         _counters()["decision.ksp2_affected_dsts"] += len(affected)
-        self.last_affected = affected
+        if self._carried is not None:
+            self._carried |= affected
         return affected
 
     # -- cold build --------------------------------------------------------
@@ -772,6 +825,7 @@ class Ksp2Engine:
         import jax.numpy as jnp
 
         self.valid = False
+        self._carried = None
         graph = state.graph
         self.state = state
         self.dsts = list(dsts)

@@ -1019,35 +1019,46 @@ class SpfSolver:
         self,
         my_node_name: str,
         area_link_states: AreaLinkStates,
+        prefix_state: PrefixState,
     ) -> int:
         """Window-opening speculation hook (the decision module calls
         this once per debounce window, from the first publication that
         finds no other queued behind it, which at one publication a
         window is the one that opens it, right AFTER it has armed the
         timer and ``prewarm`` has returned): the LSDB as it stands is
-        then what the rebuild will compute for, so solve the root's
-        view for it NOW, under the policy wait, through the committed
-        path's own ``_view`` (blocking; the timer's callback runs on
-        the same thread and so cannot fire before this returns), and
-        let the rebuild's ``_view`` land on a cache hit instead of
-        paying the solve inside the route-build critical path. Counted,
-        never silent: ``ops.spec_dispatches`` on stage, ``ops.spec_hits``
-        when the rebuild consumes the staged view, ``ops.spec_cancels``
-        when a later publication supersedes it (the committed rebuild
-        then re-solves, bit-identical: the view is pure in
-        (version, root)). A graph whose version has not moved (a
-        prefix-only window) has its view cached already: nothing is
-        dispatched and no counter moves. Stands down
-        (``ops.spec_skips``) while any chaos fault is armed: every
-        fault seam belongs to the committed path's degradation ladder,
-        and a speculative solve consuming a charge would let a fault
-        escape the rung that owns it. And for an area whose KSP2 engine
-        is live: the rebuild's view there comes out of the engine's own
-        fused dispatch (``Ksp2Engine._preload_view``), so a view staged
-        now is solved for nobody, by the dense or ELL view path that
-        area otherwise never runs (in ``fabric-1000-ksp2.adj-churn``
-        its first use compiled a snapshot patch inside the window).
-        Host and native backends return at once."""
+        then what the rebuild will compute for, so do the rebuild's
+        device step for it NOW, under the policy wait, on the committed
+        path's own code (blocking; the timer's callback runs on the
+        same thread and so cannot fire before this returns). Which step
+        is read off what serves the root's view in that area:
+
+        - no KSP2 engine: solve the view through ``_view`` and let the
+          rebuild's ``_view`` land on a cache hit;
+        - a live, valid engine at this root: the view there comes out
+          of the engine's own fused dispatch, so sync the ENGINE
+          (``_prefetch_ksp2_area``, the carry left untaken: the
+          window's ``build_route_db`` finds it at its version, its own
+          ``sync`` does nothing, and it takes what this one found
+          moved). No view is solved on the dense or ELL view path
+          there, which that area otherwise never runs.
+
+        Counted, never silent: ``ops.spec_dispatches`` on stage,
+        ``ops.spec_hits`` when the rebuild finds the view solved / the
+        engine at the version it builds for, ``ops.spec_cancels`` when
+        a later publication moved the version past the stage (a view is
+        then re-solved, bit-identical: it is pure in (version, root);
+        an engine steps on from the staged version and the build takes
+        the union, so only the overlap is lost) or the stage raised
+        (abandoned, never an escalation: a torn engine is invalid and
+        the rebuild builds it cold on its own ladder). A graph whose
+        version has not moved (a prefix-only window) has its view
+        cached already: nothing is dispatched and no counter moves.
+        Stands down (``ops.spec_skips``) while any chaos fault is
+        armed: every fault seam belongs to the committed path's
+        degradation ladder, and a speculative solve consuming a charge
+        would let a fault escape the rung that owns it. And for an
+        engine that is not valid: its next sync is a cold build, which
+        is the rebuild's. Host and native backends return at once."""
         from openr_tpu.faults.injector import get_injector
 
         reg = _get_registry()
@@ -1068,26 +1079,39 @@ class SpfSolver:
                 # (first, and cheap: a window of prefix or attribute
                 # changes only ends here with no counter moved)
                 continue
+            engine = self._ksp2_engines.get(ls)
+            if engine is not None and engine.src_name != my_node_name:
+                engine = None  # another root's: the view path serves
             prev = self._spec_staged.pop(ls, None)
-            if prev is not None:
+            if prev is not None or (engine is not None and engine.staged):
                 # an earlier stage for this graph died unconsumed
                 reg.counter_bump("ops.spec_cancels")
-            engine = self._ksp2_engines.get(ls)
-            if (
-                engine is not None
-                and engine.valid
-                and engine.src_name == my_node_name
-            ):
-                reg.counter_bump("ops.spec_skips")
-                continue
             try:
-                self._view(area, ls, my_node_name)
+                if engine is None:
+                    self._view(area, ls, my_node_name)
+                    self._spec_staged[ls] = key
+                else:
+                    engine.staged = False
+                    dsts = sorted(
+                        self._ksp2_area_dsts(
+                            my_node_name, area_link_states, prefix_state
+                        )[area]
+                    )
+                    if (
+                        not engine.valid
+                        or len(dsts) < KSP2_DEVICE_MIN_DSTS
+                    ):
+                        reg.counter_bump("ops.spec_skips")
+                        continue
+                    self._prefetch_ksp2_area(
+                        area, ls, my_node_name, dsts, take=False
+                    )
+                    engine.staged = True
             except Exception:
                 # abandoned speculation, never an escalation: the
                 # committed rebuild owns the retry ladder
                 reg.counter_bump("ops.spec_cancels")
                 continue
-            self._spec_staged[ls] = key
             reg.counter_bump("ops.spec_dispatches")
             staged += 1
         return staged
@@ -2111,6 +2135,42 @@ class SpfSolver:
 
     # -- KSP2_ED_ECMP -----------------------------------------------------
 
+    def _ksp2_area_dsts(
+        self,
+        my_node_name: str,
+        area_link_states: AreaLinkStates,
+        prefix_state: PrefixState,
+    ) -> Dict[str, Set[str]]:
+        """Per area, the nodes other than the root that advertise a
+        KSP2_ED_ECMP prefix there. The scan is O(total prefix entries):
+        cached per prefix-state version (at 100k SP-only fabrics it
+        burned ~0.4 s/event discovering an empty set every build)."""
+        dsts_key = (
+            prefix_state,
+            prefix_state.version,
+            my_node_name,
+            tuple(sorted(area_link_states)),
+        )
+        if (
+            self._ksp2_dsts_cache is not None
+            and self._ksp2_dsts_cache[0] == dsts_key
+        ):
+            return self._ksp2_dsts_cache[1]
+        area_dsts = {area: set() for area in area_link_states}
+        for prefix in prefix_state.prefixes():
+            for (node, p_area), entry in prefix_state.entries_for(
+                prefix
+            ).items():
+                if (
+                    entry.forwarding_algorithm
+                    == PrefixForwardingAlgorithm.KSP2_ED_ECMP
+                    and node != my_node_name
+                    and p_area in area_dsts
+                ):
+                    area_dsts[p_area].add(node)
+        self._ksp2_dsts_cache = (dsts_key, area_dsts)
+        return area_dsts
+
     def _prefetch_ksp2_paths(
         self,
         my_node_name: str,
@@ -2144,34 +2204,9 @@ class SpfSolver:
         unsignaled area's churn could silently change reused routes."""
         if self.backend != "device":
             return None
-        # the destination scan is O(total prefix entries): cache it per
-        # prefix-state version (at 100k SP-only fabrics it burned
-        # ~0.4 s/event discovering an empty set every build)
-        dsts_key = (
-            prefix_state,
-            prefix_state.version,
-            my_node_name,
-            tuple(sorted(area_link_states)),
+        area_dsts = self._ksp2_area_dsts(
+            my_node_name, area_link_states, prefix_state
         )
-        if (
-            self._ksp2_dsts_cache is not None
-            and self._ksp2_dsts_cache[0] == dsts_key
-        ):
-            area_dsts = self._ksp2_dsts_cache[1]
-        else:
-            area_dsts = {area: set() for area in area_link_states}
-            for prefix in prefix_state.prefixes():
-                for (node, p_area), entry in prefix_state.entries_for(
-                    prefix
-                ).items():
-                    if (
-                        entry.forwarding_algorithm
-                        == PrefixForwardingAlgorithm.KSP2_ED_ECMP
-                        and node != my_node_name
-                        and p_area in area_dsts
-                    ):
-                        area_dsts[p_area].add(node)
-            self._ksp2_dsts_cache = (dsts_key, area_dsts)
         if not any(area_dsts.values()):
             return None
 
@@ -2218,10 +2253,16 @@ class SpfSolver:
         ls: LinkState,
         my_node_name: str,
         dsts: List[str],
+        take: bool = True,
     ) -> Optional[Set[str]]:
         """Device-batch one area's KSP2 paths; returns the affected set
         (cold build = all dsts) or None when the area's paths came from
-        the legacy per-build dispatch / host fallback (no reuse)."""
+        the legacy per-build dispatch / host fallback (no reuse).
+
+        ``take=False`` is the stage (``speculate_views``): the engine
+        is synced and nothing else; what it found moved stays in the
+        engine's carry for the window's ``build_route_db``, whose own
+        call here takes it."""
         from openr_tpu.decision import ksp2_engine
 
         if (
@@ -2241,7 +2282,19 @@ class SpfSolver:
                     return None  # high diameter: host Dijkstra wins
                 engine = ksp2_engine.Ksp2Engine(my_node_name)
                 self._ksp2_engines[ls] = engine
-            affected = engine.sync(ls, dsts)
+            staged, worked = engine.staged, engine.syncs_worked
+            synced = engine.sync(ls, dsts)
+            if not take:
+                return synced
+            if staged:
+                # the build found the engine where the stage left it,
+                # at the version it builds for, or had to step on
+                _get_registry().counter_bump(
+                    "ops.spec_hits"
+                    if engine.syncs_worked == worked
+                    else "ops.spec_cancels"
+                )
+            affected = engine.take_affected()
             if engine.valid and engine.ecc_hops > KSP2_DEVICE_MAX_HOPS:
                 # diameter grew past the device win: paths for THIS
                 # build are already primed; drop the engine so later

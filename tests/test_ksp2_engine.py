@@ -718,6 +718,126 @@ def _lag_network(metric2: int = 2):
     return topo, {topo.area: ls}, ps
 
 
+class TestAffectedCarry:
+    """A sync may run before the build that uses it (the solver stages
+    one under the debounce's policy wait), so the engine keeps what
+    every sync found moved until a build takes it. The invariant: the
+    set handed to a ``build_route_db`` covers every destination whose
+    paths or routes moved since the previous build took one."""
+
+    @staticmethod
+    def _engine(seed):
+        """A 5-pod fabric's engine at an RSW, cold-built and taken, and
+        a stream of metric changes that each move some destination."""
+        topo, area_ls, _ps = _ksp2_network("fabric", 60)
+        (ls,) = area_ls.values()
+        names = sorted(topo.adj_dbs)
+        root = next(k for k in names if k.startswith("rsw"))
+        dsts = [k for k in names if k != root]
+        engine = ksp2_engine.Ksp2Engine(root)
+        assert engine.sync(ls, dsts) is None  # cold build
+        assert engine.take_affected() is None  # ... is "all"
+        rng = random.Random(seed)
+
+        def step():
+            """Mutate until a sync names a destination."""
+            for _ in range(200):
+                node = rng.choice(names)
+                db = ls.get_adjacency_databases()[node]
+                i = rng.randrange(len(db.adjacencies))
+                _mutate_metric(ls, node, i, 2 + rng.randrange(8))
+                affected = engine.sync(ls, dsts)
+                assert affected is not None
+                if affected:
+                    return affected
+            raise AssertionError("no mutation moved a destination")
+
+        return engine, ls, dsts, step
+
+    @pytest.mark.parametrize("seed", [3, 2147483659])
+    def test_two_syncs_then_one_take_is_the_union(self, seed):
+        engine, _ls, _dsts, step = self._engine(seed)
+        assert engine.take_affected() == set()
+        first = set(step())
+        second = set(step())
+        assert first and second
+        assert engine.take_affected() == first | second
+        # taken: the next build starts from nothing
+        assert engine.take_affected() == set()
+
+    def test_a_cold_build_between_absorbs_to_all(self, monkeypatch):
+        engine, ls, dsts, step = self._engine(5)
+        step()
+        with monkeypatch.context() as m:
+            m.setattr(
+                ksp2_engine.Ksp2Engine, "_diff_pairs",
+                lambda *a, **k: None,
+            )
+            _mutate_metric(ls, dsts[0], 0, 7)
+            cold = SPF_COUNTERS["decision.ksp2_cold_builds"]
+            assert engine.sync(ls, dsts) is None
+            assert SPF_COUNTERS["decision.ksp2_cold_builds"] == cold + 1
+        step()  # an incremental sync after it does not narrow "all"
+        assert engine.take_affected() is None
+        assert engine.take_affected() == set()
+
+    def test_a_sync_at_the_engines_version_does_no_work(self):
+        """... and says so: the empty set, no ``decision.ksp2_sync``
+        span (the metric ``ksp2_sync_ms`` is the median over those
+        spans, one a rebuild), neither counter that
+        ``ksp2_affected_per_sync`` divides."""
+        from openr_tpu.telemetry import get_tracer
+
+        engine, ls, dsts, step = self._engine(7)
+        tracer = get_tracer()
+        trace = tracer.start()
+        tracer.activate(trace)
+        try:
+            moved = set(step())
+            spans = [s.name for s in trace.spans]
+            assert spans.count("decision.ksp2_sync") >= 1
+            assert engine.take_affected() >= moved
+            before = dict(SPF_COUNTERS)
+            worked = engine.syncs_worked
+            assert engine.sync(ls, dsts) == set()
+            assert engine.sync(ls, dsts) == set()
+        finally:
+            tracer.deactivate()
+            tracer.finish(trace)
+        assert [s.name for s in trace.spans] == spans
+        assert engine.syncs_worked == worked
+        for name in ("decision.ksp2_incremental_syncs",
+                     "decision.ksp2_affected_dsts",
+                     "decision.ksp2_cold_builds",
+                     "decision.ksp2_warm_dispatches"):
+            assert SPF_COUNTERS[name] == before[name], name
+        assert engine.take_affected() == set()
+
+    def test_a_sync_that_raises_leaves_a_cold_build_behind(
+            self, monkeypatch):
+        """Torn between two versions, the engine is not valid: the next
+        sync builds cold and the next take is "all"."""
+        engine, ls, dsts, step = self._engine(11)
+        step()
+        assert engine.take_affected()
+        real = ksp2_engine.Ksp2Engine._prime_all
+
+        def torn(self, ls_):
+            real(self, ls_)
+            raise RuntimeError("torn")
+
+        with monkeypatch.context() as m:
+            m.setattr(ksp2_engine.Ksp2Engine, "_prime_all", torn)
+            _mutate_metric(ls, dsts[0], 0, 9)
+            with pytest.raises(RuntimeError):
+                engine.sync(ls, dsts)
+        assert not engine.valid
+        cold = SPF_COUNTERS["decision.ksp2_cold_builds"]
+        assert engine.sync(ls, dsts) is None and engine.valid
+        assert SPF_COUNTERS["decision.ksp2_cold_builds"] == cold + 1
+        assert engine.take_affected() is None
+
+
 class TestParallelLinksFirstClass:
     """VERDICT item 6: LAG members are individually maskable, so the
     incremental engine stays warm and no destination falls back to the

@@ -226,8 +226,10 @@ def test_one_event_at_a_time_the_routes_stay_the_references(
     """The engine's narrowest path: one link changes, the DAG tests name
     some destinations, ``_second_paths_may_move`` keeps those whose
     walks read the changed place of a candidate list, and only what
-    moved is re-derived. Windows of 1 to 4 events; after each the
-    routes are the plain reference's. (Seed 51 caught places going
+    moved is re-derived. Windows of 1 to 4 publications through
+    Decision's own trigger, so the first is staged under the window and
+    the rest join it; after each window the routes are the plain
+    reference's. (Seed 51 caught places going
     stale when a link joined a list ahead of them; seed 72, on four
     pods, a masked row left stale at a node that mattered to no route
     until, 300 events on, it did.)"""
@@ -243,6 +245,14 @@ def test_one_event_at_a_time_the_routes_stay_the_references(
     assert moved["decision.ksp2_affected_dsts"] < dsts * syncs // 6
     # ... and a good share of them solve nothing on the device again
     assert moved["decision.ksp2_device_batches"] < syncs
+    # every window's first publication staged the engine's sync; the
+    # rebuild found it at its version unless a second publication had
+    # joined (15% of the windows), and then stepped on from it
+    assert moved["ops.spec_dispatches"] == windows
+    assert moved["ops.spec_hits"] + moved["ops.spec_cancels"] == windows
+    assert windows // 12 < moved["ops.spec_cancels"] < windows // 4
+    assert syncs + moved["decision.ksp2_cold_builds"] \
+        == windows + moved["ops.spec_cancels"]
 
 
 def test_settle_heap_reclaims_what_an_earlier_call_froze():
@@ -316,40 +326,305 @@ def test_repeated_cold_builds_do_not_pile_up_in_the_permanent_generation(
     assert max(frozen[1:]) - frozen[0] < frozen[0] // 20, frozen
 
 
-def test_no_view_is_staged_where_the_engine_serves_the_view(fabric):
-    """The publication that opens a debounce window stages the root's
-    view for the rebuild to find (``Decision._on_publication`` ->
-    ``speculate_views``). Where a KSP2 engine is live the rebuild takes
-    its view from the engine's fused dispatch, and a staged one would
-    be solved for nobody, on the view path that area never runs
-    otherwise: it stands down, counted."""
+def _publish(decision, *events, trace=None):
+    """Each event through Decision's own trigger, as KvStore's queue
+    would hand it over; the first carries the window's trace."""
+    for ev in events:
+        decision._on_publication(Publication(
+            key_vals={ev.key: ev.value}, area="0", trace=trace))
+        trace = None
+
+
+def _loaded(fabric, seed, *backends):
+    """A traffic generator and one Decision a backend, the initial LSDB
+    built into routes (the engine's cold build, on the device side)."""
+    gen = traffic.Generator(fabric, seed, MIX, VANTAGE)
+    queues, sides = zip(*(_decision(b) for b in backends))
+    for d in sides:
+        d.process_publication(Publication(
+            key_vals=dict(gen.initial_key_vals()), area="0"))
+        d.rebuild_routes("LOAD")
+    return gen, queues, sides
+
+
+def _spec_counters():
     from openr_tpu.telemetry import get_registry
 
-    gen = traffic.Generator(fabric, 13, MIX, VANTAGE)
-    kv_q, decision = _decision("device")
     reg = get_registry()
+    out = {n: reg.counter_get(n) for n in (
+        "ops.spec_dispatches", "ops.spec_hits", "ops.spec_cancels",
+        "ops.spec_skips")}
+    out.update(SPF_COUNTERS)
+    return out
+
+
+def _delta(before, *names):
+    now = _spec_counters()
+    return tuple(now[n] - before.get(n, 0) for n in names)
+
+
+def _assert_reference(gen, live, host=None):
+    assert reference_ksp2.routes_of(live) == reference_ksp2.routes(
+        gen.adj_dbs, gen.prefix_dbs, VANTAGE)
+    assert reference_ksp2.mpls_routes_of(live) \
+        == reference_ksp2.mpls_routes(gen.adj_dbs, VANTAGE)
+    if host is not None:
+        assert wire.dumps(live) == wire.dumps(host)
+
+
+def test_the_engine_is_staged_and_still_no_view_is_solved_on_the_view_path(
+        fabric):
+    """The publication that opens a debounce window stages the rebuild's
+    device step (``Decision._on_publication`` -> ``speculate_views``).
+    Where a KSP2 engine is live the rebuild takes its view from the
+    engine's fused dispatch, so what is staged there is the engine's
+    sync: counted as a dispatch, consumed by the window's rebuild as a
+    hit, and no view is solved for nobody on the view path that area
+    never runs otherwise."""
+    gen, queues, (decision,) = _loaded(fabric, 13, "device")
     try:
-        decision.process_publication(Publication(
-            key_vals=dict(gen.initial_key_vals()), area="0"))
-        decision.rebuild_routes("LOAD")
         solver = decision.spf_solver
         (engine,) = solver._ksp2_engines.values()
         assert engine.valid and engine.src_name == VANTAGE
         for _ in range(3):
-            ev = gen.draw()
-            before = (dict(SPF_COUNTERS), reg.counter_get("ops.spec_skips"),
-                      reg.counter_get("ops.spec_dispatches"))
+            before = _spec_counters()
             # the window's opener, through Decision's own trigger
-            decision._on_publication(Publication(
-                key_vals={ev.key: ev.value}, area="0"))
+            _publish(decision, gen.draw())
             assert decision._rebuild_debounced.is_scheduled()
-            assert reg.counter_get("ops.spec_skips") == before[1] + 1
-            assert reg.counter_get("ops.spec_dispatches") == before[2]
-            assert SPF_COUNTERS["decision.device_solves"] \
-                == before[0]["decision.device_solves"]
+            assert engine.staged
+            assert _delta(
+                before, "ops.spec_dispatches", "ops.spec_skips",
+                "ops.spec_hits", "decision.device_solves",
+            ) == (1, 0, 0, 0)
             decision._on_debounce_fire()
+            assert not engine.staged
+            assert _delta(
+                before, "ops.spec_dispatches", "ops.spec_hits",
+                "ops.spec_cancels", "ops.spec_skips",
+                "decision.device_solves", "decision.ksp2_incremental_syncs",
+            ) == (1, 1, 0, 0, 0, 1)
+            _assert_reference(gen, decision.route_db.to_route_db(VANTAGE))
         # another root's view is nobody's to serve but the view path's
         other = "rsw-1-0"
-        assert solver.speculate_views(other, decision.area_link_states) == 1
+        assert solver.speculate_views(
+            other, decision.area_link_states, decision.prefix_state) == 1
     finally:
-        kv_q.close()
+        queues[0].close()
+
+
+def test_a_staged_sync_is_the_windows_one_sync_under_the_stage(fabric):
+    """Opener through ``_on_publication``, then ``_on_debounce_fire``:
+    one dispatch, one hit, no view solve, and the window's trace holds
+    exactly one ``decision.ksp2_sync``, with its children, inside
+    ``decision.speculate`` inside ``decision.debounce``; the rebuild's
+    ``decision.route_build`` holds ``decision.ksp2_routes`` and no
+    sync. Routes equal the host replay's and the reference's."""
+    gen, queues, (decision, host) = _loaded(fabric, 17, "device", "host")
+    tracer = get_tracer()
+    try:
+        for _ in range(4):
+            ev = gen.draw()
+            before = _spec_counters()
+            trace = tracer.start()
+            _publish(decision, ev, trace=trace)
+            decision._on_debounce_fire()
+            tracer.finish(trace)
+            host.process_publication(Publication(
+                key_vals={ev.key: ev.value}, area="0"))
+            host.rebuild_routes("EVENT")
+            assert _delta(
+                before, "ops.spec_dispatches", "ops.spec_hits",
+                "ops.spec_cancels", "decision.device_solves",
+                "decision.ksp2_incremental_syncs",
+            ) == (1, 1, 0, 0, 1)
+            _assert_reference(
+                gen, decision.route_db.to_route_db(VANTAGE),
+                host.route_db.to_route_db(VANTAGE))
+            assert trace.well_formed()
+            by_name = {}
+            for s in trace.spans:
+                assert s.closed
+                by_name.setdefault(s.name, []).append(s)
+            (window,) = by_name["decision.debounce"]
+            (stage,) = by_name["decision.speculate"]
+            (sync,) = by_name["decision.ksp2_sync"]
+            (build,) = by_name["decision.route_build"]
+            (routes,) = by_name["decision.ksp2_routes"]
+            assert stage.attrs["staged"] == 1
+            assert _inside(stage, window) and _inside(sync, stage)
+            assert not sync.attrs["cold"]
+            for name in ("ops.ksp2_all_pairs", "ops.ksp2_masked_solve",
+                         "decision.ksp2_trace"):
+                for s in by_name.get(name, ()):
+                    assert _inside(s, sync), name
+            assert len(by_name["ops.ksp2_all_pairs"]) == 1
+            assert build.ts_ms >= window.ts_ms + window.dur_ms - 0.5
+            assert _inside(routes, build)
+            assert routes.attrs["reused"] >= 55 - sync.attrs["affected"]
+            for name in ("graph.view_sync", "ops.spf_view_batch",
+                         "ops.ell_reconverge"):
+                assert name not in by_name
+    finally:
+        for q in queues:
+            q.close()
+
+
+@pytest.mark.parametrize("seed", [19, 2147483693])
+def test_a_publication_that_joins_after_the_stage_takes_the_union(
+        fabric, seed):
+    """Two adjacency publications in one window: the first is staged,
+    the second moves the version past the stage. The rebuild's own sync
+    steps on from the staged version (``ops.spec_cancels``: only the
+    overlap is lost), and the build re-derives the routes of the
+    destinations EITHER publication moved: the carry is the union, and
+    the route cache swallowed none of them."""
+    gen, queues, (decision, host) = _loaded(fabric, seed, "device", "host")
+    solver = decision.spf_solver
+    (engine,) = solver._ksp2_engines.values()
+    both_moved = 0
+    try:
+        for _ in range(12):
+            evs = [gen.draw(), gen.draw()]
+            before = _spec_counters()
+            old = dict(solver._route_cache)
+            _publish(decision, evs[0])
+            assert engine.staged
+            first = set(engine._carried)
+            _publish(decision, evs[1])
+            assert _delta(before, "ops.spec_dispatches") == (1,)  # the latch
+            decision._on_debounce_fire()
+            for ev in evs:
+                host.process_publication(Publication(
+                    key_vals={ev.key: ev.value}, area="0"))
+            host.rebuild_routes("EVENT")
+            assert _delta(
+                before, "ops.spec_dispatches", "ops.spec_hits",
+                "ops.spec_cancels", "decision.device_solves",
+                "decision.ksp2_incremental_syncs",
+            ) == (1, 0, 1, 0, 2)
+            _assert_reference(
+                gen, decision.route_db.to_route_db(VANTAGE),
+                host.route_db.to_route_db(VANTAGE))
+            # what the stage found moved was re-derived by the build
+            # although the build's own sync did not name it: the cached
+            # route object was replaced, not reused
+            new = solver._route_cache
+            for prefix, (entry, _best) in old.items():
+                advertisers = {
+                    node for node, _area
+                    in decision.prefix_state.entries_for(prefix)}
+                if entry is not None and advertisers & first:
+                    assert new[prefix][0] is not entry, prefix
+                    both_moved += 1
+            assert _delta(before, "decision.ksp2_route_reuses")[0] \
+                <= len(new) - len(first)
+            assert not engine._carried and not engine.staged
+    finally:
+        for q in queues:
+            q.close()
+    assert both_moved > 0, "no window's stage moved a destination"
+
+
+def test_a_staged_sync_that_raises_leaves_the_rebuild_correct(
+        fabric, monkeypatch):
+    """An abandoned speculation (``ops.spec_cancels``), never an
+    escalation: the torn engine is invalid, the window's rebuild builds
+    it cold on its own ladder's first rung, and the routes are the
+    reference's."""
+    gen, queues, (decision, host) = _loaded(fabric, 23, "device", "host")
+    (engine,) = decision.spf_solver._ksp2_engines.values()
+    real = ksp2_engine.Ksp2Engine._recompute
+    armed = []
+
+    def torn(self, *args, **kwargs):
+        if armed:
+            armed.pop()
+            real(self, *args, **kwargs)  # paths moved, nothing committed
+            raise RuntimeError("staged sync torn")
+        return real(self, *args, **kwargs)
+
+    monkeypatch.setattr(ksp2_engine.Ksp2Engine, "_recompute", torn)
+    try:
+        for _ in range(40):
+            ev = gen.draw()
+            before = _spec_counters()
+            armed.append(True)
+            _publish(decision, ev)
+            tore = not armed
+            armed.clear()
+            host.process_publication(Publication(
+                key_vals={ev.key: ev.value}, area="0"))
+            host.rebuild_routes("EVENT")
+            if tore:
+                assert not engine.valid and not engine.staged
+                assert _delta(
+                    before, "ops.spec_dispatches", "ops.spec_cancels",
+                ) == (0, 1)
+            decision._on_debounce_fire()
+            if tore:
+                assert engine.valid
+                assert _delta(
+                    before, "ops.spec_hits", "ops.spec_cancels",
+                    "decision.ksp2_cold_builds",
+                    "decision.device_state_resets",
+                ) == (0, 1, 1, 0)
+            _assert_reference(
+                gen, decision.route_db.to_route_db(VANTAGE),
+                host.route_db.to_route_db(VANTAGE))
+            if tore:
+                break
+        else:
+            pytest.fail("no event reached _recompute")
+        # and the window after it stages and hits again
+        before = _spec_counters()
+        _publish(decision, gen.draw())
+        decision._on_debounce_fire()
+        assert _delta(before, "ops.spec_dispatches", "ops.spec_hits") \
+            == (1, 1)
+        _assert_reference(gen, decision.route_db.to_route_db(VANTAGE))
+    finally:
+        for q in queues:
+            q.close()
+
+
+def test_a_cold_build_inside_a_stage_still_reaches_settle_heap(
+        fabric, monkeypatch):
+    """A staged sync that overflows into a cold build runs outside the
+    bracket ``rebuild_routes`` keeps around its own work; the window's
+    rebuild settles the heap for it all the same, once, and the build
+    takes "all" (no destination's route reused)."""
+    from openr_tpu.decision import decision as decision_mod
+
+    settled = []
+    monkeypatch.setattr(
+        decision_mod, "settle_heap", lambda: settled.append(True))
+    # every incremental sync overflows into a cold build
+    monkeypatch.setattr(
+        ksp2_engine.Ksp2Engine, "_diff_pairs", lambda *a, **k: None)
+    gen, queues, (decision,) = _loaded(fabric, 29, "device")
+    try:
+        assert settled == [True]  # the load's own cold build
+        before = _spec_counters()
+        _publish(decision, gen.draw())
+        assert _delta(
+            before, "decision.ksp2_cold_builds", "ops.spec_dispatches",
+        ) == (1, 1)
+        assert settled == [True], "not under the policy wait"
+        decision._on_debounce_fire()
+        assert settled == [True, True]
+        # (the one route reused is the vantage's own prefix, which no
+        # destination's paths reach)
+        assert _delta(
+            before, "decision.ksp2_cold_builds", "ops.spec_hits",
+            "decision.ksp2_route_reuses",
+        ) == (1, 1, 1)
+        _assert_reference(gen, decision.route_db.to_route_db(VANTAGE))
+        # a window with no cold build anywhere leaves the heap alone
+        monkeypatch.undo()
+        monkeypatch.setattr(
+            decision_mod, "settle_heap", lambda: settled.append(True))
+        _publish(decision, gen.draw())
+        decision._on_debounce_fire()
+        assert settled == [True, True]
+    finally:
+        queues[0].close()

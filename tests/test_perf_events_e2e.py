@@ -521,12 +521,12 @@ class TestSpeculationAtWindowOpening:
         entered = []  # (timer armed?, reader backlog) at each stage
         real = solver.speculate_views
 
-        def spy(my_node_name, area_link_states):
+        def spy(*args):
             entered.append((
                 decision._rebuild_debounced.is_scheduled(),
                 decision._kv_reader.size(),
             ))
-            return real(my_node_name, area_link_states)
+            return real(*args)
 
         monkeypatch.setattr(solver, "speculate_views", spy)
 

@@ -302,6 +302,7 @@ class TestChaosSeamInteraction:
         consuming the charge — the committed rebuild owns every fault
         seam, so a chaos test's armed fault can never be eaten by a
         speculative solve outside the ladder."""
+        from openr_tpu.decision.prefix_state import PrefixState
         from openr_tpu.decision.spf_solver import SpfSolver
 
         topo = topologies.grid(4)
@@ -313,7 +314,7 @@ class TestChaosSeamInteraction:
         k0 = reg.counter_get("ops.spec_skips")
         inj = get_injector()
         inj.arm("decision.spf_solve", FaultSchedule.fail_once())
-        assert solver.speculate_views(root, area_ls) == 0
+        assert solver.speculate_views(root, area_ls, PrefixState()) == 0
         assert reg.counter_get("ops.spec_skips") == k0 + 1
         assert inj.any_armed, "stand-down must not consume the charge"
 
@@ -333,7 +334,7 @@ class TestChaosSeamInteraction:
         reg = get_registry()
         d0 = reg.counter_get("ops.spec_dispatches")
         h0 = reg.counter_get("ops.spec_hits")
-        assert solver.speculate_views(root, area_ls) == 1
+        assert solver.speculate_views(root, area_ls, ps) == 1
         assert reg.counter_get("ops.spec_dispatches") == d0 + 1
         solver.build_route_db(root, area_ls, ps)
         assert reg.counter_get("ops.spec_hits") == h0 + 1

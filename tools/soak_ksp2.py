@@ -11,9 +11,12 @@ materialization extras. Two streams:
   fresh host solver, byte-exact RouteDatabase parity at every step;
 - ``soak_cell``: the events of the cell fabric-1000-ksp2.adj-churn
   (chipbench's generator: 80% metric change, 20% link flap) through
-  ``Decision``, one to four a rebuild window, and after every window
-  the RouteDatabase (unicast next hops with label stacks, node-label
-  MPLS routes) against the plain reference chipbench/reference_ksp2.py.
+  ``Decision`` as KvStore's queue hands them over (``_on_publication``
+  then ``_on_debounce_fire``: the window's first publication stages the
+  engine's sync, the rest join and move the version past the stage),
+  one to four a rebuild window, and after every window the
+  RouteDatabase (unicast next hops with label stacks, node-label MPLS
+  routes) against the plain reference chipbench/reference_ksp2.py.
   This is the stream that found the three holes in
   ``Ksp2Engine._second_paths_may_move`` (PERF.md section 6, PR 32);
   tests/test_ksp2_pipeline.py runs its seeds.
@@ -168,12 +171,15 @@ def _stale_rows(engine) -> list:
 def soak_cell(seed: int, pods: int, rsws: int, windows: int) -> dict:
     """``windows`` rebuild windows of the cell's events on a fabric of
     ``pods`` pods (2 SSW a plane, 4 FSW and ``rsws`` RSW a pod), 85%
-    of them one event and the rest two to four. Returns the counters
-    the stream moved, or where the routes left the reference or a
-    masked row of the engine's went stale."""
+    of them one publication (its staged sync is the window's: a hit)
+    and the rest two to four (the stage is stepped on from: a cancel,
+    and the build takes the union). Returns the counters the stream
+    moved, or where the routes left the reference or a masked row of
+    the engine's went stale."""
     from chipbench import reference_ksp2, topology, traffic
     from openr_tpu.decision.decision import Decision
     from openr_tpu.messaging.queue import ReplicateQueue
+    from openr_tpu.telemetry import get_registry
     from openr_tpu.types import Publication
 
     fabric = topology.build(
@@ -192,7 +198,12 @@ def soak_cell(seed: int, pods: int, rsws: int, windows: int) -> dict:
         solver_backend="device",
     )
     rng = random.Random(seed)
-    before = dict(SPF_COUNTERS)
+    reg = get_registry()
+    spec = ("ops.spec_dispatches", "ops.spec_hits", "ops.spec_cancels")
+
+    def counters() -> dict:
+        return {**SPF_COUNTERS, **{k: reg.counter_get(k) for k in spec}}
+
     out = {"seed": seed, "pods": pods, "rsws": rsws, "windows": windows,
            "dsts": len(fabric.adj_dbs) - 1}
     t0 = time.time()
@@ -200,12 +211,13 @@ def soak_cell(seed: int, pods: int, rsws: int, windows: int) -> dict:
         decision.process_publication(Publication(
             key_vals=dict(gen.initial_key_vals()), area="0"))
         decision.rebuild_routes("LOAD")
+        before = counters()
         for window in range(windows):
             for _ in range(1 if rng.random() < 0.85 else rng.randint(2, 4)):
                 ev = gen.draw()
-                decision.process_publication(Publication(
+                decision._on_publication(Publication(
                     key_vals={ev.key: ev.value}, area="0"))
-            decision.rebuild_routes("EVENT")
+            decision._on_debounce_fire()
             live = decision.route_db.to_route_db(CELL_VANTAGE)
             if reference_ksp2.routes_of(live) != reference_ksp2.routes(
                 gen.adj_dbs, gen.prefix_dbs, CELL_VANTAGE
@@ -222,8 +234,9 @@ def soak_cell(seed: int, pods: int, rsws: int, windows: int) -> dict:
         kv_q.close()
     out["parity"] = "ok"
     out["moved"] = {
-        k: SPF_COUNTERS[k] - before.get(k, 0)
-        for k in SPF_COUNTERS if k.startswith("decision.ksp2_")
+        k: v - before.get(k, 0)
+        for k, v in counters().items()
+        if k.startswith("decision.ksp2_") or k in spec
     }
     out["wall_s"] = round(time.time() - t0, 1)
     return out
