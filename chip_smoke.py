@@ -16,6 +16,10 @@ deployment uses and with the options ``OpenrConfig`` ships
 - ``ksp2_fabric_1008``: every prefix ``KSP2_ED_ECMP`` through
   ``SpfSolver(backend="device")`` over a few churn events,
   ``RouteDatabase`` equal to ``backend="host"``.
+- ``ksp2_grid_961``: every prefix ``KSP2_ED_ECMP`` on upstream's own
+  KSP2 graph, the 31 x 31 grid, through ``SpfSolver`` from the corner
+  (60 hops deep): four nodes re-cost all their links, the engine the
+  load built serves every one, device against host.
 - ``serve``: ``SolverService`` behind ``CtrlServer`` in this process,
   JAX-free client processes over the ctrl wire, every FIB digest equal
   to one built by ``SpfSolver(backend="host")``.
@@ -125,15 +129,20 @@ def _compile_summary() -> dict:
 # -- shared fixtures ---------------------------------------------------------
 
 
-def _fabric(nodes: int, **topo_kwargs):
+def _link_state(topo):
     from openr_tpu.graph.linkstate import LinkState
-    from openr_tpu.models import topologies
 
-    topo = topologies.fat_tree_nodes(nodes, **topo_kwargs)
     ls = LinkState(area=topo.area)
     for name in sorted(topo.adj_dbs):
         ls.update_adjacency_database(topo.adj_dbs[name])
-    return topo, ls
+    return ls
+
+
+def _fabric(nodes: int, **topo_kwargs):
+    from openr_tpu.models import topologies
+
+    topo = topologies.fat_tree_nodes(nodes, **topo_kwargs)
+    return topo, _link_state(topo)
 
 
 def _bump_metric(ls, node: str, step: int) -> str:
@@ -209,16 +218,24 @@ def _shard_holders(array) -> list:
     return sorted({s.device.id for s in array.addressable_shards})
 
 
-def leg_ksp2(nodes: int, events: int = 4, shard_devices=None) -> dict:
-    """All-KSP2 fabric through ``SpfSolver``: device == host. With
-    ``shard_devices`` (the engine mesh's device ids) the engine's
-    resident all-pairs matrix must hold a shard on each of them."""
-    from openr_tpu.decision.prefix_state import PrefixState
-    from openr_tpu.decision.spf_solver import SpfSolver
+def _ksp2_forwarding() -> dict:
     from openr_tpu.types.lsdb import (
         PrefixForwardingAlgorithm,
         PrefixForwardingType,
     )
+
+    return dict(
+        forwarding_algorithm=PrefixForwardingAlgorithm.KSP2_ED_ECMP,
+        forwarding_type=PrefixForwardingType.SR_MPLS,
+    )
+
+
+def _ksp2_parity(what: str, topo, ls, root: str, event, events: int):
+    """Every prefix KSP2 through ``SpfSolver`` from ``root``: the load,
+    then ``events`` times ``event(step)`` on ``ls``, device == host to
+    the byte each time. Returns (the device solver, the leg's result)."""
+    from openr_tpu.decision.prefix_state import PrefixState
+    from openr_tpu.decision.spf_solver import SpfSolver
     from openr_tpu.utils import wire
 
     watched = (
@@ -227,39 +244,45 @@ def leg_ksp2(nodes: int, events: int = 4, shard_devices=None) -> dict:
         "decision.ksp2_warm_dispatches",
     )
     before = _counter_snapshot(watched)
-    topo, ls = _fabric(
-        nodes,
-        forwarding_algorithm=PrefixForwardingAlgorithm.KSP2_ED_ECMP,
-        forwarding_type=PrefixForwardingType.SR_MPLS,
-    )
     ps = PrefixState()
     for pdb in topo.prefix_dbs.values():
         ps.update_prefix_database(pdb)
     area_ls = {topo.area: ls}
-    names = sorted(topo.adj_dbs)
-    rsw = next(k for k in names if k.startswith("rsw"))
-    fsw = next(k for k in names if k.startswith("fsw"))
-    device = SpfSolver(rsw, backend="device")
-    host = SpfSolver(rsw, backend="host")
+    device = SpfSolver(root, backend="device")
+    host = SpfSolver(root, backend="host")
     routes = 0
     for step in range(events + 1):
         if step:
-            _bump_metric(ls, fsw, step)
-        got = device.build_route_db(rsw, area_ls, ps).to_route_db(rsw)
-        want = host.build_route_db(rsw, area_ls, ps).to_route_db(rsw)
+            event(step)
+        got = device.build_route_db(root, area_ls, ps).to_route_db(root)
+        want = host.build_route_db(root, area_ls, ps).to_route_db(root)
         _require(
             wire.dumps(got) == wire.dumps(want),
-            f"ksp2 {nodes}: device RouteDatabase != host at event {step}",
+            f"{what}: device RouteDatabase != host at event {step}",
         )
         routes = len(got.unicast_routes)
-    _require(routes > 0, f"ksp2 {nodes}: empty RouteDatabase")
-    out = {
+    _require(routes > 0, f"{what}: empty RouteDatabase")
+    return device, {
         "nodes": ls.num_nodes,
         "events": events,
         "unicast_routes": routes,
         "parity": True,
         "counts": _counter_delta(before, watched),
     }
+
+
+def leg_ksp2(nodes: int, events: int = 4, shard_devices=None) -> dict:
+    """All-KSP2 fabric through ``SpfSolver``: device == host. With
+    ``shard_devices`` (the engine mesh's device ids) the engine's
+    resident all-pairs matrix must hold a shard on each of them."""
+    topo, ls = _fabric(nodes, **_ksp2_forwarding())
+    names = sorted(topo.adj_dbs)
+    rsw = next(k for k in names if k.startswith("rsw"))
+    fsw = next(k for k in names if k.startswith("fsw"))
+    device, out = _ksp2_parity(
+        f"ksp2 {nodes}", topo, ls, rsw,
+        lambda step: _bump_metric(ls, fsw, step), events,
+    )
     if shard_devices is not None:
         engine = device._ksp2_engines.get(ls)
         _require(engine is not None, f"ksp2 {nodes}: no resident engine")
@@ -269,6 +292,44 @@ def leg_ksp2(nodes: int, events: int = 4, shard_devices=None) -> dict:
             f"ksp2 {nodes}: all-pairs matrix lives on devices "
             f"{out['shard_devices']}, mesh is {sorted(shard_devices)}",
         )
+    return out
+
+
+def leg_ksp2_grid(side: int = 31, events: int = 4) -> dict:
+    """All-KSP2 ``side`` x ``side`` grid (31: upstream's own KSP2 graph,
+    ``BM_DecisionGrid`` N=1000) through ``SpfSolver`` from the corner,
+    60 hops from the far one: device == host after the load and after
+    each of ``events`` nodes re-costing every one of their links, and
+    the engine that the load built is the one that served them all."""
+    from openr_tpu.models import topologies
+
+    topo = topologies.grid(side, **_ksp2_forwarding())
+    ls = _link_state(topo)
+    root = "node-0"
+
+    def recost(step: int) -> None:
+        # nodes spread over the grid, never the root
+        node = f"node-{1 + (step * 389) % (side * side - 1)}"
+        db = ls.get_adjacency_databases()[node]
+        ls.update_adjacency_database(replace(db, adjacencies=tuple(
+            replace(a, metric=1 + (a.metric % 10)) for a in db.adjacencies
+        )))
+
+    what = f"ksp2 grid {side}x{side}"
+    device, out = _ksp2_parity(what, topo, ls, root, recost, events)
+    engine = device._ksp2_engines.get(ls)
+    _require(
+        engine is not None and engine.valid and engine.src_name == root,
+        f"{what}: no resident engine for {root}",
+    )
+    counts = out["counts"]
+    _require(
+        counts["decision.ksp2_cold_builds"] == 1
+        and counts["decision.ksp2_incremental_syncs"] == events,
+        f"{what}: not one cold build and {events} incremental syncs: "
+        f"{counts}",
+    )
+    out["hops_from_root"] = ls.get_max_hops_to_node(root)
     return out
 
 
@@ -609,6 +670,7 @@ def main() -> int:
         ("pipeline_fabric_1008", lambda: leg_pipeline(1008)),
         ("pipeline_fabric_10k", lambda: leg_pipeline(10000)),
         ("ksp2_fabric_1008", lambda: leg_ksp2(1008)),
+        ("ksp2_grid_961", leg_ksp2_grid),
         ("serve", leg_serve),
         ("kernels", lambda: leg_kernels(interpret=False)),
     ]

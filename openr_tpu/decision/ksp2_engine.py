@@ -247,7 +247,7 @@ class _TraceArrays:
 
     __slots__ = (
         "off", "link", "uid", "w", "links", "lid_of", "blocked",
-        "n_pad", "index", "_rows",
+        "n_pad", "index", "_rows", "_excl_ids",
     )
 
     def __init__(self, graph, cands_of, transit_blocked):
@@ -259,6 +259,8 @@ class _TraceArrays:
         # that flapped down and back up is a fresh-but-EQUAL object,
         # and an identity key would silently drop its exclusion
         self.lid_of: Dict[Link, int] = {}
+        # id(exclusion set) -> (the set, its links' ids)
+        self._excl_ids: Dict[int, Tuple[Set[Link], np.ndarray]] = {}
         # per node: (link ids, origin ids, weights), in canonical order
         self._rows: List[Tuple[np.ndarray, np.ndarray, np.ndarray]] = [
             self._row(cands_of(v)) for v in graph.node_names
@@ -334,15 +336,26 @@ class _TraceArrays:
     def _excl_arrays(self, excls):
         """Per-dst exclusion ranges; a link absent from the current
         candidate table is down, so its exclusion is vacuous."""
-        ids: List[int] = []
+        held, lid_of = self._excl_ids, self.lid_of
+        ids: List[np.ndarray] = []
         off = np.zeros(len(excls) + 1, np.int32)
         for i, excl in enumerate(excls):
-            for lnk in excl:
-                lid = self.lid_of.get(lnk)
-                if lid is not None:
-                    ids.append(lid)
-            off[i + 1] = len(ids)
-        return off, np.asarray(ids, np.int32)
+            # a set is traced many times over (every re-solve of its
+            # row) and link ids only ever get added: looked up once a
+            # set object, which the entry keeps alive
+            got = held.get(id(excl))
+            if got is None or got[0] is not excl:
+                got = held[id(excl)] = (excl, np.asarray(
+                    [lid for lid in map(lid_of.get, excl) if lid is not None],
+                    np.int32,
+                ))
+            ids.append(got[1])
+            off[i + 1] = off[i] + len(got[1])
+        if len(held) > 4 * len(self._rows):
+            held.clear()
+        return off, (
+            np.concatenate(ids) if ids else np.zeros(0, np.int32)
+        )
 
     def trace(self, src_id, dst_ids, rows, shared_row, excls,
               reach=None):
@@ -409,33 +422,12 @@ def _one_moved(was: np.ndarray, now: np.ndarray):
     return place, step
 
 
-def _hop_eccentricity(graph, sid: int) -> int:
-    """Links on the longest fewest-link path from node ``sid``: what
-    ``LinkState.get_max_hops_to_node`` reads off a unit-metric Dijkstra
-    (up links only, no transit through an overloaded node but the
-    root), here as a breadth-first sweep over the in-edge bands the
-    engine already holds on the host — a handful of numpy gathers
-    where the Dijkstra is tens of milliseconds of Python per link
-    flap at a thousand nodes."""
-    reached = np.zeros(graph.n_pad, dtype=bool)
-    reached[sid] = True
-    frontier = reached.copy()
-    forwards = ~np.asarray(graph.overloaded, dtype=bool)
-    forwards[sid] = True
-    hops = 0
-    while True:
-        carry = frontier & forwards
-        nxt = np.zeros(graph.n_pad, dtype=bool)
-        for band, s_b, w_b in zip(graph.bands, graph.src, graph.w):
-            nxt[band.start : band.start + band.rows] = (
-                carry[s_b] & (w_b < INF)
-            ).any(axis=1)
-        nxt &= ~reached
-        if not nxt.any():
-            return hops
-        hops += 1
-        reached |= nxt
-        frontier = nxt
+def _longest(traced: List[List[List[Link]]]) -> int:
+    """Links on the longest path of a batch of traces (``hops`` on
+    decision.ksp2_trace)."""
+    return max(
+        (len(path) for paths in traced for path in paths), default=0
+    )
 
 
 def _masked_buckets(chunk: int) -> Tuple[int, ...]:
@@ -475,7 +467,9 @@ def _pad_ids(ids: List[int], bucket_min: int = 8) -> np.ndarray:
 @thread_confined(
     "owner",
     "_carried",
+    "_link_slots",
     "_masked_warm",
+    "_slots_seen",
     "_mesh",
     "_mesh_knob",
     "_tarrays",
@@ -487,9 +481,9 @@ def _pad_ids(ids: List[int], bucket_min: int = 8) -> np.ndarray:
     "dm",
     "dst_pos",
     "dsts",
-    "ecc_hops",
     "eff_w",
     "excl",
+    "excl_slots",
     "excl_users",
     "first_paths",
     "host_dsts",
@@ -577,7 +571,7 @@ class Ksp2Engine:
                     return set()
                 self.syncs_worked += 1
                 with get_tracer().span(
-                    "decision.ksp2_sync", changed_pairs=0
+                    "decision.ksp2_sync", changed_pairs=0, refreshed_rows=0
                 ) as span:
                     affected = None
                     if fits:
@@ -710,14 +704,16 @@ class Ksp2Engine:
         # fixed point, the view and the endpoint rows
         with get_tracer().span(
             "ops.ksp2_all_pairs", rows=graph.n_pad, batches=1,
-        ):
+        ) as ap_span:
             if self._mesh is not None:
                 # nothing is donated on the mesh (residents keep their
                 # NamedSharding placement): the rebind is a plain replace
-                d_all_dev, packed = spf_sparse.sharded_ell_all_view_rows(
-                    state, srcs_dev, w_sv, ep_ids, self.d_prev_dev,
-                    self._mesh, inc=inc,
-                    inc_bucket=ENGINE_MAX_CHANGED_PAIRS,
+                d_all_dev, packed, passes = (
+                    spf_sparse.sharded_ell_all_view_rows(
+                        state, srcs_dev, w_sv, ep_ids, self.d_prev_dev,
+                        self._mesh, inc=inc,
+                        inc_bucket=ENGINE_MAX_CHANGED_PAIRS,
+                    )
                 )
                 self.d_prev_dev = d_all_dev
             else:
@@ -725,7 +721,7 @@ class Ksp2Engine:
                 # dispatch consumes the previous epoch's resident
                 # d_prev_dev (dead after this call, no retry path), which
                 # is rebound to the fresh output right below
-                d_all_dev, packed = spf_sparse.ell_all_view_rows(
+                d_all_dev, packed, passes = spf_sparse.ell_all_view_rows(
                     state, srcs_dev, w_sv, ep_ids, self.d_prev_dev, inc=inc,
                     inc_bucket=ENGINE_MAX_CHANGED_PAIRS, defer=True,
                 )
@@ -736,7 +732,9 @@ class Ksp2Engine:
                 # wrapper) only AFTER that, so a reap failure can never
                 # hand it one either
                 self.d_prev_dev = d_all_dev
-                packed = _da.reap_read(packed, kicked=True)
+                packed, passes = self._reap_all_pairs(packed, passes)
+            if ap_span is not None:
+                ap_span.attrs["passes"] = passes
         b = len(view_srcs)
         p = len(ep_ids)
         view_packed = packed[: 2 * b]
@@ -780,9 +778,11 @@ class Ksp2Engine:
             affected = moved | route_extra | (self.host_dsts & dst_set)
         if not rows_proven:
             named = aff1 | aff2
-            self._refresh_rows(
+            refreshed = self._refresh_rows(
                 state, [d for d in self.dsts if d not in named]
             )
+            if span is not None:
+                span.attrs["refreshed_rows"] = refreshed
         self._prime_all(ls)
 
         # commit snapshots
@@ -802,11 +802,6 @@ class Ksp2Engine:
         for x in label_flips:
             db = ls.get_adjacency_databases().get(x)
             self.node_label[x] = db.node_label if db else 0
-        if any(
-            w_old >= INF or w_new >= INF
-            for (w_old, w_new, _so, _sn) in changed.values()
-        ):
-            self.ecc_hops = _hop_eccentricity(graph, self.sid)
         self.d_base = d_new_src.astype(np.int32)
         self.version = ls.topology_version
         self.aversion = ls.attributes_version
@@ -867,13 +862,15 @@ class Ksp2Engine:
                 placeholder = jnp.zeros((n, n), dtype=jnp.int32)
         with get_tracer().span(
             "ops.ksp2_all_pairs", rows=n, batches=1,
-        ):
+        ) as ap_span:
             if self._mesh is not None:
-                d_all_dev, packed = spf_sparse.sharded_ell_all_view_rows(
-                    state, srcs_dev, w_sv,
-                    _pad_ids([self.sid], ENGINE_MAX_ENDPOINTS),
-                    placeholder, self._mesh,
-                    inc_bucket=ENGINE_MAX_CHANGED_PAIRS,
+                d_all_dev, packed, passes = (
+                    spf_sparse.sharded_ell_all_view_rows(
+                        state, srcs_dev, w_sv,
+                        _pad_ids([self.sid], ENGINE_MAX_ENDPOINTS),
+                        placeholder, self._mesh,
+                        inc_bucket=ENGINE_MAX_CHANGED_PAIRS,
+                    )
                 )
             else:
                 # the dispatch DONATES the placeholder (which may be the
@@ -881,13 +878,15 @@ class Ksp2Engine:
                 # failed dispatch can't leave a dead buffer behind for the
                 # next cold build to reuse
                 self.d_prev_dev = None
-                d_all_dev, packed = spf_sparse.ell_all_view_rows(
+                d_all_dev, packed, passes = spf_sparse.ell_all_view_rows(
                     state, srcs_dev, w_sv,
                     _pad_ids([self.sid], ENGINE_MAX_ENDPOINTS),
                     placeholder, inc_bucket=ENGINE_MAX_CHANGED_PAIRS,
                     defer=True,
                 )
-                packed = _da.reap_read(packed, kicked=True)
+                packed, passes = self._reap_all_pairs(packed, passes)
+            if ap_span is not None:
+                ap_span.attrs["passes"] = passes
         b = len(view_srcs)
         self._preload_view(ls, graph, view_srcs, packed[: 2 * b])
         self.d_base = packed[0].astype(np.int32)
@@ -901,6 +900,11 @@ class Ksp2Engine:
         self.second_paths: Dict[str, List[List[Link]]] = {}
         self.excl: Dict[str, Set[Link]] = {}
         self.excl_users: Dict[Link, Set[int]] = {}
+        # destination -> (the slots its exclusion set holds, whether
+        # they could carry it), and the slot index they were read off
+        self.excl_slots: Dict[str, Tuple[np.ndarray, bool]] = {}
+        self._slots_seen = None
+        self._link_slots: Dict[Link, Tuple[int, ...]] = {}
         self.node_users: Dict[str, Set[str]] = {}
         # per destination and node, how far down the node's candidate
         # list the second-path traces looked (-1: never there);
@@ -954,7 +958,6 @@ class Ksp2Engine:
             name: db.node_label
             for name, db in ls.get_adjacency_databases().items()
         }
-        self.ecc_hops = _hop_eccentricity(graph, self.sid)
         self.version = ls.topology_version
         self.aversion = ls.attributes_version
         self.valid = True
@@ -1096,14 +1099,14 @@ class Ksp2Engine:
         former invalidates the destination's MASKS (forcing a fresh
         masked solve) while the latter only needs the second paths
         re-derived. ``exact``: narrow the second set with
-        _second_paths_may_move where the change is one link's.
+        _second_paths_may_move.
         ``row_stands``: of the second set, the destinations whose
         masked row provably stands as long as their first paths, and
         so their masks, do; _recompute re-traces them off the row it
         has. ``rows_proven``: whether every row the sync does
-        not re-solve is proven to stand; where not (several links at
-        once, a drain flip), the sync re-solves them all to keep the
-        rows exact."""
+        not re-solve is proven to stand; where not (a drain flip,
+        parallel links, no native tracer), the sync re-solves them all
+        to keep the rows exact."""
         index = graph.node_index
         dst_ids = np.asarray(
             [index[d] for d in self.dsts], dtype=np.int64
@@ -1225,15 +1228,16 @@ class Ksp2Engine:
         self, ls, graph, changed, dm, ov_new, eff
     ) -> Optional[Tuple[np.ndarray, np.ndarray, List[Tuple]]]:
         """Two [D] bools: the destinations whose second-path trace can
-        come out differently after the change of ONE link, first paths
+        come out differently after the window's changes, first paths
         and masks unchanged; and, of those, the ones whose masked row
         may have moved (the rest need a trace, not a solve). Third,
-        the rows that did move, in one column only and where no walk
-        read it, as ``(column, row positions, new values)``: the
-        caller writes them into ``dm`` and they are in neither vector
-        (_one_column_moves). None where the question is not this
-        simple (several links in one window, parallel links, no native
-        tracer): the caller keeps its DAG-membership bound.
+        where the window is ONE link's, the rows that did move, in one
+        column only and where no walk read it, as ``(column, row
+        positions, new values)``: the caller writes them into ``dm``
+        and they are in neither vector (_one_column_moves). None where
+        the question is not this simple (parallel links, no native
+        tracer): the caller keeps its DAG-membership bound and
+        re-solves every row it does not name.
 
         The trace of destination i is a function of the prefixes it
         read of the predecessor lists of the nodes it consults
@@ -1251,12 +1255,37 @@ class Ksp2Engine:
         dm[u] + w undercuts dm[v]. The DAG-membership bound asks
         whether the edge lies on SOME shortest second path, which on a
         fat-tree is true of every spine link for some 300
-        destinations; this asks whether the walk ever looked."""
+        destinations; this asks whether the walk ever looked.
+
+        Several edges at once (a node that re-costs all its links, a
+        window of several events) are answered edge by edge, from the
+        rows and the walks as they were BEFORE the window; the two
+        vectors are the unions. That is sound because neither argument
+        reads anything another changed edge could have moved. (b):
+        the old row d stays feasible under the new weights where no
+        lowered or new edge undercuts it (d[u] + w_new >= d[v], each
+        read off the old row), and stays attained where every raised
+        or removed edge that was tight leaves its head another tight,
+        unexcluded predecessor over an edge the window did NOT change:
+        by induction on d (metrics are >= 1) the nearest such head
+        keeps its distance through that predecessor, which is nearer
+        still, and so on outwards; a feasible row that every node
+        attains over a tight edge is the row. Hence a predecessor
+        over another changed edge is not counted as support. (a): with
+        the row where it was, a node's predecessor list differs from
+        the old one only at the places of changed edges; a walk that
+        read none of those places reads what it read before, from its
+        first step to its last (the first place at which it could
+        diverge would be one of them), and the last search's reach is
+        closed under the new edges where none leads from inside it to
+        outside. Only the one-column mending is kept to a single
+        link: its two proofs read each other's column."""
         pairs = list(changed.items())
-        if not 1 <= len(pairs) <= 2 or (
-            len(pairs) == 2 and pairs[0][0] != pairs[1][0][::-1]
-        ):
+        if not pairs:
             return None
+        one_link = len(pairs) == 1 or (
+            len(pairs) == 2 and pairs[0][0] == pairs[1][0][::-1]
+        )
         index = graph.node_index
         # the candidate rows as the last sync's traces saw them, before
         # this event's patch lands on them
@@ -1274,6 +1303,11 @@ class Ksp2Engine:
         may = np.zeros(len(self.dsts), dtype=bool)
         row_moves = np.zeros(len(self.dsts), dtype=bool)
         mended: List[Tuple] = []
+        # head -> the tails of the window's changed edges into it: no
+        # support for a row that another of them was tight in
+        changed_into: Dict[int, Set[int]] = {}
+        for (u, v), _ in pairs:
+            changed_into.setdefault(index[v], set()).add(index[u])
         for (u, v), (w_old, w_new, _so, _sn) in pairs:
             uid, vid = index[u], index[v]
             lids, uids, ws = arrays.rows_of(vid)
@@ -1335,7 +1369,7 @@ class Ksp2Engine:
                 for lid, cu, cw in zip(
                     lids.tolist(), uids.tolist(), ws.tolist()
                 ):
-                    if cu < 0 or cu == uid or (
+                    if cu < 0 or cu in changed_into[vid] or (
                         cu != self.sid and arrays.blocked[cu]
                     ):
                         continue
@@ -1346,7 +1380,7 @@ class Ksp2Engine:
                         tight[list(users)] = False
                     other |= tight
                 moves = (dm_u < inf) & (dm_u + wo == dm_v) & ~other
-            if moves.any():
+            if one_link and moves.any():
                 at, values = self._one_column_moves(
                     ls, arrays, dm, u, v, uid, vid, wo, wn,
                     np.flatnonzero(moves & ~consulted),
@@ -1504,6 +1538,7 @@ class Ksp2Engine:
         pos = self.dst_pos[dst]
         for link in self.excl.get(dst, ()):
             self.excl_users[link].discard(pos)
+        self.excl_slots.pop(dst, None)
         self.first_paths[dst] = paths
         excl = self.excl[dst] = {l for p in paths for l in p}
         for link in excl:
@@ -1603,9 +1638,7 @@ class Ksp2Engine:
                 dst not in self.host_dsts
                 and now == (first, second)
                 and touched.isdisjoint(self.excl[dst])
-                and not any(
-                    link in touched for path in now[1] or () for link in path
-                )
+                and all(touched.isdisjoint(path) for path in now[1] or ())
             ):
                 # the fresh lists are equal to the cached ones; keep
                 # the cached objects so every holder sees one list
@@ -1627,14 +1660,38 @@ class Ksp2Engine:
                     self.node_users.setdefault(x, set()).add(dst)
         return moved
 
+    @staticmethod
+    def _reap_all_pairs(packed, passes):
+        """The fused dispatch's packed rows and its pass count on the
+        host: two outputs of one program, kicked together, brought over
+        by one reap; the count is booked here, where it is first
+        known."""
+        from openr_tpu.ops import spf_sparse
+
+        packed, passes = _da.reap_read((packed, passes), kicked=True)
+        return packed, spf_sparse.note_ksp2_passes("all_pairs", passes)
+
+    @staticmethod
+    def _reap_masked(out_dev, out_host):
+        """One masked batch's ``(rows, passes)`` on the host: reaped
+        off the device with the count booked (one chip), or as the
+        mesh's solve already returned them."""
+        from openr_tpu.ops import spf_sparse
+
+        if out_host is not None:
+            return out_host
+        rows, passes = _da.reap_read(out_dev, kicked=True)
+        return rows, spf_sparse.note_ksp2_passes("masked", passes)
+
     def _masked_dispatch(self, state, batch: List[str]):
         """Masks of one chunk's destinations, padded to one of the
         chunk's three buckets (all compiled by the cold build, so no
         affected-set size compiles later), and their masked solve
-        dispatched. Returns ``(ok, drows_dev, drows)``: which masks
-        the slots could carry, and the rows either on the device with
-        their readback kicked (one chip: the caller reaps them) or on
-        the host (the mesh's sharded solve returns them so)."""
+        dispatched. Returns ``(ok, out_dev, out_host)``: which masks
+        the slots could carry, and the program's ``(rows, passes)``
+        either on the device with their readback kicked (one chip) or
+        on the host (the mesh's sharded solve returns them so);
+        _reap_masked takes either."""
         from openr_tpu.decision import spf_solver as _ss
         from openr_tpu.ops import spf_sparse
 
@@ -1647,44 +1704,89 @@ class Ksp2Engine:
             # sharded batches divide destinations over the mesh
             ndev = self._mesh.devices.size
             bucket = -(-max(bucket, ndev) // ndev) * ndev
-        masks, ok = spf_sparse.build_edge_masks(
-            graph,
-            [self.excl[d] for d in batch] + [set()] * (bucket - len(batch)),
-        )
+        masks, ok = self._batch_masks(graph, batch, bucket)
         _counters()["decision.ksp2_device_batches"] += 1
         if self._mesh is not None:
             return ok, None, spf_sparse.sharded_ell_masked_distances_resident(
                 state, self.sid, masks, self._mesh
             )
-        # committed chain: the masked rows are kicked
-        # copy_to_host_async and the host copy is reaped once
+        # committed chain: the masked rows and their pass count are
+        # kicked copy_to_host_async and the host copies reaped once
         return ok, spf_sparse.ell_masked_distances_resident(
             state, self.sid, masks, defer=True
         ), None
 
-    def _refresh_rows(self, state, dsts: List[str]) -> None:
+    def _batch_masks(self, graph, batch: List[str], bucket: int):
+        """``build_edge_masks`` of the batch's exclusion sets padded to
+        ``bucket``, from the slots each destination's set was last
+        found to hold (``excl_slots``). A row is re-solved far more
+        often than its first paths move (a window in which a raised
+        edge was some node's only support re-solves hundreds whose
+        masks stand), so the walk over its hundred excluded links is
+        made when the set changes (_set_first_paths drops the entry) or
+        when ell_patch re-packed a row one of its links ends in, not a
+        solve."""
+        from openr_tpu.ops import spf_sparse
+
+        held = self.excl_slots
+        if graph.slot_of is not self._slots_seen:
+            # rows re-packed since the last batch: a link that came or
+            # went moves its row's other links to other slots
+            was = self._slots_seen or {}
+            shifted = {
+                key
+                for nid, now in graph.slot_of.items()
+                for old in [was.get(nid, now)]
+                if old is not now and old != now
+                for key in old.keys() | now.keys()
+                if old.get(key) != now.get(key)
+            }
+            if shifted:
+                for link, users in self.excl_users.items():
+                    if spf_sparse.link_key(link) in shifted:
+                        for pos in users:
+                            held.pop(self.dsts[pos], None)
+            self._slots_seen = graph.slot_of
+            self._link_slots = {}
+        for dst in batch:
+            if dst not in held:
+                # first paths share their links near the root: each
+                # link's two slots are looked up once an index
+                held[dst] = spf_sparse.excluded_slots(
+                    graph, self.excl[dst], memo=self._link_slots
+                )
+        ok = np.ones(bucket, dtype=bool)
+        ok[: len(batch)] = [held[dst][1] for dst in batch]
+        return spf_sparse.masks_from_slots(
+            graph, [held[dst][0] for dst in batch], bucket
+        ), ok
+
+    def _refresh_rows(self, state, dsts: List[str]) -> int:
         """Masked rows of ``dsts`` solved again and kept, nothing
         traced: what keeps ``dm`` exact through a window whose changes
-        _second_paths_may_move does not answer for."""
+        _second_paths_may_move does not answer for. Returns how many
+        rows that was (``refreshed_rows`` on decision.ksp2_sync)."""
         from openr_tpu.decision import spf_solver as _ss
 
         dsts = [d for d in dsts if d not in self.host_dsts]
         if not dsts:
-            return
+            return 0
         chunk = _ss._ksp2_chunk(state.graph)
         with get_tracer().span(
             "ops.ksp2_masked_solve", rows=len(dsts),
-            batches=-(-len(dsts) // chunk), refresh=True,
-        ):
+            batches=-(-len(dsts) // chunk), refresh=True, passes=0,
+        ) as span:
             for start in range(0, len(dsts), chunk):
                 batch = dsts[start : start + chunk]
-                ok, drows_dev, drows = self._masked_dispatch(state, batch)
-                if drows is None:
-                    drows = _da.reap_read(drows_dev, kicked=True)
+                ok, *out = self._masked_dispatch(state, batch)
+                drows, passes = self._reap_masked(*out)
+                if span is not None:
+                    span.attrs["passes"] = max(span.attrs["passes"], passes)
                 keep = np.flatnonzero(ok[: len(batch)])
                 self.dm[[self.dst_pos[batch[i]] for i in keep]] = (
                     drows[keep]
                 )
+        return len(dsts)
 
     def _solve_masked_batches(
         self, ls, state, dsts, cands_of, transit_blocked,
@@ -1699,12 +1801,13 @@ class Ksp2Engine:
         graph = state.graph
         chunk = _ss._ksp2_chunk(graph)
 
-        def _settle(batch, ok, drows_dev, drows):
+        def _settle(span, batch, ok, out_dev, out_host):
             """Stage 2: reap the masked rows, settle dm + fallback
             accounting, trace second paths — host work the NEXT
             chunk's already-submitted solve overlaps."""
-            if drows is None:
-                drows = _da.reap_read(drows_dev, kicked=True)
+            drows, passes = self._reap_masked(out_dev, out_host)
+            if span is not None:
+                span.attrs["passes"] = max(span.attrs["passes"], passes)
             traceable: List[int] = []
             for i, dst in enumerate(batch):
                 self.dm[self.dst_pos[dst]] = drows[i]
@@ -1747,10 +1850,11 @@ class Ksp2Engine:
         # host rows, so there is nothing in flight to overlap.
         # the span's self time is mask build, dispatch and readback:
         # the second-path traces of _settle nest their own span in it
+        # ``passes``: the most any of the span's batches ran
         with get_tracer().span(
             "ops.ksp2_masked_solve", rows=len(dsts),
-            batches=-(-len(dsts) // chunk),
-        ):
+            batches=-(-len(dsts) // chunk), passes=0,
+        ) as span:
             inflight = None
             for start in range(0, len(dsts), chunk):
                 # stage 1: mask build + (async) masked solve
@@ -1760,10 +1864,10 @@ class Ksp2Engine:
                     if staged[2] is not None:
                         _da.note_pipelined_dispatch(2)
                         _da.note_overlapped_reap()
-                    _settle(*inflight)
+                    _settle(span, *inflight)
                 inflight = staged
             if inflight is not None:
-                _settle(*inflight)
+                _settle(span, *inflight)
         if not index_users:
             return  # _recompute indexes the destinations that moved
         for dst in dsts:
@@ -1859,6 +1963,8 @@ class Ksp2Engine:
                     rows, shared_row, excls, reach,
                 )
                 if got is not None:
+                    if span is not None:
+                        span.attrs["hops"] = _longest(got)
                     return got
             if span is not None:
                 span.attrs["python"] = True
@@ -1870,7 +1976,7 @@ class Ksp2Engine:
                 {} if shared_row else None
             )
             row_list = rows.tolist() if shared_row else None
-            return [
+            got = [
                 trace_paths_from_row(
                     self.src_name, dst, graph.node_index,
                     row_list if shared_row else rows[i].tolist(),
@@ -1881,6 +1987,9 @@ class Ksp2Engine:
                 )
                 for i, dst in enumerate(dsts)
             ]
+            if span is not None:
+                span.attrs["hops"] = _longest(got)
+            return got
 
     # -- priming / view preload -------------------------------------------
 

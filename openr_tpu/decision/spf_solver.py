@@ -218,18 +218,6 @@ FAULT_SPF_SOLVE = register_fault_site("decision.spf_solve")
 # is cheaper than a device dispatch; batches are fixed-size so the
 # masked kernel compiles once per (topology bands, chunk) shape.
 KSP2_DEVICE_MIN_DSTS = 32
-# the masked kernel iterates one relaxation per hop: on low-diameter
-# fabrics (fat-tree: 4-6 hops) one dispatch replaces N host Dijkstras
-# (the cell fabric-1000-ksp2.adj-churn reads it as ksp2_masked_solve_ms
-# and ksp2_masked_roofline), but on a 31x31 grid (60 hops) the
-# iteration count is expected to hand the win back to host Dijkstra,
-# which no cell has measured — gate on the root's hop eccentricity
-# (the engine reads it off the bands it holds). The SP_ECMP view
-# solve pays the same per-hop iteration only when it starts cold: its
-# warm solves (spf_sparse._cone_seed) pay the depth of what changed,
-# which the cell grid-10000.drain-churn (198 hops) reads as
-# relax_passes_per_solve
-KSP2_DEVICE_MAX_HOPS = 16
 # mask-memory budget per dispatch (bool slots); the chunk adapts so
 # small graphs take ONE dispatch and one readback while 10k+-node
 # graphs stay within device memory
@@ -2275,11 +2263,6 @@ class SpfSolver:
                 # roots (ctrl queries) take the host path
                 return None
             if engine is None:
-                if (
-                    ls.get_max_hops_to_node(my_node_name)
-                    > KSP2_DEVICE_MAX_HOPS
-                ):
-                    return None  # high diameter: host Dijkstra wins
                 engine = ksp2_engine.Ksp2Engine(my_node_name)
                 self._ksp2_engines[ls] = engine
             staged, worked = engine.staged, engine.syncs_worked
@@ -2295,22 +2278,12 @@ class SpfSolver:
                     else "ops.spec_cancels"
                 )
             affected = engine.take_affected()
-            if engine.valid and engine.ecc_hops > KSP2_DEVICE_MAX_HOPS:
-                # diameter grew past the device win: paths for THIS
-                # build are already primed; drop the engine so later
-                # builds do the cheap host hop check (memoized per
-                # topology version) instead of cold-rebuilding each time
-                del self._ksp2_engines[ls]
-                return affected
             if affected is None and engine.valid:
                 # cold build: no reuse this time, but the per-prefix
                 # cache built now is valid for the NEXT event — signal
                 # "engine ran" with the all-affected set
                 return set(dsts)
             return affected
-
-        if ls.get_max_hops_to_node(my_node_name) > KSP2_DEVICE_MAX_HOPS:
-            return None  # high-diameter graph: host Dijkstra wins
 
         from openr_tpu.ops import spf_sparse
 
@@ -2344,7 +2317,7 @@ class SpfSolver:
             masks, ok = spf_sparse.build_edge_masks(
                 graph, batch_excl + [set()] * pad
             )
-            drows = spf_sparse.ell_masked_distances_resident(
+            drows, _passes = spf_sparse.ell_masked_distances_resident(
                 state, sid, masks
             )
             SPF_COUNTERS["decision.ksp2_device_batches"] += 1

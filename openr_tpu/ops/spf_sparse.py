@@ -1127,7 +1127,10 @@ def _ell_fixed_point(srcs_t, ws_t, overloaded, src_ids, bands, n,
     originate (reference: LinkState.cpp:831-838). ``warm`` is an
     optional (d_prev, inc_tail, inc_head, inc_w) tuple: seed from the
     previous distances via _warm_seed (bit-identical fixed point,
-    fewer iterations under churn)."""
+    fewer iterations under churn). Returns ``(d, passes)``: the
+    distances and the loop's own counter, the relax passes it ran (the
+    pass that builds the init is not one of them), as _ell_reconverge
+    carries its own."""
     s = src_ids.shape[0]
     unit = jnp.full((s, n), INF, dtype=jnp.int32)
     unit = unit.at[jnp.arange(s), src_ids].set(0)
@@ -1146,8 +1149,8 @@ def _ell_fixed_point(srcs_t, ws_t, overloaded, src_ids, bands, n,
         local = jnp.any(nxt < d).astype(jnp.int32)
         return nxt, local if vote is None else vote(local), it + 1
 
-    d, _, _ = jax.lax.while_loop(cond, body, (d0, jnp.int32(1), 0))
-    return d
+    d, _, it = jax.lax.while_loop(cond, body, (d0, jnp.int32(1), 0))
+    return d, jnp.asarray(it, dtype=jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("bands", "n"))
@@ -1158,7 +1161,7 @@ def _ell_from_sources(srcs_t, ws_t, overloaded, src_ids, bands, n):
     formulation (_sparse_from_sources) spends its time in
     ``jax.ops.segment_min``, which lowers to serialized scatters on
     TPU; this one vectorizes."""
-    return _ell_fixed_point(srcs_t, ws_t, overloaded, src_ids, bands, n)
+    return _ell_fixed_point(srcs_t, ws_t, overloaded, src_ids, bands, n)[0]
 
 
 def ell_distances_from_sources(graph: EllGraph, src_ids,
@@ -1253,7 +1256,11 @@ def _ell_masked_fixed_point(srcs_t, ws_t, masks_t, overloaded, src_id,
     _ell_view_batch). ``vote`` turns the local convergence bit into the
     global stop condition (identity when None; a psum for the sharded
     variant) — the SAME parameterization as _ell_fixed_point, and the
-    ONE home of this loop (three call sites share it)."""
+    ONE home of this loop (three call sites share it). Returns
+    ``(d, passes)`` as _ell_fixed_point does: every row starts cold,
+    so the passes are the source's hop eccentricity in the deepest of
+    the batch's masked graphs (the init pass reaches one hop and is not
+    counted; the pass that finds nothing left is)."""
     b = masks_t[0].shape[0]
     unit = jnp.full((b, n), INF, dtype=jnp.int32)
     unit = unit.at[:, src_id].set(0)
@@ -1271,70 +1278,113 @@ def _ell_masked_fixed_point(srcs_t, ws_t, masks_t, overloaded, src_id,
         local = jnp.any(nxt < d).astype(jnp.int32)
         return nxt, local if vote is None else vote(local), it + 1
 
-    d, _, _ = jax.lax.while_loop(cond, body, (d0, jnp.int32(1), 0))
-    return d
+    d, _, it = jax.lax.while_loop(cond, body, (d0, jnp.int32(1), 0))
+    return d, jnp.asarray(it, dtype=jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("bands", "n"))
 def _ell_masked_source_batch(srcs_t, ws_t, masks_t, overloaded, src_id,
                              bands, n):
+    """The masked batch as one program: ``(rows [B, N], passes)``, two
+    outputs ready together, so the pass count costs the host no
+    program and no sync of its own."""
     return _ell_masked_fixed_point(
         srcs_t, ws_t, masks_t, overloaded, src_id, bands, n
     )
 
 
-def build_edge_masks(graph: EllGraph, exclusion_sets, parallel_pairs=None):
-    """Per-band [B, rows, k] bool masks from per-batch-element link
-    sets. On a per-link-slot graph (compile_ell direction="in") every
-    link — parallel group members included — maps to its OWN slot via
-    ``graph.slot_of``, so ok_flags[b] is False only when an exclusion
-    references a node outside the graph (reference semantics:
-    LinkState.cpp:763 getKthPaths' linksToIgnore treats each Link as
-    first-class, LinkState.h:82).
+def excluded_slots(graph: EllGraph, links, parallel_pairs=None, memo=None):
+    """One batch element's excluded links as the slots they hold:
+    ``(flat, ok)``, ``flat`` the int64 offsets into the graph's slots
+    laid end to end, band after band, each band row-major (a band's
+    ``[rows, k]`` flattened). On a per-link-slot graph (compile_ell
+    direction="in") every link, parallel group members included, maps
+    to its OWN slot via ``graph.slot_of``, so ``ok`` is False only when
+    an exclusion references a node outside the graph (reference
+    semantics: LinkState.cpp:763 getKthPaths' linksToIgnore treats each
+    Link as first-class, LinkState.h:82). The offsets are good for as
+    long as the slots of the links' two end rows stay where they are
+    (ell_patch re-packs a row when one of its links comes or goes);
+    ``memo``, the caller's dict, keeps each link's offsets from one
+    element to the next for as long as the caller knows that holds.
 
     Collapsed graphs (no slot_of) keep the legacy behavior:
     ``parallel_pairs`` elements are unrepresentable and flag ok=False."""
-    b = len(exclusion_sets)
-    parallel_pairs = parallel_pairs or set()
-    masks = [
-        np.zeros((b, band.rows, band.k), dtype=bool)
-        for band in graph.bands
-    ]
-    ok = np.ones(b, dtype=bool)
     per_link = graph.slot_of is not None
-    for x, links in enumerate(exclusion_sets):
-        for link in links:
-            if not per_link and (
-                frozenset((link.n1, link.n2)) in parallel_pairs
-            ):
-                ok[x] = False
-                break
-            key = link_key(link) if per_link else None
-            for head in (link.n1, link.n2):
-                tail = link.other_node(head)
-                hid = graph.node_index.get(head)
-                tid = graph.node_index.get(tail)
-                if hid is None or tid is None:
-                    ok[x] = False
-                    break
-                if per_link:
-                    hit = graph.slot_of.get(hid, _EMPTY_SLOTS).get(key)
-                    if hit is None:
-                        # link not in the ELL (e.g. went down after
-                        # compile): nothing to mask
-                        continue
-                    bi, r, slot = hit
-                    masks[bi][x, r, slot] = True
+    offsets = _band_offsets(graph.bands)
+    flat: List[int] = []
+    for link in links:
+        if memo is not None and link in memo:
+            flat.extend(memo[link])
+            continue
+        if not per_link and parallel_pairs and (
+            frozenset((link.n1, link.n2)) in parallel_pairs
+        ):
+            return np.zeros(0, dtype=np.int64), False
+        key = link_key(link) if per_link else None
+        first = len(flat)
+        for head in (link.n1, link.n2):
+            tail = link.other_node(head)
+            hid = graph.node_index.get(head)
+            tid = graph.node_index.get(tail)
+            if hid is None or tid is None:
+                return np.zeros(0, dtype=np.int64), False
+            if per_link:
+                hit = graph.slot_of.get(hid, _EMPTY_SLOTS).get(key)
+                if hit is None:
+                    # link not in the ELL (e.g. went down after
+                    # compile): nothing to mask
                     continue
+                bi, r, slot = hit
+            else:
                 bi, band = _band_of(graph, hid)
                 r = hid - band.start
                 hits = np.flatnonzero(graph.src[bi][r] == tid)
                 if len(hits) == 0:
                     continue
-                masks[bi][x, r, hits[0]] = True
-            if not ok[x]:
-                break
-    return masks, ok
+                slot = int(hits[0])
+            flat.append(offsets[bi] + r * graph.bands[bi].k + slot)
+        if memo is not None:
+            memo[link] = tuple(flat[first:])
+    return np.asarray(flat, dtype=np.int64), True
+
+
+@functools.lru_cache(maxsize=64)
+def _band_offsets(bands: Tuple[EllBand, ...]) -> Tuple[int, ...]:
+    """Where each band's slots begin when the bands' ``[rows, k]`` are
+    laid end to end; the total last."""
+    offsets = [0]
+    for band in bands:
+        offsets.append(offsets[-1] + band.rows * band.k)
+    return tuple(offsets)
+
+
+def masks_from_slots(graph: EllGraph, slots, rows: int):
+    """Per-band ``[rows, band.rows, band.k]`` bool masks with element
+    x's ``slots[x]`` (excluded_slots' offsets) set; elements past
+    ``len(slots)`` exclude nothing (the padding of a batch)."""
+    offsets = _band_offsets(graph.bands)
+    flat = np.zeros((rows, offsets[-1]), dtype=bool)
+    if len(slots):
+        flat[
+            np.repeat(np.arange(len(slots)), [len(s) for s in slots]),
+            np.concatenate(slots),
+        ] = True
+    return [
+        flat[:, lo:hi].reshape(rows, band.rows, band.k)
+        for band, lo, hi in zip(graph.bands, offsets, offsets[1:])
+    ]
+
+
+def build_edge_masks(graph: EllGraph, exclusion_sets, parallel_pairs=None):
+    """Per-band [B, rows, k] bool masks from per-batch-element link
+    sets, and which elements the slots could carry (excluded_slots);
+    one that they could not excludes nothing."""
+    slots, ok = [], np.ones(len(exclusion_sets), dtype=bool)
+    for x, links in enumerate(exclusion_sets):
+        flat, ok[x] = excluded_slots(graph, links, parallel_pairs)
+        slots.append(flat)
+    return masks_from_slots(graph, slots, len(exclusion_sets)), ok
 
 
 def ell_masked_distances(graph: EllGraph, src_id: int, masks):
@@ -1342,7 +1392,7 @@ def ell_masked_distances(graph: EllGraph, src_id: int, masks):
     Rides the committed AOT executable cache — the host-graph twin of
     ``ell_masked_distances_resident`` (the serve plane's per-tenant
     KSP2 view dispatches here, so its warm waves must not retrace)."""
-    d = _aot_call(
+    d, _passes = _aot_call(
         "ksp2_masked_host", _ell_masked_source_batch,
         (
             tuple(jnp.asarray(s) for s in graph.src),
@@ -1362,12 +1412,15 @@ def ell_masked_distances_resident(
     """Masked solve over an EllState's device-RESIDENT bands — only the
     masks cross host->device per dispatch. Dispatches through the AOT
     executable cache (``ksp2_masked_resident``) so a warm churn event
-    costs a dict lookup, not a jit signature re-derivation. With
-    ``defer=True`` the [B, n_pad] product stays ON DEVICE with its
-    readback kicked on the async lane — the caller reaps it via
-    ``dispatch_accounting.reap_read(rows, kicked=True)`` inside its
-    event window (the KSP2 committed-dispatch chain)."""
-    d = _aot_call(
+    costs a dict lookup, not a jit signature re-derivation. Returns
+    ``(rows [B, n_pad], passes)``, the program's two outputs, on the
+    host and the count booked (note_ksp2_passes). With ``defer=True``
+    both stay ON DEVICE with their readback kicked on the async lane —
+    the caller reaps the pair via ONE
+    ``dispatch_accounting.reap_read(pair, kicked=True)`` inside its
+    event window (the KSP2 committed-dispatch chain) and books the
+    count itself."""
+    out = _aot_call(
         "ksp2_masked_resident", _ell_masked_source_batch,
         (
             state.src,
@@ -1379,9 +1432,22 @@ def ell_masked_distances_resident(
         dict(bands=state.graph.bands, n=state.graph.n_pad),
     )
     if defer:
-        _da.kick_async(d)
-        return d
-    return np.asarray(d)
+        for arr in out:
+            _da.kick_async(arr)
+        return out
+    rows, passes = jax.device_get(out)
+    return rows, note_ksp2_passes("masked", passes)
+
+
+def note_ksp2_passes(program: str, passes) -> int:
+    """The relax passes a KSP2 program ran (``masked``: one batch of
+    _ell_masked_source_batch; ``all_pairs``: the fixed point inside
+    _ell_all_view_rows), known where its outputs reach the host: summed
+    into the counter ``ops.ksp2.<program>_passes`` and returned for the
+    span that covers the dispatch."""
+    passes = int(passes)
+    _get_registry().counter_bump(f"ops.ksp2.{program}_passes", passes)
+    return passes
 
 
 def warm_masked_distances_resident(
@@ -1749,15 +1815,16 @@ def _ell_all_view_rows(
       3. row gathers from D (new) and ``d_prev`` (the previous build's
          resident D) for the invalidation endpoints,
 
-    returning (D, packed) where packed = [view_d | view_fh | rows_new |
-    rows_old] — the caller reads back only ``packed`` (one transfer) and
-    keeps D resident for the next event. Fusing the view and the
+    returning (D, packed, passes) where packed = [view_d | view_fh |
+    rows_new | rows_old] and passes is the fixed point's loop counter —
+    the caller reads back ``packed`` and that scalar together (one
+    ``device_get``) and keeps D resident for the next event. Fusing the view and the
     invalidation rows into the same transfer keeps a churn rebuild at
     one device round trip. The fixed point is
     warm-seeded from ``d_prev`` with the increase-edge delta
     (inc_tail/inc_head/inc_w — see _warm_seed; callers pass the
     _FORCE_RESET_EDGE sentinel for cold semantics)."""
-    d_all = _ell_fixed_point(
+    d_all, passes = _ell_fixed_point(
         srcs_t, ws_t, overloaded,
         jnp.arange(n, dtype=jnp.int32), bands, n,
         warm=(d_prev, inc_tail, inc_head, inc_w),
@@ -1776,7 +1843,7 @@ def _ell_all_view_rows(
         ],
         axis=0,
     )
-    return d_all, packed
+    return d_all, packed, passes
 
 
 def _inc_args(inc, bucket: int):
@@ -1798,17 +1865,19 @@ def _inc_args(inc, bucket: int):
 def ell_all_view_rows(state: EllState, view_srcs, w_sv, ep_ids, d_prev,
                       inc=None, inc_bucket: int = 4, defer: bool = False):
     """Run the fused all-sources + view + invalidation-rows dispatch on
-    the resident bands. Returns (d_all_dev, packed_host). ``inc`` is
-    the increase-edge delta [(tail, head, old_w)] for warm seeding,
-    padded to ``inc_bucket`` (None forces the cold seed); d_prev is
-    DONATED (invalid after the call). Rides the committed AOT
+    the resident bands. Returns (d_all_dev, packed_host, passes): the
+    passes the warm fixed point ran, already booked (note_ksp2_passes).
+    ``inc`` is the increase-edge delta [(tail, head, old_w)] for warm
+    seeding, padded to ``inc_bucket`` (None forces the cold seed);
+    d_prev is DONATED (invalid after the call). Rides the committed AOT
     executable cache (``ksp2_view_rows``); ``defer=True`` keeps
-    ``packed`` on device with its readback kicked async: the caller
-    reaps via ``dispatch_accounting.reap_read(packed, kicked=True)``
+    ``packed`` and ``passes`` on device with their readback kicked
+    async: the caller reaps the pair via ONE
+    ``dispatch_accounting.reap_read((packed, passes), kicked=True)``
     inside its event window, folding the device round trip into the
-    chain."""
+    chain, and books the count itself."""
     inc_t, inc_h, inc_w = _inc_args(inc, inc_bucket)
-    d_all, packed = _aot_call(
+    d_all, packed, passes = _aot_call(
         "ksp2_view_rows", _ell_all_view_rows,
         (
             state.src, state.w, state.overloaded,
@@ -1823,8 +1892,10 @@ def ell_all_view_rows(state: EllState, view_srcs, w_sv, ep_ids, d_prev,
     )
     if defer:
         _da.kick_async(packed)
-        return d_all, packed
-    return d_all, np.asarray(packed)
+        _da.kick_async(passes)
+        return d_all, packed, passes
+    packed, passes = jax.device_get((packed, passes))
+    return d_all, packed, note_ksp2_passes("all_pairs", passes)
 
 
 SOURCES_AXIS = "sources"
@@ -1892,7 +1963,7 @@ def _sharded_ell(src_ids, srcs_t, ws_t, overloaded, bands, n, mesh):
         return _ell_fixed_point(
             srcs_r, ws_r, ov_r, ids_blk, bands, n,
             vote=lambda bit: jax.lax.psum(bit, SOURCES_AXIS),
-        )
+        )[0]
 
     return shard_map(
         shard_fn,
@@ -1911,10 +1982,12 @@ def _sharded_ell_masked(
         srcs_r = args[len(masks_t) : 2 * len(masks_t)]
         ws_r = args[2 * len(masks_t) : 3 * len(masks_t)]
         ov_r = args[-1]
-        return _ell_masked_fixed_point(
+        d, passes = _ell_masked_fixed_point(
             srcs_r, ws_r, masks_blk, ov_r, src_id, bands, n,
             vote=lambda bit: jax.lax.psum(bit, SOURCES_AXIS),
         )
+        # the vote is global, so every shard counts the same passes
+        return d, jax.lax.pmax(passes, SOURCES_AXIS)
 
     nb = len(masks_t)
     return shard_map(
@@ -1926,7 +1999,7 @@ def _sharded_ell_masked(
             + [P(None, None)] * nb
             + [P(None)]
         ),
-        out_specs=P(SOURCES_AXIS, None),
+        out_specs=(P(SOURCES_AXIS, None), P()),
     )(*masks_t, *srcs_t, *ws_t, overloaded)
 
 
@@ -1953,7 +2026,7 @@ def sharded_ell_masked_distances(
             graph.bands,
             graph.n_pad,
             mesh,
-        )
+        )[0]
     )
 
 
@@ -1995,11 +2068,12 @@ def _sharded_warm_all_pairs(
         srcs_r = rest[:nb]
         ws_r = rest[nb : 2 * nb]
         ov_r = rest[-1]
-        return _ell_fixed_point(
+        d, passes = _ell_fixed_point(
             srcs_r, ws_r, ov_r, ids_blk, bands, n,
             vote=lambda bit: jax.lax.psum(bit, SOURCES_AXIS),
             warm=(d_prev_blk, it, ih, iw),
         )
+        return d, jax.lax.pmax(passes, SOURCES_AXIS)
 
     return shard_map(
         shard_fn,
@@ -2010,7 +2084,7 @@ def _sharded_warm_all_pairs(
             + [P(None, None)] * (2 * nb)
             + [P(None)]
         ),
-        out_specs=P(SOURCES_AXIS, None),
+        out_specs=(P(SOURCES_AXIS, None), P()),
     )(
         jnp.arange(n, dtype=jnp.int32), d_prev,
         inc_tail, inc_head, inc_w,
@@ -2030,7 +2104,7 @@ def _sharded_ell_all_view_rows(
     sharded matrix (XLA inserts the row collectives). d_all comes
     back SHARDED — the resident footprint per device is n^2/ndev,
     which is what lifts the KSP2 engine past the single-chip bound."""
-    d_all = _sharded_warm_all_pairs(
+    d_all, passes = _sharded_warm_all_pairs(
         srcs_t, ws_t, overloaded, d_prev, inc_tail, inc_head, inc_w,
         bands, n, mesh,
     )
@@ -2045,7 +2119,7 @@ def _sharded_ell_all_view_rows(
         ],
         axis=0,
     )
-    return d_all, packed
+    return d_all, packed, passes
 
 
 def sharded_ell_all_view_rows(
@@ -2053,7 +2127,8 @@ def sharded_ell_all_view_rows(
     inc=None, inc_bucket: int = 4,
 ):
     """Run the sharded all-sources + view + invalidation-rows dispatch
-    on the resident bands. Returns (d_all_dev SHARDED, packed_host).
+    on the resident bands. Returns (d_all_dev SHARDED, packed_host,
+    passes), the count booked as ell_all_view_rows books it.
     ``inc`` is the increase-edge delta for warm seeding (None forces
     the cold seed — same contract as ell_all_view_rows); d_prev is NOT
     donated. n_pad must divide by the mesh size (the engine gates on
@@ -2062,7 +2137,7 @@ def sharded_ell_all_view_rows(
         state.graph.n_pad, mesh.devices.size,
     )
     inc_t, inc_h, inc_w = _inc_args(inc, inc_bucket)
-    d_all, packed = _sharded_ell_all_view_rows(
+    d_all, packed, passes = _sharded_ell_all_view_rows(
         state.src, state.w, state.overloaded,
         _as_device_ids(view_srcs),
         w_sv if isinstance(w_sv, jax.Array) else jnp.asarray(
@@ -2072,7 +2147,8 @@ def sharded_ell_all_view_rows(
         d_prev, inc_t, inc_h, inc_w,
         state.graph.bands, state.graph.n_pad, mesh,
     )
-    return d_all, jax.device_get(packed)
+    packed, passes = jax.device_get((packed, passes))
+    return d_all, packed, note_ksp2_passes("all_pairs", passes)
 
 
 def sharded_ell_masked_distances_resident(
@@ -2084,10 +2160,11 @@ def sharded_ell_masked_distances_resident(
     divide by the mesh size (callers pad their pow2 buckets up).
     Dispatches through the same jitted _sharded_ell_masked the
     graph-argument wrapper uses — the resident tensors pass straight
-    through."""
+    through. Returns ``(rows, passes)`` on the host, the count booked,
+    as ell_masked_distances_resident does."""
     b = masks[0].shape[0]
     assert b % mesh.devices.size == 0, (b, mesh.devices.size)
-    return np.asarray(
+    rows, passes = jax.device_get(
         _sharded_ell_masked(
             state.src, state.w,
             tuple(jnp.asarray(m) for m in masks),
@@ -2095,6 +2172,7 @@ def sharded_ell_masked_distances_resident(
             state.graph.bands, state.graph.n_pad, mesh,
         )
     )
+    return rows, note_ksp2_passes("masked", passes)
 
 
 # ---------------------------------------------------------------------------

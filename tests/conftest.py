@@ -20,28 +20,6 @@ from openr_tpu.utils import compile_cache  # noqa: E402
 pin_host_cpu(8)
 compile_cache.enable()
 
-# Tests in files the benchmark owns (``BENCHMARK.json`` ``paths``: only
-# a ``benchmark`` PR may edit them) that a change to the PROGRAM has
-# made untrue. Each is expected to fail, strictly, until such a PR
-# rewrites it, and names the test that holds what it held meanwhile.
-_SUPERSEDED = {
-    "tests/chipbench/test_span_metrics.py::"
-    "test_a_traced_dense_cell_reports_every_new_metric_it_should": (
-        "sums view sync + solve dispatch + readback into decision.rebuild; "
-        "since PR 33 the publication that opens a debounce window stages "
-        "them inside decision.debounce (PERF.md section 7). Held meanwhile "
-        "by tests/chipbench/test_speculation_metrics.py::"
-        "test_a_traced_adjacency_cell_reports_the_stage_and_its_hits"
-    ),
-}
-
-
-def pytest_collection_modifyitems(items):
-    for item in items:
-        reason = _SUPERSEDED.get(item.nodeid)
-        if reason is not None:
-            item.add_marker(pytest.mark.xfail(reason=reason, strict=True))
-
 
 @pytest.fixture(autouse=True)
 def _fresh_integrity_auditor():

@@ -43,6 +43,8 @@ def test_every_leg_passes_at_tiny_sizes(artefacts, monkeypatch, capsys):
          lambda: chip_smoke.leg_pipeline(64, events=6, rate=20)),
         ("pipeline_sparse", sparse_pipeline),
         ("ksp2", lambda: chip_smoke.leg_ksp2(64, events=2)),
+        # 100 nodes, 18 hops from the corner: past the old hop gate
+        ("ksp2_grid", lambda: chip_smoke.leg_ksp2_grid(10, events=2)),
         # 12 rounds, not 3: at these sizes a client process is done in
         # well under the time the other takes to spawn on a loaded
         # machine (six xdist workers), and then no request ever arrives
@@ -72,7 +74,9 @@ def test_every_leg_passes_at_tiny_sizes(artefacts, monkeypatch, capsys):
     assert set(summary["legs"]) == {name for name, _ in legs}
     assert not any(summary["fallback_counters"].values())
     assert all(summary["mechanism_counters"].values())
-    for leg in ("pipeline_dense", "pipeline_sparse", "ksp2", "serve"):
+    assert summary["legs"]["ksp2_grid"]["hops_from_root"] == 18
+    for leg in ("pipeline_dense", "pipeline_sparse", "ksp2", "ksp2_grid",
+                "serve"):
         assert summary["legs"][leg]["parity"] is True
     kernels = summary["legs"]["kernels"]["kernels"]
     assert len(kernels) == 3

@@ -226,23 +226,31 @@ def test_support_passes_run_only_where_a_row_takes_the_cone(case, monkeypatch):
     assert passes == support + _relax_loop_count(seed, graph, state)
 
 
-# -- the KSP2 programs at fabric-1000's band shapes ---------------------------
+# -- the KSP2 programs at both KSP2 cells' band shapes ------------------------
+
+# configuration -> the (rows, slots) of the bands compile_ell gives it:
+# the fabric's three degree classes; the grid's one (degree 2 to 4)
+KSP2_BANDS = {
+    "fabric-1000-ksp2": [(624, 8), (288, 16), (104, 128)],
+    "grid-1000-ksp2": [(961, 8)],
+}
 
 
-@pytest.fixture(scope="module")
-def fabric_1000_ksp2(one_chip):
+@pytest.fixture(scope="module", params=sorted(KSP2_BANDS))
+def ksp2_shapes(request, one_chip):
     """What the KSP2 engine hands its two programs on the 1016-node
-    fabric: the bands, and shapes for the resident tensors."""
+    fabric and on the 31 x 31 grid: the bands, and shapes for the
+    resident tensors."""
     import jax.numpy as jnp
 
+    from chipbench.served_paths import pipeline_grid  # noqa: F401 - grid
     from openr_tpu.decision import ksp2_engine, spf_solver
     from openr_tpu.ops import spf_sparse
 
-    config = _config("fabric-1000-ksp2")
+    config = _config(request.param)
     ls = _link_state(config)
     graph = spf_sparse.compile_ell(ls)
-    assert [(b.rows, b.k) for b in graph.bands] == [
-        (624, 8), (288, 16), (104, 128)]
+    assert [(b.rows, b.k) for b in graph.bands] == KSP2_BANDS[request.param]
     assert graph.n_pad == 1024
     # a cold build solves every destination in one masked batch
     assert spf_solver._ksp2_chunk(graph) == 1024
@@ -277,41 +285,48 @@ def fabric_1000_ksp2(one_chip):
 
 @pytest.mark.parametrize("rows", [64, 512, 1024])
 def test_masked_batch_lowers_and_gathers_no_mask_per_edge(
-        fabric_1000_ksp2, rows):
+        ksp2_shapes, rows):
     """``jit__ell_masked_source_batch`` at the three buckets the engine
-    compiles on this graph: the two an incremental sync pads its
-    destinations to, and the cold build's one batch of them all. The
+    compiles on either graph: the two an incremental sync pads its
+    destinations to, and the one that holds them all (the cold build's
+    batch; on the grid also the refresh of a window of several links:
+    1024 rows x 961 x 8 mask bytes). The
     per-destination edge mask is an operand of the relax, selected
     against the weights; what PR 29 took out of ``_ell_relax`` — a
     ``pred`` gathered per edge — is in neither."""
+    import jax
+
     from openr_tpu.ops import spf_sparse
 
     from openr_tpu.decision import ksp2_engine, spf_solver
 
-    k = fabric_1000_ksp2
+    k = ksp2_shapes
     graph = k["graph"]
     assert rows in ksp2_engine._masked_buckets(spf_solver._ksp2_chunk(graph))
     compiled = spf_sparse._ell_masked_source_batch.lower(
         *k["bands"], k["masks"](rows), k["overloaded"], k["src_id"],
         bands=graph.bands, n=graph.n_pad,
     ).compile()
-    assert compiled.memory_analysis().output_size_in_bytes \
-        == rows * graph.n_pad * 4
+    # the rows, and the loop's own counter carried out beside them
+    assert [tuple(o.shape) for o in
+            jax.tree_util.tree_leaves(compiled.out_info)] \
+        == [(rows, graph.n_pad), ()]
     text = compiled.as_text()
     assert re.search(r"while/body/.*gather", text)
     assert _edge_shaped_pred_gathers(text, graph.bands) == []
     assert not re.search(r"= pred\[[0-9,]*\]\S* gather\(", text)
 
 
-def test_all_pairs_program_lowers_at_fabric_1000(fabric_1000_ksp2):
+def test_all_pairs_program_lowers_at_both_ksp2_cells(ksp2_shapes):
     """The fused program of a KSP2 sync, at the one shape the engine
-    runs it in: the all-pairs fixed point from all 1024 rows, the view
-    and the old and new rows of 32 endpoints."""
+    runs it in on either graph: the all-pairs fixed point from all 1024
+    rows, the view, the old and new rows of 32 endpoints, and the fixed
+    point's pass count as a third output."""
     import jax
 
     from openr_tpu.ops import spf_sparse
 
-    k = fabric_1000_ksp2
+    k = ksp2_shapes
     graph, n = k["graph"], k["graph"].n_pad
     compiled = spf_sparse._ell_all_view_rows.lower(
         *k["bands"], k["overloaded"], *k["view"],
@@ -321,7 +336,7 @@ def test_all_pairs_program_lowers_at_fabric_1000(fabric_1000_ksp2):
         tuple(o.shape) for o in jax.tree_util.tree_leaves(compiled.out_info)
     ]
     view, ep = k["view"][0].shape[0], k["view"][2].shape[0]
-    assert out == [(n, n), (2 * view + 2 * ep, n)]
+    assert out == [(n, n), (2 * view + 2 * ep, n), ()]
     text = compiled.as_text()
     # (the view's first hops look ``overloaded`` up per source row,
     # pred[16]: per row, not per edge)

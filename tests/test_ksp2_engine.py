@@ -541,8 +541,9 @@ class TestEngineChurnParity:
         bogus (total 6 vs true 8), the re-trace silently dropped a
         second path, and stale reused routes diverged from the host 12
         steps later. That path left the tree (PR 32); the engine
-        builds masks fresh from the current slot map for every solve,
-        and the stream holds it to the host."""
+        builds a solve's masks from slots it read off the slot map as
+        it is now (_batch_masks drops what a re-packed row held), and
+        the stream holds it to the host."""
         from tools.soak_ksp2 import soak_one
 
         out = soak_one(9013, "fabric", 120, 60)
@@ -555,11 +556,76 @@ class TestEngineChurnParity:
         the speculative path kept resident for that row — a dropped
         link shifted two slots and the masked solve excluded the
         wrong edges (metric-15 second path where the truth was 8).
-        Masks built fresh per solve read the current ``slot_of``."""
+        A solve's masks are built from the current ``slot_of``: slots
+        kept from an earlier solve go when their row is re-packed."""
         from tools.soak_ksp2 import soak_one
 
         out = soak_one(40018, "grid", 5, 60)
         assert out["parity"] == "ok", out
+
+    @pytest.mark.parametrize("seed", [3, 11])
+    def test_the_slots_held_are_the_masks_a_fresh_walk_builds(
+        self, seed, monkeypatch
+    ):
+        """The engine keeps, per destination, the slots its exclusion
+        set holds (``_batch_masks``) and walks the set again only when
+        the first paths move or ell_patch re-packs a row one of its
+        links ends in. Held to ``build_edge_masks`` over the live slot
+        map after every event of a stream that drops and restores
+        links (a row that loses a link shifts the others' slots), on
+        every destination; and most of them are not walked again."""
+        import numpy as np
+
+        from openr_tpu.ops import spf_sparse
+
+        rng = random.Random(seed)
+        _topo, areas, ps = _ksp2_network("grid", 6)
+        (ls,) = areas.values()
+        names = sorted(ls.get_adjacency_databases())
+        dev = SpfSolver("node-0", backend="device")
+        walks = []
+        walk = spf_sparse.excluded_slots
+        monkeypatch.setattr(
+            spf_sparse, "excluded_slots",
+            lambda *a, **k: walks.append(1) or walk(*a, **k),
+        )
+
+        def check() -> int:
+            (engine,) = dev._ksp2_engines.values()
+            graph = engine.state.graph
+            dsts = [d for d in engine.dsts if d not in engine.host_dsts]
+            got, ok = engine._batch_masks(graph, dsts, len(dsts) + 3)
+            walked = len(walks)
+            want, want_ok = spf_sparse.build_edge_masks(
+                graph, [engine.excl[d] for d in dsts] + [set()] * 3
+            )
+            del walks[walked:]
+            assert (ok == want_ok).all()
+            for a, b in zip(got, want):
+                assert np.array_equal(a, b)
+            return len(dsts)
+
+        dev.build_route_db("node-0", areas, ps)
+        checked = check()
+        cold = len(walks)
+        down = None
+        for _ in range(40):
+            victim = names[rng.randrange(len(names))]
+            db = ls.get_adjacency_databases()[victim]
+            kind = rng.random()
+            if kind < 0.35 and down is None:
+                down = (victim, _drop_adj(ls, victim, 0))
+            elif kind < 0.6 and down is not None:
+                _restore_adj(ls, *down)
+                down = None
+            else:
+                _mutate_metric(
+                    ls, victim, rng.randrange(len(db.adjacencies)),
+                    rng.randrange(1, 6),
+                )
+            dev.build_route_db("node-0", areas, ps)
+            checked += check()
+        assert 0 < len(walks) - cold < checked // 4, (len(walks), checked)
 
     def test_soak_tool_slice(self):
         """CI slice of tools/soak_ksp2: randomized mixed churn with

@@ -48,10 +48,10 @@ def fabric():
     return topo
 
 
-def _decision(backend: str):
+def _decision(backend: str, vantage: str = VANTAGE):
     kv_q = ReplicateQueue(name=f"{backend}:kvstore")
     return kv_q, Decision(
-        VANTAGE,
+        vantage,
         kvstore_updates_queue=kv_q,
         route_updates_queue=ReplicateQueue(name=f"{backend}:routes"),
         solver_backend=backend,
@@ -96,10 +96,6 @@ def test_routes_equal_reference_and_host_after_every_burst(fabric, seed):
             assert reference_ksp2.mpls_routes_of(live) \
                 == reference_ksp2.mpls_routes(gen.adj_dbs, VANTAGE)
             assert wire.dumps(live) == wire.dumps(host)
-            # the hop gate reads the bands, not a Dijkstra in Python
-            ls = sides[0].area_link_states["0"]
-            engine = sides[0].spf_solver._ksp2_engines[ls]
-            assert engine.ecc_hops == ls.get_max_hops_to_node(VANTAGE)
         assert kinds == {"metric", "flap"}
     finally:
         for q in queues:
@@ -172,51 +168,119 @@ def test_routes_equal_reference_and_host_after_every_burst(fabric, seed):
             if s.name == "decision.ksp2_trace"} == {1, 2}
 
 
-def test_hop_eccentricity_off_the_bands_is_the_unit_metric_dijkstras(fabric):
-    """The engine's hop gate, across what moves it: a drained FSW (no
-    transit but reachable), a drained root (it still originates), links
-    withdrawn until an RSW hangs off one uplink, and a node cut off."""
+@pytest.mark.parametrize("seed", [39, 2390000039])
+def test_the_engine_serves_a_grid_22_hops_deep_event_by_event(seed):
+    """Past the hop gate the solver had until PR 39 (16): a 12 x 12
+    grid solved from its corner, 22 hops to the far one, under
+    ``grid-1000-ksp2.drain-churn``'s events, one a window. The engine
+    is built at the load and stays; after every event the routes are
+    the plain reference's and ``solver_backend=host``'s to the byte;
+    and the spans say what ran: a masked batch, which starts cold, at
+    least the corner's eccentricity in passes; a window of several
+    links (a node re-costs two to four) proven edge by edge, so that
+    no row is solved again only to keep it exact; and a drained node,
+    which the proof does not answer for, the rows the sync refreshed."""
     from dataclasses import replace
 
-    from openr_tpu.graph.linkstate import LinkState
-    from openr_tpu.ops import spf_sparse
+    from chipbench.served_paths import pipeline_grid  # noqa: F401 - grid
 
-    ls = LinkState(area="0")
-    for name in sorted(fabric.adj_dbs):
-        ls.update_adjacency_database(fabric.adj_dbs[name])
-
-    def check() -> int:
-        graph = spf_sparse.compile_ell(ls)
-        hops = ksp2_engine._hop_eccentricity(
-            graph, graph.node_index[VANTAGE])
-        assert hops == ls.get_max_hops_to_node(VANTAGE)
-        return hops
-
-    assert check() == 4
-    for name in ("fsw-1-0", VANTAGE):
-        ls.update_adjacency_database(
-            replace(fabric.adj_dbs[name], is_overloaded=True))
-        check()
-    # every FSW of pod 1 drained: its RSWs are out of reach
-    for k in range(1, 4):
-        ls.update_adjacency_database(
-            replace(fabric.adj_dbs[f"fsw-1-{k}"], is_overloaded=True))
-    check()
-    for k in range(4):
-        ls.update_adjacency_database(fabric.adj_dbs[f"fsw-1-{k}"])
-    # rsw-2-0 keeps one uplink, and that FSW loses its spines but one
-    db = fabric.adj_dbs["rsw-2-0"]
-    ls.update_adjacency_database(replace(db, adjacencies=db.adjacencies[:1]))
-    up = db.adjacencies[0].other_node_name
-    db = fabric.adj_dbs[up]
-    spines = [a for a in db.adjacencies if a.other_node_name.startswith("ssw")]
-    ls.update_adjacency_database(replace(db, adjacencies=tuple(
-        a for a in db.adjacencies if a not in spines[1:])))
-    check()
-    # ... and then the last: the pod's share of that plane goes dark
-    ls.update_adjacency_database(replace(db, adjacencies=tuple(
-        a for a in db.adjacencies if a not in spines)))
-    assert check() >= 4
+    corner, events = "node-0", 24
+    grid = topology.build({"kind": "grid", "n": 12}, KSP2)
+    gen = traffic.Generator(
+        grid, seed, {"kinds": {"node-metric": 0.8, "flap": 0.2}}, corner)
+    tracer = get_tracer()
+    queues, sides = zip(*(_decision(b, corner) for b in ("device", "host")))
+    spans, kinds = [], set()
+    try:
+        for d in sides:
+            d.process_publication(Publication(
+                key_vals=dict(gen.initial_key_vals()), area="0"))
+            d.rebuild_routes("LOAD")
+        ls = sides[0].area_link_states["0"]
+        depth = ls.get_max_hops_to_node(corner)
+        assert depth == 22
+        (engine,) = sides[0].spf_solver._ksp2_engines.values()
+        assert engine.valid and engine.src_name == corner
+        before = dict(SPF_COUNTERS)
+        for _ in range(events):
+            ev = gen.draw()
+            kinds.add(ev.kind)
+            for d in sides:
+                d.process_publication(Publication(
+                    key_vals={ev.key: ev.value}, area="0"))
+            trace = tracer.start()
+            sides[0].pending.adopt_trace(trace)
+            for d in sides:
+                d.rebuild_routes("EVENT")
+            tracer.finish(trace)
+            assert trace.well_formed()
+            spans.append({})
+            for s in trace.spans:
+                spans[-1].setdefault(s.name, []).append(s)
+            live, host = (d.route_db.to_route_db(corner) for d in sides)
+            assert reference_ksp2.routes_of(live) == reference_ksp2.routes(
+                gen.adj_dbs, gen.prefix_dbs, corner)
+            assert reference_ksp2.mpls_routes_of(live) \
+                == reference_ksp2.mpls_routes(gen.adj_dbs, corner)
+            assert wire.dumps(live) == wire.dumps(host)
+            # the one engine, resident
+            assert list(sides[0].spf_solver._ksp2_engines.values()) \
+                == [engine] and engine.valid
+        # a node in the middle of the grid is drained: an overload flip
+        # moves effective weights the raw metrics do not show, the
+        # window is not proven and every row it did not name is solved
+        # again (the plain reference does not cover a drained node:
+        # held to the host backend alone)
+        drained = replace(gen.adj_dbs["node-66"], is_overloaded=True)
+        value = replace(
+            gen.initial_key_vals()["adj:node-66"], version=10 ** 6,
+            value=wire.dumps(drained))
+        for d in sides:
+            d.process_publication(Publication(
+                key_vals={"adj:node-66": value}, area="0"))
+        trace = tracer.start()
+        sides[0].pending.adopt_trace(trace)
+        for d in sides:
+            d.rebuild_routes("DRAIN")
+        tracer.finish(trace)
+        live, host = (d.route_db.to_route_db(corner) for d in sides)
+        assert wire.dumps(live) == wire.dumps(host)
+        (drain_sync,) = [
+            s for s in trace.spans if s.name == "decision.ksp2_sync"]
+        assert not drain_sync.attrs["cold"]
+        assert 0 < drain_sync.attrs["refreshed_rows"] <= 143
+        assert any(s.name == "ops.ksp2_masked_solve" and s.attrs.get("refresh")
+                   and s.attrs["passes"] >= depth for s in trace.spans)
+    finally:
+        for q in queues:
+            q.close()
+    assert kinds == {"node-metric", "flap"}
+    moved = {k: SPF_COUNTERS[k] - before.get(k, 0) for k in SPF_COUNTERS}
+    # every event, and the drain behind them
+    assert moved["decision.ksp2_incremental_syncs"] == events + 1
+    assert moved["decision.ksp2_cold_builds"] == 0
+    assert moved["decision.ksp2_host_fallbacks"] == 0
+    several = 0
+    for by_name in spans:
+        (sync,) = by_name["decision.ksp2_sync"]
+        (fused,) = by_name["ops.ksp2_all_pairs"]
+        # warm: from the pass that sees nothing change to, where a tight
+        # edge got dearer and rows restart, the graph's diameter
+        assert 1 <= fused.attrs["passes"] <= 144
+        for s in by_name.get("ops.ksp2_masked_solve", ()):
+            assert s.attrs["passes"] >= depth
+        for s in by_name.get("decision.ksp2_trace", ()):
+            assert 0 <= s.attrs["hops"] <= 143
+        # proven, however many links the window changed
+        assert sync.attrs["refreshed_rows"] == 0
+        assert not any(s.attrs.get("refresh")
+                       for s in by_name.get("ops.ksp2_masked_solve", ()))
+        several += sync.attrs["changed_pairs"] > 2
+    # a node of the grid has two to four links: most windows are not
+    # one link's
+    assert several >= events // 2
+    assert max(s.attrs["hops"] for by_name in spans
+               for s in by_name.get("decision.ksp2_trace", ())) >= depth
 
 
 @pytest.mark.parametrize("seed, pods, rsws, windows", [
@@ -233,9 +297,9 @@ def test_one_event_at_a_time_the_routes_stay_the_references(
     stale when a link joined a list ahead of them; seed 72, on four
     pods, a masked row left stale at a node that mattered to no route
     until, 300 events on, it did.)"""
-    from tools.soak_ksp2 import soak_cell
+    from tools.soak_ksp2 import fabric_world, soak_cell
 
-    out = soak_cell(seed, pods, rsws, windows)
+    out = soak_cell(seed, fabric_world(pods, rsws), windows)
     assert out["parity"] == "ok", out
     moved, dsts = out["moved"], out["dsts"]
     syncs = moved["decision.ksp2_incremental_syncs"]
@@ -253,6 +317,40 @@ def test_one_event_at_a_time_the_routes_stay_the_references(
     assert windows // 12 < moved["ops.spec_cancels"] < windows // 4
     assert syncs + moved["decision.ksp2_cold_builds"] \
         == windows + moved["ops.spec_cancels"]
+
+
+def test_a_soak_seed_of_the_grid_cell_22_hops_from_the_corner():
+    """``tools/soak_ksp2.py --cell``'s third world: the events of
+    ``grid-1000-ksp2.drain-churn`` on a 12 x 12 grid solved from its
+    corner, past the hop gate the solver had until PR 39. A node
+    re-costs two to four links at once, so nearly every window is one
+    the walk-reach proof answers edge by edge (until PR 39 it answered
+    for one link only and such a window re-solved every row it did not
+    name): after every window the routes are the reference's and no
+    masked row the engine keeps, re-solved or left, is stale."""
+    from tools.soak_ksp2 import grid_world, soak_cell
+
+    windows = 120
+    out = soak_cell(57, grid_world(12), windows)
+    assert out["parity"] == "ok", out
+    moved = out["moved"]
+    assert out["dsts"] == 143
+    syncs = moved["decision.ksp2_incremental_syncs"]
+    assert syncs >= windows - 10
+    assert moved["decision.ksp2_host_fallbacks"] == 0
+    assert moved["ops.spec_dispatches"] == windows
+    assert moved["ops.spec_hits"] + moved["ops.spec_cancels"] == windows
+    assert syncs + moved["decision.ksp2_cold_builds"] \
+        == windows + moved["ops.spec_cancels"]
+    # every masked batch starts cold, 22 hops from the corner; the warm
+    # all-pairs fixed point runs at least the pass that sees no change
+    assert moved["ops.ksp2.masked_passes"] \
+        >= 22 * moved["decision.ksp2_device_batches"]
+    assert moved["ops.ksp2.all_pairs_passes"] >= syncs
+    # what is solved again is what the proof names, a handful, in one
+    # batch a sync: no second batch that refreshes the rest
+    assert moved["decision.ksp2_device_batches"] <= syncs
+    assert moved["decision.ksp2_affected_dsts"] < out["dsts"] * syncs // 3
 
 
 def test_settle_heap_reclaims_what_an_earlier_call_froze():

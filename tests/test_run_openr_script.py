@@ -73,7 +73,9 @@ class TestRunOpenrScript:
                 except OSError:
                     return False
 
-            assert wait_until(ctrl_up), "ctrl port never opened"
+            # the daemon imports jax before it listens: beside five other
+            # workers that has taken longer than the default 30 s
+            assert wait_until(ctrl_up, timeout=90.0), "ctrl port never opened"
             if proc.poll() is not None:
                 out = proc.stdout.read().decode(errors="replace")
                 pytest.fail(f"daemon exited rc={proc.returncode}:\n{out}")

@@ -727,11 +727,12 @@ class TestOverloadMaskSitsOnDistances:
         masks = [rng.random((b,) + s.shape) < 0.15 for s in src]
         one = np.full((b, _MASK_N_PAD), INF, dtype=np.int32)
         one[:, 0] = 0
-        got_m = np.asarray(spf_sparse._ell_masked_source_batch(
+        got_m, passes = spf_sparse._ell_masked_source_batch(
             srcs_t, ws_t, tuple(jnp.asarray(m) for m in masks), ov,
             jnp.int32(0), _MASK_BANDS, _MASK_N_PAD,
-        ))
-        assert (got_m == fixed_point(masks, one)).all()
+        )
+        assert (np.asarray(got_m) == fixed_point(masks, one)).all()
+        assert 1 <= int(passes) <= _MASK_N_PAD
 
         u_src, u_w = _as_uniform(src, w)
         n, k = u_src.shape

@@ -10,7 +10,11 @@ materialization extras. Two streams:
   link drop/restore through ``SpfSolver``, device (engine) against a
   fresh host solver, byte-exact RouteDatabase parity at every step;
 - ``soak_cell``: the events of the cell fabric-1000-ksp2.adj-churn
-  (chipbench's generator: 80% metric change, 20% link flap) through
+  (chipbench's generator: 80% metric change, 20% link flap) on small
+  fabrics, and those of grid-1000-ksp2.drain-churn (80% a node re-costs
+  all its links, 20% link flap) on a 12 x 12 grid solved from its
+  corner, 22 hops deep, where nearly every window changes several
+  links and the walk-reach proof answers for each of them, through
   ``Decision`` as KvStore's queue hands them over (``_on_publication``
   then ``_on_debounce_fire``: the window's first publication stages the
   engine's sync, the rest join and move the version past the stage),
@@ -143,9 +147,27 @@ def soak_one(seed: int, kind: str, n: int, steps: int) -> dict:
     }
 
 
-CELL_VANTAGE = "rsw-0-0"
-# (pods, RSWs a pod): 56 and 50 nodes, both above KSP2_DEVICE_MIN_DSTS
-CELL_WORLDS = ((3, 12), (4, 8))
+def fabric_world(pods: int, rsws: int) -> dict:
+    """``pods`` pods of 4 FSW and ``rsws`` RSW, 2 SSW a plane, under
+    adj-churn's mix, solved from the first RSW."""
+    return {
+        "topology": {"kind": "fat_tree", "pods": pods, "ssw_per_plane": 2,
+                     "fsw_per_pod": 4, "rsw_per_pod": rsws},
+        "kinds": {"metric": 0.8, "flap": 0.2}, "vantage": "rsw-0-0",
+    }
+
+
+def grid_world(n: int) -> dict:
+    """``n`` x ``n`` grid under drain-churn's mix, solved from the
+    corner: 2 (n - 1) hops deep."""
+    return {
+        "topology": {"kind": "grid", "n": n},
+        "kinds": {"node-metric": 0.8, "flap": 0.2}, "vantage": "node-0",
+    }
+
+
+# 56 and 50 nodes and 144, all above KSP2_DEVICE_MIN_DSTS
+CELL_WORLDS = (fabric_world(3, 12), fabric_world(4, 8), grid_world(12))
 
 
 def _stale_rows(engine) -> list:
@@ -159,7 +181,7 @@ def _stale_rows(engine) -> list:
     masks, ok = spf_sparse.build_edge_masks(
         engine.state.graph, [engine.excl[d] for d in dsts]
     )
-    rows = spf_sparse.ell_masked_distances_resident(
+    rows, _passes = spf_sparse.ell_masked_distances_resident(
         engine.state, engine.sid, masks
     )
     return [
@@ -168,43 +190,43 @@ def _stale_rows(engine) -> list:
     ]
 
 
-def soak_cell(seed: int, pods: int, rsws: int, windows: int) -> dict:
-    """``windows`` rebuild windows of the cell's events on a fabric of
-    ``pods`` pods (2 SSW a plane, 4 FSW and ``rsws`` RSW a pod), 85%
+def soak_cell(seed: int, world: dict, windows: int) -> dict:
+    """``windows`` rebuild windows of a cell's events on ``world``
+    (``fabric_world`` / ``grid_world``: the network, the mix's kinds,
+    the vantage), 85%
     of them one publication (its staged sync is the window's: a hit)
     and the rest two to four (the stage is stepped on from: a cancel,
     and the build takes the union). Returns the counters the stream
     moved, or where the routes left the reference or a masked row of
     the engine's went stale."""
     from chipbench import reference_ksp2, topology, traffic
+    from chipbench.served_paths import pipeline_grid  # noqa: F401 - grid
     from openr_tpu.decision.decision import Decision
     from openr_tpu.messaging.queue import ReplicateQueue
     from openr_tpu.telemetry import get_registry
     from openr_tpu.types import Publication
 
+    vantage = world["vantage"]
     fabric = topology.build(
-        {"kind": "fat_tree", "pods": pods, "ssw_per_plane": 2,
-         "fsw_per_pod": 4, "rsw_per_pod": rsws},
-        {"algorithm": "KSP2_ED_ECMP", "type": "SR_MPLS"},
+        world["topology"], {"algorithm": "KSP2_ED_ECMP", "type": "SR_MPLS"},
     )
-    gen = traffic.Generator(
-        fabric, seed, {"kinds": {"metric": 0.8, "flap": 0.2}}, CELL_VANTAGE
-    )
+    gen = traffic.Generator(fabric, seed, {"kinds": world["kinds"]}, vantage)
     kv_q = ReplicateQueue(name="soak:kvstore")
     decision = Decision(
-        CELL_VANTAGE,
+        vantage,
         kvstore_updates_queue=kv_q,
         route_updates_queue=ReplicateQueue(name="soak:routes"),
         solver_backend="device",
     )
     rng = random.Random(seed)
     reg = get_registry()
-    spec = ("ops.spec_dispatches", "ops.spec_hits", "ops.spec_cancels")
+    spec = ("ops.spec_dispatches", "ops.spec_hits", "ops.spec_cancels",
+            "ops.ksp2.masked_passes", "ops.ksp2.all_pairs_passes")
 
     def counters() -> dict:
         return {**SPF_COUNTERS, **{k: reg.counter_get(k) for k in spec}}
 
-    out = {"seed": seed, "pods": pods, "rsws": rsws, "windows": windows,
+    out = {"seed": seed, "world": world["topology"], "windows": windows,
            "dsts": len(fabric.adj_dbs) - 1}
     t0 = time.time()
     try:
@@ -218,12 +240,12 @@ def soak_cell(seed: int, pods: int, rsws: int, windows: int) -> dict:
                 decision._on_publication(Publication(
                     key_vals={ev.key: ev.value}, area="0"))
             decision._on_debounce_fire()
-            live = decision.route_db.to_route_db(CELL_VANTAGE)
+            live = decision.route_db.to_route_db(vantage)
             if reference_ksp2.routes_of(live) != reference_ksp2.routes(
-                gen.adj_dbs, gen.prefix_dbs, CELL_VANTAGE
+                gen.adj_dbs, gen.prefix_dbs, vantage
             ) or reference_ksp2.mpls_routes_of(
                 live
-            ) != reference_ksp2.mpls_routes(gen.adj_dbs, CELL_VANTAGE):
+            ) != reference_ksp2.mpls_routes(gen.adj_dbs, vantage):
                 return {**out, "window": window, "parity": "BROKEN"}
             (engine,) = decision.spf_solver._ksp2_engines.values()
             stale = _stale_rows(engine)
@@ -256,7 +278,7 @@ def main() -> int:
     for seed in range(args.seeds):
         if args.cell:
             out = soak_cell(
-                seed, *CELL_WORLDS[seed % len(CELL_WORLDS)], args.steps
+                seed, CELL_WORLDS[seed % len(CELL_WORLDS)], args.steps
             )
         else:
             kind, n = worlds[seed % len(worlds)]
