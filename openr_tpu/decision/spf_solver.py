@@ -185,6 +185,11 @@ SPF_COUNTERS = _get_registry().counter_dict(
         "decision.ksp2_warm_dispatches",
         "decision.ksp2_affected_dsts",
         "decision.ksp2_route_reuses",
+        # prefixes the route loop walked in the builds a KSP2 engine
+        # answered for: against those builds' prefixes, how often the
+        # engine's carry served as an index (all of them on a cold
+        # build or a prefix event)
+        "decision.ksp2_routes_visited",
         "decision.sp_route_reuses",
         "decision.ell_prewarms",
         # a view's solve program was dispatched to the device (dense
@@ -782,6 +787,8 @@ def reset_device_caches() -> None:
     "_ksp2_dsts_cache",
     "_ksp2_engines",
     "_ksp2_tracked",
+    "_ksp2_tracked_of",
+    "_ksp2_untracked",
     "_label_cache",
     "_label_state",
     "_labels_cache",
@@ -865,6 +872,14 @@ class SpfSolver:
         # destinations); reuse is only sound for prefixes whose
         # advertisers all lie inside this set
         self._ksp2_tracked: Set[str] = set()
+        # what it was made from (_prefetch_ksp2_paths): it is replaced
+        # only when that changes, so its identity can key a cache
+        self._ksp2_tracked_of: Optional[tuple] = None
+        # (advertisers cache, tracked set, the KSP2 prefixes with an
+        # advertiser outside it): the part of a bulk build's visit set
+        # that no carry names, made once per prefix-state version and
+        # tracked set, not by a pass over the KSP2 prefixes per build
+        self._ksp2_untracked: Optional[tuple] = None
         # advertiser sets per prefix, cached per prefix_state VERSION:
         # rebuilding them per prefix per event made the reuse loop
         # itself the cost it was meant to avoid (~30us x n_prefixes of
@@ -939,6 +954,8 @@ class SpfSolver:
         self._advertisers_cache = None
         self._ksp2_dsts_cache = None
         self._ksp2_tracked = set()
+        self._ksp2_tracked_of = None
+        self._ksp2_untracked = None
         self._sp_reuse = {}
         self._sp_prev_seq = None
         self._label_cache = {}
@@ -1080,11 +1097,9 @@ class SpfSolver:
                     self._spec_staged[ls] = key
                 else:
                     engine.staged = False
-                    dsts = sorted(
-                        self._ksp2_area_dsts(
-                            my_node_name, area_link_states, prefix_state
-                        )[area]
-                    )
+                    dsts = self._ksp2_area_dsts(
+                        my_node_name, area_link_states, prefix_state
+                    )[0][area]
                     if (
                         not engine.valid
                         or len(dsts) < KSP2_DEVICE_MIN_DSTS
@@ -1508,14 +1523,29 @@ class SpfSolver:
         # TWO C-level dict copies instead of 100k Python-level gate
         # evaluations (~1.7 s/event at 100k).
         iter_prefixes = prefix_state.prefixes()
+        # (what the bulk path does not visit it adopts, so the cache has
+        # to hold every prefix: meta_ok says it was filled from this
+        # prefix state at this version, and the length holds it to that)
         bulk = (
             reuse_sp is not None
             and adv_map is not None
             and self._route_entries_cache is not None
+            and len(self._route_cache) == len(iter_prefixes)
         )
+        n_prefixes = len(iter_prefixes)
+        ksp2_reused = 0
         if bulk:
             _key, _amap, adv_index, ksp2_set = self._advertisers_cache
-            must: Set[IpPrefix] = set(ksp2_set)
+            if reuse is not None:
+                # the engine's carry is an index into the prefixes, as
+                # the SP dirty set is: the gate below can only refuse a
+                # KSP2 prefix with an advertiser the carry names or no
+                # engine tracks, so those are the ones it is shown
+                must: Set[IpPrefix] = set(self._ksp2_untracked_prefixes())
+                for n in reuse:
+                    must |= adv_index.get(n, _EMPTY_PREFIXES)
+            else:
+                must = set(ksp2_set)
             for n in reuse_sp:
                 must |= adv_index.get(n, _EMPTY_PREFIXES)
             route_db.unicast_routes = dict(self._route_entries_cache)
@@ -1525,10 +1555,15 @@ class SpfSolver:
                 route_db.unicast_routes.pop(p, None)
                 self.best_routes_cache.pop(p, None)
                 new_cache.pop(p, None)
-            # count what actually survived the pops: `must` may name
-            # prefixes that were never cached, so set arithmetic
-            # (len(cache) - len(must)) under-counts
-            SPF_COUNTERS["decision.sp_route_reuses"] += len(new_cache)
+            # what survived the pops is adopted: the KSP2 prefixes among
+            # it on the engine's word, the rest on the SP dirty test's
+            ksp2_reused = len(ksp2_set) - len(ksp2_set & must)
+            SPF_COUNTERS["decision.sp_route_reuses"] += (
+                len(new_cache) - ksp2_reused
+            )
+            # the prefixes this build answers for: the KSP2 ones and
+            # whatever else the loop is shown
+            n_prefixes = len(must) + ksp2_reused
             iter_prefixes = must
 
         # where the KSP2 engine ran, the loop below is the KSP2 share
@@ -1536,10 +1571,11 @@ class SpfSolver:
         # destinations the engine named, the rest served from the cache
         # counted here and booked once: a counter bump per prefix is a
         # registry round trip per prefix
-        ksp2_reused = 0
         with (
             _get_tracer().span(
-                "decision.ksp2_routes", prefixes=len(iter_prefixes)
+                "decision.ksp2_routes",
+                prefixes=n_prefixes,
+                visited=len(iter_prefixes),
             )
             if affected is not None
             else contextlib.nullcontext()
@@ -1587,6 +1623,10 @@ class SpfSolver:
                         self.best_routes_cache.get(prefix),
                     )
             SPF_COUNTERS["decision.ksp2_route_reuses"] += ksp2_reused
+            if affected is not None:
+                SPF_COUNTERS["decision.ksp2_routes_visited"] += len(
+                    iter_prefixes
+                )
             if ksp2_span is not None:
                 ksp2_span.attrs["reused"] = ksp2_reused
         self._route_cache = new_cache
@@ -1641,6 +1681,26 @@ class SpfSolver:
             route_db.add_mpls_route(RibMplsEntry(label, set(nhs)))
 
         return route_db
+
+    def _ksp2_untracked_prefixes(self) -> frozenset:
+        """The KSP2 prefixes with an advertiser outside
+        ``_ksp2_tracked`` (a node present in an area whose engine does
+        not track it, or one that advertises no KSP2 prefix itself):
+        no carry ever names them, so the reuse gate refuses them in
+        every build and a bulk build has to show them to it."""
+        adv, tracked = self._advertisers_cache, self._ksp2_tracked
+        made = self._ksp2_untracked
+        if made is None or made[0] is not adv or made[1] is not tracked:
+            _key, amap, _index, ksp2_set = adv
+            made = (
+                adv,
+                tracked,
+                frozenset(
+                    p for p in ksp2_set if not amap[p][0] <= tracked
+                ),
+            )
+            self._ksp2_untracked = made
+        return made[2]
 
     # -- node-label routes -------------------------------------------------
 
@@ -2128,11 +2188,16 @@ class SpfSolver:
         my_node_name: str,
         area_link_states: AreaLinkStates,
         prefix_state: PrefixState,
-    ) -> Dict[str, Set[str]]:
+    ) -> Tuple[Dict[str, List[str]], Dict[str, List[str]]]:
         """Per area, the nodes other than the root that advertise a
-        KSP2_ED_ECMP prefix there. The scan is O(total prefix entries):
-        cached per prefix-state version (at 100k SP-only fabrics it
-        burned ~0.4 s/event discovering an empty set every build)."""
+        KSP2_ED_ECMP prefix there, sorted (the engine's destination
+        list); and, for each such node, the areas it is NOT a
+        destination of (what ``_ksp2_tracked`` has to ask of the graphs
+        every build: empty with one area). The scan is O(total prefix
+        entries): cached per prefix-state version (at 100k SP-only
+        fabrics it burned ~0.4 s/event discovering an empty set every
+        build), and with it the sort, so that a build whose engine is
+        already synced makes no pass over the destinations."""
         dsts_key = (
             prefix_state,
             prefix_state.version,
@@ -2143,8 +2208,10 @@ class SpfSolver:
             self._ksp2_dsts_cache is not None
             and self._ksp2_dsts_cache[0] == dsts_key
         ):
-            return self._ksp2_dsts_cache[1]
-        area_dsts = {area: set() for area in area_link_states}
+            return self._ksp2_dsts_cache[1:]
+        found: Dict[str, Set[str]] = {
+            area: set() for area in area_link_states
+        }
         for prefix in prefix_state.prefixes():
             for (node, p_area), entry in prefix_state.entries_for(
                 prefix
@@ -2153,11 +2220,18 @@ class SpfSolver:
                     entry.forwarding_algorithm
                     == PrefixForwardingAlgorithm.KSP2_ED_ECMP
                     and node != my_node_name
-                    and p_area in area_dsts
+                    and p_area in found
                 ):
-                    area_dsts[p_area].add(node)
-        self._ksp2_dsts_cache = (dsts_key, area_dsts)
-        return area_dsts
+                    found[p_area].add(node)
+        area_dsts = {area: sorted(nodes) for area, nodes in found.items()}
+        elsewhere: Dict[str, List[str]] = {}
+        if len(found) > 1:
+            for node in set().union(*found.values()):
+                others = [a for a in found if node not in found[a]]
+                if others:
+                    elsewhere[node] = others
+        self._ksp2_dsts_cache = (dsts_key, area_dsts, elsewhere)
+        return area_dsts, elsewhere
 
     def _prefetch_ksp2_paths(
         self,
@@ -2192,18 +2266,17 @@ class SpfSolver:
         unsignaled area's churn could silently change reused routes."""
         if self.backend != "device":
             return None
-        area_dsts = self._ksp2_area_dsts(
+        area_dsts, elsewhere = self._ksp2_area_dsts(
             my_node_name, area_link_states, prefix_state
         )
         if not any(area_dsts.values()):
             return None
 
         union_affected: Set[str] = set()
-        union_tracked: Set[str] = set()
         all_signaled = True
         ran_any = False
         for area, ls in sorted(area_link_states.items()):
-            dsts = sorted(area_dsts[area])
+            dsts = area_dsts[area]
             if (
                 len(dsts) < KSP2_DEVICE_MIN_DSTS
                 or not ls.has_node(my_node_name)
@@ -2218,21 +2291,26 @@ class SpfSolver:
                 continue
             ran_any = True
             union_affected |= result
-            union_tracked |= set(dsts)
         if not ran_any or not all_signaled:
             return None
         # a best advertiser's paths are computed in EVERY area graph it
         # appears in: a node advertising in area a but merely PRESENT
         # in area b is untracked by b's engine, so b-churn would never
-        # land it in the affected set — its routes must not be reused
-        self._ksp2_tracked = {
+        # land it in the affected set — its routes must not be reused.
+        # Every area ran, so the tracked set is every destination but
+        # those strays; it keeps its identity while they stay the same
+        # (build_route_db's untracked prefixes are cached against it)
+        stray = frozenset(
             n
-            for n in union_tracked
-            if all(
-                (n in area_dsts[a]) or not a_ls.has_node(n)
-                for a, a_ls in area_link_states.items()
-            )
-        } | {my_node_name}
+            for n, areas in elsewhere.items()
+            if any(area_link_states[a].has_node(n) for a in areas)
+        )
+        made = self._ksp2_tracked_of
+        if made is None or made[0] is not area_dsts or made[1] != stray:
+            self._ksp2_tracked = {my_node_name}.union(
+                *area_dsts.values()
+            ).difference(stray)
+            self._ksp2_tracked_of = (area_dsts, stray)
         return union_affected
 
     def _prefetch_ksp2_area(
