@@ -20,6 +20,12 @@ deployment uses and with the options ``OpenrConfig`` ships
   KSP2 graph, the 31 x 31 grid, through ``SpfSolver`` from the corner
   (60 hops deep): four nodes re-cost all their links, the engine the
   load built serves every one, device against host.
+- ``multiarea_2x1000``: the border router of ``multi-area-2x1000``
+  (two areas of the 1k fabric, KvStore -> Decision -> PrefixManager as
+  ``daemon.py`` builds a border): cold build of both areas, one
+  adjacency event an area, one prefix toggled on and off; after each,
+  routes and the re-originated keys in each area's KvStore equal to
+  ``chipbench/reference_multiarea.py``.
 - ``serve``: ``SolverService`` behind ``CtrlServer`` in this process,
   JAX-free client processes over the ctrl wire, every FIB digest equal
   to one built by ``SpfSolver(backend="host")``.
@@ -331,6 +337,125 @@ def leg_ksp2_grid(side: int = 31, events: int = 4) -> dict:
     )
     out["hops_from_root"] = ls.get_max_hops_to_node(root)
     return out
+
+
+def leg_multiarea(topology=None, timeout_s: float = 300.0) -> dict:
+    """``multi-area-2x1000``'s border: both areas built cold, one metric
+    change in each, one prefix toggled on and then off. After each step
+    the routes Decision holds and the vantage's live re-originated keys
+    in each area's KvStore equal the plain two-area reference.
+    ``topology`` overrides sizes of the configuration's (tests)."""
+    from chipbench import reference_multiarea, spec
+    from openr_tpu.decision.decision import Decision
+    from openr_tpu.kvstore.client import KvStoreClient
+    from openr_tpu.kvstore.store import KvStore
+    from openr_tpu.messaging.queue import ReplicateQueue
+    from openr_tpu.prefixmgr.prefix_manager import PrefixManager
+    from openr_tpu.types import TTL_INFINITY, KeySetParams, Value
+    from openr_tpu.utils import keys as keyutil
+    from openr_tpu.utils import wire
+    from openr_tpu.utils.eventbase import OpenrEventBase
+
+    with open(os.path.join(
+        REPO, "chipbench", "configs", "multi-area-2x1000.json"
+    )) as f:
+        config = json.load(f)
+    config["topology"].update(topology or {})
+    bench = spec.load_driver(REPO, config["served_path"]).set_up.__globals__
+    areas, borders, name = config["areas"], config["borders"], config["vantage"]
+    (peer,) = [b for b in borders if b != name]
+    topos = bench["build"](config)
+    gen = bench["TwoAreaTraffic"](
+        topos, 1, {"kinds": {"metric": 1.0}}, name, borders)
+    peer_keys = bench["peer_reoriginations"](topos, peer, borders)
+    initial = gen.initial_key_vals()
+    for area, dbs in peer_keys.items():
+        for key, db in dbs.items():
+            initial[area][key] = Value(
+                version=1, originator_id=peer, value=wire.dumps(db),
+                ttl=TTL_INFINITY)
+
+    store = KvStore(node_id=name, areas=areas)
+    route_updates = ReplicateQueue(name=f"{name}:routeUpdates")
+    client_evb = OpenrEventBase(name=f"kvclient:{name}")
+    client = KvStoreClient(client_evb, name, store)
+    decision = Decision(
+        name,
+        kvstore_updates_queue=store.updates_queue,
+        route_updates_queue=route_updates,
+        static_routes_queue=ReplicateQueue(name=f"{name}:staticRoutes"),
+        debounce_min_s=0.01, debounce_max_s=0.25, solver_backend="device",
+    )
+    prefix_manager = PrefixManager(
+        name, client, decision_route_updates_queue=route_updates,
+        areas=areas,
+    )
+    own = f"{keyutil.PREFIX_DB_MARKER}{name}:"
+    counted = ("decision.device_solves", "prefixmgr.redistributed_keys",
+               "prefixmgr.withdrawn_keys")
+    before = _counter_snapshot(counted)
+
+    def held() -> dict:
+        return reference_multiarea.owed_of(
+            bench["live_entries"](store, areas, own))
+
+    def settle(what: str) -> tuple:
+        lsdb = gen.lsdb(peer_keys)
+        want = reference_multiarea.routes(lsdb, name)
+        owed = reference_multiarea.reoriginations(lsdb, name, areas)
+        deadline = time.monotonic() + timeout_s
+        while time.monotonic() < deadline:
+            got = reference_multiarea.routes_of(decision.evb.call_and_wait(
+                lambda: decision.route_db.to_route_db(name)))
+            if got == want and held() == owed:
+                return len(want), len(owed)
+            time.sleep(0.05)
+        raise SmokeFailure(
+            f"multiarea: {what}: routes or re-originated keys never "
+            f"equalled the reference ({len(want)} routes, {len(owed)} keys)")
+
+    def publish(ev) -> None:
+        store.set_key_vals(ev.area, KeySetParams(
+            key_vals={ev.key: ev.value},
+            originator_id=ev.value.originator_id))
+
+    store.start()
+    client_evb.run_in_thread()
+    decision.start()
+    prefix_manager.start()
+    try:
+        for area in areas:
+            store.set_key_vals(
+                area, KeySetParams(key_vals=dict(initial[area])))
+        routes, keys = settle("cold build of both areas")
+        for area in areas:
+            publish(bench["AreaEvent"](gen.gens[area].event("metric"), area))
+            settle(f"a metric change in area {area}")
+        # a non-border node's extra /128: on, then off
+        node = next(n for n in gen.gens[areas[0]]._nodes if n not in borders)
+        gen.gens[areas[0]]._pick = lambda: node
+        for what, more in (("re-originated", 1), ("withdrawn", 0)):
+            publish(bench["AreaEvent"](
+                gen.gens[areas[0]].event("prefix"), areas[0]))
+            _require(
+                settle(f"a toggled prefix {what}") == (routes + more,
+                                                       keys + more),
+                f"multiarea: a toggled prefix {what}: wrong counts")
+    finally:
+        prefix_manager.stop()
+        decision.stop()
+        client.stop()
+        client_evb.stop()
+        client_evb.join()
+        store.stop()
+    counts = _counter_delta(before, counted)
+    _require(counts["decision.device_solves"] >= len(areas) + 2,
+             f"multiarea: the device did not solve every step: {counts}")
+    _require(counts["prefixmgr.redistributed_keys"] == keys + 1
+             and counts["prefixmgr.withdrawn_keys"] == 1,
+             f"multiarea: not one key a prefix: {counts}")
+    return {"parity": True, "routes": routes, "reoriginated_keys": keys,
+            "nodes_per_area": len(topos[areas[0]].adj_dbs), "counts": counts}
 
 
 def leg_serve(clients: int = 2, tenant_sizes=(("grid", 32), ("mesh", 1000)),
@@ -671,6 +796,7 @@ def main() -> int:
         ("pipeline_fabric_10k", lambda: leg_pipeline(10000)),
         ("ksp2_fabric_1008", lambda: leg_ksp2(1008)),
         ("ksp2_grid_961", leg_ksp2_grid),
+        ("multiarea_2x1000", leg_multiarea),
         ("serve", leg_serve),
         ("kernels", lambda: leg_kernels(interpret=False)),
     ]

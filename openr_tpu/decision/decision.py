@@ -334,6 +334,8 @@ class Decision:
         # rebuild_routes keeps for settle_heap: the window's rebuild
         # settles for it
         self._staged_cold_build = False
+        # area -> its graph's topology version at the last rebuild
+        self._rebuilt_versions: Dict[str, int] = {}
         # admission/backpressure path (service plane): the controller
         # adapts the debounce ceiling to the reader backlog, and the
         # consume path sheds-by-coalescing once the backlog is deep
@@ -822,9 +824,23 @@ class Decision:
         tracer = get_tracer()
         rebuild_span = None
         full = self.pending.needs_full_rebuild()
+        # the areas whose graph moved since the last rebuild: each of
+        # them is a view to solve, the others are cache hits
+        versions = {
+            area: ls.topology_version
+            for area, ls in self.area_link_states.items()
+        }
+        areas_moved = sum(
+            version != self._rebuilt_versions.get(area)
+            for area, version in versions.items()
+        )
+        self._rebuilt_versions = versions
         if trace is not None:
             rebuild_span = trace.begin_span(
-                "decision.rebuild", full_rebuild=full
+                "decision.rebuild",
+                full_rebuild=full,
+                areas=len(versions),
+                areas_moved=areas_moved,
             )
             tracer.activate(trace)
         t_rebuild0 = time.perf_counter()

@@ -1745,13 +1745,13 @@ class SpfSolver:
         )
 
     def _store_label_state(
-        self, my_node_name: str, area: str, result, winners,
+        self, my_node_name: str, areas: Tuple[str, ...], result, winners,
         collisions, labels_by,
     ) -> None:
         self._label_state.pop(my_node_name, None)
         self._label_state[my_node_name] = (
             self._build_seq, result, winners, collisions, labels_by,
-            area,
+            areas,
         )
         while len(self._label_state) > 8:
             self._label_state.pop(next(iter(self._label_state)))
@@ -1769,18 +1769,26 @@ class SpfSolver:
         contested label's winner must be recomputed from scratch (the
         losing claimants' entries were never derived), falling back to
         the full loop."""
-        ((area, ls),) = area_link_states.items()
-        _seq, result, winners, collisions, labels_by, st_area = st
-        if st_area != area:
+        areas = tuple(sorted(area_link_states))
+        _seq, result, winners, collisions, labels_by, st_areas = st
+        if st_areas != areas:
             return None
-        adj_dbs = ls.get_adjacency_databases()
+        adj_dbs = [
+            (a, area_link_states[a].get_adjacency_databases())
+            for a in areas
+        ]
         result = dict(result)
         winners = dict(winners)
         labels_by = dict(labels_by)
         collisions = set(collisions)
         for node in sorted(dirty):
             old_label = labels_by.pop(node, None)
-            db = adj_dbs.get(node)
+            # a node of several areas (a border) is derived for the
+            # last of them, as the full loop leaves it
+            found = [(a, dbs[node]) for a, dbs in adj_dbs if node in dbs]
+            if len({db.node_label for _, db in found}) > 1:
+                return None  # one label an area: the full loop's case
+            area, db = found[-1] if found else (areas[-1], None)
             top_label = db.node_label if db is not None else 0
             if top_label == 0 or not is_mpls_label_valid(top_label):
                 top_label = None
@@ -1820,7 +1828,7 @@ class SpfSolver:
             result[top_label] = (node, entry)
             winners[node] = (top_label, entry)
         self._store_label_state(
-            my_node_name, area, result, winners, collisions, labels_by
+            my_node_name, areas, result, winners, collisions, labels_by
         )
         return result
 
@@ -1833,20 +1841,17 @@ class SpfSolver:
         """SR node-label routes for every labeled node
         (reference: Decision.cpp:600-650 buildRouteDb label loop).
 
-        Incremental fast paths (single-area device backend, no LFA):
-        (1) when the SP dirty test proves which destinations' routes
-        could have moved, the previous build's assembled map is PATCHED
-        in O(dirty) (_patch_node_label_routes) — the O(N) loop never
-        runs; (2) otherwise the batched view's column diff marks label
-        routes reusable per destination and the loop re-derives only
-        the changed ones."""
+        Incremental fast paths (device backend, no LFA): (1) when the
+        SP dirty test proves which destinations' routes could have
+        moved (over every area: it unions the areas' dirty sets), the
+        previous build's assembled map is PATCHED in O(dirty)
+        (_patch_node_label_routes) — the O(N) loop never runs, with one
+        area or several; (2) otherwise, with one area, the batched
+        view's column diff marks label routes reusable per destination
+        and the loop re-derives only the changed ones."""
         label_to_node: Dict[int, Tuple[str, RibMplsEntry]] = {}
 
-        if (
-            sp_dirty is not None
-            and len(area_link_states) == 1
-            and not self.compute_lfa_paths
-        ):
+        if sp_dirty is not None and not self.compute_lfa_paths:
             st = self._label_state.get(my_node_name)
             if (
                 st is not None
@@ -1906,9 +1911,14 @@ class SpfSolver:
         built: Dict[str, Tuple[int, RibMplsEntry]] = {}
         labels_by: Dict[str, int] = {}
         collisions: Set[int] = set()
+        # a node that shows another label in another area leaves two
+        # routes behind: nothing the O(dirty) patch keeps track of
+        one_label_a_node = True
         for area, ls in sorted(area_link_states.items()):
             for node, adj_db in sorted(ls.get_adjacency_databases().items()):
                 top_label = adj_db.node_label
+                if labels_by.get(node, top_label) != top_label:
+                    one_label_a_node = False
                 if top_label == 0:
                     continue
                 if not is_mpls_label_valid(top_label):
@@ -1917,7 +1927,7 @@ class SpfSolver:
                 # label collision: deterministically keep the smaller name
                 # (reference: Decision.cpp:620-633)
                 existing = label_to_node.get(top_label)
-                if existing is not None:
+                if existing is not None and existing[0] != node:
                     collisions.add(top_label)
                     if existing[0] < node:
                         continue
@@ -1945,12 +1955,13 @@ class SpfSolver:
             self._label_cache[my_node_name] = (*cache_probe, built)
             while len(self._label_cache) > 8:  # bound ctrl-query growth
                 self._label_cache.pop(next(iter(self._label_cache)))
-        if len(area_link_states) == 1:
-            ((only_area, _ls),) = area_link_states.items()
+        if one_label_a_node:
             self._store_label_state(
-                my_node_name, only_area, label_to_node, built,
-                collisions, labels_by,
+                my_node_name, tuple(sorted(area_link_states)),
+                label_to_node, built, collisions, labels_by,
             )
+        else:
+            self._label_state.pop(my_node_name, None)
         return label_to_node
 
     def create_route_for_prefix(

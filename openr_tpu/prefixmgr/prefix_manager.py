@@ -13,15 +13,24 @@ Behavioral parity with the reference ``openr/prefix-manager/PrefixManager``:
   on the stack). Reference: PrefixManager consuming
   decisionRouteUpdatesQueue + areaStack loop suppression
   (openr/prefix-manager/PrefixManager.cpp, SURVEY §2.1).
+- KvStore is synced by delta, as upstream syncs it: a route update or an
+  advertise/withdraw touches only the keys of the prefixes it names, in
+  each area (set, or cleared with a ``delete_prefix`` tombstone). What
+  the store holds afterwards is what a sync of the whole table leaves.
+  One update's redistribution is the span ``prefixmgr.redistribute`` on
+  the update's trace and the counters ``prefixmgr.redistribute_runs``,
+  ``.redistributed_keys``, ``.withdrawn_keys`` and ``.kvstore_calls``.
 """
 
 from __future__ import annotations
 
 import enum
+import time
 from dataclasses import dataclass, field, replace
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Set, Tuple
 
 from openr_tpu.messaging.queue import ReplicateQueue
+from openr_tpu.telemetry import get_registry
 from openr_tpu.types import IpPrefix, PrefixDatabase, PrefixEntry, PrefixType
 from openr_tpu.types.lsdb import PrefixMetrics
 from openr_tpu.utils import keys as keyutil
@@ -69,7 +78,8 @@ class PrefixManager:
         self._redistributed: Dict[
             IpPrefix, Tuple[PrefixEntry, Tuple[str, ...]]
         ] = {}
-        self._advertised_keys: set = set()  # {(area, key)}
+        # what KvStore holds of ours: (area, key) -> payload
+        self._advertised: Dict[Tuple[str, str], bytes] = {}
         if prefix_updates_queue is not None:
             self.evb.add_queue_reader(
                 prefix_updates_queue.get_reader(f"pm:{my_node_name}"),
@@ -112,50 +122,89 @@ class PrefixManager:
 
     def _on_route_update(self, update) -> None:
         """Re-originate Decision's best routes into other areas
-        (reference: PrefixManager's decisionRouteUpdatesQueue consumer)."""
-        changed = False
+        (reference: PrefixManager's decisionRouteUpdatesQueue consumer).
+        Only the prefixes whose redistribution this update changes
+        reach KvStore."""
+        start = (time.time() * 1000.0, time.perf_counter())
+        to_update = getattr(update, "unicast_routes_to_update", {})
+        to_delete = getattr(update, "unicast_routes_to_delete", [])
+        touched: Set[IpPrefix] = set()
         own_prefixes = {
             p for (t, p) in self._prefixes if t != PrefixType.RIB
         }
-        for prefix, entry in getattr(
-            update, "unicast_routes_to_update", {}
-        ).items():
-            best = entry.best_prefix_entry
-            if best is None or prefix in own_prefixes:
-                # a prefix we originate ourselves is never redistributed;
-                # drop any redistribution recorded before it became ours
-                changed |= self._redistributed.pop(prefix, None) is not None
-                continue
-            new_stack = tuple(best.area_stack)
-            if entry.best_area and entry.best_area not in new_stack:
-                new_stack = new_stack + (entry.best_area,)
-            targets = tuple(a for a in self._areas if a not in new_stack)
-            if not targets:
-                changed |= self._redistributed.pop(prefix, None) is not None
-                continue
-            redist = PrefixEntry(
-                prefix=prefix,
-                type=PrefixType.RIB,
-                forwarding_type=best.forwarding_type,
-                forwarding_algorithm=best.forwarding_algorithm,
-                min_nexthop=best.min_nexthop,
-                # bump distance so the re-originated copy always loses
-                # best-route selection to the original — without this,
-                # two border routers' identical-metric copies can tie
-                # with the source and oscillate advertise/withdraw
-                metrics=replace(
-                    best.metrics, distance=best.metrics.distance + 1
-                ),
-                tags=best.tags,
-                area_stack=new_stack,
+        for prefix, entry in to_update.items():
+            redist = (
+                None
+                if prefix in own_prefixes
+                else self._redistribution_of(prefix, entry)
             )
-            if self._redistributed.get(prefix) != (redist, targets):
-                self._redistributed[prefix] = (redist, targets)
-                changed = True
-        for prefix in getattr(update, "unicast_routes_to_delete", []):
-            changed |= self._redistributed.pop(prefix, None) is not None
-        if changed:
-            self._update_kvstore()
+            # a prefix we originate ourselves, or whose best entry has
+            # crossed every area of ours, is not redistributed: drop
+            # what was recorded before
+            if self._redistributed.get(prefix) != redist:
+                if redist is None:
+                    del self._redistributed[prefix]
+                else:
+                    self._redistributed[prefix] = redist
+                touched.add(prefix)
+        for prefix in to_delete:
+            if self._redistributed.pop(prefix, None) is not None:
+                touched.add(prefix)
+        keys_set, keys_cleared = (
+            self._sync_kvstore(touched) if touched else (0, 0)
+        )
+        registry = get_registry()
+        registry.counter_bump("prefixmgr.redistribute_runs")
+        registry.counter_bump("prefixmgr.redistributed_keys", keys_set)
+        registry.counter_bump("prefixmgr.withdrawn_keys", keys_cleared)
+        registry.counter_bump(
+            "prefixmgr.kvstore_calls", keys_set + keys_cleared
+        )
+        trace = getattr(update, "trace", None)
+        if trace is not None:
+            # the trace is Fib's by now, on its own thread: recorded
+            # after the fact, closed, beside whatever Fib has open
+            trace.closed_span(
+                "prefixmgr.redistribute",
+                start,
+                (time.perf_counter() - start[1]) * 1e3,
+                depth=0,
+                routes=len(to_update) + len(to_delete),
+                keys_set=keys_set,
+                keys_cleared=keys_cleared,
+            )
+
+    def _redistribution_of(
+        self, prefix: IpPrefix, entry
+    ) -> Optional[Tuple[PrefixEntry, Tuple[str, ...]]]:
+        """The ``RIB`` entry a best route is re-originated as, and the
+        areas it goes to; None where it goes nowhere."""
+        best = entry.best_prefix_entry
+        if best is None:
+            return None
+        new_stack = tuple(best.area_stack)
+        if entry.best_area and entry.best_area not in new_stack:
+            new_stack = new_stack + (entry.best_area,)
+        targets = tuple(a for a in self._areas if a not in new_stack)
+        if not targets:
+            return None
+        redist = PrefixEntry(
+            prefix=prefix,
+            type=PrefixType.RIB,
+            forwarding_type=best.forwarding_type,
+            forwarding_algorithm=best.forwarding_algorithm,
+            min_nexthop=best.min_nexthop,
+            # bump distance so the re-originated copy always loses
+            # best-route selection to the original — without this,
+            # two border routers' identical-metric copies can tie
+            # with the source and oscillate advertise/withdraw
+            metrics=replace(
+                best.metrics, distance=best.metrics.distance + 1
+            ),
+            tags=best.tags,
+            area_stack=new_stack,
+        )
+        return (redist, targets)
 
     # -- public API (thread-safe) -----------------------------------------
 
@@ -202,21 +251,25 @@ class PrefixManager:
         """reference: PrefixManager.cpp advertisePrefixesImpl."""
         for entry in entries:
             self._record_own(entry)
-        self._update_kvstore()
+        self._sync_kvstore({e.prefix for e in entries})
 
     def _withdraw(self, prefixes: List[IpPrefix]) -> None:
-        for key in [k for k in self._prefixes if k[1] in set(prefixes)]:
+        gone = set(prefixes)
+        for key in [k for k in self._prefixes if k[1] in gone]:
             del self._prefixes[key]
-        self._update_kvstore()
+        self._sync_kvstore(gone)
 
     def _sync_by_type(
         self, prefix_type: PrefixType, entries: List[PrefixEntry]
     ) -> None:
-        for key in [k for k in self._prefixes if k[0] == prefix_type]:
+        old = [k for k in self._prefixes if k[0] == prefix_type]
+        for key in old:
             del self._prefixes[key]
         for entry in entries:
             self._record_own(replace(entry, type=prefix_type))
-        self._update_kvstore()
+        self._sync_kvstore(
+            {p for _, p in old} | {e.prefix for e in entries}
+        )
 
     def _best_own_entries(self) -> Dict[IpPrefix, PrefixEntry]:
         """One advertisement per prefix: the best-metrics entry among the
@@ -231,63 +284,76 @@ class PrefixManager:
                 best[prefix] = (rank, entry)
         return {p: e for p, (_, e) in best.items()}
 
-    def _update_kvstore(self) -> None:
-        # (area, key) -> payload; keys repeat across areas in full-db mode
-        wanted: Dict[Tuple[str, str], bytes] = {}
+    def _sync_kvstore(self, prefixes: Iterable[IpPrefix]) -> Tuple[int, int]:
+        """Bring the keys of ``prefixes`` in every area to what this
+        node owes KvStore for them: its best own entry, else the
+        redistributed one where the area is a target, else nothing (the
+        key is cleared with a delete marker, so that other Decisions
+        drop the entry). Returns how many keys were set and how many
+        cleared."""
         own = self._best_own_entries()
-        for area in self._areas:
-            redist = {
-                p: e
-                for p, (e, targets) in self._redistributed.items()
-                if area in targets and p not in own
-            }
-            if self._per_prefix_keys:
-                for prefix, entry in {**own, **redist}.items():
-                    key = keyutil.per_prefix_key(
-                        self.my_node_name, area, prefix
+        if not self._per_prefix_keys:
+            return (self._sync_full_db(own), 0)
+        keys_set = keys_cleared = 0
+        for prefix in prefixes:
+            entry, targets = (
+                (own[prefix], self._areas)
+                if prefix in own
+                else self._redistributed.get(prefix, (None, ()))
+            )
+            for area in self._areas:
+                key = keyutil.per_prefix_key(
+                    self.my_node_name, area, prefix
+                )
+                if area in targets:
+                    payload = wire.dumps(
+                        PrefixDatabase(
+                            this_node_name=self.my_node_name,
+                            prefix_entries=(entry,),
+                            area=area,
+                        )
                     )
-                    db = PrefixDatabase(
+                    if self._advertised.get((area, key)) != payload:
+                        self._client.persist_key(area, key, payload)
+                        self._advertised[(area, key)] = payload
+                        keys_set += 1
+                elif self._advertised.pop((area, key), None) is not None:
+                    delete_db = PrefixDatabase(
                         this_node_name=self.my_node_name,
-                        prefix_entries=(entry,),
+                        prefix_entries=(PrefixEntry(prefix=prefix),),
+                        delete_prefix=True,
                         area=area,
                     )
-                    wanted[(area, key)] = wire.dumps(db)
-            else:
-                key = keyutil.prefix_db_key(self.my_node_name)
-                db = PrefixDatabase(
+                    self._client.clear_key(
+                        area,
+                        key,
+                        wire.dumps(delete_db),
+                        ttl=KVSTORE_TOMBSTONE_TTL_MS,
+                    )
+                    keys_cleared += 1
+        return (keys_set, keys_cleared)
+
+    def _sync_full_db(self, own: Dict[IpPrefix, PrefixEntry]) -> int:
+        """Full-db mode: one key an area holds every entry, so any
+        change rewrites it."""
+        key = keyutil.prefix_db_key(self.my_node_name)
+        keys_set = 0
+        for area in self._areas:
+            entries = dict(own)
+            for prefix, (entry, targets) in self._redistributed.items():
+                if area in targets and prefix not in own:
+                    entries[prefix] = entry
+            payload = wire.dumps(
+                PrefixDatabase(
                     this_node_name=self.my_node_name,
                     prefix_entries=tuple(
-                        e
-                        for _, e in sorted(
-                            {**own, **redist}.items(),
-                            key=lambda kv: kv[0],
-                        )
+                        e for _, e in sorted(entries.items())
                     ),
                     area=area,
                 )
-                wanted[(area, key)] = wire.dumps(db)
-
-        # withdraw keys that are no longer advertised: flood the delete
-        # marker so other Decisions drop the entries
-        for area, key in list(self._advertised_keys):
-            if (area, key) not in wanted:
-                parsed = keyutil.parse_per_prefix_key(key)
-                delete_db = PrefixDatabase(
-                    this_node_name=self.my_node_name,
-                    prefix_entries=(
-                        (PrefixEntry(prefix=parsed[2]),) if parsed else ()
-                    ),
-                    delete_prefix=True,
-                    area=area,
-                )
-                self._client.clear_key(
-                    area,
-                    key,
-                    wire.dumps(delete_db),
-                    ttl=KVSTORE_TOMBSTONE_TTL_MS,
-                )
-                self._advertised_keys.discard((area, key))
-
-        for (area, key), payload in wanted.items():
-            self._client.persist_key(area, key, payload)
-            self._advertised_keys.add((area, key))
+            )
+            if self._advertised.get((area, key)) != payload:
+                self._client.persist_key(area, key, payload)
+                self._advertised[(area, key)] = payload
+                keys_set += 1
+        return keys_set

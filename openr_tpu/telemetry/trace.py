@@ -186,13 +186,20 @@ class Trace:
 
     def closed_span(
         self, name: str, start: Tuple[float, float], dur_ms: float,
-        **attrs: Any
+        depth: Optional[int] = None, **attrs: Any
     ) -> Span:
         """A span recorded after the fact, closed: ``start`` on both
         clocks (``Span.mark_at``, ``end_mark``) and its length. It nests
-        in whatever is open; it is not a hand-off, so the next
-        ``gap_span`` still starts where the last live span closed."""
-        span = Span(name, depth=len(self._stack), start=start)
+        in whatever is open, unless ``depth`` places it: a stretch that
+        another thread worked beside the trace's owner (PrefixManager's
+        redistribution beside Fib) is inside nothing the owner has
+        open. It is not a hand-off, so the next ``gap_span`` still
+        starts where the last live span closed."""
+        span = Span(
+            name,
+            depth=len(self._stack) if depth is None else depth,
+            start=start,
+        )
         span.dur_ms = max(0.0, dur_ms)
         span.attrs.update(attrs)
         self.spans.append(span)

@@ -45,6 +45,11 @@ def test_every_leg_passes_at_tiny_sizes(artefacts, monkeypatch, capsys):
         ("ksp2", lambda: chip_smoke.leg_ksp2(64, events=2)),
         # 100 nodes, 18 hops from the corner: past the old hop gate
         ("ksp2_grid", lambda: chip_smoke.leg_ksp2_grid(10, events=2)),
+        # two areas of a 22-node fabric joined by two borders
+        ("multiarea", lambda: chip_smoke.leg_multiarea(
+            topology={"pods": 3, "ssw_per_plane": 2, "fsw_per_pod": 2,
+                      "rsw_per_pod": 4}, timeout_s=60.0,
+        )),
         # 12 rounds, not 3: at these sizes a client process is done in
         # well under the time the other takes to spawn on a loaded
         # machine (six xdist workers), and then no request ever arrives
@@ -75,8 +80,11 @@ def test_every_leg_passes_at_tiny_sizes(artefacts, monkeypatch, capsys):
     assert not any(summary["fallback_counters"].values())
     assert all(summary["mechanism_counters"].values())
     assert summary["legs"]["ksp2_grid"]["hops_from_root"] == 18
+    multiarea = summary["legs"]["multiarea"]
+    assert (multiarea["nodes_per_area"], multiarea["routes"],
+            multiarea["reoriginated_keys"]) == (22, 41, 41)
     for leg in ("pipeline_dense", "pipeline_sparse", "ksp2", "ksp2_grid",
-                "serve"):
+                "multiarea", "serve"):
         assert summary["legs"][leg]["parity"] is True
     kernels = summary["legs"]["kernels"]["kernels"]
     assert len(kernels) == 3

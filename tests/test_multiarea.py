@@ -311,3 +311,203 @@ class TestMultiAreaSystem:
         assert wait_until(lambda: self.has_route(net["c"], a_pfx))
         net["a"].prefix_manager.withdraw_prefixes([a_pfx])
         assert wait_until(lambda: not self.has_route(net["c"], a_pfx))
+
+
+class TestRedistributionIsADelta:
+    """A route update touches only the keys of the prefixes it names;
+    what KvStore holds afterwards is what a sync of the whole table
+    would have left."""
+
+    AREAS = ["1", "2", "3"]
+
+    def make_node(self, name="border"):
+        from openr_tpu.kvstore.client import KvStoreClient
+        from openr_tpu.kvstore.store import KvStore
+        from openr_tpu.utils.eventbase import OpenrEventBase
+
+        store = KvStore(node_id=name, areas=self.AREAS)
+        evb = OpenrEventBase(name=f"kvclient:{name}")
+        client = KvStoreClient(evb, name, store)
+        q = ReplicateQueue(name="routeUpdates")
+        pm = PrefixManager(
+            name, client, decision_route_updates_queue=q, areas=self.AREAS
+        )
+        store.start()
+        evb.run_in_thread()
+        pm.start()
+
+        def stop():
+            pm.stop()
+            client.stop()
+            evb.stop()
+            evb.join()
+            store.stop()
+
+        return pm, q, store, stop
+
+    @staticmethod
+    def entry(prefix, best_area, stack=(), distance=0):
+        return RibUnicastEntry(
+            prefix=prefix,
+            best_prefix_entry=PrefixEntry(
+                prefix=prefix,
+                metrics=PrefixMetrics(path_preference=700, distance=distance),
+                area_stack=stack,
+            ),
+            best_area=best_area,
+        )
+
+    def live_keys(self, store):
+        """area -> {key: the entry it holds}; a tombstone is absent."""
+        from openr_tpu.types import KeyDumpParams, PrefixDatabase
+        from openr_tpu.utils import wire
+
+        out = {}
+        for area in self.AREAS:
+            pub = store.dump_with_filters(area, KeyDumpParams(prefix="prefix:"))
+            out[area] = {}
+            for key, value in pub.key_vals.items():
+                db = wire.loads(value.value, PrefixDatabase)
+                if not db.delete_prefix:
+                    (out[area][key],) = db.prefix_entries
+        return out
+
+    @staticmethod
+    def redistribute(q, updates):
+        """Push, and wait until each update has been redistributed (one
+        PrefixManager works at a time here, and each update it reads is
+        one ``prefixmgr.redistribute_runs``)."""
+        from openr_tpu.telemetry import get_registry
+
+        reg = get_registry()
+        done = reg.counter_get("prefixmgr.redistribute_runs") + len(updates)
+        for update in updates:
+            q.push(update)
+        assert wait_until(
+            lambda: reg.counter_get("prefixmgr.redistribute_runs") == done
+        )
+
+    @pytest.mark.parametrize("seed", [1, 7, 2300000011])
+    def test_a_stream_of_updates_leaves_what_a_whole_sync_leaves(self, seed):
+        import random
+
+        rng = random.Random(seed)
+        prefixes = [IpPrefix.from_str(f"fd00:{i:x}::/64") for i in range(40)]
+        own = prefixes[0]
+        pm, q, store, stop = self.make_node()
+        fresh, fresh_q, fresh_store, fresh_stop = self.make_node()
+        try:
+            rib, updates = {}, []
+            for _ in range(60):
+                update = DecisionRouteUpdate()
+                for prefix in rng.sample(prefixes[1:], rng.randint(1, 6)):
+                    if rng.random() < 0.3 and prefix in rib:
+                        del rib[prefix]
+                        update.unicast_routes_to_delete.append(prefix)
+                        continue
+                    area = rng.choice(self.AREAS)
+                    others = [a for a in self.AREAS if a != area]
+                    # now and then a best entry that has crossed areas
+                    # already: one, or every one there is
+                    stack = rng.choice(
+                        [(), (), (others[0],), tuple(others)])
+                    rib[prefix] = self.entry(
+                        prefix, area, stack, distance=len(stack))
+                    update.unicast_routes_to_update[prefix] = rib[prefix]
+                updates.append(update)
+            # half way, a prefix that was redistributed becomes the
+            # node's own; Decision goes on naming it now and then
+            learned = DecisionRouteUpdate(
+                unicast_routes_to_update={own: self.entry(own, "1")})
+            self.redistribute(q, updates[:30] + [learned])
+            assert own in pm.get_redistributed()
+            pm.advertise_prefixes([PrefixEntry(
+                prefix=own, type=PrefixType.LOOPBACK)])
+            self.redistribute(q, updates[30:45] + [learned] + updates[45:])
+            # a fresh node: its own prefix, then the whole RIB at once
+            fresh.advertise_prefixes([PrefixEntry(
+                prefix=own, type=PrefixType.LOOPBACK)])
+            rib[own] = self.entry(own, "1")
+            self.redistribute(fresh_q, [DecisionRouteUpdate(
+                unicast_routes_to_update=dict(rib))])
+            assert pm.get_redistributed() == fresh.get_redistributed()
+            assert own not in pm.get_redistributed()
+            held, want = self.live_keys(store), self.live_keys(fresh_store)
+            assert held == want
+            assert sum(map(len, want.values())) > 20
+            # never into an area on the entry's stack, nor its own
+            for area, keys in held.items():
+                for e in keys.values():
+                    assert area not in e.area_stack
+                    if e.type == PrefixType.RIB:
+                        assert e.metrics.distance == len(e.area_stack)
+                assert any(k.endswith(f"[{own.to_str()}]") for k in keys)
+        finally:
+            stop()
+            fresh_stop()
+
+    def test_a_one_prefix_update_costs_the_areas_not_the_table(self):
+        from openr_tpu.telemetry import get_registry
+
+        pm, q, store, stop = self.make_node()
+        reg = get_registry()
+        try:
+            table = DecisionRouteUpdate()
+            for i in range(300):
+                p = IpPrefix.from_str(f"fd01:{i:x}::/64")
+                table.unicast_routes_to_update[p] = self.entry(p, "1")
+            self.redistribute(q, [table])
+            assert len(pm.get_redistributed()) == 300
+            calls0 = reg.counter_get("prefixmgr.kvstore_calls")
+            runs0 = reg.counter_get("prefixmgr.redistribute_runs")
+            assert calls0 >= 600  # 300 prefixes into two other areas
+
+            one = IpPrefix.from_str("fd02::/64")
+            self.redistribute(q, [DecisionRouteUpdate(
+                unicast_routes_to_update={one: self.entry(one, "2")})])
+            assert one in pm.get_redistributed()
+            assert reg.counter_get("prefixmgr.redistribute_runs") == runs0 + 1
+            assert reg.counter_get("prefixmgr.kvstore_calls") - calls0 \
+                == len(self.AREAS) - 1
+            # an update that changes no redistribution reaches no key
+            calls1 = reg.counter_get("prefixmgr.kvstore_calls")
+            self.redistribute(q, [table])
+            assert reg.counter_get("prefixmgr.kvstore_calls") == calls1
+            # the withdraw clears as many keys as the add set
+            gone = DecisionRouteUpdate()
+            gone.unicast_routes_to_delete.append(one)
+            withdrawn0 = reg.counter_get("prefixmgr.withdrawn_keys")
+            self.redistribute(q, [gone])
+            assert one not in pm.get_redistributed()
+            assert reg.counter_get("prefixmgr.withdrawn_keys") - withdrawn0 \
+                == len(self.AREAS) - 1
+            assert not any(
+                "fd02::" in k for keys in self.live_keys(store).values()
+                for k in keys)
+        finally:
+            stop()
+
+    def test_the_span_rides_the_updates_trace(self):
+        from openr_tpu.telemetry import get_tracer
+
+        pm, q, store, stop = self.make_node()
+        try:
+            trace = get_tracer().start("kvstore.publish")
+            held_open = trace.begin_span("fib.program")
+            p = IpPrefix.from_str("fd03::/64")
+            update = DecisionRouteUpdate(
+                unicast_routes_to_update={p: self.entry(p, "1")})
+            update.trace = trace
+            q.push(update)
+            assert wait_until(lambda: any(
+                s.name == "prefixmgr.redistribute" for s in trace.spans))
+            (span,) = [s for s in trace.spans
+                       if s.name == "prefixmgr.redistribute"]
+            assert span.closed and span.depth == 0
+            assert (span.attrs["routes"], span.attrs["keys_set"],
+                    span.attrs["keys_cleared"]) == (1, 2, 0)
+            # beside what Fib has open, never inside it
+            trace.end_span(held_open)
+            assert trace.well_formed()
+        finally:
+            stop()

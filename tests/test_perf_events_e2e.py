@@ -360,7 +360,11 @@ class TestSpanTreeEndToEnd:
                 "updated", "deleted", "identical", "compared"}
             # the route engine's accounting is not the rebuild's
             assert set(by_name["decision.rebuild"].attrs) == {
-                "full_rebuild", "routes_updated", "routes_deleted"}
+                "full_rebuild", "routes_updated", "routes_deleted",
+                "areas", "areas_moved"}
+            # one area, and the event moved its graph
+            assert by_name["decision.rebuild"].attrs["areas"] == 1
+            assert by_name["decision.rebuild"].attrs["areas_moved"] == 1
             after_adj = counters()
             assert after_adj["decision.device_solves"] == (
                 before["decision.device_solves"] + 1)

@@ -472,3 +472,158 @@ class TestSpRouteReuse:
         dev.build_route_db(root, area_d, ps)
         dev.build_route_db(root, area_d, ps)
         assert SPF_COUNTERS["decision.sp_route_reuses"] == before
+
+
+class TestTwoAreaLabelRoutesPatch:
+    """Node-label routes of a border's two areas are patched in
+    O(dirty), as one area's are (``_patch_node_label_routes``): since
+    PR 41 the full loop, one derivation a node of either area, no
+    longer runs in every build of a two-area Decision."""
+
+    @staticmethod
+    def _world():
+        """Two 22-node fabrics that share two borders, each in an RSW's
+        place in both (the shape of ``multi-area-2x1000``)."""
+        area_ls, ps = {}, PrefixState()
+        label = 100
+        for area in ("A", "B"):
+            topo = topologies.fat_tree(
+                3, 2, 2, 4, area=area,
+                forwarding_algorithm=PrefixForwardingAlgorithm.SP_ECMP,
+                forwarding_type=PrefixForwardingType.SR_MPLS,
+            )
+            rename = {"rsw-0-0": "border-0", "rsw-1-0": "border-1"}
+
+            def name(n):
+                return rename.get(n, f"{area.lower()}-{n}")
+
+            ls = area_ls[area] = LinkState(area=area)
+            for n in sorted(topo.adj_dbs):
+                db = topo.adj_dbs[n]
+                label += 1
+                ls.update_adjacency_database(replace(
+                    db,
+                    this_node_name=name(n),
+                    # a border shows one label in both areas
+                    node_label=(
+                        50 + int(n[4]) if n in rename else label
+                    ),
+                    adjacencies=tuple(
+                        replace(
+                            a,
+                            other_node_name=name(a.other_node_name),
+                            if_name=f"if_{name(n)}_{name(a.other_node_name)}",
+                            other_if_name=(
+                                f"if_{name(a.other_node_name)}_{name(n)}"
+                            ),
+                        )
+                        for a in db.adjacencies
+                    ),
+                ))
+            for n, pdb in topo.prefix_dbs.items():
+                if n in rename and area == "B":
+                    continue  # the same loopback: advertised once
+                ps.update_prefix_database(
+                    replace(pdb, this_node_name=name(n))
+                )
+        return area_ls, ps
+
+    def test_random_churn_in_both_areas_matches_a_fresh_host_solver(
+        self, monkeypatch
+    ):
+        import random
+
+        area_d, ps = self._world()
+        area_h, ps_h = self._world()
+        root = "border-0"
+        dev = SpfSolver(root, backend="device")
+        derived = []
+        derive = SpfSolver._derive_label_entry
+        monkeypatch.setattr(
+            SpfSolver, "_derive_label_entry",
+            lambda self, me, node, *a: (
+                derived.append(node) if self is dev else None,
+                derive(self, me, node, *a),
+            )[1],
+        )
+
+        def check(step):
+            d = dev.build_route_db(root, area_d, ps)
+            h = SpfSolver(root, backend="host").build_route_db(
+                root, area_h, ps_h
+            )
+            assert d.to_route_db(root) == h.to_route_db(root), step
+            assert len(d.mpls_routes) >= 40
+
+        check("cold")
+        nodes = len(derived)
+        assert nodes >= 42  # the full loop: every node of either area
+        del derived[:]
+        check("warm")
+        assert not derived  # nothing dirty, nothing derived
+        rng = random.Random(2300000011)
+        dropped = []
+        patched = 0
+        for step in range(80):
+            area = rng.choice(["A", "B"])
+            names = sorted(area_d[area].get_adjacency_databases())
+            node = rng.choice(names)
+            roll = rng.random()
+            db = area_d[area].get_adjacency_databases()[node]
+            both = (area_d[area], area_h[area])
+            if roll < 0.55:
+                for ls in both:
+                    _mutate_metric(
+                        ls, node, step % len(db.adjacencies), 1 + step % 7
+                    )
+            elif roll < 0.7:
+                if len(db.adjacencies) > 1 and node != root:
+                    for ls in both:
+                        adj = _drop_adj(ls, node, 0)
+                    dropped.append((area, node, adj))
+            elif roll < 0.8:
+                if dropped:
+                    a, n, adj = dropped.pop(0)
+                    _restore_adj(area_d[a], n, adj)
+                    _restore_adj(area_h[a], n, adj)
+            elif roll < 0.9:
+                if not node.startswith("border"):
+                    for ls in both:
+                        ls.update_adjacency_database(replace(
+                            ls.get_adjacency_databases()[node],
+                            node_label=7000 + step,
+                        ))
+            else:
+                for ls in both:
+                    cur = ls.get_adjacency_databases()[node]
+                    ls.update_adjacency_database(replace(
+                        cur, is_overloaded=not cur.is_overloaded
+                    ))
+            del derived[:]
+            check(step)
+            patched += len(derived) < nodes
+        # nearly every build re-derived the few nodes the dirty test
+        # named; the rest (a re-indexed graph, a neighbour set that
+        # changed) fell back to the loop, and agreed all the same
+        assert patched >= 60
+
+    def test_a_border_with_another_label_in_each_area_takes_the_loop(self):
+        area_d, ps = self._world()
+        area_h, ps_h = self._world()
+        root = "border-0"
+        for ls in (area_d["B"], area_h["B"]):
+            ls.update_adjacency_database(replace(
+                ls.get_adjacency_databases()["border-1"], node_label=777
+            ))
+        dev = SpfSolver(root, backend="device")
+        for step in range(4):
+            for ls in (area_d["A"], area_h["A"]):
+                _mutate_metric(ls, "a-fsw-2-0", 0, 2 + step)
+            d = dev.build_route_db(root, area_d, ps)
+            h = SpfSolver(root, backend="host").build_route_db(
+                root, area_h, ps_h
+            )
+            assert d.to_route_db(root) == h.to_route_db(root), step
+            # both of the border's labels have a route
+            assert {51, 777} <= set(d.mpls_routes)
+            assert root not in dev._label_state
