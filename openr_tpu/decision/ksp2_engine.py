@@ -51,7 +51,7 @@ device round trip and O(changed) host work.
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Set, Tuple
+from typing import Dict, FrozenSet, List, NamedTuple, Optional, Set, Tuple
 
 import numpy as np
 
@@ -231,7 +231,8 @@ def make_cands_of(ls: LinkState, node_index: Dict[str, int]):
 # one instance per engine, built and patched inside that engine's
 # sync: the engine's own "owner" confinement covers it
 @thread_confined(
-    "owner", "_rows", "blocked", "link", "off", "uid", "w",
+    "owner", "_blocked_names", "_rows", "blocked", "link", "off", "uid",
+    "w",
 )
 class _TraceArrays:
     """Int-encoded view of the candidate structure for the native batch
@@ -242,12 +243,14 @@ class _TraceArrays:
     the nodes the LinkState journals name are re-derived (building it
     whole sorts every node's links, O(E log E) of Python per event,
     which at 1016 nodes was the largest single item of an incremental
-    sync). Shared by every trace site of an event; the Python tracer
-    remains the fallback and the semantic reference."""
+    sync) and written into the flat CSR where they lie (flattening it
+    anew concatenates every node's row to move two). Shared by every
+    trace site of an event; the Python tracer remains the fallback and
+    the semantic reference."""
 
     __slots__ = (
         "off", "link", "uid", "w", "links", "lid_of", "blocked",
-        "n_pad", "index", "_rows", "_excl_ids",
+        "n_pad", "index", "_rows", "_excl_ids", "_blocked_names",
     )
 
     def __init__(self, graph, cands_of, transit_blocked):
@@ -266,6 +269,7 @@ class _TraceArrays:
             self._row(cands_of(v)) for v in graph.node_names
         ]
         self._flatten()
+        self._blocked_names = None
         self.set_blocked(transit_blocked)
 
     def _row(self, cands):
@@ -306,32 +310,60 @@ class _TraceArrays:
         in-links, in canonical order."""
         return self._rows[node_id]
 
-    def patch(self, cands_of, dirty, transit_blocked) -> List[Tuple]:
+    def patch(
+        self, cands_of, dirty, transit_blocked
+    ) -> Tuple[List[Tuple], int, int]:
         """Re-derive the rows of the ``dirty`` nodes (names) — a changed
-        adjacency moves the candidate rows of its two ends only.
-        Returns (node id, what moved) for the nodes whose candidates
-        are not, place for place, the origins they were: ``(place, +1)``
+        adjacency moves the candidate rows of its two ends only — and
+        put each into the flat CSR where the old one lies: written over
+        it where it is as long (a metric change), spliced in, with the
+        offsets behind it moved, where it is not (a flap). Returns
+        first (node id, what moved) for the nodes whose candidates are
+        not, place for place, the origins they were: ``(place, +1)``
         where one link came in at ``place``, ``(place, -1)`` where the
-        one at ``place`` went, None where more than that changed."""
+        one at ``place`` went, None where more than that changed; then
+        how many rows were written in place and how many spliced."""
         reordered = []
+        written = spliced = 0
+        off = self.off
         for name in dirty:
             i = self.index.get(name)
-            if i is not None:
-                row = self._row(cands_of(name))
-                if not np.array_equal(row[1], self._rows[i][1]):
-                    reordered.append((i, _one_moved(self._rows[i][1], row[1])))
-                self._rows[i] = row
-        self._flatten()
+            if i is None:
+                continue
+            row = self._row(cands_of(name))
+            if not np.array_equal(row[1], self._rows[i][1]):
+                reordered.append((i, _one_moved(self._rows[i][1], row[1])))
+            self._rows[i] = row
+            start, end = int(off[i]), int(off[i + 1])
+            grew = len(row[0]) - (end - start)
+            for flat, part in zip(("link", "uid", "w"), row):
+                was = getattr(self, flat)
+                if grew == 0:
+                    was[start:end] = part
+                else:
+                    setattr(self, flat, np.concatenate(
+                        (was[:start], part, was[end:])
+                    ))
+            if grew == 0:
+                written += 1
+            else:
+                off[i + 1 :] += grew
+                spliced += 1
         self.set_blocked(transit_blocked)
-        return reordered
+        return reordered, written, spliced
 
     def set_blocked(self, transit_blocked) -> None:
+        """The transit-blocked bitmap, rewritten where the set of
+        drained nodes is not the one it was written from."""
+        if transit_blocked == self._blocked_names:
+            return
         blocked = np.zeros(self.n_pad, np.uint8)
         for nm in transit_blocked:
             bi = self.index.get(nm)
             if bi is not None:
                 blocked[bi] = 1
         self.blocked = blocked
+        self._blocked_names = frozenset(transit_blocked)
 
     def _excl_arrays(self, excls):
         """Per-dst exclusion ranges; a link absent from the current
@@ -441,6 +473,16 @@ def _masked_buckets(chunk: int) -> Tuple[int, ...]:
     return tuple(sorted({max(8, chunk // 16), max(8, chunk // 2), chunk}))
 
 
+class _ViewBatch(NamedTuple):
+    """The root's view batch as an engine holds it across syncs."""
+
+    index: Dict[str, int]  # the graph's node index it was derived under
+    near: FrozenSet[str]  # the root and its up-neighbours, by name
+    srcs: List[int]  # their ids, padded: ell_source_batch
+    srcs_dev: object  # ... on the device
+    w_sv_dev: object  # the root's direct metrics to them, on the device
+
+
 def _pad_ids(ids: List[int], bucket_min: int = 8) -> np.ndarray:
     """Pad an id list to a power-of-two bucket by repeating the first id
     (inert for row gathers) so jit shapes stay bounded."""
@@ -466,20 +508,26 @@ def _pad_ids(ids: List[int], bucket_min: int = 8) -> np.ndarray:
 # hence "owner" confinement (same contract as WorldManager).
 @thread_confined(
     "owner",
+    "_blocked",
     "_carried",
+    "_journal_read",
     "_link_slots",
     "_masked_warm",
     "_slots_seen",
     "_mesh",
     "_mesh_knob",
+    "_primed",
     "_tarrays",
+    "_view",
     "attr_sig",
     "aversion",
     "band_shapes",
     "d_base",
     "d_prev_dev",
     "dm",
+    "dst_ids",
     "dst_pos",
+    "dst_rows",
     "dsts",
     "eff_w",
     "excl",
@@ -535,6 +583,25 @@ class Ksp2Engine:
         # ((topology, attributes) versions, _TraceArrays) of the last
         # trace; None until one ran on the native core
         self._tarrays = None
+        # ((versions from, versions to), nodes): the last reading of
+        # the LinkState journals, which the sync and the trace arrays
+        # it patches both ask for
+        self._journal_read = None
+        self._drop_held()
+
+    def _drop_held(self) -> None:
+        """Forget the tables derived from the WHOLE graph that a warm
+        sync patches from the window's change instead of deriving them
+        again: a cold build makes them anew, and an engine that is not
+        valid holds none."""
+        # {(root, destination, rank): paths}: what _prime_all hands
+        # LinkState's kth-path cache, both ranks of every destination
+        # the host does not answer for
+        self._primed: Dict[Tuple[str, str, int], List[List[Link]]] = {}
+        # the drained nodes no path may run through (never the root),
+        # as self.ov has them
+        self._blocked: Optional[Set[str]] = None
+        self._view: Optional[_ViewBatch] = None
 
     # -- public entry ------------------------------------------------------
 
@@ -607,6 +674,7 @@ class Ksp2Engine:
         self.staged = False
         # a dispatch that raised may have consumed the donated buffer
         self.d_prev_dev = None
+        self._drop_held()
 
     def _fits(self, state, dsts: List[str]) -> bool:
         """The engine was built for this resident state, root and
@@ -628,12 +696,12 @@ class Ksp2Engine:
     def _sync_window(
         self, ls: LinkState, state, dsts: List[str], span=None
     ) -> Optional[Set[str]]:
-        affected_nodes = ls.affected_since(self.version)
-        attr_nodes = ls.attr_affected_since(self.aversion)
-        if affected_nodes is None or attr_nodes is None:
+        affected_nodes = self._journal_nodes(
+            ls, (self.version, self.aversion)
+        )
+        if affected_nodes is None:
             self._cold_build(ls, state, dsts)
             return None
-        affected_nodes = set(affected_nodes) | set(attr_nodes)
         changed = self._diff_pairs(ls, affected_nodes)
         if span is not None and changed is not None:
             span.attrs["changed_pairs"] = len(changed)
@@ -665,6 +733,15 @@ class Ksp2Engine:
         if len(changed) > ENGINE_MAX_CHANGED_PAIRS:
             self._cold_build(ls, state, dsts)
             return None
+        # the overload map and the transit-blocked set as they are NOW:
+        # the ones held, with the window's flips applied (the root's
+        # own flip went to a cold build above)
+        ov_new, blocked = self.ov, self._blocked
+        if ov_flips:
+            ov_new, blocked = dict(ov_new), set(blocked)
+            for x in ov_flips:
+                ov_new[x] = ls.is_node_overloaded(x)
+                (blocked.add if ov_new[x] else blocked.discard)(x)
 
         graph = state.graph
         ep = sorted(
@@ -680,8 +757,12 @@ class Ksp2Engine:
         # one fused dispatch: all-pairs + view + old/new endpoint rows
         from openr_tpu.ops import spf_sparse
 
-        view_srcs = spf_sparse.ell_source_batch(graph, ls, self.src_name)
-        srcs_dev, w_sv = spf_sparse._batch_args(graph, view_srcs)
+        view_srcs, srcs_dev, w_sv, view_reused = self._view_batch(
+            ls, graph, affected_nodes
+        )
+        counters = _counters()
+        patched_were = counters["decision.ksp2_trace_rows_patched"]
+        spliced_were = counters["decision.ksp2_trace_rows_spliced"]
         # padded to the limits checked above, as the cold build pads
         # its own: every sync of an engine runs ONE compiled shape of
         # the fused program, whatever the window carried
@@ -699,7 +780,7 @@ class Ksp2Engine:
             ]
             # both the single-chip and the sharded dispatches thread
             # the delta into the warm-seeded fixed point now
-            _counters()["decision.ksp2_warm_dispatches"] += 1
+            counters["decision.ksp2_warm_dispatches"] += 1
         # dispatch to readback of the one fused program: the all-pairs
         # fixed point, the view and the endpoint rows
         with get_tracer().span(
@@ -747,7 +828,7 @@ class Ksp2Engine:
 
         aff1, aff2, row_stands, rows_proven = self._affected_dsts(
             ls, graph, changed, d_new_src, rows_new, rows_old,
-            exact=not ov_flips,
+            ov_new, blocked, exact=not ov_flips,
         )
         dst_set = set(self.dst_pos)
         aff1 &= dst_set
@@ -771,7 +852,7 @@ class Ksp2Engine:
         if aff1 or aff2:
             moved = self._recompute(
                 ls, state, aff1, aff2, d_new_src, changed,
-                row_stands, rows_proven,
+                row_stands, rows_proven, blocked,
             )
             # of the destinations the tests named, those whose paths
             # came back as they were keep their routes
@@ -797,19 +878,74 @@ class Ksp2Engine:
                 self.attr_sig[pair] = sig_new
                 for end in pair:
                     self.pairs_by_node.setdefault(end, set()).add(pair)
-        for x in ov_flips:
-            self.ov[x] = ls.is_node_overloaded(x)
+        self.ov, self._blocked = ov_new, blocked
         for x in label_flips:
             db = ls.get_adjacency_databases().get(x)
             self.node_label[x] = db.node_label if db else 0
         self.d_base = d_new_src.astype(np.int32)
         self.version = ls.topology_version
         self.aversion = ls.attributes_version
-        _counters()["decision.ksp2_incremental_syncs"] += 1
-        _counters()["decision.ksp2_affected_dsts"] += len(affected)
+        counters["decision.ksp2_incremental_syncs"] += 1
+        counters["decision.ksp2_affected_dsts"] += len(affected)
+        if span is not None:
+            # what following the window's change came to: candidate
+            # rows of the trace arrays written in place and spliced,
+            # and whether the held view batch went to the device as it
+            # was
+            span.attrs["trace_rows_patched"] = (
+                counters["decision.ksp2_trace_rows_patched"] - patched_were
+            )
+            span.attrs["trace_rows_spliced"] = (
+                counters["decision.ksp2_trace_rows_spliced"] - spliced_were
+            )
+            span.attrs["view_reused"] = int(view_reused)
         if self._carried is not None:
             self._carried |= affected
         return affected
+
+    def _journal_nodes(
+        self, ls: LinkState, since: Tuple[int, int]
+    ) -> Optional[Set[str]]:
+        """Nodes the LinkState's two journals name between ``since``
+        (topology, attributes versions) and now; None where they cannot
+        say. The sync and the trace arrays it patches start from the
+        same versions, so the second to ask gets the first's reading
+        (callers do not change the set)."""
+        stretch = (since, (ls.topology_version, ls.attributes_version))
+        if self._journal_read is None or self._journal_read[0] != stretch:
+            moved = ls.affected_since(since[0])
+            attrs = ls.attr_affected_since(since[1])
+            self._journal_read = (
+                stretch,
+                None if moved is None or attrs is None
+                else set(moved) | set(attrs),
+            )
+        return self._journal_read[1]
+
+    def _view_batch(self, ls: LinkState, graph, affected_nodes=None):
+        """The root's view batch (its id and its up-neighbours', padded)
+        and its direct metrics to them: ``(ids, ids on the device,
+        metrics on the device, reused)``. They change only with one of
+        the root's own links, whose two ends the journals then name;
+        a sync whose ``affected_nodes`` hold neither the root nor a
+        neighbour sends the held device arrays as they are."""
+        held = self._view
+        reused = (
+            held is not None
+            and affected_nodes is not None
+            and held.index is graph.node_index
+            and held.near.isdisjoint(affected_nodes)
+        )
+        if not reused:
+            from openr_tpu.ops import spf_sparse
+
+            srcs = spf_sparse.ell_source_batch(graph, ls, self.src_name)
+            held = self._view = _ViewBatch(
+                graph.node_index,
+                frozenset(graph.node_names[i] for i in srcs),
+                srcs, *spf_sparse._batch_args(graph, srcs),
+            )
+        return held.srcs, held.srcs_dev, held.w_sv_dev, reused
 
     # -- cold build --------------------------------------------------------
 
@@ -821,6 +957,7 @@ class Ksp2Engine:
 
         self.valid = False
         self._carried = None
+        self._drop_held()
         graph = state.graph
         self.state = state
         self.dsts = list(dsts)
@@ -836,12 +973,18 @@ class Ksp2Engine:
         if self.sid is None:
             return
         self.dst_pos = {d: i for i, d in enumerate(dsts)}
+        # the destinations' node ids and their rows of dm: fixed while
+        # _fits holds (a patched graph keeps its node index; a
+        # recompiled one is another resident state)
+        self.dst_ids = np.asarray(
+            [graph.node_index[d] for d in dsts], dtype=np.int64
+        )
+        self.dst_rows = np.arange(len(dsts))
         n = graph.n_pad
 
         # fused dispatch seeds the resident all-pairs matrix AND serves
         # the view; d_prev is a placeholder on the cold path
-        view_srcs = spf_sparse.ell_source_batch(graph, ls, self.src_name)
-        srcs_dev, w_sv = spf_sparse._batch_args(graph, view_srcs)
+        view_srcs, srcs_dev, w_sv, _ = self._view_batch(ls, graph)
         placeholder = getattr(self, "d_prev_dev", None)
         if placeholder is None or placeholder.shape != (n, n):
             if self._mesh is not None:
@@ -895,7 +1038,16 @@ class Ksp2Engine:
         # first paths traced from the device base row (identical to the
         # host get_kth_paths(.., 1) trace — same canonical order)
         cands_of = make_cands_of(ls, graph.node_index)
-        transit_blocked = self._transit_blocked(ls, graph)
+        self.ov = {
+            name: ls.is_node_overloaded(name)
+            for name in graph.node_names
+        }
+        # drained nodes: reachable, but no path runs through one (the
+        # root, drained or not, still originates)
+        transit_blocked = self._blocked = {
+            name for name, drained in self.ov.items()
+            if drained and name != self.src_name
+        }
         self.first_paths: Dict[str, List[List[Link]]] = {}
         self.second_paths: Dict[str, List[List[Link]]] = {}
         self.excl: Dict[str, Set[Link]] = {}
@@ -926,6 +1078,8 @@ class Ksp2Engine:
         self._solve_masked_batches(
             ls, state, dsts, cands_of, transit_blocked
         )
+        for dst in dsts:
+            self._note_paths(dst)
         self._prime_all(ls)
         warm_key = (self.band_shapes, n, self.sid)
         if self._mesh is None and self._masked_warm != warm_key:
@@ -950,10 +1104,6 @@ class Ksp2Engine:
         for pair in self.eff_w:
             self.pairs_by_node.setdefault(pair[0], set()).add(pair)
             self.pairs_by_node.setdefault(pair[1], set()).add(pair)
-        self.ov = {
-            name: ls.is_node_overloaded(name)
-            for name in graph.node_names
-        }
         self.node_label = {
             name: db.node_label
             for name, db in ls.get_adjacency_databases().items()
@@ -1092,13 +1242,17 @@ class Ksp2Engine:
         d_new_src: np.ndarray,
         rows_new: Dict[int, np.ndarray],
         rows_old: Dict[int, np.ndarray],
+        ov_new: Dict[str, bool],
+        blocked: Set[str],
         exact: bool = False,
     ) -> Tuple[Set[str], Set[str], Set[str], bool]:
         """Returns (first-path affected, masked/second-path affected,
         row_stands, rows_proven). The first two are split because the
         former invalidates the destination's MASKS (forcing a fresh
         masked solve) while the latter only needs the second paths
-        re-derived. ``exact``: narrow the second set with
+        re-derived. ``ov_new`` / ``blocked``: the overload map and the
+        transit-blocked set after the window (``self.ov`` holds the
+        map before it). ``exact``: narrow the second set with
         _second_paths_may_move.
         ``row_stands``: of the second set, the destinations whose
         masked row provably stands as long as their first paths, and
@@ -1108,9 +1262,7 @@ class Ksp2Engine:
         parallel links, no native tracer), the sync re-solves them all
         to keep the rows exact."""
         index = graph.node_index
-        dst_ids = np.asarray(
-            [index[d] for d in self.dsts], dtype=np.int64
-        )
+        dst_ids = self.dst_ids
         d_old_src = self.d_base.astype(np.int64)
         d_new = d_new_src  # already int64
         inf = np.int64(INF)
@@ -1123,7 +1275,7 @@ class Ksp2Engine:
         # int32 as held: widened a column at a time where sums are
         # taken (the whole matrix is 8 MB a sync to widen)
         dm = self.dm
-        dm_total = dm[np.arange(len(self.dsts)), dst_ids].astype(np.int64)
+        dm_total = dm[self.dst_rows, dst_ids].astype(np.int64)
 
         def eff(w, origin, ov_map):
             if w >= INF:
@@ -1132,9 +1284,6 @@ class Ksp2Engine:
                 return inf
             return np.int64(w)
 
-        ov_new = {
-            x: ls.is_node_overloaded(x) for x in graph.node_names
-        }
         # links (either direction) usable now that were not before
         appeared = len({
             frozenset((u, v))
@@ -1201,7 +1350,7 @@ class Ksp2Engine:
                     aff2_vec |= ~reachable_m & (dm_u < inf)
         if exact:
             verdict = self._second_paths_may_move(
-                ls, graph, changed, dm, ov_new, eff
+                ls, graph, changed, dm, ov_new, eff, blocked
             )
             if verdict is not None:
                 # a destination whose first paths move gets fresh masks
@@ -1225,7 +1374,7 @@ class Ksp2Engine:
         return aff1, aff2, row_stands, rows_proven
 
     def _second_paths_may_move(
-        self, ls, graph, changed, dm, ov_new, eff
+        self, ls, graph, changed, dm, ov_new, eff, blocked
     ) -> Optional[Tuple[np.ndarray, np.ndarray, List[Tuple]]]:
         """Two [D] bools: the destinations whose second-path trace can
         come out differently after the window's changes, first paths
@@ -1294,8 +1443,7 @@ class Ksp2Engine:
             return None
         was = {index[v]: cached[1].rows_of(index[v]) for (_u, v), _ in pairs}
         arrays = self._trace_arrays(
-            ls, graph, make_cands_of(ls, graph.node_index),
-            self._transit_blocked(ls, graph),
+            ls, graph, make_cands_of(ls, graph.node_index), blocked
         )
         if arrays is None:
             return None
@@ -1444,9 +1592,7 @@ class Ksp2Engine:
         for a, b in self.pairs_by_node.get(v, ()):
             near.add(index[b if a == v else a])
         near.discard(vid)
-        dst_ids = np.asarray(
-            [index[self.dsts[i]] for i in rows], dtype=np.int64
-        )
+        dst_ids = self.dst_ids[rows]
         unread = (dst_ids != vid) & (self.reach2[rows, vid] < 0)
         for sid in near:
             unread &= self.reach2[rows, sid] < 0
@@ -1523,15 +1669,6 @@ class Ksp2Engine:
                     cur = nxt
         return over
 
-    def _transit_blocked(self, ls: LinkState, graph) -> Set[str]:
-        """Drained nodes: reachable, but no path runs through one (the
-        root, drained or not, still originates)."""
-        return {
-            name
-            for name in graph.node_names
-            if ls.is_node_overloaded(name) and name != self.src_name
-        }
-
     def _set_first_paths(self, dst: str, paths: List[List[Link]]) -> None:
         """First paths, the exclusion set the masked solve takes from
         them, and the index of which destinations exclude a link."""
@@ -1548,6 +1685,7 @@ class Ksp2Engine:
         self, ls: LinkState, state, aff1: Set[str], aff2: Set[str],
         d_new_src: np.ndarray, changed,
         row_stands: Set[str], rows_proven: bool,
+        transit_blocked: Set[str],
     ) -> Set[str]:
         """Re-derive the paths of the destinations the membership tests
         named (``aff1``: first paths, ``aff2``: second) and return
@@ -1573,7 +1711,6 @@ class Ksp2Engine:
         named destination is re-solved as it always was."""
         graph = state.graph
         cands_of = make_cands_of(ls, graph.node_index)
-        transit_blocked = self._transit_blocked(ls, graph)
         named = sorted(aff1 | aff2)
         before = {
             dst: (self.first_paths.get(dst), self.second_paths.get(dst))
@@ -1658,6 +1795,8 @@ class Ksp2Engine:
             for path in now[0] + (now[1] or []):
                 for x in _path_nodes(self.src_name, path):
                     self.node_users.setdefault(x, set()).add(dst)
+        for dst in named:
+            self._note_paths(dst)
         return moved
 
     @staticmethod
@@ -1903,20 +2042,24 @@ class Ksp2Engine:
             return cached[1]
         dirty = None
         if cached is not None:
-            moved = ls.affected_since(cached[0][0])
-            attrs = ls.attr_affected_since(cached[0][1])
-            if moved is not None and attrs is not None:
-                dirty = set(moved) | set(attrs)
+            dirty = self._journal_nodes(ls, cached[0])
         reach2 = getattr(self, "reach2", None)
         if dirty is None:
+            if self._tarrays is not None:
+                # arrays were held and could not be patched: in a churn
+                # window this stays where the cold build left it
+                _counters()["decision.ksp2_trace_reflattens"] += 1
             arrays = _TraceArrays(graph, cands_of, transit_blocked)
             if reach2 is not None:
                 reach2[reach2 >= 0] = _ALL_CONSULTED
         else:
             arrays = cached[1]
-            for nid, moved in arrays.patch(
+            reordered, written, spliced = arrays.patch(
                 cands_of, dirty, transit_blocked
-            ):
+            )
+            _counters()["decision.ksp2_trace_rows_patched"] += written
+            _counters()["decision.ksp2_trace_rows_spliced"] += spliced
+            for nid, moved in reordered:
                 # reach2 holds places in the list as it was. Where one
                 # link came or went, what was read past its place
                 # shifts by one (a walk that read as far as the link
@@ -1993,16 +2136,26 @@ class Ksp2Engine:
 
     # -- priming / view preload -------------------------------------------
 
+    def _note_paths(self, dst: str) -> None:
+        """Bring the two entries ``dst`` has in the priming map to the
+        paths the engine holds of it now; none for a destination the
+        host answers for (LinkState computes those lazily, host SPF)."""
+        first, second = (self.src_name, dst, 1), (self.src_name, dst, 2)
+        if dst in self.host_dsts:
+            self._primed.pop(first, None)
+            self._primed.pop(second, None)
+        else:
+            self._primed[first] = self.first_paths[dst]
+            self._primed[second] = self.second_paths.get(dst, [])
+
     def _prime_all(self, ls: LinkState) -> None:
-        for dst in self.dsts:
-            if dst in self.host_dsts:
-                continue  # LinkState computes these lazily (host SPF)
-            ls.prime_kth_paths(
-                self.src_name, dst, 1, self.first_paths[dst]
-            )
-            ls.prime_kth_paths(
-                self.src_name, dst, 2, self.second_paths.get(dst, [])
-            )
+        """The sync's last step: both ranks of every destination into
+        LinkState's kth-path cache, which a topology change emptied
+        (the ctrl API and the host fallback read it). The map is kept
+        from sync to sync and patched where a window moved a
+        destination (_note_paths), so this is one bulk update and no
+        Python per destination."""
+        ls.prime_kth_paths_bulk(self._primed)
 
     def _preload_view(self, ls, graph, view_srcs, view_packed) -> None:
         from openr_tpu.decision import spf_solver as _ss

@@ -184,6 +184,13 @@ SPF_COUNTERS = _get_registry().counter_dict(
         "decision.ksp2_incremental_syncs",
         "decision.ksp2_warm_dispatches",
         "decision.ksp2_affected_dsts",
+        # the KSP2 engine's trace arrays under churn: candidate rows a
+        # sync wrote into the flat CSR in place (a metric change) and
+        # spliced in (a flap), and whole rebuilds of arrays an engine
+        # already held (0 once its cold build is behind it)
+        "decision.ksp2_trace_rows_patched",
+        "decision.ksp2_trace_rows_spliced",
+        "decision.ksp2_trace_reflattens",
         "decision.ksp2_route_reuses",
         # prefixes the route loop walked in the builds a KSP2 engine
         # answered for: against those builds' prefixes, how often the

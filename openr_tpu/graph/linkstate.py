@@ -485,9 +485,11 @@ class LinkState:
         if not journal or journal[0][0] > version + 1:
             return None  # history evicted: coverage unknown
         affected: Set[str] = set()
-        for v, nodes in journal:
+        # newest first, as far back as ``version``: versions ascend, so
+        # the walk is as long as what changed since, not as the journal
+        for v, nodes in reversed(journal):
             if v <= version:
-                continue
+                break
             if not nodes:
                 return None  # a change with unrecorded blast radius
             affected |= nodes
@@ -810,6 +812,14 @@ class LinkState:
         solver's device-batched masked-SPF KSP2 prefetch); entries are
         dropped with the cache on any topology change."""
         self._kth_path_cache[(src, dest, k)] = paths
+
+    def prime_kth_paths_bulk(
+        self, paths_of: Dict[Tuple[str, str, int], List[Path]]
+    ) -> None:
+        """prime_kth_paths for every ``(src, dest, k)`` of a mapping the
+        caller keeps (the KSP2 engine's, patched where a window moved a
+        destination): one C-level update, no Python per destination."""
+        self._kth_path_cache.update(paths_of)
 
     def parallel_pairs(self) -> Set[FrozenSet[str]]:
         """Node pairs connected by more than one (parallel) link."""

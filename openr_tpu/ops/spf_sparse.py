@@ -220,6 +220,10 @@ def _as_device_ids(src_ids) -> jnp.ndarray:
     """int32 device ids; a jax array passes through WITHOUT a host sync
     (chained-dispatch timing depends on ids staying on device)."""
     if isinstance(src_ids, jax.Array):
+        # one the caller holds as int32 (the KSP2 engine's view batch)
+        # is the argument as it is
+        if src_ids.dtype == jnp.int32:
+            return src_ids
         return src_ids.astype(jnp.int32)
     return jnp.asarray(np.asarray(src_ids, dtype=np.int32))
 
