@@ -304,6 +304,14 @@ class Decision:
         self.area_link_states: Dict[str, LinkState] = {}
         self.prefix_state = PrefixState()
         self.route_db = DecisionRouteDb()
+        # the handshake that lets a rebuild install what its build
+        # touched instead of diffing the table (_emit_update):
+        # (solver, seq) of the last build whose result route_db took IN
+        # FULL, every key of it then holding the solver's own object.
+        # None after anything else wrote route_db or nothing has; the
+        # reason is kept for the next whole diff's span
+        self._route_anchor: Optional[tuple] = None
+        self._anchor_lost = ""
         self.pending = DecisionPendingUpdates(my_node_name)
         self.fib_times: Dict[str, float] = {}
         self.rib_policy = None  # set via set_rib_policy
@@ -897,6 +905,11 @@ class Decision:
                     trace.end_span(
                         rebuild_span, routes_updated=-1, routes_deleted=-1
                     )
+            if payload is None:
+                # whatever the rungs did to the solver's table, none
+                # of it reached route_db
+                self._route_anchor = None
+                self._anchor_lost = "ladder_exhausted"
 
         self.pending.add_event("ROUTE_UPDATE")
         perf_events = self.pending.move_out_events()
@@ -916,36 +929,64 @@ class Decision:
     def _emit_update(
         self, payload, trace, rebuild_span, perf_events
     ) -> None:
-        """Emit stage of a rebuild: diff the solved db against the
-        installed one, apply, and publish. Runs inline on the module
-        thread, the only place route_db is mutated."""
+        """Emit stage of a rebuild: diff the solved routes against the
+        installed ones, apply, and publish. Runs inline on the module
+        thread, the only place route_db is mutated.
+
+        A full build comes as the solver's record of what it wrote. If
+        its untouched entries date from the very build route_db last
+        took in full (``_route_anchor``), route_db holds the solver's
+        own object under every key the build did not touch, so the
+        touched keys are the whole diff (``path="carried"``). Anything
+        else (``why``) takes the whole table: materialised, diffed by
+        ``calculate_update``, which heals identity, and anchored
+        anew."""
         tracer = get_tracer()
-        kind, value = payload
-        if kind == "db":
+        kind, value, build, why = payload
+        if kind == "delta":
+            update = value
+        else:
             # the diff runs HERE, not in the solve rung: this stage
             # is where the installed table is adopted (calculate_update
             # heals identity on it), and the ladder's rungs stay free
             # of route_db so a failed rung leaves nothing to undo
+            registry = get_registry()
             with tracer.span("decision.route_diff", trace=trace) as span:
-                update = self.route_db.calculate_update(value)
+                if kind == "build" and self._route_anchor != (
+                    build.solver, build.base_seq
+                ):
+                    # the table moved on without route_db (a ctrl
+                    # query built in between), or route_db without
+                    # the table
+                    why = self._anchor_lost or "built_in_between"
+                    kind, value = "db", build.materialise()
+                if kind == "build":
+                    update = self.route_db.calculate_touched_update(
+                        build.touched_unicast,
+                        build.touched_mpls,
+                        build.size,
+                    )
+                    registry.counter_bump("decision.route_delta_builds")
+                else:
+                    update = self.route_db.calculate_update(value)
+                    registry.counter_bump("decision.route_delta_fallbacks")
                 if span is not None:
                     span.attrs.update(
                         updated=len(update.unicast_routes_to_update),
                         deleted=len(update.unicast_routes_to_delete),
                         identical=update.diff_identical,
                         compared=update.diff_compared,
+                        path="carried" if kind == "build" else "whole",
+                        why=why,
                     )
             # identical / (identical + compared) is the share of the
             # table the diff settled by object identity
-            registry = get_registry()
             registry.counter_bump(
                 "decision.route_diff_identical", update.diff_identical
             )
             registry.counter_bump(
                 "decision.route_diff_compared", update.diff_compared
             )
-        else:
-            update = value
         if trace is not None:
             trace.end_span(
                 rebuild_span,
@@ -956,6 +997,15 @@ class Decision:
         # trace is Fib's, which records the hop as fib.queue_wait
         with tracer.span("decision.emit", trace=trace):
             self.route_db.update(update)
+            # in step with the solver's table only after a full build's
+            # own routes went in: a per-prefix delta or a policy's
+            # rewrite leaves route_db on objects the table does not hold
+            if build is not None:
+                self._route_anchor = (build.solver, build.seq)
+                self._anchor_lost = ""
+            else:
+                self._route_anchor = None
+                self._anchor_lost = why or "prefix_delta"
             if (
                 self.supervisor.state is HealthState.HEALTHY
                 and not quarantine_active()
@@ -969,7 +1019,7 @@ class Decision:
     @fault_boundary
     def _solve_update(
         self, full: bool, reset: bool, backend: str
-    ) -> Tuple[str, object]:
+    ) -> Tuple[str, object, object, str]:
         """One ladder rung: compute this rebuild's routes. ``reset``
         drops every device-derived cache first (so a torn dispatch
         can't leak into the result); a backend flip does the same
@@ -979,9 +1029,14 @@ class Decision:
         diffs against the installed db, so the emitted delta is
         identical.
 
-        Returns an emit payload — ``("db", DecisionRouteDb)`` for a
-        full build (the emit stage diffs it against the installed db)
-        or ``("delta", DecisionRouteUpdate)`` for the per-prefix
+        Returns an emit payload ``(kind, value, build, why)`` — for a
+        full build ``("build", None, RouteBuild, "")`` where the solver
+        patched its table in place (the emit stage installs what the
+        build touched, if route_db is in step with that table), else
+        ``("db", DecisionRouteDb, build, why)`` (the emit stage diffs
+        the whole db; ``build`` is None where the db is no longer the
+        solver's table entry for entry: a rib policy rewrote it); or
+        ``("delta", DecisionRouteUpdate, None, "")`` for the per-prefix
         incremental pass — so the rung itself never touches route_db
         and a failed rung leaves nothing to undo."""
         flipped = self.spf_solver.backend != backend
@@ -995,6 +1050,11 @@ class Decision:
             if full_build
             else self.pending.updated_prefixes
         )
+        rung = (
+            "warm" if not reset
+            else "cold" if backend == self._primary_backend
+            else "host"
+        )
         # everything this rung does to turn LSDB into routes; a view
         # built on a cache miss nests its own spans inside, so this
         # span's self time is route materialisation
@@ -1002,27 +1062,33 @@ class Decision:
             "decision.route_build",
             full=full_build,
             prefixes=len(prefixes),
-            rung=(
-                "warm" if not reset
-                else "cold" if backend == self._primary_backend
-                else "host"
-            ),
-        ):
+            rung=rung,
+        ) as span:
             if full_build:
-                new_db = (
-                    self.spf_solver.build_route_db(
-                        self.my_node_name,
-                        self.area_link_states,
-                        self.prefix_state,
-                    )
-                    or DecisionRouteDb()
+                build = self.spf_solver.build_routes(
+                    self.my_node_name,
+                    self.area_link_states,
+                    self.prefix_state,
                 )
+                if build is None:
+                    return ("db", DecisionRouteDb(), None, "no_routes")
+                if span is not None:
+                    # the keys the build wrote: the table's where it
+                    # filled a new one
+                    span.attrs["touched"] = build.touched
                 if (
                     self.rib_policy is not None
                     and self.rib_policy.is_active()
                 ):
+                    # the policy rewrites the db it is given: a copy,
+                    # never the solver's table
+                    new_db = build.materialise()
                     self.rib_policy.apply_policy(new_db.unicast_routes)
-                return ("db", new_db)
+                    return ("db", new_db, None, "rib_policy")
+                if build.base_seq is None:
+                    why = build.why if rung == "warm" else f"rung_{rung}"
+                    return ("db", build.materialise(), build, why)
+                return ("build", None, build, "")
             update = DecisionRouteUpdate()
             for prefix in prefixes:
                 entry = self.spf_solver.create_route_for_prefix(
@@ -1040,7 +1106,7 @@ class Decision:
                     update.unicast_routes_to_update
                 )
                 update.unicast_routes_to_delete.extend(change.deleted_routes)
-            return ("delta", update)
+            return ("delta", update, None, "")
 
     # -- public (thread-safe) APIs ---------------------------------------
 

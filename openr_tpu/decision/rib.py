@@ -136,6 +136,31 @@ def _diff_table(
     return changed, gone, identical
 
 
+def _diff_touched(installed: Dict, touched: Dict) -> Tuple[List, List, int]:
+    """``_diff_table`` for a build that says which keys it wrote
+    (``touched``: key -> its new entry, None for one it dropped) and
+    left every other key of ``installed`` on the object it was: the
+    entries to install, the keys to drop, and how many entries took the
+    field-by-field test (or were new), by the same rules. An entry that
+    equals the installed one without being it is not in the delta and
+    takes its twin's place. Nothing but ``touched`` is read."""
+    changed: List = []
+    gone: List = []
+    compared = 0
+    for key, entry in touched.items():
+        old = installed.get(key)
+        if entry is None:
+            if old is not None:
+                gone.append(key)
+        elif old is not entry:
+            compared += 1
+            if old is None or old != entry:
+                changed.append(entry)
+            else:
+                installed[key] = entry
+    return changed, gone, compared
+
+
 @dataclass
 class DecisionRouteUpdate:
     """Route delta published by Decision, consumed by Fib / PrefixManager.
@@ -230,6 +255,31 @@ class DecisionRouteDb:
             diff_compared=(
                 len(new_db.unicast_routes) + len(new_db.mpls_routes) - identical
             ),
+        )
+
+    def calculate_touched_update(
+        self, touched_unicast: Dict, touched_mpls: Dict, size: int
+    ) -> DecisionRouteUpdate:
+        """``calculate_update`` against a db of ``size`` routes that
+        differs from self in the touched keys at most, every other key
+        holding the very object self holds (``_diff_touched``): the
+        same delta, from the touched keys alone. Entries come in the
+        order the build wrote them, which is where the new table has
+        them; keys to delete in that order too."""
+        u_new, u_gone, u_compared = _diff_touched(
+            self.unicast_routes, touched_unicast
+        )
+        m_new, m_gone, m_compared = _diff_touched(
+            self.mpls_routes, touched_mpls
+        )
+        compared = u_compared + m_compared
+        return DecisionRouteUpdate(
+            unicast_routes_to_update={e.prefix: e for e in u_new},
+            unicast_routes_to_delete=u_gone,
+            mpls_routes_to_update=m_new,
+            mpls_routes_to_delete=m_gone,
+            diff_identical=size - compared,
+            diff_compared=compared,
         )
 
     def update(self, delta: DecisionRouteUpdate) -> None:

@@ -261,6 +261,8 @@ _LINKS_SIG_MEMO: "_weakref.WeakKeyDictionary" = (
 )
 
 _EMPTY_PREFIXES: frozenset = frozenset()
+# what a label that no node's route holds reads as: (node, entry)
+_NO_LABEL_ROUTE = (None, None)
 
 
 def _local_links_sig(ls: LinkState, node: str) -> tuple:
@@ -780,6 +782,84 @@ def reset_device_caches() -> None:
         pass
 
 
+class _RouteTable:
+    """The routes of the root the solver last built for, held from one
+    build to the next. A build that can name what moved since the one
+    before it (``seq``) patches ``unicast`` and ``mpls`` in place;
+    every other build fills a new table. Never handed out: callers get
+    copies (``RouteBuild.materialise``)."""
+
+    __slots__ = ("meta", "seq", "n_prefixes", "unicast", "mpls", "own_labels")
+
+    def __init__(self) -> None:
+        # what the table was filled from (build_routes' reuse meta)
+        self.meta: Optional[tuple] = None
+        # the build that last wrote it
+        self.seq = 0
+        # prefixes it answers for, routeless ones included
+        self.n_prefixes = 0
+        self.unicast: Dict[IpPrefix, RibUnicastEntry] = {}
+        self.mpls: Dict[int, RibMplsEntry] = {}
+        # the labels of the root's own adjacency and static routes,
+        # which every build lists anew
+        self.own_labels: frozenset = frozenset()
+
+
+class RouteBuild:
+    """What one full build did to the solver's table.
+
+    ``base_seq`` is the build the table's untouched entries date from:
+    every key outside ``touched_unicast`` / ``touched_mpls`` (key -> new
+    entry, or None for a route that went) maps to the very object it
+    mapped to after build ``base_seq`` of ``solver``. None where the
+    build filled a new table (``why`` says what stood in the way); the
+    touched maps are then None too and ``materialise`` is the result."""
+
+    __slots__ = (
+        "solver", "seq", "base_seq", "touched_unicast", "touched_mpls",
+        "why", "_table",
+    )
+
+    def __init__(
+        self, solver, table: _RouteTable, base_seq: Optional[int],
+        touched_unicast: Optional[Dict], touched_mpls: Optional[Dict],
+        why: str,
+    ) -> None:
+        self.solver = solver
+        self.seq = table.seq
+        self.base_seq = base_seq
+        self.touched_unicast = touched_unicast
+        self.touched_mpls = touched_mpls
+        self.why = why
+        self._table = table
+
+    @property
+    def size(self) -> int:
+        """Routes in the table this build left."""
+        return len(self._table.unicast) + len(self._table.mpls)
+
+    @property
+    def touched(self) -> int:
+        """Keys this build wrote: the whole table where it filled one."""
+        if self.base_seq is None:
+            return self.size
+        return len(self.touched_unicast) + len(self.touched_mpls)
+
+    def materialise(self) -> DecisionRouteDb:
+        """The whole result as a db of the caller's own. Good until the
+        solver's next build for this root, which may patch the table."""
+        table = self._table
+        if table.seq != self.seq:
+            raise RuntimeError(
+                f"route build {self.seq} read after build {table.seq} "
+                "patched its table"
+            )
+        return DecisionRouteDb(
+            unicast_routes=dict(table.unicast),
+            mpls_routes=dict(table.mpls),
+        )
+
+
 # externally serialized, never internally locked: every solver is
 # created and driven by exactly one plane — Decision's under evb, a
 # ctrl handler's (fleet FIB builds, replica absorb) under
@@ -799,10 +879,7 @@ def reset_device_caches() -> None:
     "_label_cache",
     "_label_state",
     "_labels_cache",
-    "_route_best_cache",
-    "_route_cache",
-    "_route_cache_meta",
-    "_route_entries_cache",
+    "_route_table",
     "_sp_prev_seq",
     "_sp_reuse",
     "_spec_staged",
@@ -871,10 +948,11 @@ class SpfSolver:
         # keyed like _ksp2_engines; the staged view itself lives in
         # _views (it IS the rebuild's cache entry on a hit)
         self._spec_staged = _weakref.WeakKeyDictionary()
-        # per-prefix route reuse across churn (driven by the engine's
-        # affected set): prefix -> (RibUnicastEntry | None, best result)
-        self._route_cache: Dict[IpPrefix, tuple] = {}
-        self._route_cache_meta: Optional[tuple] = None
+        # route reuse across churn: the routes of the last build's
+        # root, patched in place by a build that knows what moved (the
+        # engine's affected set, the SP dirty test) and refilled by one
+        # that does not. Its best-route results are best_routes_cache
+        self._route_table: Optional[_RouteTable] = None
         # nodes the engine's affected set actually covers (its KSP2
         # destinations); reuse is only sound for prefixes whose
         # advertisers all lie inside this set
@@ -908,11 +986,6 @@ class SpfSolver:
         # per-prefix-state-version KSP2 destination sets (see
         # _prefetch_ksp2_paths)
         self._ksp2_dsts_cache: Optional[tuple] = None
-        # previous build's non-None unicast entries / best results —
-        # the bulk-reuse path's dict-copy starting point (same
-        # lifecycle as _route_cache)
-        self._route_entries_cache: Optional[Dict] = None
-        self._route_best_cache: Optional[Dict] = None
         # root -> (seq, label_to_node, winners, collision labels,
         # labels-by-node, area): the assembled node-label route map,
         # patchable in O(dirty) when the SP dirty test names the only
@@ -954,10 +1027,7 @@ class SpfSolver:
         self._ksp2_engines = _weakref.WeakKeyDictionary()
         self._spec_staged = _weakref.WeakKeyDictionary()
         self._labels_cache = _weakref.WeakKeyDictionary()
-        self._route_cache = {}
-        self._route_cache_meta = None
-        self._route_entries_cache = None
-        self._route_best_cache = None
+        self._route_table = None
         self._advertisers_cache = None
         self._ksp2_dsts_cache = None
         self._ksp2_tracked = set()
@@ -1429,20 +1499,36 @@ class SpfSolver:
         area_link_states: AreaLinkStates,
         prefix_state: PrefixState,
     ) -> Optional[DecisionRouteDb]:
-        """Full RIB computation. reference: Decision.cpp:569 buildRouteDb."""
+        """Full RIB computation. reference: Decision.cpp:569 buildRouteDb.
+        The db is the caller's own: no later build writes to it."""
+        build = self.build_routes(
+            my_node_name, area_link_states, prefix_state
+        )
+        return build.materialise() if build is not None else None
+
+    def build_routes(
+        self,
+        my_node_name: str,
+        area_link_states: AreaLinkStates,
+        prefix_state: PrefixState,
+    ) -> Optional[RouteBuild]:
+        """``build_route_db`` for a caller that holds the previous
+        result and wants what changed: the same computation, handed
+        back as the record of what it wrote (``RouteBuild``)."""
         if not any(ls.has_node(my_node_name) for ls in area_link_states.values()):
             return None
 
         self._build_seq += 1
-        route_db = DecisionRouteDb()
-        self.best_routes_cache.clear()
+        # out of the solver while it is written: a build that raises
+        # half way leaves no table, and the next one fills a new one
+        table, self._route_table = self._route_table, None
         self._world_preload(my_node_name, area_link_states)
         affected = self._prefetch_ksp2_paths(
             my_node_name, area_link_states, prefix_state
         )
 
         # Per-prefix route reuse: any prefix whose advertisers provably
-        # produce a byte-identical route is served from the cache
+        # produce a byte-identical route is served from the table
         # instead of re-derived (reference analogue: the per-prefix
         # incremental rebuild, Decision.cpp:1896-1917).
         # object references, not id()s: a recycled id on a NEW
@@ -1470,7 +1556,15 @@ class SpfSolver:
             if not self.compute_lfa_paths
             else (False, None)
         )
-        meta_ok = self._route_cache_meta == meta
+        iter_prefixes = prefix_state.prefixes()
+        # (what a build does not visit it keeps, so the table has to
+        # hold every prefix: the meta says it was filled from this
+        # prefix state at this version, and the count holds it to that)
+        meta_ok = (
+            table is not None
+            and table.meta == meta
+            and table.n_prefixes == len(iter_prefixes)
+        )
         reuse = (
             affected
             if (
@@ -1484,8 +1578,6 @@ class SpfSolver:
         populate = (
             affected is not None or sp_stored
         ) and not self.compute_lfa_paths
-        self._route_cache_meta = meta if populate else None
-        new_cache: Dict[IpPrefix, tuple] = {}
 
         adv_map = None
         if reuse is not None or reuse_sp is not None:
@@ -1526,21 +1618,19 @@ class SpfSolver:
         # Bulk reuse: with a valid SP dirty set, only prefixes
         # advertised by a dirty node (or carrying a KSP2 entry, whose
         # gate needs the engine's affected set) can produce a different
-        # route — every other cached (entry, best) pair is adopted with
-        # TWO C-level dict copies instead of 100k Python-level gate
-        # evaluations (~1.7 s/event at 100k).
-        iter_prefixes = prefix_state.prefixes()
-        # (what the bulk path does not visit it adopts, so the cache has
-        # to hold every prefix: meta_ok says it was filled from this
-        # prefix state at this version, and the length holds it to that)
+        # route — every other route stays where it is in the table, and
+        # the build neither visits nor copies it. The dirty set says
+        # what moved since the build that stored the signature it was
+        # compared with, so that has to be the build the table dates
+        # from.
         bulk = (
             reuse_sp is not None
             and adv_map is not None
-            and self._route_entries_cache is not None
-            and len(self._route_cache) == len(iter_prefixes)
+            and table.seq == self._sp_prev_seq
         )
         n_prefixes = len(iter_prefixes)
         ksp2_reused = 0
+        why = ""
         if bulk:
             _key, _amap, adv_index, ksp2_set = self._advertisers_cache
             if reuse is not None:
@@ -1555,27 +1645,34 @@ class SpfSolver:
                 must = set(ksp2_set)
             for n in reuse_sp:
                 must |= adv_index.get(n, _EMPTY_PREFIXES)
-            route_db.unicast_routes = dict(self._route_entries_cache)
-            self.best_routes_cache.update(self._route_best_cache)
-            new_cache = dict(self._route_cache)
-            for p in must:
-                route_db.unicast_routes.pop(p, None)
-                self.best_routes_cache.pop(p, None)
-                new_cache.pop(p, None)
-            # what survived the pops is adopted: the KSP2 prefixes among
-            # it on the engine's word, the rest on the SP dirty test's
+            # what the loop is not shown is adopted: the KSP2 prefixes
+            # among it on the engine's word, the rest on the SP dirty
+            # test's
             ksp2_reused = len(ksp2_set) - len(ksp2_set & must)
             SPF_COUNTERS["decision.sp_route_reuses"] += (
-                len(new_cache) - ksp2_reused
+                table.n_prefixes - len(must) - ksp2_reused
             )
             # the prefixes this build answers for: the KSP2 ones and
             # whatever else the loop is shown
             n_prefixes = len(must) + ksp2_reused
             iter_prefixes = must
+            base_seq: Optional[int] = table.seq
+            touched_unicast: Optional[Dict] = {}
+        else:
+            # a new table, filled from the old one where the gate
+            # allows; create_route_for_prefix files best results under
+            # the attribute, so that starts anew as well
+            why = self._why_not_patched(table, meta, sp_dirty)
+            base_seq, touched_unicast = None, None
+            old_unicast = table.unicast if meta_ok else {}
+            old_best = self.best_routes_cache
+            self.best_routes_cache = {}
+            table = _RouteTable()
+        unicast = table.unicast
 
         # where the KSP2 engine ran, the loop below is the KSP2 share
         # of route derivation: label stacks re-derived for the
-        # destinations the engine named, the rest served from the cache
+        # destinations the engine named, the rest served from the table
         # counted here and booked once: a counter bump per prefix is a
         # registry round trip per prefix
         with (
@@ -1588,9 +1685,9 @@ class SpfSolver:
             else contextlib.nullcontext()
         ) as ksp2_span:
             for prefix in iter_prefixes:
-                if adv_map is not None and prefix in self._route_cache:
+                if adv_map is not None:
                     advertisers, has_ksp2 = adv_map[prefix]
-                    # a cached route is reusable when every input that
+                    # a held route is reusable when every input that
                     # could change it is provably unchanged:
                     # - non-KSP2 prefix + every advertiser clean under the
                     #   SP dirty test (column-wise vs the previous build)
@@ -1612,23 +1709,23 @@ class SpfSolver:
                         ok = True
                         ksp2_reused += 1
                     if ok:
-                        entry, best = self._route_cache[prefix]
-                        if best is not None:
-                            self.best_routes_cache[prefix] = best
-                        if entry is not None:
-                            route_db.add_unicast_route(entry)
-                        new_cache[prefix] = (entry, best)
+                        if not bulk:
+                            entry = old_unicast.get(prefix)
+                            if entry is not None:
+                                unicast[prefix] = entry
+                            best = old_best.get(prefix)
+                            if best is not None:
+                                self.best_routes_cache[prefix] = best
                         continue
                 entry = self.create_route_for_prefix(
                     my_node_name, area_link_states, prefix_state, prefix
                 )
                 if entry is not None:
-                    route_db.add_unicast_route(entry)
-                if populate:
-                    new_cache[prefix] = (
-                        entry,
-                        self.best_routes_cache.get(prefix),
-                    )
+                    unicast[prefix] = entry
+                elif bulk:
+                    unicast.pop(prefix, None)
+                if bulk:
+                    touched_unicast[prefix] = entry
             SPF_COUNTERS["decision.ksp2_route_reuses"] += ksp2_reused
             if affected is not None:
                 SPF_COUNTERS["decision.ksp2_routes_visited"] += len(
@@ -1636,29 +1733,79 @@ class SpfSolver:
                 )
             if ksp2_span is not None:
                 ksp2_span.attrs["reused"] = ksp2_reused
-        self._route_cache = new_cache
-        if populate:
-            # the bulk path's starting point next build: previous
-            # non-None unicast entries and best-route results
-            self._route_entries_cache = dict(route_db.unicast_routes)
-            self._route_best_cache = dict(self.best_routes_cache)
-        else:
-            self._route_entries_cache = None
-            self._route_best_cache = None
+        table.meta = meta
+        table.seq = self._build_seq
+        table.n_prefixes = len(prefix_state.prefixes())
 
         # MPLS routes for node (SR) labels (label routes depend only on
         # the graph, so the raw dirty set applies regardless of the
-        # prefix-state meta gate)
-        label_to_node = self._build_node_label_routes(
+        # prefix-state meta gate), then the root's own: its adjacency
+        # labels and the static routes, over a node label of the same
+        # number
+        label_to_node, touched_labels = self._build_node_label_routes(
             my_node_name, area_link_states, sp_dirty=sp_dirty
         )
-        # bulk-assemble: mpls_routes is a label-keyed dict, so
-        # insertion order is irrelevant; per-entry add calls cost
-        # ~250 ms/build at 100k
-        route_db.mpls_routes.update(
-            {lab: ne[1] for lab, ne in label_to_node.items()}
+        own = self._own_label_routes(my_node_name, area_link_states)
+        touched_mpls: Optional[Dict] = None
+        if bulk and touched_labels is not None:
+            # a node's label the patch moved, an own label that went
+            # (the node label it stood over shows again, if any), and
+            # the own routes as this build made them
+            touched_mpls = {
+                label: label_to_node.get(label, _NO_LABEL_ROUTE)[1]
+                for label in touched_labels.union(
+                    table.own_labels.difference(own)
+                )
+            }
+            touched_mpls.update(own)
+            mpls = table.mpls
+            for label, entry in touched_mpls.items():
+                if entry is None:
+                    mpls.pop(label, None)
+                else:
+                    mpls[label] = entry
+        else:
+            if bulk:
+                why = "label_map_rebuilt"
+                base_seq, touched_unicast = None, None
+            # bulk-assemble: mpls_routes is a label-keyed dict, so
+            # insertion order is irrelevant; per-entry add calls cost
+            # ~250 ms/build at 100k
+            table.mpls = {lab: ne[1] for lab, ne in label_to_node.items()}
+            table.mpls.update(own)
+        table.own_labels = frozenset(own)
+
+        if populate:
+            self._route_table = table
+        return RouteBuild(
+            self, table, base_seq, touched_unicast, touched_mpls, why
         )
 
+    @staticmethod
+    def _why_not_patched(
+        table: Optional[_RouteTable], meta: tuple,
+        sp_dirty: Optional[Set[str]],
+    ) -> str:
+        """Why a build fills a new table, for the record it returns."""
+        if table is None:
+            # none kept: the first build, one after a reset, a backend
+            # flip or a build that raised; or nothing to keep one for
+            # (LFA, a backend with no change detector)
+            return "no_table"
+        if table.meta != meta:
+            # another root, prefix state or version of it, static
+            # routes, set of areas
+            return "inputs_changed"
+        if sp_dirty is None:
+            return "no_dirty_set"
+        return "table_not_of_last_build"
+
+    def _own_label_routes(
+        self, my_node_name: str, area_link_states: AreaLinkStates
+    ) -> Dict[int, RibMplsEntry]:
+        """The MPLS routes of the root's own links and the static ones:
+        a handful, made anew by every build."""
+        own: Dict[int, RibMplsEntry] = {}
         # MPLS routes for adjacency labels
         for _, ls in sorted(area_link_states.items()):
             for link in ls.ordered_links_from_node(my_node_name):
@@ -1667,27 +1814,24 @@ class SpfSolver:
                     continue
                 if not is_mpls_label_valid(top_label):
                     continue
-                route_db.add_mpls_route(
-                    RibMplsEntry(
-                        top_label,
-                        {
-                            make_next_hop(
-                                link.nh_v6_from(my_node_name),
-                                link.iface_from(my_node_name),
-                                link.metric_from(my_node_name),
-                                MplsAction(action=MplsActionCode.PHP),
-                                link.area,
-                                link.other_node(my_node_name),
-                            )
-                        },
-                    )
+                own[top_label] = RibMplsEntry(
+                    top_label,
+                    {
+                        make_next_hop(
+                            link.nh_v6_from(my_node_name),
+                            link.iface_from(my_node_name),
+                            link.metric_from(my_node_name),
+                            MplsAction(action=MplsActionCode.PHP),
+                            link.area,
+                            link.other_node(my_node_name),
+                        )
+                    },
                 )
 
         # static MPLS routes
         for label, nhs in self.static_mpls_routes.items():
-            route_db.add_mpls_route(RibMplsEntry(label, set(nhs)))
-
-        return route_db
+            own[label] = RibMplsEntry(label, set(nhs))
+        return own
 
     def _ksp2_untracked_prefixes(self) -> frozenset:
         """The KSP2 prefixes with an advertiser outside
@@ -1769,13 +1913,14 @@ class SpfSolver:
         area_link_states: AreaLinkStates,
         dirty: Set[str],
         st: tuple,
-    ) -> Optional[Dict[int, Tuple[str, "RibMplsEntry"]]]:
-        """O(dirty) update of the node-label route map: re-derive only
-        the destinations the SP dirty test names, keeping every other
-        (node, entry) pair of the previous build. Returns None when a
-        contested label's winner must be recomputed from scratch (the
-        losing claimants' entries were never derived), falling back to
-        the full loop."""
+    ) -> Optional[Tuple[Dict[int, Tuple[str, "RibMplsEntry"]], Set[int]]]:
+        """O(dirty) update of the node-label route map, in place:
+        re-derive only the destinations the SP dirty test names,
+        keeping every other (node, entry) pair of the previous build
+        where it is. Returns the map and the labels it wrote or
+        dropped, or None when a contested label's winner must be
+        recomputed from scratch (the losing claimants' entries were
+        never derived), falling back to the full loop."""
         areas = tuple(sorted(area_link_states))
         _seq, result, winners, collisions, labels_by, st_areas = st
         if st_areas != areas:
@@ -1784,10 +1929,10 @@ class SpfSolver:
             (a, area_link_states[a].get_adjacency_databases())
             for a in areas
         ]
-        result = dict(result)
-        winners = dict(winners)
-        labels_by = dict(labels_by)
-        collisions = set(collisions)
+        # out of the solver while it is patched: giving up (or a raise)
+        # half way leaves no state, and the full loop stores a new one
+        del self._label_state[my_node_name]
+        touched: Set[int] = set()
         for node in sorted(dirty):
             old_label = labels_by.pop(node, None)
             # a node of several areas (a border) is derived for the
@@ -1820,6 +1965,7 @@ class SpfSolver:
                 if old_label in collisions:
                     return None
                 result.pop(old_label, None)
+                touched.add(old_label)
                 winners.pop(node, None)
             if top_label is None:
                 continue
@@ -1833,20 +1979,23 @@ class SpfSolver:
                     continue  # smaller name keeps the label
                 winners.pop(existing[0], None)
             result[top_label] = (node, entry)
+            touched.add(top_label)
             winners[node] = (top_label, entry)
         self._store_label_state(
             my_node_name, areas, result, winners, collisions, labels_by
         )
-        return result
+        return result, touched
 
     def _build_node_label_routes(
         self,
         my_node_name: str,
         area_link_states: AreaLinkStates,
         sp_dirty: Optional[Set[str]] = None,
-    ) -> Dict[int, Tuple[str, "RibMplsEntry"]]:
+    ) -> Tuple[Dict[int, Tuple[str, "RibMplsEntry"]], Optional[Set[int]]]:
         """SR node-label routes for every labeled node
-        (reference: Decision.cpp:600-650 buildRouteDb label loop).
+        (reference: Decision.cpp:600-650 buildRouteDb label loop), and
+        the labels whose route differs from the previous build's map
+        where that map was patched (None where it was built anew).
 
         Incremental fast paths (device backend, no LFA): (1) when the
         SP dirty test proves which destinations' routes could have
@@ -1855,7 +2004,9 @@ class SpfSolver:
         (_patch_node_label_routes) — the O(N) loop never runs, with one
         area or several; (2) otherwise, with one area, the batched
         view's column diff marks label routes reusable per destination
-        and the loop re-derives only the changed ones."""
+        and the loop re-derives only the changed ones. The map that
+        comes back is the solver's own, patched again by the next
+        build: read it, do not keep it."""
         label_to_node: Dict[int, Tuple[str, RibMplsEntry]] = {}
 
         if sp_dirty is not None and not self.compute_lfa_paths:
@@ -1963,13 +2114,16 @@ class SpfSolver:
             while len(self._label_cache) > 8:  # bound ctrl-query growth
                 self._label_cache.pop(next(iter(self._label_cache)))
         if one_label_a_node:
+            # the winners are patched in place from here on, and
+            # _label_cache keeps ``built`` beside the matrices it was
+            # derived from: a copy
             self._store_label_state(
                 my_node_name, tuple(sorted(area_link_states)),
-                label_to_node, built, collisions, labels_by,
+                label_to_node, dict(built), collisions, labels_by,
             )
         else:
             self._label_state.pop(my_node_name, None)
-        return label_to_node
+        return label_to_node, None
 
     def create_route_for_prefix(
         self,

@@ -26,8 +26,8 @@ from openr_tpu.utils import wire
 class DecisionHarness:
     """KvStore + Decision wired through real queues."""
 
-    def __init__(self, my_node, solver_backend="device"):
-        self.store = KvStoreWrapper(f"store:{my_node}")
+    def __init__(self, my_node, solver_backend="device", areas=None):
+        self.store = KvStoreWrapper(f"store:{my_node}", areas=areas)
         self.route_q = ReplicateQueue(name="routeUpdates")
         self.route_reader = self.route_q.get_reader("test")
         self.decision = Decision(
@@ -46,17 +46,19 @@ class DecisionHarness:
         self.decision.stop()
         self.store.stop()
 
+    def _publish(self, key, db):
+        """Into the area the db names (one of the store's)."""
+        slot = (db.area, key)
+        v = self._versions[slot] = self._versions.get(slot, 0) + 1
+        self.store.set_key(key, wire.dumps(db), version=v, area=db.area,
+                           originator=db.this_node_name)
+
     def publish_adj(self, adj_db: AdjacencyDatabase):
-        key = keyutil.adj_key(adj_db.this_node_name)
-        v = self._versions[key] = self._versions.get(key, 0) + 1
-        self.store.set_key(key, wire.dumps(adj_db), version=v,
-                           originator=adj_db.this_node_name)
+        self._publish(keyutil.adj_key(adj_db.this_node_name), adj_db)
 
     def publish_prefixes(self, prefix_db: PrefixDatabase):
-        key = keyutil.prefix_db_key(prefix_db.this_node_name)
-        v = self._versions[key] = self._versions.get(key, 0) + 1
-        self.store.set_key(key, wire.dumps(prefix_db), version=v,
-                           originator=prefix_db.this_node_name)
+        self._publish(
+            keyutil.prefix_db_key(prefix_db.this_node_name), prefix_db)
 
     def publish_topology(self, topo):
         for db in topo.adj_dbs.values():
@@ -446,7 +448,8 @@ class TestRouteDbOwnership:
 
         seen = []
         db = harness.decision.route_db
-        for name in ("update", "calculate_update"):
+        for name in ("update", "calculate_update",
+                     "calculate_touched_update"):
             def wrapper(*a, _real=getattr(db, name), _name=name, **kw):
                 seen.append((_name, threading.current_thread().name))
                 return _real(*a, **kw)
@@ -465,7 +468,8 @@ class TestRouteDbOwnership:
             assert harness.drain_updates(first_timeout=5.0)
 
         assert sum(n == "update" for n, _ in seen) >= 4
-        assert sum(n == "calculate_update" for n, _ in seen) >= 3
+        # a diff of the whole table, or of the keys the build touched
+        assert sum(n.startswith("calculate_") for n, _ in seen) >= 3
         assert {t for _, t in seen} == {"decision:a"}, seen
 
     def test_stop_leaves_no_decision_thread(self):

@@ -584,7 +584,7 @@ def test_a_publication_that_joins_after_the_stage_takes_the_union(
         for _ in range(12):
             evs = [gen.draw(), gen.draw()]
             before = _spec_counters()
-            old = dict(solver._route_cache)
+            old = dict(solver._route_table.unicast)
             _publish(decision, evs[0])
             assert engine.staged
             first = set(engine._carried)
@@ -604,18 +604,19 @@ def test_a_publication_that_joins_after_the_stage_takes_the_union(
                 gen, decision.route_db.to_route_db(VANTAGE),
                 host.route_db.to_route_db(VANTAGE))
             # what the stage found moved was re-derived by the build
-            # although the build's own sync did not name it: the cached
-            # route object was replaced, not reused
-            new = solver._route_cache
-            for prefix, (entry, _best) in old.items():
+            # although the build's own sync did not name it: the route
+            # object the solver's table held was replaced, not kept
+            table = solver._route_table
+            new = table.unicast
+            for prefix, entry in old.items():
                 advertisers = {
                     node for node, _area
                     in decision.prefix_state.entries_for(prefix)}
-                if entry is not None and advertisers & first:
-                    assert new[prefix][0] is not entry, prefix
+                if advertisers & first:
+                    assert new.get(prefix) is not entry, prefix
                     both_moved += 1
             assert _delta(before, "decision.ksp2_route_reuses")[0] \
-                <= len(new) - len(first)
+                <= table.n_prefixes - len(first)
             assert not engine._carried and not engine.staged
     finally:
         for q in queues:

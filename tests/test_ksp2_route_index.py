@@ -3,7 +3,7 @@
 ``SpfSolver.build_route_db``'s bulk path shows the reuse gate only the
 prefixes that the carry (``Ksp2Engine.take_affected``) or the SP dirty
 test names an advertiser of, and the KSP2 prefixes with an advertiser no
-engine tracks; the rest of the cache is adopted by three dict copies.
+engine tracks; the rest of the solver's route table stays as it is.
 The visit set only has to be a superset of what the gate would refuse,
 so the cases here are the deployments where a narrower one could drop a
 route that had to be re-derived: KSP2 and SP_ECMP prefixes side by side

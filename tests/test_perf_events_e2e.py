@@ -353,11 +353,15 @@ class TestSpanTreeEndToEnd:
                     "formulation": "dense", "rows": 3}
                 assert set(by_name["ops.spf_view_batch"].attrs) == {
                     "batch", "n_pad"}
+            # the build patched the solver's table at b's prefix and b's
+            # label route, and the diff read those two keys
             assert by_name["decision.route_build"].attrs == {
-                "full": True, "prefixes": 3, "rung": "warm"}
+                "full": True, "prefixes": 3, "rung": "warm", "touched": 2}
             assert by_name["ops.solve_readback"].attrs["bytes"] > 0
             assert set(by_name["decision.route_diff"].attrs) == {
-                "updated", "deleted", "identical", "compared"}
+                "updated", "deleted", "identical", "compared",
+                "path", "why"}
+            assert by_name["decision.route_diff"].attrs["path"] == "carried"
             # the route engine's accounting is not the rebuild's
             assert set(by_name["decision.rebuild"].attrs) == {
                 "full_rebuild", "routes_updated", "routes_deleted",

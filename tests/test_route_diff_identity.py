@@ -15,6 +15,9 @@ topology). These are counts, not times.
 from __future__ import annotations
 
 from dataclasses import replace
+from types import SimpleNamespace
+
+import pytest
 
 from openr_tpu.decision.spf_solver import SPF_COUNTERS
 from openr_tpu.models import topologies
@@ -25,13 +28,15 @@ ROOT = "rsw-0-0"
 
 
 class _Fabric:
-    def __init__(self):
-        topo = topologies.fat_tree(
-            3, ssw_per_plane=2, fsw_per_pod=2, rsw_per_pod=4)
-        # no SR node labels to start with: the table is the unicast
-        # routes alone until the label event adds one MPLS route
+    def __init__(self, labels=False, **shape):
+        shape = shape or dict(
+            pods=3, ssw_per_plane=2, fsw_per_pod=2, rsw_per_pod=4)
+        topo = topologies.fat_tree(**shape)
+        # no SR node labels to start with (unless asked for): the table
+        # is the unicast routes alone until the label event adds one
+        # MPLS route
         self.dbs = {
-            name: replace(db, node_label=0)
+            name: db if labels else replace(db, node_label=0)
             for name, db in topo.adj_dbs.items()
         }
         self.prefix_dbs = topo.prefix_dbs
@@ -164,5 +169,152 @@ def test_the_diff_compares_what_the_build_rederived_and_the_share_holds():
 
         assert share(seen[1]) == 1.0
         assert share(seen[-1]) >= share(seen[1])
+    finally:
+        fabric.h.stop()
+
+
+class _Counting(dict):
+    """A route table that counts how it is read: key by key, or as a
+    whole (``len`` is neither)."""
+
+    def __init__(self, *args):
+        super().__init__(*args)
+        self.keyed = []
+        self.passes = 0
+
+    def get(self, key, default=None):
+        self.keyed.append(key)
+        return super().get(key, default)
+
+    def __getitem__(self, key):
+        self.keyed.append(key)
+        return super().__getitem__(key)
+
+    def __contains__(self, key):
+        self.keyed.append(key)
+        return super().__contains__(key)
+
+    def __setitem__(self, key, value):
+        self.keyed.append(key)
+        super().__setitem__(key, value)
+
+    def pop(self, key, *default):
+        self.keyed.append(key)
+        return super().pop(key, *default)
+
+    def __iter__(self):
+        self.passes += 1
+        return super().__iter__()
+
+    def keys(self):
+        self.passes += 1
+        return super().keys()
+
+    def values(self):
+        self.passes += 1
+        return super().values()
+
+    def items(self):
+        self.passes += 1
+        return super().items()
+
+    def copy(self):
+        self.passes += 1
+        return super().copy()
+
+
+_SIZES = {
+    # name: (fat_tree shape, routes in the table: a prefix and a label
+    # route a node, less the root's own prefix)
+    "43 routes": (dict(
+        pods=3, ssw_per_plane=2, fsw_per_pod=2, rsw_per_pod=4), 43),
+    "367 routes": (dict(
+        pods=6, ssw_per_plane=4, fsw_per_pod=4, rsw_per_pod=24), 367),
+}
+
+
+@pytest.mark.parametrize("size", sorted(_SIZES))
+def test_a_carried_rebuild_reads_the_keys_its_build_touched_and_no_more(
+        size):
+    """The cost of a rebuild's diff follows what the build re-derived,
+    not the table: the installed table is read at the keys the build
+    touched, each once for the diff and once more where the update
+    writes it, and never as a whole. The same three events on a table
+    of 43 routes and on one of 367."""
+    shape, routes = _SIZES[size]
+    fabric = _Fabric(labels=True, **shape)
+    decision = fabric.h.decision
+    try:
+        fabric.bulk_load()
+        assert len(fabric.installed) == routes
+        # one rebuild to anchor on whatever the bulk load's last window
+        # left, then the counting tables go in under the same objects
+        fabric.rebuild(lambda f: f.metric("ssw-0-0", "fsw-1-0", 2))
+        route_db = decision.route_db
+
+        def count():
+            route_db.unicast_routes = _Counting(route_db.unicast_routes)
+            route_db.mpls_routes = _Counting(route_db.mpls_routes)
+
+        decision.evb.call_and_wait(count)
+
+        def read(event):
+            tables = (route_db.unicast_routes, route_db.mpls_routes)
+            for table in tables:
+                table.keyed.clear()
+                table.passes = 0
+            registry = get_registry()
+            carried0 = registry.counter_get("decision.route_delta_builds")
+            event(fabric)
+            update = fabric.h.next_update(timeout=20.0)
+            spans = {s.name: s for s in update.trace.spans}
+            changed = (
+                len(update.unicast_routes_to_update)
+                + len(update.unicast_routes_to_delete)
+                + len(update.mpls_routes_to_update)
+                + len(update.mpls_routes_to_delete))
+            return SimpleNamespace(
+                carried=registry.counter_get(
+                    "decision.route_delta_builds") - carried0,
+                path=spans["decision.route_diff"].attrs["path"],
+                touched=spans["decision.route_build"].attrs["touched"],
+                compared=spans["decision.route_diff"].attrs["compared"],
+                identical=spans["decision.route_diff"].attrs["identical"],
+                changed=changed,
+                keyed=sum(len(t.keyed) for t in tables),
+                keys=set().union(*(t.keyed for t in tables)),
+                passes=sum(t.passes for t in tables),
+            )
+
+        seen = {}
+        for name, event in (
+            # nothing the root routes over moves: ECMP over the other spine
+            ("nothing", lambda f: f.metric("ssw-0-0", "fsw-1-0", 3)),
+            # a rack's uplink leaves the root's paths to it, and is back
+            ("one rack", lambda f: f.metric("fsw-1-0", "rsw-1-1", 5)),
+            ("and back", lambda f: f.metric("fsw-1-0", "rsw-1-1", 1)),
+        ):
+            got = seen[name] = read(event)
+            assert (got.carried, got.path) == (1, "carried"), name
+            assert got.passes == 0, name
+            # read once a touched key, written once a changed one
+            assert got.keyed == got.touched + got.changed, (name, got)
+            assert len(got.keys) == got.touched, (name, got)
+            assert got.compared == got.touched, name
+            assert got.identical == routes - got.compared, name
+        assert seen["nothing"].touched == 0
+        # the rack's prefix and its label route, at either size
+        assert seen["one rack"].touched == 2
+        assert seen["one rack"].changed == 2
+        assert seen["and back"].touched == 2
+
+        # the counter does see a whole pass when there is one: a ctrl
+        # query for the root moves the solver's table on without
+        # route_db, and the next rebuild goes through calculate_update
+        decision.get_decision_route_db()
+        got = read(lambda f: f.metric("ssw-0-0", "fsw-1-0", 4))
+        assert (got.carried, got.path) == (0, "whole")
+        assert got.passes >= 2
+        assert got.identical + got.compared == routes
     finally:
         fabric.h.stop()
