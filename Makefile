@@ -179,8 +179,8 @@ fleet-smoke: native
 	env JAX_PLATFORMS=cpu python -m tools.fleet_smoke --out /tmp/openr_tpu_fleet_smoke.json
 
 # the quickest proof that the served paths still start on the chip:
-# KvStore -> Decision -> Fib at 1008 and 10k nodes, KSP2, SolverService
-# and the Pallas kernels, each checked against the host reference.
+# KvStore -> Decision -> Fib at 1008 and 10k nodes, KSP2, the multi-area
+# border and SolverService, each checked against the host reference.
 # Exits != 0 without a TPU; run it on the machine that holds one.
 chip-smoke:
 	python chip_smoke.py

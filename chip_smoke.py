@@ -3,7 +3,7 @@
 
 One process owns the accelerator and drives, through the entry points a
 deployment uses and with the options ``OpenrConfig`` ships
-(``solver_backend="device"``, jnp kernels, no ``OPENR_*`` variable set):
+(``solver_backend="device"``, no ``OPENR_*`` variable set):
 
 - ``pipeline_fabric_1008`` / ``pipeline_fabric_10k``: KvStore -> Decision
   -> Fib wired as ``daemon.py`` wires them (``SustainedLoadHarness``):
@@ -29,8 +29,6 @@ deployment uses and with the options ``OpenrConfig`` ships
 - ``serve``: ``SolverService`` behind ``CtrlServer`` in this process,
   JAX-free client processes over the ctrl wire, every FIB digest equal
   to one built by ``SpfSolver(backend="host")``.
-- ``kernels``: every Pallas entry compiled with ``interpret=False`` at
-  the shapes the legs above hand its jnp twin, compared bit for bit.
 - ``mesh4`` (>= 4 devices only): the KSP2 leg and a 10k route-engine
   churn sharded over four devices.
 
@@ -47,7 +45,6 @@ Nothing here is a benchmark.
 
 from __future__ import annotations
 
-import functools
 import json
 import os
 import sys
@@ -72,7 +69,6 @@ FALLBACK_COUNTERS = (
     "decision.ksp2_host_fallbacks",
     "route_engine.fallbacks",
     "ops.aot_fallbacks",
-    "ops.autotune_disqualified",
     "serve.errors",
 )
 
@@ -529,100 +525,6 @@ def leg_serve(clients: int = 2, tenant_sizes=(("grid", 32), ("mesh", 1000)),
     }
 
 
-def leg_kernels(interpret: bool, dense_nodes: int = 1008,
-                grouped_nodes: int = 10000, grouped_batch: int = 128) -> dict:
-    """Every Pallas entry against its jnp twin, bit for bit, on the
-    tensors the fabric legs build: a partially relaxed distance panel
-    over the real snapshot / segment layout, so INF and finite cells
-    mix. A kernel that does not lower raises here with the compiler's
-    message — a selectable kernel that cannot run is a defect."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from openr_tpu.graph.snapshot import compile_snapshot
-    from openr_tpu.ops import pallas_minplus, spf, spf_grouped
-
-    kernels: dict = {}
-
-    def check(name: str, shapes, pallas_thunk, want) -> None:
-        try:
-            got = np.asarray(pallas_thunk())
-        except Exception as exc:  # noqa: BLE001 - re-raised with shapes
-            raise SmokeFailure(
-                f"kernels: {name} did not lower at {shapes}: "
-                f"{type(exc).__name__}: {exc}"
-            ) from exc
-        same = bool(np.array_equal(got, np.asarray(want)))
-        kernels[name] = {
-            "shapes": shapes, "lowered": True, "bit_identical": same,
-        }
-        _require(same, f"kernels: {name} differs from its jnp twin")
-
-    # -- dense min-plus at the {source} + neighbors batch ----------------
-    topo, ls = _fabric(dense_nodes)
-    rsw = next(k for k in sorted(topo.adj_dbs) if k.startswith("rsw"))
-    snap = compile_snapshot(ls)
-    dev = snap.device_arrays()
-    _srcs, srcs_dev = spf.source_batch(snap, snap.id_of(rsw))
-
-    # operands are prepared under one jit each: op-by-op eager dispatch
-    # would pay a compile per op
-    @jax.jit
-    def dense_operands(metric, overloaded, srcs):
-        t = spf._mask_transit_rows(metric, overloaded)
-        d0 = metric[srcs, :].at[jnp.arange(srcs.shape[0]), srcs].set(0)
-        return jnp.minimum(d0, spf._minplus(d0, t)), t
-
-    d1, t = dense_operands(dev.metric, dev.overloaded, srcs_dev)
-    check(
-        "pallas_minplus.minplus",
-        [list(d1.shape), list(t.shape)],
-        lambda: pallas_minplus.minplus(d1, t, interpret=interpret),
-        jax.jit(spf._minplus)(d1, t),
-    )
-
-    # -- grouped block contractions over the fabric's segment shapes -----
-    _topo, ls = _fabric(grouped_nodes)
-    graph = spf_grouped.compile_grouped(ls)
-    src_t, w_t = spf_grouped.device_tensors(graph)
-    ov = jnp.asarray(graph.overloaded)
-    meta = spf_grouped.band_meta(graph)
-
-    @jax.jit
-    def segment_operands(src_t, w_t, ov):
-        ids = jnp.arange(grouped_batch, dtype=jnp.int32)
-        d = jnp.full((grouped_batch, graph.n_pad), spf.INF, jnp.int32)
-        d = d.at[ids, ids].set(0)
-        for _ in range(2):
-            d = spf_grouped._grouped_relax(d, meta, src_t, w_t, ov, None)
-        return [d[:, src] for src in src_t]  # [B, G, S] per segment
-
-    gaths = segment_operands(src_t, w_t, ov)
-    shapes = [
-        [int(g.shape[0]), *(int(x) for x in w.shape)]
-        for g, w in zip(gaths, w_t)
-    ]
-
-    @functools.partial(jax.jit, static_argnums=0)
-    def contract_all(impl):
-        return jnp.concatenate([
-            spf_grouped._contract(g, w, impl).reshape(g.shape[0], -1)
-            for g, w in zip(gaths, w_t)
-        ], axis=1)
-
-    want = contract_all(spf.JNP)
-    for entry, name in (
-        ("batched_minplus", "pallas"), ("batched_minplus_t", "pallas_t"),
-    ):
-        impl = spf.KernelImpl(name, interpret)
-        check(
-            f"pallas_grouped.{entry}", shapes,
-            lambda impl=impl: contract_all(impl), want,
-        )
-    return {"interpret": interpret, "kernels": kernels}
-
-
 def leg_mesh4(nodes_ksp2: int = 1008, nodes_engine: int = 10000,
               events: int = 4) -> dict:
     """Four devices: the KSP2 leg under the engine mesh ``main.py``
@@ -798,7 +700,6 @@ def main() -> int:
         ("ksp2_grid_961", leg_ksp2_grid),
         ("multiarea_2x1000", leg_multiarea),
         ("serve", leg_serve),
-        ("kernels", lambda: leg_kernels(interpret=False)),
     ]
     if device["count"] >= 4:
         legs.append(("mesh4", leg_mesh4))

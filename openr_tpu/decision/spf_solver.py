@@ -204,11 +204,6 @@ SPF_COUNTERS = _get_registry().counter_dict(
         "decision.device_solves",
         "decision.device_state_resets",
         "decision.backend_switches",
-        # multi-area batched dispatch (ops.world_batch): builds whose
-        # area views were solved as one tenant-plane dispatch, and
-        # preload attempts that fell back to sequential solves
-        "decision.world_preloads",
-        "decision.world_preload_failures",
         # SpfSolver._views LRU demotions — the miniature of
         # tenancy.evictions; a hot loop here means the view cache cap
         # (OPENR_VIEW_CACHE_CAP) is below the live area count
@@ -902,7 +897,6 @@ class SpfSolver:
         enable_best_route_selection: bool = True,
         backend: str = "device",
         view_cache_cap: Optional[int] = None,
-        world_batch: Optional[bool] = None,
     ):
         self.my_node_name = my_node_name
         self.enable_v4 = enable_v4
@@ -917,14 +911,6 @@ class SpfSolver:
             view_cache_cap
             if view_cache_cap is not None
             else VIEW_CACHE_CAP_DEFAULT,
-        )
-        # multi-area tenant-plane dispatch (ops.world_batch): None ->
-        # env opt-in. Off by default — single-area deployments gain
-        # nothing and the sequential path is the proven one.
-        self.world_batch = (
-            world_batch
-            if world_batch is not None
-            else os.environ.get("OPENR_WORLD_BATCH") == "1"
         )
         self.static_mpls_routes: Dict[int, List[NextHop]] = {}
         self.best_routes_cache: Dict[IpPrefix, BestRouteSelectionResult] = {}
@@ -1195,47 +1181,6 @@ class SpfSolver:
             reg.counter_bump("ops.spec_dispatches")
             staged += 1
         return staged
-
-    def _world_preload(
-        self,
-        my_node_name: str,
-        area_link_states: AreaLinkStates,
-    ) -> None:
-        """Solve every eligible area's {root}+neighbors view as ONE
-        batched tenant-plane dispatch (ops.world_batch) and preload the
-        results into the resident-view consumption path, so the
-        per-area SpfView constructions below become host-side slices
-        instead of N sequential device round trips. Strictly an
-        optimization: any failure (or an area already holding a cached
-        view) falls back to the per-area sequential solve."""
-        if self.backend != "device" or not self.world_batch:
-            return
-        items = []
-        for area in sorted(area_link_states):
-            ls = area_link_states[area]
-            if not ls.has_node(my_node_name):
-                continue
-            per_ls = self._views.get(ls)
-            if per_ls is not None and (
-                (ls.topology_version, my_node_name) in per_ls
-            ):
-                continue  # cached view: a preload would go unconsumed
-            items.append((f"{area}/{my_node_name}", ls, my_node_name))
-        if len(items) < 2:
-            return  # nothing to batch
-        try:
-            from openr_tpu.ops import world_batch as _world_batch
-
-            views = _world_batch.get_world_manager().solve_views(
-                [(tid, ls, root) for tid, ls, root in items]
-            )
-            for (_tid, ls, _root), (graph, srcs, packed) in zip(
-                items, views
-            ):
-                _ELL_RESIDENT.preload_view(ls, graph, srcs, packed)
-            SPF_COUNTERS["decision.world_preloads"] += 1
-        except Exception:
-            SPF_COUNTERS["decision.world_preload_failures"] += 1
 
     def _view(self, area: str, ls: LinkState, root: str) -> SpfView:
         del area  # identity of the LinkState object is the key
@@ -1522,7 +1467,6 @@ class SpfSolver:
         # out of the solver while it is written: a build that raises
         # half way leaves no table, and the next one fills a new one
         table, self._route_table = self._route_table, None
-        self._world_preload(my_node_name, area_link_states)
         affected = self._prefetch_ksp2_paths(
             my_node_name, area_link_states, prefix_state
         )

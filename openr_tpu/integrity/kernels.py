@@ -117,14 +117,12 @@ def ell_residual(dr, v_t, w_t, overloaded, bands):
     return jnp.sum((nxt != dr).astype(jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("meta", "impl"))
-def grouped_residual(dr, v_t, w_t, overloaded, meta, impl):
+@functools.partial(jax.jit, static_argnames=("meta",))
+def grouped_residual(dr, v_t, w_t, overloaded, meta):
     """Grouped backend: same identity check through the per-segment
     dense contraction the grouped solver runs."""
     t_ids = jnp.arange(dr.shape[0], dtype=jnp.int32)
-    nxt = sg._grouped_relax(
-        dr, meta, v_t, w_t, overloaded, t_ids, impl=impl
-    )
+    nxt = sg._grouped_relax(dr, meta, v_t, w_t, overloaded, t_ids)
     return jnp.sum((nxt != dr).astype(jnp.int32))
 
 
@@ -153,10 +151,10 @@ def ell_sample_oracle(dr, ids, v_t, w_t, overloaded, bands, n):
     return jnp.sum(jnp.any(cold != dr[ids], axis=1).astype(jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("meta", "n", "impl"))
-def grouped_sample_oracle(dr, ids, v_t, w_t, overloaded, meta, n, impl):
+@functools.partial(jax.jit, static_argnames=("meta", "n"))
+def grouped_sample_oracle(dr, ids, v_t, w_t, overloaded, meta, n):
     cold = sg._grouped_fixed_point(
-        meta, v_t, w_t, overloaded, ids, n, reverse=True, impl=impl
+        meta, v_t, w_t, overloaded, ids, n, reverse=True
     )
     return jnp.sum(jnp.any(cold != dr[ids], axis=1).astype(jnp.int32))
 

@@ -2972,12 +2972,9 @@ class RouteSweepEngine(ResidentEngineContract):
 from openr_tpu.ops import spf_grouped as sg  # noqa: E402
 
 
-@functools.partial(
-    jax.jit, static_argnames=("meta", "n", "impl")
-)
+@functools.partial(jax.jit, static_argnames=("meta", "n"))
 def _grouped_full_resident(
     v_t, w_t, overloaded, samp_ids, samp_v, samp_w, pos_w, meta, n,
-    impl,
 ):
     """Grouped-backend cold build: every destination row solved through
     the gather-free block-bipartite relaxation (ops.spf_grouped), DR +
@@ -2986,7 +2983,7 @@ def _grouped_full_resident(
     bit-comparable by canonical digest."""
     t_ids = jnp.arange(n, dtype=jnp.int32)
     dr = sg._grouped_fixed_point(
-        meta, v_t, w_t, overloaded, t_ids, n, reverse=True, impl=impl
+        meta, v_t, w_t, overloaded, t_ids, n, reverse=True
     )
     nh_count = sg._grouped_nh_counts(
         dr, meta, v_t, w_t, overloaded, t_ids
@@ -3000,12 +2997,10 @@ def _grouped_full_resident(
     return dr, digests, packed
 
 
-@functools.partial(
-    jax.jit, static_argnames=("meta", "n", "mesh", "impl")
-)
+@functools.partial(jax.jit, static_argnames=("meta", "n", "mesh"))
 def _sharded_grouped_full_resident(
     v_t, w_t, overloaded, samp_ids, samp_v, samp_w, pos_w, meta, n,
-    mesh, impl,
+    mesh,
 ):
     nseg = len(v_t)
 
@@ -3016,7 +3011,6 @@ def _sharded_grouped_full_resident(
         vote = lambda bit: jax.lax.psum(bit, SOURCES_AXIS)  # noqa: E731
         dr = sg._grouped_fixed_point(
             meta, v_r, w_r, ov_r, t_blk, n, reverse=True, vote=vote,
-            impl=impl,
         )
         nh_count = sg._grouped_nh_counts(
             dr, meta, v_r, w_r, ov_r, t_blk
@@ -3065,16 +3059,14 @@ def _patch_segments_fn(w_t, upd_g, upd_s, upd_r, upd_w):
 _patch_segments = jax.jit(_patch_segments_fn)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("meta", "n", "k", "impl")
-)
+@functools.partial(jax.jit, static_argnames=("meta", "n", "k"))
 def _grouped_churn_step(
     v_t, w_t, upd_g, upd_s, upd_r, upd_w,
     dr, digests, packed_res,
     e_u, e_v, e_w_old, e_w_new,
     overloaded_new,
     samp_ids, samp_v, samp_w, pos_w,
-    meta, n, k, impl,
+    meta, n, k,
 ):
     """Fused single-chip grouped churn dispatch: detection against the
     resident DR, segment-slot weight scatter, affected-row re-solve
@@ -3087,7 +3079,6 @@ def _grouped_churn_step(
     dr, digests, packed_res, out = _resolve_and_pack(
         lambda t: sg._grouped_fixed_point(
             meta, v_t, new_w, overloaded_new, t, n, reverse=True,
-            impl=impl,
         ),
         lambda rows, t: sg._grouped_nh_counts(
             rows, meta, v_t, new_w, overloaded_new, t
@@ -3098,15 +3089,13 @@ def _grouped_churn_step(
     return new_w, dr, digests, packed_res, out
 
 
-@functools.partial(
-    jax.jit, static_argnames=("meta", "n", "k", "mesh", "impl")
-)
+@functools.partial(jax.jit, static_argnames=("meta", "n", "k", "mesh"))
 def _sharded_grouped_churn_step(
     v_t, w_t, dr, digests, packed_res,
     e_u, e_v, e_w_old, e_w_new,
     overloaded_new,
     samp_ids, samp_v, samp_w, pos_w,
-    meta, n, k, mesh, impl,
+    meta, n, k, mesh,
 ):
     """Sharded grouped churn: per-shard detection + re-solve over the
     row-sharded resident DR (segment tensors arrive ALREADY PATCHED by
@@ -3130,7 +3119,6 @@ def _sharded_grouped_churn_step(
         return _resolve_and_pack(
             lambda t: sg._grouped_fixed_point(
                 meta, v_r, w_r, ov_r, t, n, reverse=True, vote=vote,
-                impl=impl,
             ),
             lambda rows, t: sg._grouped_nh_counts(
                 rows, meta, v_r, w_r, ov_r, t
@@ -3186,12 +3174,10 @@ def _grouped_frontier_probe(
     return cone, meta_row
 
 
-@functools.partial(
-    jax.jit, static_argnames=("meta", "n", "impl")
-)
+@functools.partial(jax.jit, static_argnames=("meta", "n"))
 def _grouped_frontier_step(
     v_t, w_t, cone, dr, overloaded, samp_ids, samp_v, samp_w, pos_w,
-    meta, n, impl,
+    meta, n,
 ):
     """Grouped frontier re-solve: full-width WARM fixed point through
     the gather-free grouped relaxation over the PATCHED segments, cone
@@ -3203,7 +3189,7 @@ def _grouped_frontier_step(
     t_ids = jnp.arange(n, dtype=jnp.int32)
     warm0 = jnp.where(cone, INF, dr)
     dr2 = sg._grouped_fixed_point(
-        meta, v_t, w_t, overloaded, t_ids, n, reverse=True, impl=impl,
+        meta, v_t, w_t, overloaded, t_ids, n, reverse=True,
         init=warm0,
     )
     nh_count = sg._grouped_nh_counts(
@@ -3220,13 +3206,12 @@ def _grouped_frontier_step(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("meta", "n", "n_real", "max_jumps", "impl"),
+    static_argnames=("meta", "n", "n_real", "max_jumps"),
 )
 def _grouped_overflow_chain(
     v_t, w_old_t, w_new_t, dr, packed_res,
     e_u, e_v, e_w_old, e_w_new, cell_limit, overloaded_new,
     samp_ids, samp_v, samp_w, pos_w, meta, n, n_real, max_jumps,
-    impl,
 ):
     """Grouped fused overflow chain: cone probe over the PRE-patch
     segment slabs, on-device frontier-vs-full seed select (the same
@@ -3249,7 +3234,7 @@ def _grouped_overflow_chain(
     warm0 = jnp.where(eff_cone, INF, dr)
     dr2 = sg._grouped_fixed_point(
         meta, v_t, w_new_t, overloaded_new, t_ids, n, reverse=True,
-        impl=impl, init=warm0,
+        init=warm0,
     )
     nh_count = sg._grouped_nh_counts(
         dr2, meta, v_t, w_new_t, overloaded_new, t_ids
@@ -3306,12 +3291,10 @@ def _sharded_grouped_frontier_probe(
     )(dr, *v_t, *w_t, e_u, e_v, e_w_old, e_w_new, cell_limit)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("meta", "n", "mesh", "impl")
-)
+@functools.partial(jax.jit, static_argnames=("meta", "n", "mesh"))
 def _sharded_grouped_frontier_step(
     v_t, w_t, cone, dr, overloaded, samp_ids, samp_v, samp_w, pos_w,
-    meta, n, mesh, impl,
+    meta, n, mesh,
 ):
     """Sharded grouped frontier re-solve over the PATCHED (replicated)
     segment tensors, each shard warm-seeding its own DR rows outside
@@ -3326,7 +3309,7 @@ def _sharded_grouped_frontier_step(
         warm0 = jnp.where(cone_s, INF, dr_s)
         dr2 = sg._grouped_fixed_point(
             meta, v_r, w_r, ov_r, t_blk, n, reverse=True, vote=vote,
-            impl=impl, init=warm0,
+            init=warm0,
         )
         nh_count = sg._grouped_nh_counts(
             dr2, meta, v_r, w_r, ov_r, t_blk
@@ -3362,14 +3345,13 @@ def _sharded_grouped_frontier_step(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("meta", "n", "n_real", "max_jumps", "mesh",
-                     "impl"),
+    static_argnames=("meta", "n", "n_real", "max_jumps", "mesh"),
 )
 def _sharded_grouped_overflow_chain(
     v_t, w_old_t, w_new_t, dr, packed_res,
     e_u, e_v, e_w_old, e_w_new, cell_limit, overloaded_new,
     samp_ids, samp_v, samp_w, pos_w, meta, n, n_real, max_jumps,
-    mesh, impl,
+    mesh,
 ):
     """Sharded grouped fused overflow chain — the grouped twin of
     _sharded_overflow_chain: psum-voted per-shard probe (policy inputs
@@ -3401,7 +3383,7 @@ def _sharded_grouped_overflow_chain(
         warm0 = jnp.where(eff_cone, INF, dr_s)
         dr2 = sg._grouped_fixed_point(
             meta, v_r, w_n, ov_r, t_blk, n, reverse=True, vote=vote,
-            impl=impl, init=warm0,
+            init=warm0,
         )
         nh_count = sg._grouped_nh_counts(
             dr2, meta, v_r, w_n, ov_r, t_blk
@@ -3463,7 +3445,6 @@ class GroupedRouteSweepEngine(RouteSweepEngine):
         return int(jax.device_get(integrity_kernels.grouped_residual(
             self._dr, self.sweeper.v_t, self.sweeper.w_t,
             self.sweeper.overloaded, self.sweeper.meta,
-            sg.get_grouped_impl(),
         )))
 
     def _sample_oracle(self, ids_t):
@@ -3471,7 +3452,7 @@ class GroupedRouteSweepEngine(RouteSweepEngine):
         return integrity_kernels.grouped_sample_oracle(
             self._dr, ids_t, self.sweeper.v_t, self.sweeper.w_t,
             self.sweeper.overloaded, self.sweeper.meta,
-            self.graph.n_pad, sg.get_grouped_impl(),
+            self.graph.n_pad,
         )
 
     def _compile_backend(self, ls):
@@ -3490,7 +3471,6 @@ class GroupedRouteSweepEngine(RouteSweepEngine):
         )
 
     def _full_resident(self, graph):
-        impl = sg.get_grouped_impl()
         if self.mesh is None:
             # openr-lint: disable=sharding-spec -- single-chip cold
             # build (mesh is None): one device, no axis to spec
@@ -3503,7 +3483,7 @@ class GroupedRouteSweepEngine(RouteSweepEngine):
                     self.sweeper._samp_v_dev,
                     self.sweeper._samp_w_dev, self.sweeper._pos_w_dev,
                 ),
-                dict(meta=self.sweeper.meta, n=graph.n_pad, impl=impl),
+                dict(meta=self.sweeper.meta, n=graph.n_pad),
             )
         return aot_call(
             "grouped_full_resident_sharded",
@@ -3516,7 +3496,7 @@ class GroupedRouteSweepEngine(RouteSweepEngine):
             ),
             dict(
                 meta=self.sweeper.meta, n=graph.n_pad,
-                mesh=self.mesh, impl=impl,
+                mesh=self.mesh,
             ),
         )
 
@@ -3592,7 +3572,6 @@ class GroupedRouteSweepEngine(RouteSweepEngine):
         fault_point(FAULT_DISPATCH)
         fault_point(FAULT_DEVICE_LOST)
         graph = ctx["patched"]
-        impl = sg.get_grouped_impl()
         upd_g, upd_s, upd_r, upd_w = ctx["upd"]
         if self.mesh is None:
             (new_w, dr, digests, packed_res,
@@ -3612,7 +3591,6 @@ class GroupedRouteSweepEngine(RouteSweepEngine):
                 ),
                 dict(
                     meta=self.sweeper.meta, n=graph.n_pad, k=k,
-                    impl=impl,
                 ),
             )
             # cache the fused step's on-device segment patch for an
@@ -3638,7 +3616,7 @@ class GroupedRouteSweepEngine(RouteSweepEngine):
                 ),
                 dict(
                     meta=self.sweeper.meta, n=graph.n_pad, k=k,
-                    mesh=self.mesh, impl=impl,
+                    mesh=self.mesh,
                 ),
             )
             segments = self._split_segments(packed_dev, k)
@@ -3717,7 +3695,6 @@ class GroupedRouteSweepEngine(RouteSweepEngine):
         """Grouped masked full-width dispatch: warm fixed point with
         only cone cells reset, over the ALREADY-PATCHED resident
         segment tensors (_apply_patch_resident ran)."""
-        impl = sg.get_grouped_impl()
         if self.mesh is None:
             # openr-lint: disable=sharding-spec -- single-chip frontier
             # re-solve (mesh is None): no mesh axis to spec
@@ -3732,7 +3709,6 @@ class GroupedRouteSweepEngine(RouteSweepEngine):
                 ),
                 dict(
                     meta=self.sweeper.meta, n=self.graph.n_pad,
-                    impl=impl,
                 ),
             )
         return aot_call(
@@ -3746,7 +3722,7 @@ class GroupedRouteSweepEngine(RouteSweepEngine):
             ),
             dict(
                 meta=self.sweeper.meta, n=self.graph.n_pad,
-                mesh=self.mesh, impl=impl,
+                mesh=self.mesh,
             ),
         )
 
@@ -3763,7 +3739,6 @@ class GroupedRouteSweepEngine(RouteSweepEngine):
         lim = jnp.asarray([limit], dtype=jnp.float32)
         if self.plan is not None:
             lim = self.plan.replicate(lim)
-        impl = sg.get_grouped_impl()
         if self.mesh is None:
             # openr-lint: disable=sharding-spec -- single-chip fused
             # overflow chain (mesh is None): no mesh axis to spec
@@ -3780,7 +3755,6 @@ class GroupedRouteSweepEngine(RouteSweepEngine):
                 dict(
                     meta=self.sweeper.meta, n=self.graph.n_pad,
                     n_real=self.graph.n, max_jumps=_FRONTIER_MAX_JUMPS,
-                    impl=impl,
                 ),
             )
         return aot_call(
@@ -3796,6 +3770,6 @@ class GroupedRouteSweepEngine(RouteSweepEngine):
             dict(
                 meta=self.sweeper.meta, n=self.graph.n_pad,
                 n_real=self.graph.n, max_jumps=_FRONTIER_MAX_JUMPS,
-                mesh=self.mesh, impl=impl,
+                mesh=self.mesh,
             ),
         )

@@ -34,8 +34,6 @@ at INF = 2**30 - 1 (int32-safe: INF + INF == 2**31 - 2 < 2**31 - 1).
 from __future__ import annotations
 
 import functools
-import os
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -57,71 +55,26 @@ def _mask_transit_rows(d: jnp.ndarray, overloaded: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(overloaded[:, None], ident_row, d)
 
 
-class KernelImpl(NamedTuple):
-    """A relaxation kernel choice as a jitted function sees it: which
-    implementation and, for a Pallas one, whether it runs interpreted
-    (CPU tests) or compiled (the chip). Never inferred from the
-    platform: whoever selects a Pallas kernel says which. Hashable, so
-    it rides the static ``impl`` argument of every dispatch."""
-
-    name: str
-    interpret: bool = False
+# The one value ``_spf_view_batch``'s ``impl`` keyword accepts. Both exist
+# for tests/chipbench/test_tpu_lowering.py, which lowers that program with
+# ``impl=spf.JNP`` and is the benchmark's file; they go with ROADMAP B18.
+JNP = "jnp"
 
 
-JNP = KernelImpl("jnp")
-
-# min-plus implementation selector: "jnp" (XLA fused broadcast+reduce),
-# "pallas" (explicit VMEM tiling, openr_tpu.ops.pallas_minplus), or
-# "auto" — a MEASURED per-shape winner picked by ops.autotune at the
-# first eager call for each operand shape (the jnp-vs-pallas winner
-# flips with shape and hardware; see ops/autotune.py). Resolution
-# happens in the public wrappers below, before jit entry, so traces
-# only ever see a concrete impl as their static argument.
-_MINPLUS_IMPL = KernelImpl(os.environ.get("OPENR_MINPLUS", "jnp"))
-
-
-def set_minplus_impl(name: str, interpret: bool = False) -> None:
-    global _MINPLUS_IMPL
-    assert name in ("jnp", "pallas", "auto"), name
-    _MINPLUS_IMPL = KernelImpl(name, interpret)
-
-
-def get_minplus_impl() -> KernelImpl:
-    return _MINPLUS_IMPL
-
-
-def _impl_for(shape) -> KernelImpl:
-    """Concrete impl for one dispatch: "auto" resolves to the measured
-    per-shape winner ([rows, n] against [n, n])."""
-    if _MINPLUS_IMPL.name != "auto":
-        return _MINPLUS_IMPL
-    from openr_tpu.ops import autotune
-
-    return autotune.resolve_minplus(
-        tuple(shape), _MINPLUS_IMPL.interpret
-    )
-
-
-def _minplus(
-    a: jnp.ndarray, b: jnp.ndarray, impl: KernelImpl = JNP
-) -> jnp.ndarray:
+def _minplus(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """(a (x) b)[s, j] = min_k a[s, k] + b[k, j], saturating at INF.
 
-    jnp path: XLA fuses the broadcast-add into the min-reduction, so the
+    XLA fuses the broadcast-add into the min-reduction, so the
     [S, N, N] intermediate is never materialized in HBM.
     """
-    if impl.name == "pallas":
-        from openr_tpu.ops.pallas_minplus import minplus as pallas_minplus
-
-        return pallas_minplus(a, b, interpret=impl.interpret)
     return jnp.minimum(
         jnp.min(a[:, :, None] + b[None, :, :], axis=1), INF
     ).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("impl",))
+@jax.jit
 def _all_pairs_distances(
-    w: jnp.ndarray, overloaded: jnp.ndarray, impl: KernelImpl
+    w: jnp.ndarray, overloaded: jnp.ndarray
 ) -> jnp.ndarray:
     n = w.shape[0]
     eye = (
@@ -137,7 +90,7 @@ def _all_pairs_distances(
     def body(state):
         d, _, it = state
         d_transit = _mask_transit_rows(d, overloaded)
-        nxt = jnp.minimum(d, _minplus(d, d_transit, impl))
+        nxt = jnp.minimum(d, _minplus(d, d_transit))
         return nxt, jnp.any(nxt < d), it + 1
 
     d, _, _ = jax.lax.while_loop(cond, body, (d0, jnp.bool_(True), 0))
@@ -152,15 +105,14 @@ def all_pairs_distances(
     w: [N, N] one-hop metric matrix (INF = no edge). Diagonal is forced
     to 0. overloaded: [N] bool transit-exclusion mask.
     """
-    return _all_pairs_distances(w, overloaded, _impl_for(w.shape))
+    return _all_pairs_distances(w, overloaded)
 
 
-@functools.partial(jax.jit, static_argnames=("impl",))
+@jax.jit
 def _distances_from_sources(
     w: jnp.ndarray,
     overloaded: jnp.ndarray,
     src_ids: jnp.ndarray,
-    impl: KernelImpl,
 ) -> jnp.ndarray:
     n = w.shape[0]
     t = _mask_transit_rows(w, overloaded)
@@ -173,7 +125,7 @@ def _distances_from_sources(
 
     def body(state):
         d, _, it = state
-        nxt = jnp.minimum(d, _minplus(d, t, impl))
+        nxt = jnp.minimum(d, _minplus(d, t))
         return nxt, jnp.any(nxt < d), it + 1
 
     d, _, _ = jax.lax.while_loop(cond, body, (d0, jnp.bool_(True), 0))
@@ -188,10 +140,7 @@ def distances_from_sources(
     Bellman-Ford over the transit-masked one-hop matrix. Initial rows are
     the sources' direct edges (so an overloaded source still originates).
     """
-    return _distances_from_sources(
-        w, overloaded, src_ids,
-        _impl_for((src_ids.shape[0], w.shape[-1])),
-    )
+    return _distances_from_sources(w, overloaded, src_ids)
 
 
 @jax.jit
@@ -261,8 +210,9 @@ def _spf_view_batch(
     overloaded: jnp.ndarray,
     srcs: jnp.ndarray,
     use_link_metric: bool,
-    impl: KernelImpl,
+    impl: str = JNP,
 ):
+    assert impl == JNP, impl
     n = metric.shape[0]
     b = srcs.shape[0]
     w = metric if use_link_metric else jnp.where(metric < INF, jnp.int32(1), INF)
@@ -276,7 +226,7 @@ def _spf_view_batch(
 
     def body(state):
         d, _, it = state
-        nxt = jnp.minimum(d, _minplus(d, t, impl))
+        nxt = jnp.minimum(d, _minplus(d, t))
         return nxt, jnp.any(nxt < d), it + 1
 
     d, _, _ = jax.lax.while_loop(cond, body, (d0, jnp.bool_(True), 0))
@@ -325,10 +275,7 @@ def spf_view_batch(
     is True iff batch node i is a valid ECMP first hop from the source
     toward j.
     """
-    packed = _spf_view_batch(
-        metric, overloaded, srcs, use_link_metric,
-        _impl_for((srcs.shape[0], metric.shape[-1])),
-    )
+    packed = _spf_view_batch(metric, overloaded, srcs, use_link_metric)
     b = srcs.shape[0]
     return packed[:b], packed[b:].astype(jnp.bool_)
 
@@ -342,15 +289,10 @@ def spf_view_batch_packed(
     """Single-buffer variant of ``spf_view_batch``: returns [2B, N] int32
     (rows [0, B) distances, rows [B, 2B) first-hop 0/1) so the host pays
     exactly one device->host transfer."""
-    return _spf_view_batch(
-        metric, overloaded, srcs, use_link_metric,
-        _impl_for((srcs.shape[0], metric.shape[-1])),
-    )
+    return _spf_view_batch(metric, overloaded, srcs, use_link_metric)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("use_link_metric", "impl")
-)
+@functools.partial(jax.jit, static_argnames=("use_link_metric",))
 def _reconverge_step(
     metric: jnp.ndarray,
     patch_ids: jnp.ndarray,
@@ -358,10 +300,9 @@ def _reconverge_step(
     overloaded: jnp.ndarray,
     srcs: jnp.ndarray,
     use_link_metric: bool,
-    impl: KernelImpl,
 ):
     m = metric.at[patch_ids, :].set(patch_vals)
-    packed = _spf_view_batch(m, overloaded, srcs, use_link_metric, impl)
+    packed = _spf_view_batch(m, overloaded, srcs, use_link_metric)
     return m, packed
 
 
@@ -382,22 +323,20 @@ def reconverge_step(
     steady-state churn — and the packed result costs one transfer.
     """
     return _reconverge_step(
-        metric, patch_ids, patch_vals, overloaded, srcs, use_link_metric,
-        _impl_for((srcs.shape[0], metric.shape[-1])),
+        metric, patch_ids, patch_vals, overloaded, srcs, use_link_metric
     )
 
 
-@functools.partial(jax.jit, static_argnames=("use_link_metric", "impl"))
+@functools.partial(jax.jit, static_argnames=("use_link_metric",))
 def _spf_from_source_with_first_hops(
     metric: jnp.ndarray,
     hop: jnp.ndarray,
     overloaded: jnp.ndarray,
     src_id: jnp.ndarray,
     use_link_metric: bool,
-    impl: KernelImpl,
 ):
     w = metric if use_link_metric else hop
-    d_all = _all_pairs_distances(w, overloaded, impl)
+    d_all = _all_pairs_distances(w, overloaded)
     d_src = d_all[src_id, :]
     fh = first_hop_matrix(w, overloaded, src_id, d_src, d_all)
     return d_src, d_all, fh
@@ -416,6 +355,5 @@ def spf_from_source_with_first_hops(
     Returns (d_src [N], d_all [N, N], first_hops [N, N] bool).
     """
     return _spf_from_source_with_first_hops(
-        metric, hop, overloaded, src_id, use_link_metric,
-        _impl_for(metric.shape),
+        metric, hop, overloaded, src_id, use_link_metric
     )

@@ -53,7 +53,6 @@ renumbering.
 from __future__ import annotations
 
 import functools
-import os
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -63,7 +62,7 @@ import jax.numpy as jnp
 from jax import shard_map
 import numpy as np
 
-from openr_tpu.ops.spf import INF, JNP, KernelImpl
+from openr_tpu.ops.spf import INF
 from openr_tpu.ops.spf_sparse import (
     _as_device_ids,
     _in_edges,
@@ -71,52 +70,9 @@ from openr_tpu.ops.spf_sparse import (
     _pad_up,
 )
 
-# Relaxation contraction backend: "jnp" leaves the broadcast+min-reduce
-# to XLA's fuser; "pallas"/"pallas_t" run ops.pallas_grouped (explicit
-# VMEM tiling); "auto" resolves to a MEASURED winner via ops.autotune
-# (coarse: one representative block shape per platform — the grouped
-# contraction's tiling is dominated by platform, not by the exact
-# segment dims). Nothing arms "auto" since PR 28 (ROADMAP D3).
-_GROUPED_IMPL = KernelImpl(os.environ.get("OPENR_GROUPED_IMPL", "jnp"))
-
-# representative [B, G, S, R] probe block for the "auto" measurement
-_AUTO_PROBE_SHAPE = (32, 8, 8, 16)
-
-
-def set_grouped_impl(name: str, interpret: bool = False) -> None:
-    global _GROUPED_IMPL
-    assert name in ("jnp", "pallas", "pallas_t", "auto"), name
-    _GROUPED_IMPL = KernelImpl(name, interpret)
-
-
-def get_grouped_impl() -> KernelImpl:
-    """The concrete contraction every grouped dispatch passes as its
-    static ``impl`` ("auto" resolved to the measured winner)."""
-    if _GROUPED_IMPL.name != "auto":
-        return _GROUPED_IMPL
-    from openr_tpu.ops import autotune
-
-    return autotune.resolve_grouped(
-        _AUTO_PROBE_SHAPE, _GROUPED_IMPL.interpret
-    )
-
-
-def _contract(gath, w, impl: KernelImpl):
-    """c[b, g, r] = min_s gath[b, g, s] + w[g, s, r] (INF-saturating)."""
-    if impl.name == "pallas":
-        from openr_tpu.ops import pallas_grouped
-
-        c = pallas_grouped.batched_minplus(
-            jnp.transpose(gath, (1, 0, 2)), w, interpret=impl.interpret
-        )  # [G, B, R]
-        return jnp.transpose(c, (1, 0, 2))
-    if impl.name == "pallas_t":
-        from openr_tpu.ops import pallas_grouped
-
-        c = pallas_grouped.batched_minplus_t(
-            jnp.transpose(gath, (1, 2, 0)), w, interpret=impl.interpret
-        )  # [G, R, B] — lanes carry the batch, sublanes carry R
-        return jnp.transpose(c, (2, 0, 1))
+def _contract(gath, w):
+    """c[b, g, r] = min_s gath[b, g, s] + w[g, s, r] (INF-saturating).
+    The broadcast+min-reduce is left to XLA's fuser."""
     return jnp.min(
         jnp.minimum(gath[:, :, :, None] + w[None], INF), axis=2
     )
@@ -376,8 +332,7 @@ def device_tensors(graph: GroupedGraph):
     return tuple(srcs), tuple(ws)
 
 
-def _grouped_relax(d, meta, srcs_t, ws_t, overloaded, t_ids,
-                   impl=JNP):
+def _grouped_relax(d, meta, srcs_t, ws_t, overloaded, t_ids):
     """One relaxation [B, N] -> [B, N] over the grouped bands as dense
     per-segment contractions. ``t_ids`` None => forward transit mask
     (edge origin overloaded); else the reverse row-dependent mask
@@ -401,7 +356,7 @@ def _grouped_relax(d, meta, srcs_t, ws_t, overloaded, t_ids,
                     src[None, :, :] != t_ids[:, None, None]
                 )
             gath = jnp.where(blocked, INF, gath)
-            c = _contract(gath, w, impl)  # [B, G, R]
+            c = _contract(gath, w)  # [B, G, R]
             if axis == 2:
                 c = jnp.transpose(c, (0, 2, 1))  # -> [B, G1, G2]
             acc = jnp.minimum(acc, c.reshape(c.shape[0], rows))
@@ -413,7 +368,7 @@ def _grouped_relax(d, meta, srcs_t, ws_t, overloaded, t_ids,
 
 def _grouped_fixed_point(
     meta, srcs_t, ws_t, overloaded, ids, n, reverse, vote=None,
-    impl=JNP, init=None,
+    init=None,
 ):
     """Distance fixed point from unit init. ``reverse=False``: rows are
     SOURCES (forward all-sources; init = one unmasked relax so an
@@ -432,9 +387,7 @@ def _grouped_fixed_point(
     else:
         assert init is None, "warm seed is a reverse-sweep contract"
         no_overload = jnp.zeros_like(overloaded)
-        d0 = _grouped_relax(
-            unit, meta, srcs_t, ws_t, no_overload, None, impl=impl
-        )
+        d0 = _grouped_relax(unit, meta, srcs_t, ws_t, no_overload, None)
 
     t_ids = ids if reverse else None
 
@@ -444,9 +397,7 @@ def _grouped_fixed_point(
 
     def body(state):
         d, _, it = state
-        nxt = _grouped_relax(
-            d, meta, srcs_t, ws_t, overloaded, t_ids, impl=impl
-        )
+        nxt = _grouped_relax(d, meta, srcs_t, ws_t, overloaded, t_ids)
         local = jnp.any(nxt < d).astype(jnp.int32)
         return nxt, local if vote is None else vote(local), it + 1
 
@@ -454,10 +405,10 @@ def _grouped_fixed_point(
     return d
 
 
-@functools.partial(jax.jit, static_argnames=("meta", "n", "impl"))
-def _grouped_from_sources(srcs_t, ws_t, overloaded, ids, meta, n, impl):
+@functools.partial(jax.jit, static_argnames=("meta", "n"))
+def _grouped_from_sources(srcs_t, ws_t, overloaded, ids, meta, n):
     return _grouped_fixed_point(
-        meta, srcs_t, ws_t, overloaded, ids, n, reverse=False, impl=impl
+        meta, srcs_t, ws_t, overloaded, ids, n, reverse=False
     )
 
 
@@ -479,7 +430,7 @@ def grouped_distances_from_sources(
     st = state if state is not None else GroupedState(graph)
     return _grouped_from_sources(
         st.src, st.w, st.overloaded,
-        _as_device_ids(src_ids), st.meta, graph.n_pad, get_grouped_impl(),
+        _as_device_ids(src_ids), st.meta, graph.n_pad,
     )
 
 
@@ -637,7 +588,7 @@ def _grouped_cone_expand(sel_dr, meta, srcs_t, ws_t, e_u, e_v, e_w_old,
 
 def _grouped_route_block_body(
     srcs_t, ws_t, overloaded, t_ids, samp_ids, samp_v, samp_w, pos_w,
-    meta, n, vote=None, impl=JNP,
+    meta, n, vote=None,
 ):
     """Grouped twin of route_sweep._route_block_body: same packed
     layout, same digest algebra — only the relaxation backend differs,
@@ -646,7 +597,7 @@ def _grouped_route_block_body(
 
     dr = _grouped_fixed_point(
         meta, srcs_t, ws_t, overloaded, t_ids, n, reverse=True,
-        vote=vote, impl=impl,
+        vote=vote,
     )
     nh_count = _grouped_nh_counts(
         dr, meta, srcs_t, ws_t, overloaded, t_ids
@@ -670,14 +621,14 @@ def _grouped_route_block_body(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("meta", "n", "impl"))
+@functools.partial(jax.jit, static_argnames=("meta", "n"))
 def _grouped_route_block(
     srcs_t, ws_t, overloaded, t_ids, samp_ids, samp_v, samp_w, pos_w,
-    meta, n, impl,
+    meta, n,
 ):
     return _grouped_route_block_body(
         srcs_t, ws_t, overloaded, t_ids, samp_ids, samp_v, samp_w,
-        pos_w, meta, n, impl=impl,
+        pos_w, meta, n,
     )
 
 
@@ -724,7 +675,6 @@ class GroupedRouteSweeper:
             _as_device_ids(t_ids),
             self._samp_ids_dev, self._samp_v_dev, self._samp_w_dev,
             self._pos_w_dev, self.meta, self.graph.n_pad,
-            get_grouped_impl(),
         )
 
     # the block loop and result assembly are layout-independent —
@@ -854,12 +804,10 @@ def grouped_patch(
     return patched, updates
 
 
-@functools.partial(
-    jax.jit, static_argnames=("meta", "n", "mesh", "impl")
-)
+@functools.partial(jax.jit, static_argnames=("meta", "n", "mesh"))
 def _sharded_grouped_route_blocks(
     srcs_t, ws_t, overloaded, t_ids, samp_ids, samp_v, samp_w, pos_w,
-    meta, n, mesh, impl,
+    meta, n, mesh,
 ):
     from jax.sharding import PartitionSpec as P
 
@@ -873,7 +821,6 @@ def _sharded_grouped_route_blocks(
         return _grouped_route_block_body(
             s_r, w_r, ov_r, t_blk, sid_r, sv_r, sw_r, pw_r, meta, n,
             vote=lambda bit: jax.lax.psum(bit, SOURCES_AXIS),
-            impl=impl,
         )
 
     ns = len(srcs_t)
@@ -908,7 +855,7 @@ def sharded_grouped_route_sweep(graph: GroupedGraph, sample_names, mesh):
             jnp.asarray(np.arange(n, dtype=np.int32)),
             sweeper._samp_ids_dev, sweeper._samp_v_dev,
             sweeper._samp_w_dev, sweeper._pos_w_dev,
-            sweeper.meta, n, mesh, get_grouped_impl(),
+            sweeper.meta, n, mesh,
         )
     )
     return rs.assemble_result(sweeper, packed)

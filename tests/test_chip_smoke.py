@@ -57,10 +57,6 @@ def test_every_leg_passes_at_tiny_sizes(artefacts, monkeypatch, capsys):
         ("serve", lambda: chip_smoke.leg_serve(
             tenant_sizes=(("grid", 4), ("mesh", 20)), rounds=12,
         )),
-        ("kernels", lambda: chip_smoke.leg_kernels(
-            interpret=True, dense_nodes=64, grouped_nodes=120,
-            grouped_batch=8,
-        )),
         ("mesh4", lambda: chip_smoke.leg_mesh4(
             nodes_ksp2=64, nodes_engine=64, events=1,
         )),
@@ -86,9 +82,6 @@ def test_every_leg_passes_at_tiny_sizes(artefacts, monkeypatch, capsys):
     for leg in ("pipeline_dense", "pipeline_sparse", "ksp2", "ksp2_grid",
                 "multiarea", "serve"):
         assert summary["legs"][leg]["parity"] is True
-    kernels = summary["legs"]["kernels"]["kernels"]
-    assert len(kernels) == 3
-    assert all(k["lowered"] and k["bit_identical"] for k in kernels.values())
     assert summary["legs"]["mesh4"]["shard_devices"] == [0, 1, 2, 3]
     assert summary["compile"]["compiles"] >= 0
     with open(artefacts / "chip_smoke.json") as f:

@@ -320,15 +320,33 @@ class TestSpanTreeEndToEnd:
                 t.to_dict() for t in tracer.traces()[-3:]]
             return find()[-1]
 
+        def decision_idle():
+            """Nothing queued for Decision, no window open, no rebuild
+            under way (the question is asked on its own loop)."""
+            d = h.decision
+            return d.evb.call_and_wait(lambda: (
+                d._kv_reader.size() == 0
+                and not d._rebuild_debounced.is_scheduled()
+                and d.pending.count == 0
+            ))
+
         h = PipelineHarness(solver_backend="device")
         try:
             topo = line_topology()
-            for db in topo.adj_dbs.values():
-                h.publish_adj(db)
+            # adjacencies last. On a loaded machine the load falls into
+            # several debounce windows, and a last window of prefix keys
+            # alone runs the per-prefix pass, which leaves the solver's
+            # table stamped with the older prefix state: the event's
+            # build below then fills a new table (touched: 5, the diff's
+            # path "whole"). With an adjacency in it, the load's last
+            # window is a whole build over the final prefix state
+            # however the windows fall.
             for pdb in topo.prefix_dbs.values():
                 h.publish_prefixes(pdb)
+            for db in topo.adj_dbs.values():
+                h.publish_adj(db)
             assert wait_until(lambda: len(h.fib.unicast_routes) >= 2)
-            time.sleep(0.4)  # the last debounce window of the load
+            assert wait_until(decision_idle)
             before = counters()
             newest = max(t.trace_id for t in tracer.traces())
 

@@ -21,6 +21,7 @@ from openr_tpu.decision.prefix_state import PrefixState
 from openr_tpu.decision.spf_solver import SPF_COUNTERS, SpfSolver
 from openr_tpu.graph.linkstate import LinkState
 from openr_tpu.models import topologies
+from openr_tpu.types import IpPrefix, PrefixDatabase, PrefixEntry
 from openr_tpu.types.lsdb import (
     PrefixForwardingAlgorithm,
     PrefixForwardingType,
@@ -356,6 +357,54 @@ class TestSpRouteReuse:
         assert (
             SPF_COUNTERS["decision.sp_route_reuses"] - before > 0
         )
+
+    def test_multi_area_build_parity(self):
+        """Three areas of unlike shape (two grids, a random mesh) that
+        share the vantage's name: the device backend's cold build and
+        its rebuild after a metric change in one area equal the host
+        backend's, unicast and MPLS."""
+
+        def build_world():
+            area_ls = {}
+            ps = PrefixState()
+            for i, topo in enumerate((
+                topologies.grid(3),
+                topologies.grid(4),
+                topologies.random_mesh(20, 3, seed=7),
+            )):
+                area = f"area{i}"
+                ls = LinkState(area=topo.area)
+                for name in sorted(topo.adj_dbs):
+                    ls.update_adjacency_database(topo.adj_dbs[name])
+                area_ls[area] = ls
+                for node in sorted(topo.adj_dbs)[:4]:
+                    nid = node.split("-")[-1]
+                    ps.update_prefix_database(
+                        PrefixDatabase(
+                            this_node_name=node,
+                            prefix_entries=(
+                                PrefixEntry(
+                                    prefix=IpPrefix.from_str(
+                                        f"fd00:{i}:{nid}::/64"
+                                    )
+                                ),
+                            ),
+                            area=area,
+                        )
+                    )
+            return area_ls, ps
+
+        area_d, ps = build_world()
+        area_h, ps_h = build_world()
+        dev = SpfSolver("node-0", backend="device")
+        host = SpfSolver("node-0", backend="host")
+        for tag in ("build1", "build2"):
+            d = dev.build_route_db("node-0", area_d, ps)
+            h = host.build_route_db("node-0", area_h, ps_h)
+            assert d.unicast_routes == h.unicast_routes, tag
+            assert d.mpls_routes == h.mpls_routes, tag
+            for ls in (area_d["area1"], area_h["area1"]):
+                _mutate_metric(ls, "node-1", 0, 44)
 
     def test_rib_policy_does_not_pollute_reuse_cache(self):
         """Decision applies RibPolicy to the dict build_route_db

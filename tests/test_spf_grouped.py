@@ -7,7 +7,6 @@ runSpf), same route product (canonical digests equal bit-for-bit
 despite the two layouts numbering nodes differently)."""
 
 import numpy as np
-import pytest
 from dataclasses import replace
 
 from openr_tpu.graph.linkstate import LinkState
@@ -153,50 +152,6 @@ class TestGroupedRouteSweep:
                 metric, nhs = got[dst]
                 assert metric == want.metric, (src, dst)
                 assert nhs == set(want.next_hops), (src, dst)
-
-    @pytest.mark.parametrize("impl", ["pallas", "pallas_t"])
-    def test_pallas_impl_matches_jnp(self, impl):
-        """Both pallas batched min-plus contractions (interpret mode on
-        CPU) must reproduce the jnp route product bit-exactly — the
-        same choice-by-measurement contract as the dense kernel."""
-        from openr_tpu.ops import spf_grouped as sg
-
-        topo = topologies.fat_tree(
-            pods=3, ssw_per_plane=2, fsw_per_pod=2, rsw_per_pod=4
-        )
-        ls = load(topo, overloaded_nodes={"fsw-0-1"})
-        names = sorted(ls.get_adjacency_databases().keys())
-        graph = sg.compile_out_grouped(ls)
-        sweeper = sg.GroupedRouteSweeper(graph, [names[0]])
-        jnp_result = sweeper.sweep(block=16)
-        sg.set_grouped_impl(impl, interpret=True)
-        try:
-            pallas_result = sweeper.sweep(block=16)
-        finally:
-            sg.set_grouped_impl("jnp")
-        np.testing.assert_array_equal(
-            jnp_result.digests, pallas_result.digests
-        )
-        np.testing.assert_array_equal(
-            jnp_result.sample_metrics, pallas_result.sample_metrics
-        )
-        np.testing.assert_array_equal(
-            jnp_result.sample_masks, pallas_result.sample_masks
-        )
-
-    @pytest.mark.parametrize("impl", ["pallas", "pallas_t"])
-    def test_pallas_forward_matches_oracle(self, impl):
-        from openr_tpu.ops import spf_grouped as sg
-
-        topo = topologies.fat_tree(
-            pods=2, ssw_per_plane=2, fsw_per_pod=2, rsw_per_pod=3
-        )
-        ls = load(topo)
-        sg.set_grouped_impl(impl, interpret=True)
-        try:
-            assert_forward_parity(ls)
-        finally:
-            sg.set_grouped_impl("jnp")
 
     def test_random_mesh_digest_parity(self):
         topo = topologies.random_mesh(20, degree=4, seed=3, max_metric=9)

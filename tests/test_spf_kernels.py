@@ -330,184 +330,3 @@ class TestNativeBackend:
             my, area_ls, prefix_state
         )
         assert db_native.to_route_db(my) == db_device.to_route_db(my)
-
-
-class TestPallasMinplus:
-    def test_interpret_matches_jnp(self):
-        from openr_tpu.ops.pallas_minplus import minplus
-
-        rng = np.random.default_rng(0)
-        s, k, n = 128, 128, 256
-        a = rng.integers(0, 100, size=(s, k)).astype(np.int32)
-        b = rng.integers(0, 100, size=(k, n)).astype(np.int32)
-        # sprinkle INF (missing edges)
-        a[rng.random((s, k)) < 0.3] = INF
-        b[rng.random((k, n)) < 0.3] = INF
-        got = np.asarray(minplus(jnp.asarray(a), jnp.asarray(b), interpret=True))
-        want = np.minimum(
-            np.min(
-                a[:, :, None].astype(np.int64) + b[None, :, :], axis=1
-            ),
-            int(INF),
-        ).astype(np.int32)
-        np.testing.assert_array_equal(got, want)
-
-    def test_impl_switch_consistency(self):
-        from openr_tpu.ops import spf as spf_ops
-
-        topo = topologies.grid(4)
-        ls = load(topo)
-        snap = compile_snapshot(ls)
-        w = jnp.asarray(snap.metric)
-        ov = jnp.asarray(snap.overloaded)
-        d_jnp = np.asarray(spf_ops.all_pairs_distances(w, ov))
-        assert spf_ops.get_minplus_impl() == spf_ops.JNP
-        # the selector carries interpret explicitly: nothing infers it
-        # from the platform, so the CPU run says interpret=True
-        spf_ops.set_minplus_impl("pallas", interpret=True)
-        try:
-            d_pallas = np.asarray(spf_ops.all_pairs_distances(w, ov))
-        finally:
-            spf_ops.set_minplus_impl("jnp")
-        np.testing.assert_array_equal(d_jnp, d_pallas)
-
-
-class TestAutotuner:
-    """Measured kernel selection: in-process only, family-checked, and
-    never quiet about a candidate that raises."""
-
-    def test_auto_resolves_to_measured_winner_with_interpret(self):
-        from openr_tpu.ops import autotune
-        from openr_tpu.ops import spf as spf_ops
-
-        calls = []
-
-        def measure(thunk, reps=3):
-            thunk()  # both candidates must actually run (interpreted)
-            calls.append(1)
-            return float(len(calls))  # first candidate (jnp) is fastest
-
-        prev = autotune.get_autotuner()
-        autotune.set_autotuner(autotune.Autotuner(measure=measure))
-        try:
-            got = autotune.resolve_minplus((8, 128), interpret=True)
-            assert got == spf_ops.KernelImpl("jnp", True)
-            assert len(calls) == 2
-            # memoized for the life of the process: no re-measure
-            autotune.resolve_minplus((8, 128), interpret=True)
-            assert len(calls) == 2
-        finally:
-            autotune.set_autotuner(prev)
-
-    def test_record_rejects_out_of_family_winner(self):
-        from openr_tpu.ops import autotune
-
-        t = autotune.Autotuner()
-        t.record("minplus", "8x128", "pallas")
-        assert t.pick("minplus", "8x128", {"jnp": None, "pallas": None}) \
-            == "pallas"
-        with pytest.raises(AssertionError):
-            t.record("minplus", "8x128", "pallas_t")
-        with pytest.raises(AssertionError):
-            t.record("ell_relax", "128x4", "pallas")
-
-    def test_disqualified_candidate_is_counted_and_logged(self, caplog):
-        from openr_tpu.ops import autotune
-        from openr_tpu.telemetry import get_registry
-
-        def boom():
-            raise NotImplementedError("Only 2D gather is supported")
-
-        reg = get_registry()
-        d0 = reg.counter_get("ops.autotune_disqualified")
-        t = autotune.Autotuner(measure=lambda thunk, reps=3: thunk() or 1.0)
-        with caplog.at_level("ERROR", logger="openr_tpu.ops.autotune"):
-            winner = t.pick(
-                "minplus", "8x256", {"jnp": lambda: None, "pallas": boom}
-            )
-        assert winner == "jnp"
-        assert reg.counter_get("ops.autotune_disqualified") == d0 + 1
-        assert "Only 2D gather is supported" in caplog.text
-
-    def test_every_candidate_failing_raises(self):
-        from openr_tpu.ops import autotune
-
-        def boom():
-            raise NotImplementedError("no lowering")
-
-        t = autotune.Autotuner(measure=lambda thunk, reps=3: thunk() or 1.0)
-        with pytest.raises(RuntimeError, match="every minplus candidate"):
-            t.pick("minplus", "8x512", {"jnp": boom, "pallas": boom})
-
-
-class TestPallasGroupedTiling:
-    """Shape-sweep parity for the group-blocked batched min-plus
-    (ops.pallas_grouped): every tiling regime — full-extent lanes,
-    tiled lanes (R > 512), s-grid revisit (S > 512), TG group padding,
-    non-multiple batch — must reproduce the jnp broadcast bit-exactly
-    (interpret mode on CPU; the scale bench A/Bs the same shapes
-    on-chip)."""
-
-    # (G, B, S, R) spanning the regimes; the first row is the measured
-    # 10k fat-tree band-0 segment shape that exposed the grid-step
-    # collapse of the first kernel generation
-    SHAPES = [
-        (624, 256, 4, 12),
-        (4, 64, 4, 624),     # lane-tiled R, tiny G (TG padding inert)
-        (4, 64, 624, 4),     # s-grid revisit path
-        (7, 40, 37, 130),    # nothing aligned
-        (1, 8, 1, 1),        # degenerate minima
-        (85, 136, 9, 513),   # TG boundary + b_pad re-pad + R just over cap
-    ]
-
-    def _want(self, gath, w):
-        return np.minimum(
-            np.min(
-                gath[:, :, :, None].astype(np.int64) + w[:, None, :, :],
-                axis=2,
-            ),
-            int(INF),
-        ).astype(np.int32)
-
-    def test_shape_sweep_matches_jnp(self):
-        from openr_tpu.ops.pallas_grouped import batched_minplus
-
-        rng = np.random.default_rng(7)
-        for g, b, s, r in self.SHAPES:
-            gath = rng.integers(0, 1000, size=(g, b, s)).astype(np.int32)
-            w = rng.integers(0, 1000, size=(g, s, r)).astype(np.int32)
-            gath[rng.random((g, b, s)) < 0.3] = INF
-            w[rng.random((g, s, r)) < 0.3] = INF
-            got = np.asarray(
-                batched_minplus(
-                    jnp.asarray(gath), jnp.asarray(w), interpret=True
-                )
-            )
-            np.testing.assert_array_equal(
-                got, self._want(gath, w), err_msg=str((g, b, s, r))
-            )
-
-    def test_shape_sweep_matches_jnp_transposed(self):
-        """Same regimes through batched_minplus_t — its _pick_tiles_t
-        branches (sublane-tiled R, s revisit, TG padding) are distinct
-        from batched_minplus's and must be swept independently."""
-        from openr_tpu.ops.pallas_grouped import batched_minplus_t
-
-        rng = np.random.default_rng(11)
-        for g, b, s, r in self.SHAPES:
-            gath = rng.integers(0, 1000, size=(g, b, s)).astype(np.int32)
-            w = rng.integers(0, 1000, size=(g, s, r)).astype(np.int32)
-            gath[rng.random((g, b, s)) < 0.3] = INF
-            w[rng.random((g, s, r)) < 0.3] = INF
-            got_t = np.asarray(
-                batched_minplus_t(
-                    jnp.asarray(np.transpose(gath, (0, 2, 1))),
-                    jnp.asarray(w),
-                    interpret=True,
-                )
-            )  # [G, R, B]
-            np.testing.assert_array_equal(
-                np.transpose(got_t, (0, 2, 1)),
-                self._want(gath, w),
-                err_msg=str((g, b, s, r)),
-            )

@@ -6,7 +6,6 @@ evict -> warm-rehydrate round trip."""
 import numpy as np
 import pytest
 
-from openr_tpu.decision.prefix_state import PrefixState
 from openr_tpu.decision.spf_solver import (
     SPF_COUNTERS,
     SpfSolver,
@@ -26,7 +25,6 @@ from openr_tpu.ops.world_batch import (
     reset_world_manager,
 )
 from openr_tpu.telemetry import get_registry, jax_hooks
-from openr_tpu.types import IpPrefix, PrefixDatabase, PrefixEntry
 from tests.test_sp_route_reuse import (
     _drop_adj,
     _mutate_metric,
@@ -219,61 +217,6 @@ class TestResidencyArbiter:
 
 
 class TestDecisionWiring:
-    def _areas(self):
-        return {
-            f"area{i}": load(t)
-            for i, t in enumerate(
-                [
-                    topologies.grid(3),
-                    topologies.grid(4),
-                    topologies.random_mesh(20, 3, seed=7),
-                ]
-            )
-        }
-
-    def _prefixes(self, areas):
-        ps = PrefixState()
-        for a, ls in areas.items():
-            for node in sorted(ls.get_adjacency_databases())[:4]:
-                nid = node.split("-")[-1]
-                ps.update_prefix_database(
-                    PrefixDatabase(
-                        this_node_name=node,
-                        prefix_entries=(
-                            PrefixEntry(
-                                prefix=IpPrefix.from_str(
-                                    f"fd00:{a[-1]}:{nid}::/64"
-                                )
-                            ),
-                        ),
-                        area=a,
-                    )
-                )
-        return ps
-
-    def _routes(self, world_batch):
-        reset_device_caches()
-        areas = self._areas()
-        ps = self._prefixes(areas)
-        solver = SpfSolver("node-0", world_batch=world_batch)
-        db1 = solver.build_route_db("node-0", areas, ps)
-        _mutate_metric(areas["area1"], "node-1", 0, 44)
-        db2 = solver.build_route_db("node-0", areas, ps)
-        return db1, db2
-
-    def test_multi_area_build_parity(self):
-        try:
-            p0 = SPF_COUNTERS["decision.world_preloads"]
-            seq = self._routes(world_batch=False)
-            assert SPF_COUNTERS["decision.world_preloads"] == p0
-            world = self._routes(world_batch=True)
-            assert SPF_COUNTERS["decision.world_preloads"] > p0
-            for tag, a, b in zip(("build1", "build2"), seq, world):
-                assert a.unicast_routes == b.unicast_routes, tag
-                assert a.mpls_routes == b.mpls_routes, tag
-        finally:
-            reset_device_caches()
-
     def test_reset_device_caches_resets_world(self):
         mgr = get_world_manager()
         topo = topologies.grid(3)
