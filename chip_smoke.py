@@ -244,6 +244,7 @@ def _ksp2_parity(what: str, topo, ls, root: str, event, events: int):
         "decision.ksp2_device_batches", "decision.ksp2_cold_builds",
         "decision.ksp2_incremental_syncs",
         "decision.ksp2_warm_dispatches",
+        "decision.ksp2_matrix_deferred",
     )
     before = _counter_snapshot(watched)
     ps = PrefixState()
@@ -327,9 +328,12 @@ def leg_ksp2_grid(side: int = 31, events: int = 4) -> dict:
     counts = out["counts"]
     _require(
         counts["decision.ksp2_cold_builds"] == 1
-        and counts["decision.ksp2_incremental_syncs"] == events,
-        f"{what}: not one cold build and {events} incremental syncs: "
-        f"{counts}",
+        and counts["decision.ksp2_incremental_syncs"] == events
+        # one chip: every sync waited for its rows alone and sent the
+        # all-pairs matrix solve behind the window
+        and counts["decision.ksp2_matrix_deferred"] == events,
+        f"{what}: not one cold build and {events} incremental syncs, "
+        f"each with its matrix solve behind the window: {counts}",
     )
     out["hops_from_root"] = ls.get_max_hops_to_node(root)
     return out

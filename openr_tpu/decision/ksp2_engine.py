@@ -38,15 +38,33 @@ a sound distance-algebra test:
 
 The distances come from a device-resident all-pairs matrix over the
 sliced-ELL bands (ops/spf_sparse.py): at KSP2 scale (n_pad <= 4096, the
-engine's activation bound) a full all-sources solve is ONE source block
-(dispatch to readback it is the span ops.ksp2_all_pairs, which the cell
-fabric-1000-ksp2.adj-churn reads as ksp2_all_pairs_ms beside the
-device's own time), so every churn event recomputes it, swaps it with
-the previous event's matrix (kept resident — no transfer), and reads
-back one fused packet: the SPF view batch (served to SpfView, saving
-its separate dispatch) plus old/new distance rows for the changed-edge
-endpoints. Steady-state churn that touches no cached path costs ONE
-device round trip and O(changed) host work.
+engine's activation bound) a full all-sources solve is ONE source
+block. A churn event is served by TWO programs, split by who reads the
+result when:
+
+- the ROWS solve (spf_sparse._ell_view_ep_rows), on the critical path:
+  the fixed point from the root's view batch and the changed-edge
+  endpoints only (40-48 source rows), warm-seeded from their rows of
+  the previous event's matrix. It returns one fused packet: the SPF
+  view batch (served to SpfView, saving its separate dispatch) plus
+  old/new distance rows for the endpoints. The sync WAITS for this
+  one (dispatch to readback it is the span ops.ksp2_all_pairs, which
+  the KSP2 cells read as ksp2_all_pairs_ms), and while it is in flight
+  does the host work that needs no row (the trace arrays' patch, the
+  walk-reach proof);
+- the MATRIX solve (spf_sparse._ell_all_view_rows), behind the window:
+  the same warm fixed point from every node, relaxing the previous
+  matrix in place (donated). No sync reads it on the host; it is the
+  NEXT event's warm seed and the source of its old rows. It is
+  dispatched after the last masked batch of the window, so that in
+  device order it stands behind everything the window's host code
+  waits on, and nobody blocks on it: ``d_prev_dev`` is a future until
+  the next event's rows solve queues behind it.
+
+Steady-state churn that touches no cached path WAITS for one device
+round trip of 40-48 rows and does O(changed) host work. A mesh engine
+(set_engine_mesh) keeps both in one fused, sharded program: its rows
+come back on the host with the call.
 """
 
 from __future__ import annotations
@@ -106,8 +124,9 @@ def engine_max_nodes() -> int:
 
 
 # churn larger than this falls back to a full (cold) rebuild; the
-# fused dispatch pads its endpoint rows and its increase list to these,
-# so every sync of an engine runs one compiled shape
+# rows solve pads its endpoint rows, and both solves their increase
+# list, to these, so every sync of an engine runs one compiled shape of
+# each
 ENGINE_MAX_CHANGED_PAIRS = 64
 ENGINE_MAX_ENDPOINTS = 32
 
@@ -513,6 +532,8 @@ def _pad_ids(ids: List[int], bucket_min: int = 8) -> np.ndarray:
     "_journal_read",
     "_link_slots",
     "_masked_warm",
+    "_matrix_due",
+    "_matrix_passes",
     "_slots_seen",
     "_mesh",
     "_mesh_knob",
@@ -587,6 +608,13 @@ class Ksp2Engine:
         # the LinkState journals, which the sync and the trace arrays
         # it patches both ask for
         self._journal_read = None
+        # the matrix solve the sync in hand still owes its window:
+        # (resident state, the window's increase triple on the device),
+        # from the rows dispatch until _dispatch_matrix sends it
+        self._matrix_due = None
+        # pass counts of matrix solves sent, on the device with their
+        # readback kicked: booked once landed (_book_matrix_passes)
+        self._matrix_passes: List = []
         self._drop_held()
 
     def _drop_held(self) -> None:
@@ -672,8 +700,11 @@ class Ksp2Engine:
         self.valid = False
         self._carried = None
         self.staged = False
-        # a dispatch that raised may have consumed the donated buffer
+        # a dispatch that raised may have consumed the donated buffer;
+        # a matrix solve still owed would warm-seed an epoch that the
+        # cold build replaces whole
         self.d_prev_dev = None
+        self._matrix_due = None
         self._drop_held()
 
     def _fits(self, state, dsts: List[str]) -> bool:
@@ -754,7 +785,7 @@ class Ksp2Engine:
         if not ep:
             ep = [self.sid]
 
-        # one fused dispatch: all-pairs + view + old/new endpoint rows
+        # the rows this window reads: view + old/new endpoint rows
         from openr_tpu.ops import spf_sparse
 
         view_srcs, srcs_dev, w_sv, view_reused = self._view_batch(
@@ -765,9 +796,9 @@ class Ksp2Engine:
         spliced_were = counters["decision.ksp2_trace_rows_spliced"]
         # padded to the limits checked above, as the cold build pads
         # its own: every sync of an engine runs ONE compiled shape of
-        # the fused program, whatever the window carried
+        # each program, whatever the window carried
         ep_ids = _pad_ids(ep, ENGINE_MAX_ENDPOINTS)
-        # increase-edge delta for the warm-started fixed point: pairs
+        # increase-edge delta for the warm-started fixed points: pairs
         # whose collapsed min weight went UP since d_prev_dev's epoch.
         # An overload flip changes effective weights without touching
         # the raw metrics the tight test runs on — force a cold seed.
@@ -778,13 +809,20 @@ class Ksp2Engine:
                 for (u, v), (w_old, w_new, _so, _sn) in changed.items()
                 if w_new > w_old
             ]
-            # both the single-chip and the sharded dispatches thread
-            # the delta into the warm-seeded fixed point now
+            # the single-chip programs and the sharded dispatch all
+            # thread the delta into the warm-seeded fixed point
             counters["decision.ksp2_warm_dispatches"] += 1
-        # dispatch to readback of the one fused program: the all-pairs
-        # fixed point, the view and the endpoint rows
+        verdict = None
+        # dispatch to readback of the program the sync waits for its
+        # distances in: the rows solve on one chip (the view batch and
+        # the endpoints), the fused all-pairs program on a mesh
         with get_tracer().span(
-            "ops.ksp2_all_pairs", rows=graph.n_pad, batches=1,
+            "ops.ksp2_all_pairs",
+            rows=(
+                graph.n_pad if self._mesh is not None
+                else len(view_srcs) + len(ep_ids)
+            ),
+            batches=1,
         ) as ap_span:
             if self._mesh is not None:
                 # nothing is donated on the mesh (residents keep their
@@ -798,21 +836,34 @@ class Ksp2Engine:
                 )
                 self.d_prev_dev = d_all_dev
             else:
-                # openr-lint: disable=donation-hazard -- intentional: the
-                # dispatch consumes the previous epoch's resident
-                # d_prev_dev (dead after this call, no retry path), which
-                # is rebound to the fresh output right below
-                d_all_dev, packed, passes = spf_sparse.ell_all_view_rows(
-                    state, srcs_dev, w_sv, ep_ids, self.d_prev_dev, inc=inc,
-                    inc_bucket=ENGINE_MAX_CHANGED_PAIRS, defer=True,
+                self._book_matrix_passes()
+                if not self.d_prev_dev.is_ready():
+                    # the previous window's matrix solve still runs:
+                    # the rows solve queues behind it on the device,
+                    # and this sync waits for both
+                    counters["decision.ksp2_matrix_unready"] += 1
+                # d_prev_dev is READ here and stays the engine's live
+                # matrix: the solve that consumes it is owed from now
+                # (_matrix_due) and sent by _dispatch_matrix behind the
+                # window's last masked batch. A raise anywhere before
+                # that finds the previous epoch's matrix, a raise in
+                # that dispatch finds None (it drops the reference
+                # first), and either way sync() invalidates: no path
+                # hands _cold_build a donated buffer
+                packed, passes, inc_dev = spf_sparse.ell_view_ep_rows(
+                    state, srcs_dev, w_sv, ep_ids, self.d_prev_dev,
+                    inc=inc, inc_bucket=ENGINE_MAX_CHANGED_PAIRS,
                 )
-                # adopt the output NOW, before any fallback below can
-                # hand the dead buffer to _cold_build (which reuses
-                # d_prev_dev as its placeholder), and reap the packed
-                # readback (kicked copy_to_host_async inside the
-                # wrapper) only AFTER that, so a reap failure can never
-                # hand it one either
-                self.d_prev_dev = d_all_dev
+                self._matrix_due = (state, inc_dev)
+            if not ov_flips:
+                # with the rows in flight, what needs none of them: the
+                # trace arrays' patch for the window's nodes and the
+                # walk-reach proof over the masked rows the engine
+                # holds (the mesh's call came back with its rows)
+                verdict = self._second_paths_may_move(
+                    ls, graph, changed, ov_new, blocked
+                )
+            if self._mesh is None:
                 packed, passes = self._reap_all_pairs(packed, passes)
             if ap_span is not None:
                 ap_span.attrs["passes"] = passes
@@ -827,8 +878,7 @@ class Ksp2Engine:
         d_new_src = view_packed[0].astype(np.int64)
 
         aff1, aff2, row_stands, rows_proven = self._affected_dsts(
-            ls, graph, changed, d_new_src, rows_new, rows_old,
-            ov_new, blocked, exact=not ov_flips,
+            graph, changed, d_new_src, rows_new, rows_old, ov_new, verdict,
         )
         dst_set = set(self.dst_pos)
         aff1 &= dst_set
@@ -853,6 +903,8 @@ class Ksp2Engine:
             moved = self._recompute(
                 ls, state, aff1, aff2, d_new_src, changed,
                 row_stands, rows_proven, blocked,
+                # a refresh sends the window's last masked batch
+                matrix_behind=rows_proven,
             )
             # of the destinations the tests named, those whose paths
             # came back as they were keep their routes
@@ -864,6 +916,8 @@ class Ksp2Engine:
             )
             if span is not None:
                 span.attrs["refreshed_rows"] = refreshed
+        # a window that named no destination sent no masked batch
+        self._dispatch_matrix()
         self._prime_all(ls)
 
         # commit snapshots
@@ -982,8 +1036,8 @@ class Ksp2Engine:
         self.dst_rows = np.arange(len(dsts))
         n = graph.n_pad
 
-        # fused dispatch seeds the resident all-pairs matrix AND serves
-        # the view; d_prev is a placeholder on the cold path
+        # seed the resident all-pairs matrix and serve the view; d_prev
+        # is a placeholder on the cold path
         view_srcs, srcs_dev, w_sv, _ = self._view_batch(ls, graph)
         placeholder = getattr(self, "d_prev_dev", None)
         if placeholder is None or placeholder.shape != (n, n):
@@ -1003,37 +1057,49 @@ class Ksp2Engine:
                 )()
             else:
                 placeholder = jnp.zeros((n, n), dtype=jnp.int32)
+        ep_ids = _pad_ids([self.sid], ENGINE_MAX_ENDPOINTS)
         with get_tracer().span(
             "ops.ksp2_all_pairs", rows=n, batches=1,
         ) as ap_span:
             if self._mesh is not None:
                 d_all_dev, packed, passes = (
                     spf_sparse.sharded_ell_all_view_rows(
-                        state, srcs_dev, w_sv,
-                        _pad_ids([self.sid], ENGINE_MAX_ENDPOINTS),
+                        state, srcs_dev, w_sv, ep_ids,
                         placeholder, self._mesh,
                         inc_bucket=ENGINE_MAX_CHANGED_PAIRS,
                     )
                 )
+                self.d_prev_dev = d_all_dev
             else:
-                # the dispatch DONATES the placeholder (which may be the
+                # the matrix solve first, cold-seeded, then the rows
+                # solve off the matrix it leaves (nothing increased
+                # since: its seed is the fixed point), at the shapes
+                # every later sync runs them in: both executables are
+                # compiled here, none in a churn window. The matrix
+                # dispatch DONATES the placeholder (which may be the
                 # previous d_prev_dev): drop our reference first so a
-                # failed dispatch can't leave a dead buffer behind for the
-                # next cold build to reuse
+                # failed dispatch can't leave a dead buffer behind for
+                # the next cold build to reuse
                 self.d_prev_dev = None
-                d_all_dev, packed, passes = spf_sparse.ell_all_view_rows(
-                    state, srcs_dev, w_sv,
-                    _pad_ids([self.sid], ENGINE_MAX_ENDPOINTS),
-                    placeholder, inc_bucket=ENGINE_MAX_CHANGED_PAIRS,
-                    defer=True,
+                self.d_prev_dev, matrix_passes = (
+                    spf_sparse.ell_all_view_rows(
+                        state, placeholder, spf_sparse._inc_args(
+                            None, ENGINE_MAX_CHANGED_PAIRS
+                        ),
+                    )
+                )
+                self._matrix_passes.append(matrix_passes)
+                packed, passes, _ = spf_sparse.ell_view_ep_rows(
+                    state, srcs_dev, w_sv, ep_ids, self.d_prev_dev,
+                    inc=[], inc_bucket=ENGINE_MAX_CHANGED_PAIRS,
                 )
                 packed, passes = self._reap_all_pairs(packed, passes)
+                self._book_matrix_passes()
             if ap_span is not None:
                 ap_span.attrs["passes"] = passes
         b = len(view_srcs)
         self._preload_view(ls, graph, view_srcs, packed[: 2 * b])
         self.d_base = packed[0].astype(np.int32)
-        self.d_prev_dev = d_all_dev
 
         # first paths traced from the device base row (identical to the
         # host get_kth_paths(.., 1) trace — same canonical order)
@@ -1234,26 +1300,35 @@ class Ksp2Engine:
 
     # -- affected-set computation -----------------------------------------
 
+    def _eff(self, w, origin: str, ov_map: Dict[str, bool]):
+        """The EFFECTIVE weight of an edge of raw weight ``w`` out of
+        ``origin`` under an overload map: no path runs on through a
+        drained node (the root, drained or not, still originates)."""
+        if w >= INF:
+            return np.int64(INF)
+        if ov_map.get(origin, False) and origin != self.src_name:
+            return np.int64(INF)
+        return np.int64(w)
+
     def _affected_dsts(
         self,
-        ls: LinkState,
         graph,
         changed: Dict[Tuple[str, str], Tuple],
         d_new_src: np.ndarray,
         rows_new: Dict[int, np.ndarray],
         rows_old: Dict[int, np.ndarray],
         ov_new: Dict[str, bool],
-        blocked: Set[str],
-        exact: bool = False,
+        verdict: Optional[Tuple[np.ndarray, np.ndarray, List[Tuple]]],
     ) -> Tuple[Set[str], Set[str], Set[str], bool]:
         """Returns (first-path affected, masked/second-path affected,
         row_stands, rows_proven). The first two are split because the
         former invalidates the destination's MASKS (forcing a fresh
         masked solve) while the latter only needs the second paths
-        re-derived. ``ov_new`` / ``blocked``: the overload map and the
-        transit-blocked set after the window (``self.ov`` holds the
-        map before it). ``exact``: narrow the second set with
-        _second_paths_may_move.
+        re-derived. ``ov_new``: the overload map after the window
+        (``self.ov`` holds the map before it). ``verdict``: what
+        _second_paths_may_move said of the window (the caller asked it
+        while the rows were in flight: it reads none of them), which
+        narrows the second set; None where it does not answer.
         ``row_stands``: of the second set, the destinations whose
         masked row provably stands as long as their first paths, and
         so their masks, do; _recompute re-traces them off the row it
@@ -1277,12 +1352,7 @@ class Ksp2Engine:
         dm = self.dm
         dm_total = dm[self.dst_rows, dst_ids].astype(np.int64)
 
-        def eff(w, origin, ov_map):
-            if w >= INF:
-                return inf
-            if ov_map.get(origin, False) and origin != self.src_name:
-                return inf
-            return np.int64(w)
+        eff = self._eff
 
         # links (either direction) usable now that were not before
         appeared = len({
@@ -1348,35 +1418,36 @@ class Ksp2Engine:
                     aff2_vec |= ~reachable_m
                 else:
                     aff2_vec |= ~reachable_m & (dm_u < inf)
-        if exact:
-            verdict = self._second_paths_may_move(
-                ls, graph, changed, dm, ov_new, eff, blocked
-            )
-            if verdict is not None:
-                # a destination whose first paths move gets fresh masks
-                # and a fresh solve whatever this says; one whose row
-                # may move is re-solved even where the bound above sees
-                # no shortest second path through the edge, because the
-                # verdict reads the rows as exact (a row left stale at
-                # a node that mattered to nothing is a wrong verdict
-                # the day it matters)
-                may_move, row_moves, mended = verdict
-                for col, at, values in mended:
-                    self.dm[at, col] = values
-                aff2_vec = (aff2_vec & may_move) | row_moves
-                row_stands = {
-                    self.dsts[i]
-                    for i in np.flatnonzero(aff2_vec & ~row_moves)
-                }
-                rows_proven = True
+        if verdict is not None:
+            # a destination whose first paths move gets fresh masks
+            # and a fresh solve whatever this says; one whose row
+            # may move is re-solved even where the bound above sees
+            # no shortest second path through the edge, because the
+            # verdict reads the rows as exact (a row left stale at
+            # a node that mattered to nothing is a wrong verdict
+            # the day it matters)
+            may_move, row_moves, mended = verdict
+            for col, at, values in mended:
+                self.dm[at, col] = values
+            aff2_vec = (aff2_vec & may_move) | row_moves
+            row_stands = {
+                self.dsts[i]
+                for i in np.flatnonzero(aff2_vec & ~row_moves)
+            }
+            rows_proven = True
         aff1 = {self.dsts[i] for i in np.flatnonzero(aff)}
         aff2 = {self.dsts[i] for i in np.flatnonzero(aff2_vec)}
         return aff1, aff2, row_stands, rows_proven
 
     def _second_paths_may_move(
-        self, ls, graph, changed, dm, ov_new, eff, blocked
+        self, ls, graph, changed, ov_new, blocked
     ) -> Optional[Tuple[np.ndarray, np.ndarray, List[Tuple]]]:
-        """Two [D] bools: the destinations whose second-path trace can
+        """Of a window with no drain flip (``ov_new``, ``blocked``: the
+        overload map and the transit-blocked set after it), read off
+        the masked rows ``self.dm`` and the walks ``self.reach2`` as
+        the engine holds them and off NO distance row of the window's
+        own solve, so the sync asks while that solve is in flight.
+        Two [D] bools: the destinations whose second-path trace can
         come out differently after the window's changes, first paths
         and masks unchanged; and, of those, the ones whose masked row
         may have moved (the rest need a trace, not a solve). Third,
@@ -1432,6 +1503,7 @@ class Ksp2Engine:
         pairs = list(changed.items())
         if not pairs:
             return None
+        dm, eff = self.dm, self._eff
         one_link = len(pairs) == 1 or (
             len(pairs) == 2 and pairs[0][0] == pairs[1][0][::-1]
         )
@@ -1685,7 +1757,7 @@ class Ksp2Engine:
         self, ls: LinkState, state, aff1: Set[str], aff2: Set[str],
         d_new_src: np.ndarray, changed,
         row_stands: Set[str], rows_proven: bool,
-        transit_blocked: Set[str],
+        transit_blocked: Set[str], matrix_behind: bool = True,
     ) -> Set[str]:
         """Re-derive the paths of the destinations the membership tests
         named (``aff1``: first paths, ``aff2``: second) and return
@@ -1708,7 +1780,12 @@ class Ksp2Engine:
         names it, and re-traced off the row the engine holds, no mask
         built and nothing sent to the device, if ``row_stands`` has it
         (_second_paths_may_move). Where they are not proven, every
-        named destination is re-solved as it always was."""
+        named destination is re-solved as it always was.
+
+        ``matrix_behind``: no masked batch follows this call's in the
+        window, so the matrix solve the sync owes goes to the device
+        behind the last one here (_dispatch_matrix), ahead of the host
+        work that follows it."""
         graph = state.graph
         cands_of = make_cands_of(ls, graph.node_index)
         named = sorted(aff1 | aff2)
@@ -1745,8 +1822,10 @@ class Ksp2Engine:
         if solve:
             self._solve_masked_batches(
                 ls, state, solve, cands_of, transit_blocked,
-                index_users=False,
+                index_users=False, matrix_behind=matrix_behind,
             )
+        elif matrix_behind:
+            self._dispatch_matrix()
         if retrace:
             at = [self.dst_pos[dst] for dst in retrace]
             reach = np.full((len(retrace), graph.n_pad), -1, dtype=np.int32)
@@ -1799,12 +1878,52 @@ class Ksp2Engine:
             self._note_paths(dst)
         return moved
 
+    def _dispatch_matrix(self) -> None:
+        """Send the matrix solve the sync in hand owes its window (once:
+        nothing where none is owed, as in a cold build or on a mesh):
+        the all-sources fixed point that relaxes ``d_prev_dev`` in
+        place, warm-seeded with the window's increase triple as the
+        rows solve put it on the device. Called behind the window's
+        last masked dispatch and ahead of the host work that follows
+        it, so that in device order it stands behind everything this
+        window's host code waits on; its output is adopted as
+        ``d_prev_dev`` at once, a future nobody blocks on."""
+        from openr_tpu.ops import spf_sparse
+
+        due, self._matrix_due = self._matrix_due, None
+        if due is None:
+            return
+        state, inc_dev = due
+        # the dispatch DONATES the matrix: drop our reference first, so
+        # a dispatch that raises leaves None and not a dead buffer
+        d_prev, self.d_prev_dev = self.d_prev_dev, None
+        self.d_prev_dev, passes = spf_sparse.ell_all_view_rows(
+            state, d_prev, inc_dev
+        )
+        self._matrix_passes.append(passes)
+        _counters()["decision.ksp2_matrix_deferred"] += 1
+
+    def _book_matrix_passes(self) -> None:
+        """Book the pass counts of the matrix solves that have landed
+        (``ops.ksp2.matrix_passes``); one still running keeps its count
+        for a later call. Never blocks."""
+        from openr_tpu.ops import spf_sparse
+
+        landed, running = [], []
+        for passes in self._matrix_passes:
+            (landed if passes.is_ready() else running).append(passes)
+        if not landed:
+            return
+        self._matrix_passes = running
+        for passes in _da.reap_read(landed, kicked=True):
+            spf_sparse.note_ksp2_passes("matrix", passes)
+
     @staticmethod
     def _reap_all_pairs(packed, passes):
-        """The fused dispatch's packed rows and its pass count on the
-        host: two outputs of one program, kicked together, brought over
-        by one reap; the count is booked here, where it is first
-        known."""
+        """The packed rows and the pass count of the program the sync
+        waits for on the host: two outputs of one program, kicked
+        together, brought over by one reap; the count is booked here,
+        where it is first known."""
         from openr_tpu.ops import spf_sparse
 
         packed, passes = _da.reap_read((packed, passes), kicked=True)
@@ -1918,6 +2037,10 @@ class Ksp2Engine:
             for start in range(0, len(dsts), chunk):
                 batch = dsts[start : start + chunk]
                 ok, *out = self._masked_dispatch(state, batch)
+                if start + chunk >= len(dsts):
+                    # the window's last masked batch: the matrix solve
+                    # behind it, ahead of its reap
+                    self._dispatch_matrix()
                 drows, passes = self._reap_masked(*out)
                 if span is not None:
                     span.attrs["passes"] = max(span.attrs["passes"], passes)
@@ -1929,12 +2052,14 @@ class Ksp2Engine:
 
     def _solve_masked_batches(
         self, ls, state, dsts, cands_of, transit_blocked,
-        index_users: bool = True,
+        index_users: bool = True, matrix_behind: bool = True,
     ) -> None:
         """Masked-SPF rows + second-path traces + dm/node_users updates
         for a destination subset (shared by cold build and incremental
         recompute; the two loops MUST stay identical — fallback
-        accounting drifting between them was a review finding)."""
+        accounting drifting between them was a review finding).
+        ``matrix_behind``: as _recompute's (a cold build owes no matrix
+        solve, and _dispatch_matrix then sends none)."""
         from openr_tpu.decision import spf_solver as _ss
 
         graph = state.graph
@@ -1999,6 +2124,9 @@ class Ksp2Engine:
                 # stage 1: mask build + (async) masked solve
                 batch = dsts[start : start + chunk]
                 staged = (batch, *self._masked_dispatch(state, batch))
+                if matrix_behind and start + chunk >= len(dsts):
+                    # behind the last batch, ahead of every settle
+                    self._dispatch_matrix()
                 if inflight is not None:
                     if staged[2] is not None:
                         _da.note_pipelined_dispatch(2)

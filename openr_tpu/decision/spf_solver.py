@@ -183,6 +183,13 @@ SPF_COUNTERS = _get_registry().counter_dict(
         "decision.ksp2_cold_builds",
         "decision.ksp2_incremental_syncs",
         "decision.ksp2_warm_dispatches",
+        # the all-pairs matrix a one-chip engine keeps is solved BEHIND
+        # the window (ksp2_engine._dispatch_matrix): incremental syncs
+        # that dispatched it so, and syncs that began while the
+        # previous window's was still not ready (their rows solve then
+        # queued behind it on the device)
+        "decision.ksp2_matrix_deferred",
+        "decision.ksp2_matrix_unready",
         "decision.ksp2_affected_dsts",
         # the KSP2 engine's trace arrays under churn: candidate rows a
         # sync wrote into the flat CSR in place (a metric change) and
@@ -584,8 +591,8 @@ class _EllResidentCache:
     def __init__(self) -> None:
         # ls -> (synced topology_version, EllState)
         self._cache = _weakref.WeakKeyDictionary()
-        # views the KSP2 engines already computed inside their fused
-        # dispatches this build — consumed (popped) by view_packed so
+        # views the KSP2 engines already computed inside their rows
+        # solves this build — consumed (popped) by view_packed so
         # SpfView does not pay a second device round trip. Entries are
         # (weakref(ls), version, root, graph, srcs, packed): identity
         # goes through the weakref (id() reuse after gc must never
@@ -1103,7 +1110,7 @@ class SpfSolver:
         - no KSP2 engine: solve the view through ``_view`` and let the
           rebuild's ``_view`` land on a cache hit;
         - a live, valid engine at this root: the view there comes out
-          of the engine's own fused dispatch, so sync the ENGINE
+          of the engine's own rows solve, so sync the ENGINE
           (``_prefetch_ksp2_area``, the carry left untaken: the
           window's ``build_route_db`` finds it at its version, its own
           ``sync`` does nothing, and it takes what this one found

@@ -957,8 +957,9 @@ def _first_hops_from_rows(d, srcs, w_sv, overloaded, n):
     """ECMP first-hop bits [B, N] from the batch's distance rows (same
     algebra as the dense kernel): neighbor v forwards toward j iff
     w(src,v) + d(v, j) == d(src, j), plus the direct-neighbor case.
-    Shared by _ell_view_batch and _ell_all_view_rows — the engine's
-    preloaded view must stay byte-identical to the fallback dispatch."""
+    Shared by _ell_view_batch and the KSP2 engine's rows solves
+    (_pack_view_rows) — the engine's preloaded view must stay
+    byte-identical to the fallback dispatch."""
     b = srcs.shape[0]
     d_src = d[0]
     is_neighbor = w_sv < INF
@@ -1445,10 +1446,13 @@ def ell_masked_distances_resident(
 
 def note_ksp2_passes(program: str, passes) -> int:
     """The relax passes a KSP2 program ran (``masked``: one batch of
-    _ell_masked_source_batch; ``all_pairs``: the fixed point inside
-    _ell_all_view_rows), known where its outputs reach the host: summed
-    into the counter ``ops.ksp2.<program>_passes`` and returned for the
-    span that covers the dispatch."""
+    _ell_masked_source_batch; ``all_pairs``: the fixed point the sync
+    waits for its distances in, _ell_view_ep_rows on one chip and the
+    fused _sharded_ell_all_view_rows on a mesh; ``matrix``: the
+    all-sources fixed point of _ell_all_view_rows, behind the window),
+    known where its outputs reach the host: summed into the counter
+    ``ops.ksp2.<program>_passes`` and returned for the span that covers
+    the dispatch."""
     passes = int(passes)
     _get_registry().counter_bump(f"ops.ksp2.{program}_passes", passes)
     return passes
@@ -1800,54 +1804,81 @@ def ell_reconverge_step(state: EllState, patched: EllGraph, srcs):
     return state.reconverge(patched, srcs)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("bands", "n"),
-    donate_argnums=(6,),  # d_prev: dead after the call, relax in place
-)
-def _ell_all_view_rows(
+def _pack_view_rows(view_d, view_srcs, w_sv, overloaded, n,
+                    rows_new, rows_old):
+    """The packet a KSP2 sync reads back, in the one layout its host
+    side unpacks: [view_d | view_fh | rows_new | rows_old], the view's
+    first hops by the algebra _ell_view_batch shares."""
+    fh = _first_hops_from_rows(view_d, view_srcs, w_sv, overloaded, n)
+    return jnp.concatenate(
+        [view_d, fh.astype(jnp.int32), rows_new, rows_old], axis=0
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("bands", "n"))
+def _ell_view_ep_rows(
     srcs_t, ws_t, overloaded, view_srcs, w_sv, ep_ids, d_prev,
     inc_tail, inc_head, inc_w, bands, n,
 ):
-    """One fused dispatch for the incremental-KSP2 churn step at
-    moderate N (n_pad <= ~4k, where a full all-sources block fits):
+    """The rows an incremental-KSP2 sync READS, and nothing else: the
+    program the sync waits for (ksp2_engine._sync_window, the span
+    ops.ksp2_all_pairs).
 
-      1. all-sources distances D [n, n] over the resident bands,
-      2. the batched {root} + neighbors view (distances + packed first
-         hops — same algebra as _ell_view_batch) DERIVED from D's rows
-         instead of a second fixed point,
-      3. row gathers from D (new) and ``d_prev`` (the previous build's
-         resident D) for the invalidation endpoints,
+      1. distances from ``view_srcs ++ ep_ids`` only (the root's view
+         batch and the window's changed-edge endpoints: 40 rows on the
+         31x31 grid, 48 on the 1k fabric) over the resident bands,
+         warm-seeded from THEIR rows of ``d_prev`` (the previous
+         epoch's resident all-pairs matrix) with the increase-edge
+         delta (inc_tail/inc_head/inc_w: see _warm_seed; the
+         _FORCE_RESET_EDGE sentinel for cold semantics),
+      2. the view's packed first hops (same algebra as _ell_view_batch)
+         from the view rows,
 
-    returning (D, packed, passes) where packed = [view_d | view_fh |
-    rows_new | rows_old] and passes is the fixed point's loop counter —
-    the caller reads back ``packed`` and that scalar together (one
-    ``device_get``) and keeps D resident for the next event. Fusing the view and the
-    invalidation rows into the same transfer keeps a churn rebuild at
-    one device round trip. The fixed point is
-    warm-seeded from ``d_prev`` with the increase-edge delta
-    (inc_tail/inc_head/inc_w — see _warm_seed; callers pass the
-    _FORCE_RESET_EDGE sentinel for cold semantics)."""
-    d_all, passes = _ell_fixed_point(
+    returning (packed, passes): packed = [view_d | view_fh | rows_new |
+    rows_old] with rows_old = ``d_prev[ep_ids]``, and the fixed point's
+    loop counter. Rows of an all-sources solve are independent
+    single-source fixed points over the same bands under the same
+    overload mask, and int32 min-relaxation has one fixed point: these
+    rows are bit-identical to the rows _ell_all_view_rows leaves in the
+    matrix for the same epoch. Nothing is donated: ``d_prev`` stays the
+    engine's live matrix until the matrix solve behind the window
+    consumes it."""
+    src_ids = jnp.concatenate([view_srcs, ep_ids])
+    d, passes = _ell_fixed_point(
+        srcs_t, ws_t, overloaded, src_ids, bands, n,
+        warm=(d_prev[src_ids], inc_tail, inc_head, inc_w),
+    )
+    b = view_srcs.shape[0]
+    packed = _pack_view_rows(
+        d[:b], view_srcs, w_sv, overloaded, n, d[b:], d_prev[ep_ids]
+    )
+    return packed, passes
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("bands", "n"),
+    donate_argnums=(3,),  # d_prev: dead after the call, relax in place
+)
+def _ell_all_view_rows(
+    srcs_t, ws_t, overloaded, d_prev, inc_tail, inc_head, inc_w, bands, n,
+):
+    """The matrix an incremental-KSP2 engine KEEPS: all-sources
+    distances D [n, n] over the resident bands at moderate N (n_pad <=
+    ~4k, where a full all-sources block fits), warm-seeded from
+    ``d_prev`` (the previous epoch's D, donated: relaxed in place) with
+    the increase-edge delta, as _ell_view_ep_rows is. Returns (D,
+    passes). No sync reads D on the host: it is the NEXT window's warm
+    seed and the source of its ``rows_old``, so the engine dispatches
+    this behind the window's last masked batch and blocks on nothing
+    of it (ell_all_view_rows). The name is the benchmark's handle:
+    chipbench/roofline_ksp2.ALL_PAIRS finds the compiled module by
+    it."""
+    return _ell_fixed_point(
         srcs_t, ws_t, overloaded,
         jnp.arange(n, dtype=jnp.int32), bands, n,
         warm=(d_prev, inc_tail, inc_head, inc_w),
     )
-
-    # view from D rows (shared first-hop algebra with _ell_view_batch)
-    d = d_all[view_srcs]  # [B, n]
-    fh = _first_hops_from_rows(d, view_srcs, w_sv, overloaded, n)
-
-    packed = jnp.concatenate(
-        [
-            d,
-            fh.astype(jnp.int32),
-            d_all[ep_ids],
-            d_prev[ep_ids],
-        ],
-        axis=0,
-    )
-    return d_all, packed, passes
 
 
 def _inc_args(inc, bucket: int):
@@ -1856,7 +1887,7 @@ def _inc_args(inc, bucket: int):
     row); an (possibly empty) increase list warm-starts. ``bucket``:
     the length every list is padded to, which is the caller's bound on
     a list (ksp2_engine.ENGINE_MAX_CHANGED_PAIRS), so that an engine
-    runs one compiled shape of the fused dispatch and not one per power
+    runs one compiled shape of each dispatch and not one per power
     of two a window of events happens to reach."""
     inc_t, inc_h, inc_w = pad_increase_edges(
         [_FORCE_RESET_EDGE] if inc is None else list(inc),
@@ -1865,24 +1896,23 @@ def _inc_args(inc, bucket: int):
     return jnp.asarray(inc_t), jnp.asarray(inc_h), jnp.asarray(inc_w)
 
 
-@donates("d_prev")
-def ell_all_view_rows(state: EllState, view_srcs, w_sv, ep_ids, d_prev,
-                      inc=None, inc_bucket: int = 4, defer: bool = False):
-    """Run the fused all-sources + view + invalidation-rows dispatch on
-    the resident bands. Returns (d_all_dev, packed_host, passes): the
-    passes the warm fixed point ran, already booked (note_ksp2_passes).
-    ``inc`` is the increase-edge delta [(tail, head, old_w)] for warm
-    seeding, padded to ``inc_bucket`` (None forces the cold seed);
-    d_prev is DONATED (invalid after the call). Rides the committed AOT
-    executable cache (``ksp2_view_rows``); ``defer=True`` keeps
-    ``packed`` and ``passes`` on device with their readback kicked
-    async: the caller reaps the pair via ONE
+def ell_view_ep_rows(state: EllState, view_srcs, w_sv, ep_ids, d_prev,
+                     inc=None, inc_bucket: int = 4):
+    """Dispatch the rows solve (_ell_view_ep_rows) on the resident
+    bands: the program a KSP2 sync waits for. Returns ``(packed,
+    passes, inc_dev)``: the first two ON DEVICE with their readback
+    kicked async (the caller reaps the pair via ONE
     ``dispatch_accounting.reap_read((packed, passes), kicked=True)``
-    inside its event window, folding the device round trip into the
-    chain, and books the count itself."""
-    inc_t, inc_h, inc_w = _inc_args(inc, inc_bucket)
-    d_all, packed, passes = _aot_call(
-        "ksp2_view_rows", _ell_all_view_rows,
+    inside its event window and books the count itself), and the
+    increase triple as it went to the device, for the matrix solve of
+    the same window (ell_all_view_rows), which then puts nothing
+    there. ``inc`` is the increase-edge delta [(tail, head, old_w)]
+    for warm seeding, padded to ``inc_bucket`` (None forces the cold
+    seed); ``d_prev`` is read, not donated. Rides the committed AOT
+    executable cache (``ksp2_rows``)."""
+    inc_dev = _inc_args(inc, inc_bucket)
+    packed, passes = _aot_call(
+        "ksp2_rows", _ell_view_ep_rows,
         (
             state.src, state.w, state.overloaded,
             _as_device_ids(view_srcs),
@@ -1890,16 +1920,32 @@ def ell_all_view_rows(state: EllState, view_srcs, w_sv, ep_ids, d_prev,
                 np.asarray(w_sv, dtype=np.int32)
             ),
             _as_device_ids(ep_ids),
-            d_prev, inc_t, inc_h, inc_w,
+            d_prev, *inc_dev,
         ),
         dict(bands=state.graph.bands, n=state.graph.n_pad),
     )
-    if defer:
-        _da.kick_async(packed)
-        _da.kick_async(passes)
-        return d_all, packed, passes
-    packed, passes = jax.device_get((packed, passes))
-    return d_all, packed, note_ksp2_passes("all_pairs", passes)
+    _da.kick_async(packed)
+    _da.kick_async(passes)
+    return packed, passes, inc_dev
+
+
+@donates("d_prev")
+def ell_all_view_rows(state: EllState, d_prev, inc_dev):
+    """Dispatch the matrix solve (_ell_all_view_rows) on the resident
+    bands and wait for nothing: returns ``(d_all, passes)`` ON DEVICE,
+    the count's readback kicked async for whoever reaps it later
+    (``note_ksp2_passes("matrix", ...)``). ``inc_dev`` is the device
+    increase triple of the window (ell_view_ep_rows returns the one it
+    sent; ``_inc_args(None, bucket)`` forces the cold seed); d_prev is
+    DONATED (invalid after the call). Rides the committed AOT
+    executable cache (``ksp2_view_rows``)."""
+    d_all, passes = _aot_call(
+        "ksp2_view_rows", _ell_all_view_rows,
+        (state.src, state.w, state.overloaded, d_prev, *inc_dev),
+        dict(bands=state.graph.bands, n=state.graph.n_pad),
+    )
+    _da.kick_async(passes)
+    return d_all, passes
 
 
 SOURCES_AXIS = "sources"
@@ -2101,27 +2147,24 @@ def _sharded_ell_all_view_rows(
     srcs_t, ws_t, overloaded, view_srcs, w_sv, ep_ids, d_prev,
     inc_tail, inc_head, inc_w, bands, n, mesh,
 ):
-    """Mesh-sharded twin of _ell_all_view_rows: the all-pairs fixed
-    point runs with source rows sharded over the mesh (1-bit psum
-    vote), WARM-SEEDED from the row-sharded previous distances, and
-    the view/endpoint row gathers run as global-view ops on the
-    sharded matrix (XLA inserts the row collectives). d_all comes
-    back SHARDED — the resident footprint per device is n^2/ndev,
-    which is what lifts the KSP2 engine past the single-chip bound."""
+    """The mesh engine's ONE fused dispatch (the single-chip engine
+    splits it in two, _ell_view_ep_rows and _ell_all_view_rows; here
+    the rows come back on the host with the call and no cell runs it):
+    the all-pairs fixed point runs with source rows sharded over the
+    mesh (1-bit psum vote), WARM-SEEDED from the row-sharded previous
+    distances, and the view/endpoint row gathers run as global-view
+    ops on the sharded matrix (XLA inserts the row collectives).
+    Returns (d_all, packed = [view_d | view_fh | rows_new | rows_old],
+    passes). d_all comes back SHARDED — the resident footprint per
+    device is n^2/ndev, which is what lifts the KSP2 engine past the
+    single-chip bound."""
     d_all, passes = _sharded_warm_all_pairs(
         srcs_t, ws_t, overloaded, d_prev, inc_tail, inc_head, inc_w,
         bands, n, mesh,
     )
-    d = d_all[view_srcs]
-    fh = _first_hops_from_rows(d, view_srcs, w_sv, overloaded, n)
-    packed = jnp.concatenate(
-        [
-            d,
-            fh.astype(jnp.int32),
-            d_all[ep_ids],
-            d_prev[ep_ids],
-        ],
-        axis=0,
+    packed = _pack_view_rows(
+        d_all[view_srcs], view_srcs, w_sv, overloaded, n,
+        d_all[ep_ids], d_prev[ep_ids],
     )
     return d_all, packed, passes
 
@@ -2132,9 +2175,9 @@ def sharded_ell_all_view_rows(
 ):
     """Run the sharded all-sources + view + invalidation-rows dispatch
     on the resident bands. Returns (d_all_dev SHARDED, packed_host,
-    passes), the count booked as ell_all_view_rows books it.
+    passes), the count booked (note_ksp2_passes, ``all_pairs``).
     ``inc`` is the increase-edge delta for warm seeding (None forces
-    the cold seed — same contract as ell_all_view_rows); d_prev is NOT
+    the cold seed — same contract as ell_view_ep_rows); d_prev is NOT
     donated. n_pad must divide by the mesh size (the engine gates on
     this and falls back to the single-chip dispatch otherwise)."""
     assert state.graph.n_pad % mesh.devices.size == 0, (

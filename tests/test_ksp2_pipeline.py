@@ -136,12 +136,18 @@ def test_routes_equal_reference_and_host_after_every_burst(fabric, seed):
         else:
             assert 1 <= sync.attrs["changed_pairs"] \
                 <= ksp2_engine.ENGINE_MAX_CHANGED_PAIRS
-        # one fused dispatch; a sync that names too many destinations
-        # only after it falls to the cold build, which dispatches again
+        # one dispatch the sync waits for; a sync that names too many
+        # destinations only after it falls to the cold build, which
+        # dispatches again
         assert len(by_name["ops.ksp2_all_pairs"]) <= 1 + sync.attrs["cold"]
         for s in by_name["ops.ksp2_all_pairs"]:
             assert _inside(s, sync)
-            assert s.attrs["rows"] == 128  # 56 nodes, padded
+            # a cold build waits for the matrix (56 nodes, padded), an
+            # incremental sync for the rows it reads: the view batch
+            # (the root and its 4 uplinks, padded) and the endpoints
+            assert s.attrs["rows"] == (
+                128 if sync.attrs["cold"]
+                else 8 + ksp2_engine.ENGINE_MAX_ENDPOINTS)
             assert s.attrs["batches"] == 1
         for s in by_name.get("decision.ksp2_trace", ()):
             assert _inside(s, sync)
