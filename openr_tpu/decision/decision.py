@@ -324,8 +324,16 @@ class Decision:
         self._full_db_entries: Dict[
             Tuple[str, str], Dict[IpPrefix, PrefixEntry]
         ] = {}
+        # (area, adj: key) -> the value last decoded for it (its bytes,
+        # the database, where its adjacencies begin): a publication
+        # re-encodes the node's whole database to change one adjacency,
+        # so the next value of the key is decoded against this one and
+        # keeps every adjacency whose bytes stand (wire.loads_reusing)
+        self._adj_decoded: Dict[Tuple[str, str], wire.Decoded] = {}
         self.counters: Dict[str, int] = {
             "decision.adj_db_update": 0,
+            "decision.adj_elements_decoded": 0,
+            "decision.adj_elements_reused": 0,
             "decision.prefix_db_update": 0,
             "decision.route_build_runs": 0,
             "decision.publications": 0,
@@ -508,8 +516,22 @@ class Decision:
             node_name = keyutil.get_node_name_from_key(key)
             try:
                 if keyutil.is_adj_key(key):
-                    adj_db = wire.loads(value.value, AdjacencyDatabase)
+                    slot = (area, key)
+                    decoded = wire.loads_reusing(
+                        value.value,
+                        AdjacencyDatabase,
+                        "adjacencies",
+                        self._adj_decoded.get(slot),
+                    )
+                    adj_db = decoded.obj
                     assert adj_db.this_node_name == node_name
+                    self._adj_decoded[slot] = decoded
+                    self.counters["decision.adj_elements_reused"] += (
+                        decoded.reused
+                    )
+                    self.counters["decision.adj_elements_decoded"] += (
+                        decoded.decoded
+                    )
                     if adj_db.area != area:
                         adj_db = AdjacencyDatabase(
                             this_node_name=adj_db.this_node_name,
@@ -566,6 +588,7 @@ class Decision:
         for key in pub.expired_keys:
             node_name = keyutil.get_node_name_from_key(key)
             if keyutil.is_adj_key(key):
+                self._adj_decoded.pop((area, key), None)
                 self.pending.apply_link_state_change(
                     node_name,
                     link_state.delete_adjacency_database(node_name),
