@@ -314,6 +314,12 @@ class EllGraph:
     # nesting keeps ell_patch's copy O(N) shallow (replace affected
     # nodes' inner dicts) instead of O(E) deep per churn event.
     slot_of: Optional[Dict[int, Dict[Tuple, Tuple[int, int, int]]]] = None
+    # filled slots over all bands (w < INF: the directed edges the
+    # relax needs, a parallel link each in an "in" graph); the rest of
+    # sum(rows * k) is padding. compile_ell counts, ell_patch keeps it
+    # by the rows it re-derives; EllState.reconverge says both on its
+    # span.
+    edges: int = 0
 
 
 def _in_edges(ls, name, index) -> Dict[int, int]:
@@ -484,6 +490,7 @@ def compile_ell(ls, align: int = _NODE_PAD,
     ws: List[np.ndarray] = []
     slot_of: Dict[int, Dict[Tuple, Tuple[int, int, int]]] = {}
     overloaded = np.zeros(n_pad, dtype=bool)
+    n_edges = 0
     i = 0
     while i < n:
         k = class_k(degree[names[i]])
@@ -499,15 +506,17 @@ def compile_ell(ls, align: int = _NODE_PAD,
             if per_link:
                 nid = index[name]
                 nd: Dict[Tuple, Tuple[int, int, int]] = {}
-                for slot, (sid, m, key) in enumerate(
-                    _in_edge_slots(ls, name, index)
-                ):
+                row_slots = _in_edge_slots(ls, name, index)
+                for slot, (sid, m, key) in enumerate(row_slots):
                     src_b[r, slot] = sid
                     w_b[r, slot] = m
                     nd[key] = (len(bands), r, slot)
                 slot_of[nid] = nd
+                n_edges += len(row_slots)
             else:
-                _fill_row(src_b[r], w_b[r], edges_of(ls, name, index))
+                edges = edges_of(ls, name, index)
+                _fill_row(src_b[r], w_b[r], edges)
+                n_edges += len(edges)
         bands.append(EllBand(start=i, rows=rows, k=k))
         srcs.append(src_b)
         ws.append(w_b)
@@ -519,6 +528,7 @@ def compile_ell(ls, align: int = _NODE_PAD,
         bands=tuple(bands), src=tuple(srcs), w=tuple(ws),
         overloaded=overloaded, direction=direction,
         slot_of=slot_of if per_link else None,
+        edges=n_edges,
     )
 
 
@@ -555,6 +565,7 @@ def ell_patch(
     changed: Dict[int, List[int]] = {}
     widened: set = set()
     copied: set = set()
+    n_edges = graph.edges
     for name in affected:
         i = graph.node_index.get(name)
         if i is None:
@@ -596,6 +607,7 @@ def ell_patch(
             w[bi] = w[bi].copy()
             copied.add(bi)
         r = i - band.start
+        n_edges += n_entries - int(np.count_nonzero(w[bi][r] < INF))
         src[bi][r] = np.full(band.k, i, dtype=np.int32)
         w[bi][r] = INF
         if per_link:
@@ -620,6 +632,7 @@ def ell_patch(
         direction=graph.direction,
         slot_of=slot_of,
         widened=frozenset(widened) if widened else None,
+        edges=n_edges,
     )
 
 
@@ -1775,6 +1788,10 @@ class EllState:
             warm=warm,
             dispatch_ms=round(_dispatch_ms, 4),
             host_overhead_ms=round(_total_ms - _dispatch_ms, 4),
+            # what every pass streams against what it needs: the
+            # bands' slots, padding included, and the filled ones
+            slots=sum(band.rows * band.k for band in patched.bands),
+            edges=patched.edges,
         )
         return packed
 
