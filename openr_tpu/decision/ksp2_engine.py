@@ -69,6 +69,7 @@ come back on the host with the call.
 
 from __future__ import annotations
 
+import time
 from typing import Dict, FrozenSet, List, NamedTuple, Optional, Set, Tuple
 
 import numpy as np
@@ -727,19 +728,31 @@ class Ksp2Engine:
     def _sync_window(
         self, ls: LinkState, state, dsts: List[str], span=None
     ) -> Optional[Set[str]]:
-        affected_nodes = self._journal_nodes(
-            ls, (self.version, self.aversion)
-        )
-        if affected_nodes is None:
-            self._cold_build(ls, state, dsts)
-            return None
-        changed = self._diff_pairs(ls, affected_nodes)
+        tracer = get_tracer()
+        # what the window changed: the journals' nodes, their links'
+        # pairs against the engine's snapshot, the drain and label
+        # flips; ``flips`` stays None where the answer is a cold build
+        with tracer.span("decision.ksp2_diff", nodes=0, pairs=0) as diff:
+            affected_nodes = self._journal_nodes(
+                ls, (self.version, self.aversion)
+            )
+            changed = flips = None
+            if affected_nodes is not None:
+                changed = self._diff_pairs(ls, affected_nodes)
+                if (
+                    changed is not None
+                    and len(changed) <= ENGINE_MAX_CHANGED_PAIRS
+                ):
+                    flips = self._diff_nodes(ls, affected_nodes)
+            if diff is not None:
+                diff.attrs["nodes"] = len(affected_nodes or ())
+                diff.attrs["pairs"] = len(changed or ())
         if span is not None and changed is not None:
             span.attrs["changed_pairs"] = len(changed)
-        if changed is None or len(changed) > ENGINE_MAX_CHANGED_PAIRS:
+        if flips is None:
             self._cold_build(ls, state, dsts)
             return None
-        ov_flips, label_flips = self._diff_nodes(ls, affected_nodes)
+        ov_flips, label_flips = flips
         if self.src_name in ov_flips:
             # the root's own drain state gates route selection broadly
             self._cold_build(ls, state, dsts)
@@ -816,7 +829,7 @@ class Ksp2Engine:
         # dispatch to readback of the program the sync waits for its
         # distances in: the rows solve on one chip (the view batch and
         # the endpoints), the fused all-pairs program on a mesh
-        with get_tracer().span(
+        with tracer.span(
             "ops.ksp2_all_pairs",
             rows=(
                 graph.n_pad if self._mesh is not None
@@ -860,9 +873,19 @@ class Ksp2Engine:
                 # trace arrays' patch for the window's nodes and the
                 # walk-reach proof over the masked rows the engine
                 # holds (the mesh's call came back with its rows)
-                verdict = self._second_paths_may_move(
-                    ls, graph, changed, ov_new, blocked
-                )
+                # (the all-pairs span less this one = the dispatch
+                # and the blocked part of the reap)
+                with tracer.span(
+                    "decision.ksp2_walk_proof", candidates=0, proven=0
+                ) as proof:
+                    verdict = self._second_paths_may_move(
+                        ls, graph, changed, ov_new, blocked
+                    )
+                    if proof is not None and verdict is not None:
+                        # destinations it could not clear, and cleared
+                        may = int(verdict[0].sum())
+                        proof.attrs["candidates"] = may
+                        proof.attrs["proven"] = len(self.dsts) - may
             if self._mesh is None:
                 packed, passes = self._reap_all_pairs(packed, passes)
             if ap_span is not None:
@@ -877,22 +900,33 @@ class Ksp2Engine:
         self._preload_view(ls, graph, view_srcs, view_packed)
         d_new_src = view_packed[0].astype(np.int64)
 
-        aff1, aff2, row_stands, rows_proven = self._affected_dsts(
-            graph, changed, d_new_src, rows_new, rows_old, ov_new, verdict,
-        )
-        dst_set = set(self.dst_pos)
-        aff1 &= dst_set
-        aff2 &= dst_set
-        # label/overload materialization extras: paths are unchanged
-        # (distance tests cover path changes) but the ROUTES built from
-        # them embed labels / drain state — invalidate route reuse only
-        route_extra: Set[str] = set()
-        for x in ov_flips | label_flips:
-            if x in self.dst_pos:
-                route_extra.add(x)
-            route_extra |= self.node_users.get(x, set())
-        route_extra &= dst_set
-        affected = aff1 | aff2 | route_extra | (self.host_dsts & dst_set)
+        # the membership tests and the set algebra on what they name
+        with tracer.span(
+            "decision.ksp2_affected", first=0, second=0
+        ) as aff_span:
+            aff1, aff2, row_stands, rows_proven = self._affected_dsts(
+                graph, changed, d_new_src, rows_new, rows_old, ov_new,
+                verdict,
+            )
+            dst_set = set(self.dst_pos)
+            aff1 &= dst_set
+            aff2 &= dst_set
+            # label/overload materialization extras: paths are
+            # unchanged (distance tests cover path changes) but the
+            # ROUTES built from them embed labels / drain state —
+            # invalidate route reuse only
+            route_extra: Set[str] = set()
+            for x in ov_flips | label_flips:
+                if x in self.dst_pos:
+                    route_extra.add(x)
+                route_extra |= self.node_users.get(x, set())
+            route_extra &= dst_set
+            affected = (
+                aff1 | aff2 | route_extra | (self.host_dsts & dst_set)
+            )
+            if aff_span is not None:
+                aff_span.attrs["first"] = len(aff1)
+                aff_span.attrs["second"] = len(aff2)
 
         # however many the tests name, the incremental machinery is the
         # cheaper way: it re-derives what moved, where a cold build
@@ -900,12 +934,21 @@ class Ksp2Engine:
         # the root's own pod names nine destinations in ten and moves
         # 0 to all of them)
         if aff1 or aff2:
-            moved = self._recompute(
-                ls, state, aff1, aff2, d_new_src, changed,
-                row_stands, rows_proven, blocked,
-                # a refresh sends the window's last masked batch
-                matrix_behind=rows_proven,
-            )
+            # its self time is the bookkeeping (_set_first_paths,
+            # node_users, the exclusion sets' slots): the masked solves
+            # and the traces are its children
+            with tracer.span(
+                "decision.ksp2_recompute",
+                first=len(aff1), second=len(aff2), moved=0,
+            ) as rc_span:
+                moved = self._recompute(
+                    ls, state, aff1, aff2, d_new_src, changed,
+                    row_stands, rows_proven, blocked,
+                    # a refresh sends the window's last masked batch
+                    matrix_behind=rows_proven, sync_span=span,
+                )
+                if rc_span is not None:
+                    rc_span.attrs["moved"] = len(moved)
             # of the destinations the tests named, those whose paths
             # came back as they were keep their routes
             affected = moved | route_extra | (self.host_dsts & dst_set)
@@ -917,7 +960,7 @@ class Ksp2Engine:
             if span is not None:
                 span.attrs["refreshed_rows"] = refreshed
         # a window that named no destination sent no masked batch
-        self._dispatch_matrix()
+        self._dispatch_matrix(span)
         self._prime_all(ls)
 
         # commit snapshots
@@ -1758,6 +1801,7 @@ class Ksp2Engine:
         d_new_src: np.ndarray, changed,
         row_stands: Set[str], rows_proven: bool,
         transit_blocked: Set[str], matrix_behind: bool = True,
+        sync_span=None,
     ) -> Set[str]:
         """Re-derive the paths of the destinations the membership tests
         named (``aff1``: first paths, ``aff2``: second) and return
@@ -1785,7 +1829,9 @@ class Ksp2Engine:
         ``matrix_behind``: no masked batch follows this call's in the
         window, so the matrix solve the sync owes goes to the device
         behind the last one here (_dispatch_matrix), ahead of the host
-        work that follows it."""
+        work that follows it. ``sync_span``: the window's
+        ``decision.ksp2_sync``, which that dispatch is booked on where
+        no masked span is open to take it."""
         graph = state.graph
         cands_of = make_cands_of(ls, graph.node_index)
         named = sorted(aff1 | aff2)
@@ -1825,7 +1871,7 @@ class Ksp2Engine:
                 index_users=False, matrix_behind=matrix_behind,
             )
         elif matrix_behind:
-            self._dispatch_matrix()
+            self._dispatch_matrix(sync_span)
         if retrace:
             at = [self.dst_pos[dst] for dst in retrace]
             reach = np.full((len(retrace), graph.n_pad), -1, dtype=np.int32)
@@ -1878,9 +1924,12 @@ class Ksp2Engine:
             self._note_paths(dst)
         return moved
 
-    def _dispatch_matrix(self) -> None:
+    def _dispatch_matrix(self, span=None) -> None:
         """Send the matrix solve the sync in hand owes its window (once:
-        nothing where none is owed, as in a cold build or on a mesh):
+        nothing where none is owed, as in a cold build or on a mesh;
+        ``span``: the span open around the call, the masked solve's or
+        the sync's, which gets the dispatch's host time as
+        ``matrix_dispatch_ms``):
         the all-sources fixed point that relaxes ``d_prev_dev`` in
         place, warm-seeded with the window's increase triple as the
         rows solve put it on the device. Called behind the window's
@@ -1894,6 +1943,7 @@ class Ksp2Engine:
         if due is None:
             return
         state, inc_dev = due
+        t0 = time.perf_counter()
         # the dispatch DONATES the matrix: drop our reference first, so
         # a dispatch that raises leaves None and not a dead buffer
         d_prev, self.d_prev_dev = self.d_prev_dev, None
@@ -1902,6 +1952,10 @@ class Ksp2Engine:
         )
         self._matrix_passes.append(passes)
         _counters()["decision.ksp2_matrix_deferred"] += 1
+        if span is not None:
+            span.attrs["matrix_dispatch_ms"] = round(
+                (time.perf_counter() - t0) * 1000.0, 4
+            )
 
     def _book_matrix_passes(self) -> None:
         """Book the pass counts of the matrix solves that have landed
@@ -1941,11 +1995,13 @@ class Ksp2Engine:
         rows, passes = _da.reap_read(out_dev, kicked=True)
         return rows, spf_sparse.note_ksp2_passes("masked", passes)
 
-    def _masked_dispatch(self, state, batch: List[str]):
+    def _masked_dispatch(self, state, batch: List[str], span=None):
         """Masks of one chunk's destinations, padded to one of the
         chunk's three buckets (all compiled by the cold build, so no
         affected-set size compiles later), and their masked solve
-        dispatched. Returns ``(ok, out_dev, out_host)``: which masks
+        dispatched; the mask build's host time and the masks' bytes
+        are summed onto ``span`` (the caller's ops.ksp2_masked_solve:
+        ``masks_ms``, ``mask_bytes``). Returns ``(ok, out_dev, out_host)``: which masks
         the slots could carry, and the program's ``(rows, passes)``
         either on the device with their readback kicked (one chip) or
         on the host (the mesh's sharded solve returns them so);
@@ -1962,7 +2018,16 @@ class Ksp2Engine:
             # sharded batches divide destinations over the mesh
             ndev = self._mesh.devices.size
             bucket = -(-max(bucket, ndev) // ndev) * ndev
+        t0 = time.perf_counter()
         masks, ok = self._batch_masks(graph, batch, bucket)
+        if span is not None:
+            # attributes, not child spans: the span's self time is
+            # what ksp2_masked_solve_ms reads
+            span.attrs["masks_ms"] = round(
+                span.attrs["masks_ms"]
+                + (time.perf_counter() - t0) * 1000.0, 4
+            )
+            span.attrs["mask_bytes"] += sum(m.nbytes for m in masks)
         _counters()["decision.ksp2_device_batches"] += 1
         if self._mesh is not None:
             return ok, None, spf_sparse.sharded_ell_masked_distances_resident(
@@ -2033,14 +2098,15 @@ class Ksp2Engine:
         with get_tracer().span(
             "ops.ksp2_masked_solve", rows=len(dsts),
             batches=-(-len(dsts) // chunk), refresh=True, passes=0,
+            masks_ms=0.0, mask_bytes=0,
         ) as span:
             for start in range(0, len(dsts), chunk):
                 batch = dsts[start : start + chunk]
-                ok, *out = self._masked_dispatch(state, batch)
+                ok, *out = self._masked_dispatch(state, batch, span)
                 if start + chunk >= len(dsts):
                     # the window's last masked batch: the matrix solve
                     # behind it, ahead of its reap
-                    self._dispatch_matrix()
+                    self._dispatch_matrix(span)
                 drows, passes = self._reap_masked(*out)
                 if span is not None:
                     span.attrs["passes"] = max(span.attrs["passes"], passes)
@@ -2118,15 +2184,18 @@ class Ksp2Engine:
         with get_tracer().span(
             "ops.ksp2_masked_solve", rows=len(dsts),
             batches=-(-len(dsts) // chunk), passes=0,
+            masks_ms=0.0, mask_bytes=0,
         ) as span:
             inflight = None
             for start in range(0, len(dsts), chunk):
                 # stage 1: mask build + (async) masked solve
                 batch = dsts[start : start + chunk]
-                staged = (batch, *self._masked_dispatch(state, batch))
+                staged = (
+                    batch, *self._masked_dispatch(state, batch, span)
+                )
                 if matrix_behind and start + chunk >= len(dsts):
                     # behind the last batch, ahead of every settle
-                    self._dispatch_matrix()
+                    self._dispatch_matrix(span)
                 if inflight is not None:
                     if staged[2] is not None:
                         _da.note_pipelined_dispatch(2)

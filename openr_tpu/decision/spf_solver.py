@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import contextlib
 import os
+import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Set, Tuple
 
@@ -655,14 +656,25 @@ class _EllResidentCache:
             version, state = entry
             if version == ls.topology_version:
                 return state, None
-            affected = ls.affected_since(version)
-            patched = (
-                spf_sparse.ell_patch(
-                    state.graph, ls, sorted(affected), widen=True
+            # the host's re-derivation of the changed rows: under
+            # decision.prewarm, or under graph.view_sync where no
+            # prewarm ran
+            with _get_tracer().span(
+                "ops.ell_patch", rows=0, widened=0
+            ) as span:
+                affected = ls.affected_since(version)
+                patched = (
+                    spf_sparse.ell_patch(
+                        state.graph, ls, sorted(affected), widen=True
+                    )
+                    if affected is not None
+                    else None
                 )
-                if affected is not None
-                else None
-            )
+                if span is not None and patched is not None:
+                    span.attrs["rows"] = sum(
+                        len(r) for r in (patched.changed or {}).values()
+                    )
+                    span.attrs["widened"] = len(patched.widened or ())
             if patched is not None:
                 SPF_COUNTERS["decision.ell_patches"] += 1
                 return state, patched
@@ -879,6 +891,7 @@ class RouteBuild:
     "_build_seq",
     "_ksp2_dsts_cache",
     "_ksp2_engines",
+    "_ksp2_select",
     "_ksp2_tracked",
     "_ksp2_tracked_of",
     "_ksp2_untracked",
@@ -980,6 +993,10 @@ class SpfSolver:
         # SP dirty set was diffed against
         self._build_seq = 0
         self._sp_prev_seq: Optional[int] = None
+        # [seconds in, calls of] _select_best_paths_ksp2 since the
+        # per-prefix pass in hand began (``select_ms`` / ``selected``
+        # on decision.ksp2_routes: two clock reads a call, no span)
+        self._ksp2_select = [0.0, 0]
         # per-prefix-state-version KSP2 destination sets (see
         # _prefetch_ksp2_paths)
         self._ksp2_dsts_cache: Optional[tuple] = None
@@ -1084,12 +1101,20 @@ class SpfSolver:
                 entry = _ELL_RESIDENT._cache.get(ls)
                 if entry is None or entry[0] == ls.topology_version:
                     continue
-                with _get_tracer().span(
+                tracer = _get_tracer()
+                with tracer.span(
                     "decision.prewarm",
                     trace=trace,
                     rows=len(ls.affected_since(entry[0]) or ()),
                 ):
-                    _ELL_RESIDENT.state_for(ls)
+                    # the window's trace active on this thread, so the
+                    # patch's own spans (ops.ell_patch, ops.ell_scatter)
+                    # nest in this one
+                    tracer.activate(trace)
+                    try:
+                        _ELL_RESIDENT.state_for(ls)
+                    finally:
+                        tracer.deactivate()
                 SPF_COUNTERS["decision.ell_prewarms"] += 1
             except Exception:
                 continue
@@ -1639,6 +1664,7 @@ class SpfSolver:
             if affected is not None
             else contextlib.nullcontext()
         ) as ksp2_span:
+            self._ksp2_select = [0.0, 0]
             for prefix in iter_prefixes:
                 if adv_map is not None:
                     advertisers, has_ksp2 = adv_map[prefix]
@@ -1688,6 +1714,9 @@ class SpfSolver:
                 )
             if ksp2_span is not None:
                 ksp2_span.attrs["reused"] = ksp2_reused
+                select_s, selected = self._ksp2_select
+                ksp2_span.attrs["select_ms"] = round(select_s * 1e3, 4)
+                ksp2_span.attrs["selected"] = selected
         table.meta = meta
         table.seq = self._build_seq
         table.n_prefixes = len(prefix_state.prefixes())
@@ -2151,15 +2180,20 @@ class SpfSolver:
                 area_link_states,
             )
         if falgo == PrefixForwardingAlgorithm.KSP2_ED_ECMP:
-            return self._select_best_paths_ksp2(
-                my_node_name,
-                prefix,
-                best,
-                entries,
-                has_bgp,
-                ftype,
-                area_link_states,
-            )
+            t0 = time.perf_counter()
+            try:
+                return self._select_best_paths_ksp2(
+                    my_node_name,
+                    prefix,
+                    best,
+                    entries,
+                    has_bgp,
+                    ftype,
+                    area_link_states,
+                )
+            finally:
+                self._ksp2_select[0] += time.perf_counter() - t0
+                self._ksp2_select[1] += 1
         return None
 
     # -- best route selection --------------------------------------------

@@ -54,11 +54,14 @@ log = logging.getLogger(__name__)
 _UNCOMPILABLE = object()  # poison marker: lower/compile failed once
 
 
-def _profiled(tag: str, thunk):
+def _profiled(tag: str, thunk, unread: bool = False):
     """Run one dispatch under device-time attribution: host wall time
-    always, sampled block-for-ready device time per the profiler's
-    cadence, both folded into the active event window's stage table.
-    Disabled profiler == the bare call (one attribute read)."""
+    always, sampled device time per the profiler's cadence (a mark on
+    the active event window, closed by its next ``reap_read``; a block
+    for the result only outside any window), both folded into the
+    active event window's stage table. ``unread``: nobody reads the
+    output, so the dispatch is never sampled. Disabled profiler == the
+    bare call (one attribute read)."""
     prof = get_profiler()
     if not prof.enabled:
         return thunk()
@@ -66,7 +69,11 @@ def _profiled(tag: str, thunk):
         t0 = time.perf_counter()
         out = thunk()
         host_ms = (time.perf_counter() - t0) * 1000.0
-    device_ms = prof.on_dispatch(tag, out, host_ms)
+    window = dispatch_accounting.current_window()
+    device_ms = prof.on_dispatch(
+        tag, out, host_ms, t0=t0,
+        marks=None if window is None else window.marks, unread=unread,
+    )
     dispatch_accounting.attribute_stage(tag, host_ms, device_ms)
     return out
 
@@ -121,15 +128,21 @@ class AotDispatchCache:
         return key, exe
 
     def call(self, tag: str, fn, dyn_args: Tuple,
-             statics: Dict[str, Any]):
+             statics: Dict[str, Any], unread: bool = False):
         """Dispatch ``fn(*dyn_args, **statics)`` through the cached
-        executable for this shape key, compiling it on first miss."""
+        executable for this shape key, compiling it on first miss.
+        ``unread``: the caller waits for nothing and no host read
+        follows this dispatch's output (``_profiled``)."""
         reg = get_registry()
         dispatch_accounting.count_dispatch()
+
+        def jitted():
+            return fn(*dyn_args, **statics)
+
         key, exe = self._lookup(tag, fn, dyn_args, statics)
         if key is None or exe is _UNCOMPILABLE:
             reg.counter_bump("ops.aot_fallbacks")
-            return _profiled(tag, lambda: fn(*dyn_args, **statics))
+            return _profiled(tag, jitted, unread)
         if exe is None:
             try:
                 exe = fn.lower(*dyn_args, **statics).compile()
@@ -141,7 +154,7 @@ class AotDispatchCache:
                 with self._lock:
                     self._exes[key] = _UNCOMPILABLE
                 reg.counter_bump("ops.aot_fallbacks")
-                return _profiled(tag, lambda: fn(*dyn_args, **statics))
+                return _profiled(tag, jitted, unread)
             with self._lock:
                 self._exes[key] = exe
             reg.counter_bump("ops.aot_compiles")
@@ -150,7 +163,7 @@ class AotDispatchCache:
         try:
             # dynamic operands ONLY: the statics were baked at lower
             # time and no longer exist as parameters of the executable
-            return _profiled(tag, lambda: exe(*dyn_args))
+            return _profiled(tag, lambda: exe(*dyn_args), unread)
         except Exception:  # noqa: BLE001 - absorb into jitted path
             # a donated operand the failed call already consumed makes
             # the retry raise in its own right; that one propagates
@@ -159,7 +172,7 @@ class AotDispatchCache:
                 "jitted function", tag,
             )
             reg.counter_bump("ops.aot_fallbacks")
-            return _profiled(tag, lambda: fn(*dyn_args, **statics))
+            return _profiled(tag, jitted, unread)
 
     def warm(self, tag: str, fn, dyn_args: Tuple,
              statics: Dict[str, Any]) -> bool:
@@ -191,5 +204,6 @@ def get_aot_cache() -> AotDispatchCache:
     return _CACHE
 
 
-def aot_call(tag: str, fn, dyn_args: Tuple, statics: Dict[str, Any]):
-    return _CACHE.call(tag, fn, dyn_args, statics)
+def aot_call(tag: str, fn, dyn_args: Tuple, statics: Dict[str, Any],
+             unread: bool = False):
+    return _CACHE.call(tag, fn, dyn_args, statics, unread)

@@ -19,7 +19,9 @@ never calls ``jax.device_get`` / ``.block_until_ready`` directly (the
   ``kicked=True`` means the transfer was staged earlier and the reap
   normally finds it landed (counted ``ops.async_reaps``);
   ``kicked=False`` is a genuine blocking device->host sync (counted
-  ``ops.blocking_syncs``).
+  ``ops.blocking_syncs``). Either way it is also where the profiler's
+  sampled dispatches of the window get their device time
+  (``EventWindow.marks``): telemetry adds no wait of its own.
 
 ``event_window(tag)`` brackets one event: consecutive dispatches
 collapse into one submit phase and consecutive reads into one read
@@ -67,7 +69,7 @@ class EventWindow:
     __slots__ = (
         "tag", "dispatches", "blocking_syncs", "async_reaps",
         "submit_phases", "read_phases", "_last",
-        "t0", "device_ms", "stages", "windows", "drain",
+        "t0", "device_ms", "stages", "windows", "drain", "marks",
     )
 
     def __init__(self, tag: str, drain: bool = False):
@@ -88,6 +90,10 @@ class EventWindow:
         # feeds the per-drain histograms instead of only per-window
         self.windows = 1
         self.drain = drain
+        # sampled dispatches whose device time is still open
+        # (Profiler.on_dispatch): closed by this window's next
+        # reap_read, dropped with the window
+        self.marks: list = []
 
     def _mark(self, phase: str) -> None:
         if self._last != phase:
@@ -251,4 +257,9 @@ def reap_read(arr, kicked: bool = False):
             w.blocking_syncs += 1
     if w is not None:
         w._mark("read")
-    return jax.device_get(arr)
+    out = jax.device_get(arr)
+    if w is not None and w.marks:
+        # the program turned the device around here anyway: the
+        # profiler's samples ride this read and make none of their own
+        get_profiler().close_marks(w.marks)
+    return out

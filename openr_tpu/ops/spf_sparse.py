@@ -2029,23 +2029,40 @@ class EllState:
         ids are unchanged, so every id-keyed resident consumer stays
         valid. The increase delta is journaled so a later reconverge
         can still warm-start across the un-solved patch."""
-        ov_changed = self._sync_overloaded(patched)
-        self._note_patch(patched, ov_changed)
-        # one jitted scatter per band that has changed rows (one
-        # compiled shape per band x bucket), fed the host row blocks
-        # directly; a band with nothing to scatter launches nothing
-        src, w = list(self.src), list(self.w)
-        for bi, (widened, rows) in enumerate(_band_patch_rows(patched)):
-            if widened:
-                src[bi] = jnp.asarray(patched.src[bi])
-                w[bi] = jnp.asarray(patched.w[bi])
-            elif rows is not None:
-                src[bi], w[bi] = _patch_band(
-                    src[bi], w[bi], rows,
-                    patched.src[bi][rows], patched.w[bi][rows],
-                )
-        self.src, self.w = tuple(src), tuple(w)
-        self.graph = _replace(patched, changed=None)
+        # the span's whole is the scatter as the window pays it
+        # (ell_patch_scatter_ms): the overload sync, the journal, and
+        # per band a launch or a re-upload; ``bytes``: host bytes
+        # handed to the device
+        with _get_tracer().span(
+            "ops.ell_scatter", bands=0, rows=0, bytes=0
+        ) as span:
+            ov_changed = self._sync_overloaded(patched)
+            self._note_patch(patched, ov_changed)
+            # one jitted scatter per band that has changed rows (one
+            # compiled shape per band x bucket), fed the host row
+            # blocks directly; a band with nothing to scatter launches
+            # nothing
+            src, w = list(self.src), list(self.w)
+            bands = n_rows = nbytes = 0
+            for bi, (widened, rows) in enumerate(_band_patch_rows(patched)):
+                if widened:
+                    src[bi] = jnp.asarray(patched.src[bi])
+                    w[bi] = jnp.asarray(patched.w[bi])
+                    sent = (patched.src[bi], patched.w[bi])
+                elif rows is not None:
+                    sent = (rows, patched.src[bi][rows], patched.w[bi][rows])
+                    src[bi], w[bi] = _patch_band(src[bi], w[bi], *sent)
+                else:
+                    continue
+                bands += 1
+                n_rows += len(sent[-1])
+                nbytes += sum(a.nbytes for a in sent)
+            self.src, self.w = tuple(src), tuple(w)
+            self.graph = _replace(patched, changed=None)
+            if span is not None:
+                if ov_changed:
+                    nbytes += patched.overloaded.nbytes
+                span.attrs.update(bands=bands, rows=n_rows, bytes=nbytes)
 
     @solve_window
     def reconverge(self, patched: EllGraph, srcs):
@@ -2058,92 +2075,100 @@ class EllState:
         inputs with a no-op scatter — same discipline as apply_patch;
         the new band shapes cost one jit recompile."""
         # span on the enclosing module's active trace (no-op outside a
-        # traced churn event); attrs carry the warm/cold verdict plus
-        # the device-dispatch vs host-overhead split
-        _tracer = _get_tracer()
-        _span = _tracer.span_active("ops.ell_reconverge")
-        _t0 = time.perf_counter()
-        ov_changed = self._sync_overloaded(patched)
-        self._note_patch(patched, ov_changed)
-        in_src, in_w, patch_ids, patch_src, patch_w = (
-            band_patch_inputs(self.src, self.w, patched)
-        )
-        srcs_key = tuple(int(s) for s in srcs)
-        b = len(srcs_key)
-        warm = (
-            self._d_dev is not None
-            and self._warm_key == srcs_key
-        )
-        if warm:
-            # every journaled edge, the increases vs the SNAPSHOT
-            # weights the resident distances were solved under (edges
-            # that moved and came back to or below their snapshot need
-            # no reset: the old rows are still valid upper bounds);
-            # effective-weight aware, so drain flips and link removals
-            # ride the same warm seed
-            # openr-lint: disable=host-sync-in-window -- overloaded is
-            # a host ndarray on EllGraph; no device transfer happens
-            ov_now = np.asarray(patched.overloaded)
-            inc = self._emit_changes(ov_now)
-            d_prev = self._d_dev
-            ELL_COUNTERS["ell_warm_solves"] += 1
-            if self._pending_structural:
-                ELL_COUNTERS["ell_structural_warm_solves"] += 1
-        else:
-            inc = [_FORCE_RESET_EDGE]
-            d_prev = (
-                self._d_dev
-                if self._d_dev is not None
-                and self._d_dev.shape == (b, patched.n_pad)
-                else jnp.zeros((b, patched.n_pad), dtype=jnp.int32)
+        # traced churn event), scoped: closed on a raise, and on a
+        # profiler session's host plane; attrs carry the warm/cold
+        # verdict plus the device-dispatch vs host-overhead split
+        with _get_tracer().span("ops.ell_reconverge") as _span:
+            _t0 = time.perf_counter()
+            ov_changed = self._sync_overloaded(patched)
+            self._note_patch(patched, ov_changed)
+            in_src, in_w, patch_ids, patch_src, patch_w = (
+                band_patch_inputs(self.src, self.w, patched)
             )
-            ELL_COUNTERS["ell_cold_solves"] += 1
-        inc_t, inc_h, inc_w = pad_increase_edges(inc)
-        # openr-lint: disable=host-sync-in-window -- srcs is a host
-        # list of sample ids, not a device array; no transfer happens
-        srcs_dev = jnp.asarray(np.asarray(srcs, dtype=np.int32))
-        _t_dispatch = time.perf_counter()
-        # openr-lint: disable=donation-hazard -- intentional: the warm
-        # path CONSUMES the previous resident distances (d_prev is dead
-        # after this dispatch) and self._d_dev is rebound to the fresh
-        # output below; no retry ladder re-reads the donated buffer
-        # openr-lint: disable=sharding-spec -- single-chip resident
-        # reconvergence (mesh callers go through the sharded_ell_*
-        # shard_map wrappers): no mesh axis to spec
-        self.src, self.w, packed, d, self._stats_dev = _ell_reconverge(
-            in_src, in_w, patch_ids, patch_src, patch_w,
-            jnp.asarray(inc_t), jnp.asarray(inc_h), jnp.asarray(inc_w),
-            self.overloaded, d_prev, srcs_dev,
-            patched.bands, patched.n_pad,
-        )
-        _t_end = time.perf_counter()
-        self._d_dev = d
-        self._warm_key = srcs_key
-        self._solved_warm = warm
-        self._pending_edges = {}
-        # openr-lint: disable=host-sync-in-window -- host ndarray copy
-        # (the overload mask the resident distances were solved under)
-        self._ov_solved = np.array(patched.overloaded, copy=True)
-        self._pending_structural = False
-        self.graph = _replace(patched, changed=None)
-        _total_ms = (_t_end - _t0) * 1000.0
-        _dispatch_ms = (_t_end - _t_dispatch) * 1000.0
-        _reg = _get_registry()
-        _reg.observe("ops.ell.reconverge_ms", _total_ms)
-        _reg.observe(
-            "ops.ell.host_overhead_ms", _total_ms - _dispatch_ms
-        )
-        _tracer.end_span_active(
-            _span,
-            warm=warm,
-            dispatch_ms=round(_dispatch_ms, 4),
-            host_overhead_ms=round(_total_ms - _dispatch_ms, 4),
-            # what every pass streams against what it needs: the
-            # bands' slots, padding included, and the filled ones
-            slots=sum(band.rows * band.k for band in patched.bands),
-            edges=patched.edges,
-        )
-        return packed
+            srcs_key = tuple(int(s) for s in srcs)
+            b = len(srcs_key)
+            warm = (
+                self._d_dev is not None
+                and self._warm_key == srcs_key
+            )
+            if warm:
+                # every journaled edge, the increases vs the SNAPSHOT
+                # weights the resident distances were solved under (edges
+                # that moved and came back to or below their snapshot need
+                # no reset: the old rows are still valid upper bounds);
+                # effective-weight aware, so drain flips and link removals
+                # ride the same warm seed
+                # openr-lint: disable=host-sync-in-window -- overloaded is
+                # a host ndarray on EllGraph; no device transfer happens
+                ov_now = np.asarray(patched.overloaded)
+                inc = self._emit_changes(ov_now)
+                d_prev = self._d_dev
+                ELL_COUNTERS["ell_warm_solves"] += 1
+                if self._pending_structural:
+                    ELL_COUNTERS["ell_structural_warm_solves"] += 1
+            else:
+                inc = [_FORCE_RESET_EDGE]
+                d_prev = (
+                    self._d_dev
+                    if self._d_dev is not None
+                    and self._d_dev.shape == (b, patched.n_pad)
+                    else jnp.zeros((b, patched.n_pad), dtype=jnp.int32)
+                )
+                ELL_COUNTERS["ell_cold_solves"] += 1
+            inc_t, inc_h, inc_w = pad_increase_edges(inc)
+            # openr-lint: disable=host-sync-in-window -- srcs is a host
+            # list of sample ids, not a device array; no transfer happens
+            srcs_dev = jnp.asarray(np.asarray(srcs, dtype=np.int32))
+            _t_dispatch = time.perf_counter()
+            # the increase triple's three puts, then the jitted call:
+            # dispatch_ms = put_ms + launch_ms
+            inc_t, inc_h, inc_w = (
+                jnp.asarray(inc_t), jnp.asarray(inc_h), jnp.asarray(inc_w)
+            )
+            _t_launch = time.perf_counter()
+            # openr-lint: disable=donation-hazard -- intentional: the warm
+            # path CONSUMES the previous resident distances (d_prev is dead
+            # after this dispatch) and self._d_dev is rebound to the fresh
+            # output below; no retry ladder re-reads the donated buffer
+            # openr-lint: disable=sharding-spec -- single-chip resident
+            # reconvergence (mesh callers go through the sharded_ell_*
+            # shard_map wrappers): no mesh axis to spec
+            self.src, self.w, packed, d, self._stats_dev = _ell_reconverge(
+                in_src, in_w, patch_ids, patch_src, patch_w,
+                inc_t, inc_h, inc_w,
+                self.overloaded, d_prev, srcs_dev,
+                patched.bands, patched.n_pad,
+            )
+            _t_end = time.perf_counter()
+            self._d_dev = d
+            self._warm_key = srcs_key
+            self._solved_warm = warm
+            self._pending_edges = {}
+            # openr-lint: disable=host-sync-in-window -- host ndarray copy
+            # (the overload mask the resident distances were solved under)
+            self._ov_solved = np.array(patched.overloaded, copy=True)
+            self._pending_structural = False
+            self.graph = _replace(patched, changed=None)
+            _total_ms = (_t_end - _t0) * 1000.0
+            _dispatch_ms = (_t_end - _t_dispatch) * 1000.0
+            _reg = _get_registry()
+            _reg.observe("ops.ell.reconverge_ms", _total_ms)
+            _reg.observe(
+                "ops.ell.host_overhead_ms", _total_ms - _dispatch_ms
+            )
+            if _span is not None:
+                _span.attrs.update(
+                    warm=warm,
+                    dispatch_ms=round(_dispatch_ms, 4),
+                    put_ms=round((_t_launch - _t_dispatch) * 1000.0, 4),
+                    launch_ms=round((_t_end - _t_launch) * 1000.0, 4),
+                    host_overhead_ms=round(_total_ms - _dispatch_ms, 4),
+                    # what every pass streams against what it needs: the
+                    # bands' slots, padding included, and the filled ones
+                    slots=sum(band.rows * band.k for band in patched.bands),
+                    edges=patched.edges,
+                )
+            return packed
 
     def fetch_view(self, packed):
         """The packed view ``reconverge`` just returned, on the host,
@@ -2312,11 +2337,16 @@ def ell_all_view_rows(state: EllState, d_prev, inc_dev):
     increase triple of the window (ell_view_ep_rows returns the one it
     sent; ``_inc_args(None, bucket)`` forces the cold seed); d_prev is
     DONATED (invalid after the call). Rides the committed AOT
-    executable cache (``ksp2_view_rows``)."""
+    executable cache (``ksp2_view_rows``: ``ops.host_ms`` only, no
+    ``ops.device_ms``; its device time is ``jit__ell_all_view_rows``
+    on a profiler session)."""
     d_all, passes = _aot_call(
         "ksp2_view_rows", _ell_all_view_rows,
         (state.src, state.w, state.overloaded, d_prev, *inc_dev),
         dict(bands=state.graph.bands, n=state.graph.n_pad),
+        # nobody reads the matrix: the profiler takes no sample of it
+        # (the next reap is the masked batch's it was sent behind)
+        unread=True,
     )
     _da.kick_async(passes)
     return d_all, passes

@@ -8,10 +8,16 @@ dispatch plane itself:
 
 - every ``aot_call`` dispatch is wall-timed on the host
   (``ops.host_ms.<tag>``), and every ``sample_every``-th call per tag
-  additionally blocks until the result is ready so the full
-  submit-to-ready device time lands in ``ops.device_ms.<tag>`` — the
-  timed-dispatch sampling fallback that works on CPU where
-  ``jax.profiler`` device traces don't exist;
+  is sampled: its submit-to-read time lands in ``ops.device_ms.<tag>``
+  — the timed-dispatch sampling fallback that works on CPU where
+  ``jax.profiler`` device traces don't exist. Inside a
+  ``dispatch_accounting`` window the sample never waits: it leaves a
+  mark on the window, and the window's next ``reap_read`` that finds
+  the dispatch's output ready closes it (``close_marks``), so the
+  number is an upper bound — dispatch start to the end of the host
+  read the program made anyway, the host work between them included.
+  Outside any window nothing was deferred behind the dispatch and the
+  sample blocks for the result, as it always did;
 - where a ``jax.profiler`` session IS collecting, ``annotate(tag)``
   wraps the same dispatches in ``TraceAnnotation`` so the XLA timeline
   carries the stage names (free when no session is active);
@@ -25,10 +31,14 @@ dispatch plane itself:
 
 Overhead budget (<5% on the churn bench, gated by ``make obs-smoke``):
 the un-sampled path is one ``perf_counter`` pair, one histogram
-observe, and a thread-local read. The sampled path adds ONE
-``block_until_ready`` per ``sample_every`` dispatches — a deliberate,
-counted pipeline bubble (``ops.profile_samples``), never inside the
-two-touch accounting (it does not ride ``reap_read``).
+observe, and a thread-local read. The sampled path inside a window
+adds a tuple on a list and, at the next ``reap_read``, one
+``is_ready`` query and the observes (``ops.profile_samples`` counts
+marks closed); outside a window it adds ONE ``block_until_ready`` per
+``sample_every`` dispatches. A mark no read closes before its window
+retires is dropped, and a dispatch whose output nobody reads
+(``unread``: the KSP2 engine's deferred matrix solve) is never sampled
+— a wait or a mark there would charge it another program's time.
 
 Disabled (``OPENR_PROFILE=0``) the plane costs one attribute read per
 dispatch and nothing else.
@@ -52,6 +62,26 @@ def _sanitize(value: Any) -> str:
     """fb303-safe label value: lowercase alnum + underscore."""
     s = str(value).lower()
     return "".join(c if c.isalnum() else "_" for c in s).strip("_") or "x"
+
+
+def _first_array(out: Any) -> Any:
+    """The first leaf of a dispatch's output that can say whether it
+    is ready (a ``jax.Array``), None where none can (host shims)."""
+    stack = [out]
+    while stack:
+        x = stack.pop()
+        if isinstance(x, (tuple, list)):
+            stack.extend(reversed(x))
+        elif hasattr(x, "is_ready"):
+            return x
+    return None
+
+
+def _is_ready(leaf: Any) -> bool:
+    try:
+        return bool(leaf.is_ready())
+    except Exception:  # noqa: BLE001 - donated since: it ran
+        return True
 
 
 class _TagState:
@@ -139,11 +169,20 @@ class Profiler:
             return nullcontext()
 
     # -- per-dispatch attribution -----------------------------------
-    def on_dispatch(self, tag: str, out: Any, host_ms: float) -> float:
-        """Record one dispatch's host wall time; on sampled calls also
-        block for the device result and record measured device time.
-        Returns the best device-time estimate for this call (measured,
-        else the tag's EWMA, else the host time)."""
+    def on_dispatch(
+        self, tag: str, out: Any, host_ms: float,
+        t0: Optional[float] = None, marks: Optional[list] = None,
+        unread: bool = False,
+    ) -> float:
+        """Record one dispatch's host wall time, and on sampled calls
+        its device time: a mark on ``marks`` (the active accounting
+        window's list, closed by that window's next ``reap_read``
+        through ``close_marks``) where the caller is inside a window,
+        a block for the result where it is not. ``t0``: the dispatch's
+        start on ``perf_counter`` (now less ``host_ms`` without it).
+        ``unread``: nobody reads this dispatch's output, so it is
+        never sampled. Returns the best device-time estimate for this
+        call (measured, else the tag's EWMA, else the host time)."""
         if not self.enabled:
             return host_ms
         reg = get_registry()
@@ -153,22 +192,51 @@ class Profiler:
             if st is None:
                 st = self._tags[tag] = _TagState()
             st.calls += 1
-            sampled = (st.calls % self.sample_every) == 1 or \
-                self.sample_every == 1
+            sampled = not unread and (
+                (st.calls % self.sample_every) == 1
+                or self.sample_every == 1
+            )
             ewma = st.device_ewma_ms
+        estimate = ewma if ewma is not None else host_ms
         if not sampled:
-            return ewma if ewma is not None else host_ms
-        t0 = time.perf_counter()
+            return estimate
+        if t0 is None:
+            t0 = time.perf_counter() - host_ms / 1000.0
+        if marks is not None:
+            marks.append((tag, t0, self._active_labels(), _first_array(out)))
+            return estimate
         try:
             import jax
 
             jax.block_until_ready(out)
         except Exception:  # noqa: BLE001 - host shims / non-arrays
             pass
-        device_ms = host_ms + (time.perf_counter() - t0) * 1000.0
+        return self._sample(
+            tag, (time.perf_counter() - t0) * 1000.0, self._active_labels()
+        )
+
+    def close_marks(self, marks: list) -> None:
+        """The host has just read from the device: close, as measured
+        device time from their dispatch's start to now, the marks of
+        ``marks`` whose output is ready, and keep the rest (a batch
+        dispatched behind the one just read) for the next read. Never
+        blocks."""
+        now = time.perf_counter()
+        still = []
+        for mark in marks:
+            tag, t0, labels, leaf = mark
+            if leaf is not None and not _is_ready(leaf):
+                still.append(mark)
+                continue
+            self._sample(tag, (now - t0) * 1000.0, labels)
+        marks[:] = still
+
+    def _sample(
+        self, tag: str, device_ms: float, labels: Optional[Dict[str, str]]
+    ) -> float:
+        reg = get_registry()
         reg.counter_bump("ops.profile_samples")
         reg.observe(f"ops.device_ms.{tag}", device_ms)
-        labels = self._active_labels()
         if labels:
             for key, val in labels.items():
                 reg.observe(f"ops.device_ms.by_{key}.{val}", device_ms)

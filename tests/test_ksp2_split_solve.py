@@ -482,10 +482,10 @@ def test_a_sync_that_finds_the_matrix_still_running_says_so(monkeypatch):
     monkeypatch.setattr(spf_sparse, "ell_view_ep_rows", rows)
     real_dispatch = ksp2_engine.Ksp2Engine._dispatch_matrix
 
-    def dispatch(eng):
+    def dispatch(eng, span=None):
         if isinstance(eng.d_prev_dev, NotYet):
             eng.d_prev_dev = eng.d_prev_dev.arr
-        return real_dispatch(eng)
+        return real_dispatch(eng, span)
 
     monkeypatch.setattr(ksp2_engine.Ksp2Engine, "_dispatch_matrix", dispatch)
     counts = []

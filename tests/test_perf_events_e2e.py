@@ -232,7 +232,8 @@ ADJ_EVENT_TREE = [
 # reconverge
 ELL_ADJ_EVENT_TREE = (
     ADJ_EVENT_TREE[:3]
-    + [("decision.prewarm", 1)]
+    + [("decision.prewarm", 1), ("ops.ell_patch", 2),
+       ("ops.ell_scatter", 2)]
     + ADJ_EVENT_TREE[3:5]
     + [("ops.ell_reconverge", 2)]
     + ADJ_EVENT_TREE[6:]

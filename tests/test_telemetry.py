@@ -670,6 +670,9 @@ class TestProfiler:
         reset_profiler()
 
     def test_sampling_cadence_and_histograms(self):
+        """Outside any accounting window nothing was deferred behind
+        the dispatch: a sampled call blocks for its result and the
+        sample lands at once."""
         reg = get_registry()
         prof = self._fresh(sample_every=4)
         h0 = reg.histogram_if_exists("ops.host_ms.t_stage")
@@ -682,6 +685,106 @@ class TestProfiler:
         d = reg.histogram_if_exists("ops.device_ms.t_stage")
         assert h.count - host0 == 8  # every call carries host time
         assert d.count - dev0 == 2  # calls 1 and 5 sampled
+
+    def test_a_sample_inside_a_window_is_a_mark_the_next_reap_closes(
+        self, monkeypatch
+    ):
+        """Inside a window the profiler waits for nothing: a sampled
+        dispatch leaves a mark, the window's next ``reap_read`` closes
+        it as dispatch start -> end of that read, and a mark whose
+        output is not ready then waits for the read after."""
+        import jax
+        import jax.numpy as jnp
+
+        from openr_tpu.ops import dispatch_accounting as da
+        from openr_tpu.ops.aot_cache import aot_call
+
+        reg = get_registry()
+        self._fresh(sample_every=1)
+        waits = []
+        real = jax.block_until_ready
+        monkeypatch.setattr(
+            jax, "block_until_ready", lambda x: waits.append(x) or real(x)
+        )
+
+        def dev(tag):
+            h = reg.histogram_if_exists(f"ops.device_ms.{tag}")
+            return h.count if h else 0
+
+        samples = reg.counter_get("ops.profile_samples")
+        fn = jax.jit(lambda x: x + 1)
+        with da.event_window("t_mark_win") as win:
+            out = aot_call("t_mark", fn, (jnp.arange(4),), {})
+            assert [m[0] for m in win.marks] == ["t_mark"]
+            assert dev("t_mark") == 0 and not waits
+            real(out)
+            da.reap_read(out)
+            assert not win.marks and dev("t_mark") == 1
+            # a batch dispatched behind the one being read
+            aot_call("t_mark", fn, (jnp.arange(4),), {})
+            (mark,) = win.marks
+
+            class NotYet:
+                def is_ready(self):
+                    return False
+
+            win.marks[0] = mark[:3] + (NotYet(),)
+            da.reap_read(out)
+            assert len(win.marks) == 1 and dev("t_mark") == 1
+            win.marks[0] = mark
+            da.reap_read(out)
+            assert not win.marks and dev("t_mark") == 2
+        assert not waits
+        assert reg.counter_get("ops.profile_samples") - samples == 2
+        h = reg.histogram_if_exists("ops.device_ms.t_mark")
+        assert h.percentile(0.5) > 0.0
+
+    def test_a_mark_no_read_closes_is_dropped_with_its_window(self):
+        import jax
+        import jax.numpy as jnp
+
+        from openr_tpu.ops import dispatch_accounting as da
+        from openr_tpu.ops.aot_cache import aot_call
+
+        reg = get_registry()
+        self._fresh(sample_every=1)
+        samples = reg.counter_get("ops.profile_samples")
+        fn = jax.jit(lambda x: x * 2)
+        with da.event_window("t_drop_win") as win:
+            aot_call("t_drop", fn, (jnp.arange(4),), {})
+            assert len(win.marks) == 1
+        with da.event_window("t_drop_win") as win:
+            assert not win.marks
+            da.reap_read(jnp.arange(2))
+        assert reg.histogram_if_exists("ops.device_ms.t_drop") is None
+        assert reg.histogram_if_exists("ops.host_ms.t_drop").count == 1
+        assert reg.counter_get("ops.profile_samples") == samples
+
+    def test_a_dispatch_nobody_reads_is_never_sampled(self, monkeypatch):
+        """``unread`` (the KSP2 engine's deferred matrix solve): host
+        time on every call, no mark, no wait, in a window or out."""
+        import jax
+        import jax.numpy as jnp
+
+        from openr_tpu.ops import dispatch_accounting as da
+        from openr_tpu.ops.aot_cache import aot_call
+
+        reg = get_registry()
+        self._fresh(sample_every=1)
+        waits = []
+        real = jax.block_until_ready
+        monkeypatch.setattr(
+            jax, "block_until_ready", lambda x: waits.append(x) or real(x)
+        )
+        fn = jax.jit(lambda x: x - 1)
+        aot_call("t_unread", fn, (jnp.arange(4),), {}, unread=True)
+        with da.event_window("t_unread_win") as win:
+            aot_call("t_unread", fn, (jnp.arange(4),), {}, unread=True)
+            assert not win.marks
+            assert "t_unread" in win.stages
+        assert not waits
+        assert reg.histogram_if_exists("ops.host_ms.t_unread").count == 2
+        assert reg.histogram_if_exists("ops.device_ms.t_unread") is None
 
     def test_labels_land_sampled_device_time_per_dimension(self):
         reg = get_registry()
