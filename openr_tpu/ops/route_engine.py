@@ -1262,7 +1262,10 @@ class RouteSweepEngine(ResidentEngineContract):
         # resident DR stays valid, at the cost of one jit recompile)
         from openr_tpu.ops.spf_sparse import band_patch_inputs
 
-        in_v, in_w, patch_ids, patch_v, patch_w = band_patch_inputs(
+        # (no resident no-op triples: a mesh engine commits every
+        # input replicated below, and a held single-device array would
+        # make that a device-to-device copy)
+        in_v, in_w, patch_ids, patch_v, patch_w, _ = band_patch_inputs(
             self.sweeper.v_t, self.sweeper.w_t, patched
         )
         if self.plan is not None:

@@ -1854,38 +1854,82 @@ def _band_patch_rows(patched: EllGraph):
         )
 
 
-def band_patch_inputs(resident_src, resident_w, patched: EllGraph):
+def band_patch_inputs(resident_src, resident_w, patched: EllGraph,
+                      noops=None):
     """The band patch as inputs of a FUSED dispatch, whose signature
     carries a scatter triple for every band (EllState.reconverge and
-    the route engine's churn prep): _band_patch_rows' ids, a zeros(1)
-    no-op where a band has nothing to scatter, and a widened band's
-    re-upload in place of the resident tensor. Returns
-    (in_src, in_w, patch_ids, patch_src, patch_w) as tuples of device
-    arrays: dispatch inputs plus the scatter triples."""
+    the route engine's churn prep): _band_patch_rows' ids with their
+    rows of the host bands, a no-op triple where a band has nothing to
+    scatter, and a widened band's re-upload in place of the resident
+    tensor.
+
+    The no-op is ``ids = [band.rows]``, an id no row has, with a zero
+    row each for src and w (int32[1], int32[1, k] twice: the shapes a
+    one-row patch has, so no executable of its own). ``.at[ids,
+    :].set(...)`` drops an update whose index is out of range, so the
+    band comes back bit for bit WHATEVER it holds: the triple reads
+    nothing from the host band and never goes stale. A caller that
+    dispatches again and again hands in ``noops``, a dict it owns,
+    keyed by band shape ``(rows, k)``: the triple is put once per
+    shape and is the same device arrays from then on (a widened band
+    has a new shape and so a new entry; the dispatch donates its
+    bands, never its triples). Without ``noops`` the triple is put
+    afresh each call, for a caller that commits each input to a
+    placement of its own (the route engine's mesh).
+
+    Returns (in_src, in_w, patch_ids, patch_src, patch_w, puts): the
+    dispatch inputs and the scatter triples as tuples of device
+    arrays, and the number of host arrays put to make them."""
+    def put(host):
+        return tuple(jnp.asarray(a) for a in host)
+
     in_src = list(resident_src)
     in_w = list(resident_w)
-    patch_ids, patch_src, patch_w = [], [], []
+    triples = []
+    noops = {} if noops is None else noops
+    puts = 0
     for bi, (widened, rows) in enumerate(_band_patch_rows(patched)):
+        src_h, w_h = patched.src[bi], patched.w[bi]
         if widened:
-            in_src[bi] = jnp.asarray(patched.src[bi])
-            in_w[bi] = jnp.asarray(patched.w[bi])
-        if rows is None:
-            rows = np.zeros(1, dtype=np.int32)  # no-op scatter
-        patch_ids.append(jnp.asarray(rows))
-        patch_src.append(jnp.asarray(patched.src[bi][rows]))
-        patch_w.append(jnp.asarray(patched.w[bi][rows]))
+            in_src[bi], in_w[bi] = put((src_h, w_h))
+            puts += 2
+        if rows is not None:
+            triple = put((rows, src_h[rows], w_h[rows]))
+            puts += 3
+        else:
+            band = patched.bands[bi]
+            triple = noops.get((band.rows, band.k))
+            if triple is None:
+                triple = noops[(band.rows, band.k)] = put((
+                    np.full(1, band.rows, dtype=np.int32),
+                    np.zeros((1, band.k), dtype=src_h.dtype),
+                    np.zeros((1, band.k), dtype=w_h.dtype),
+                ))
+                puts += 3
+        triples.append(triple)
+    patch_ids, patch_src, patch_w = zip(*triples)
     return (
-        tuple(in_src), tuple(in_w),
-        tuple(patch_ids), tuple(patch_src), tuple(patch_w),
+        tuple(in_src), tuple(in_w), patch_ids, patch_src, patch_w, puts
     )
 
 
 class EllState:
     """Caller-owned resident device bands for the churn loop.
 
-    Everything a dispatch consumes lives on the device: the bands, and
-    the overloaded mask (re-uploaded only when it actually changes, so
-    a steady-state dispatch carries no host->device transfer for it)."""
+    Everything a dispatch consumes lives on the device, and an
+    argument the device already holds is not transferred again: the
+    bands; the overloaded mask (re-uploaded only when it actually
+    changes); the no-op scatter triple of each band shape
+    (``_noops``: the publication-time prewarm has usually scattered a
+    window's rows already, so the solve's dispatch has nothing to
+    scatter in any band, and band_patch_inputs' no-op is an id no row
+    has, which holds whatever the band holds); and the source batch
+    (``_srcs_dev``: the vantage and its neighbours, the same ids
+    solve after solve, put again only when they differ). What a
+    prewarmed window's dispatch does hand over is the journal's
+    increase triple, three small host arrays given to the jitted call
+    as they are: the span's ``puts`` says how many host arrays each
+    dispatch sent."""
 
     def __init__(self, graph: EllGraph):
         self.graph = graph
@@ -1918,6 +1962,10 @@ class EllState:
         # whether that solve was seeded from the previous rows
         self._solved_warm = False
         self._warm_key: Optional[Tuple[int, ...]] = None
+        # band shape (rows, k) -> its no-op scatter triple, and (key,
+        # ids) of the last solve's source batch: both on the device
+        self._noops: Dict[Tuple[int, int], tuple] = {}
+        self._srcs_dev: Tuple[Tuple[int, ...], object] = ((), None)
         self._pending_edges: Dict[
             Tuple[int, int], Tuple[int, int]
         ] = {}
@@ -2058,7 +2106,10 @@ class EllState:
                 n_rows += len(sent[-1])
                 nbytes += sum(a.nbytes for a in sent)
             self.src, self.w = tuple(src), tuple(w)
-            self.graph = _replace(patched, changed=None)
+            # the held graph names no work: a ``widened`` left on it
+            # would re-upload the band on every solve until the next
+            # patch (reconverge(self.graph) is the prewarmed solve)
+            self.graph = _replace(patched, changed=None, widened=None)
             if span is not None:
                 if ov_changed:
                     nbytes += patched.overloaded.nbytes
@@ -2082,10 +2133,22 @@ class EllState:
             _t0 = time.perf_counter()
             ov_changed = self._sync_overloaded(patched)
             self._note_patch(patched, ov_changed)
-            in_src, in_w, patch_ids, patch_src, patch_w = (
-                band_patch_inputs(self.src, self.w, patched)
+            # stage_ms: the scatter triples and the source ids, which a
+            # prewarmed window finds on the device
+            _t_stage = time.perf_counter()
+            in_src, in_w, patch_ids, patch_src, patch_w, puts = (
+                band_patch_inputs(self.src, self.w, patched, self._noops)
             )
             srcs_key = tuple(int(s) for s in srcs)
+            if self._srcs_dev[0] != srcs_key:
+                # openr-lint: disable=host-sync-in-window -- srcs is a
+                # host list of sample ids, not a device array
+                srcs_host = np.asarray(srcs_key, dtype=np.int32)
+                self._srcs_dev = (srcs_key, jnp.asarray(srcs_host))
+                puts += 1
+            srcs_dev = self._srcs_dev[1]
+            _stage_ms = (time.perf_counter() - _t_stage) * 1000.0
+            puts += int(ov_changed) + 3  # the mask; the increase triple
             b = len(srcs_key)
             warm = (
                 self._d_dev is not None
@@ -2116,16 +2179,13 @@ class EllState:
                 )
                 ELL_COUNTERS["ell_cold_solves"] += 1
             inc_t, inc_h, inc_w = pad_increase_edges(inc)
-            # openr-lint: disable=host-sync-in-window -- srcs is a host
-            # list of sample ids, not a device array; no transfer happens
-            srcs_dev = jnp.asarray(np.asarray(srcs, dtype=np.int32))
-            _t_dispatch = time.perf_counter()
-            # the increase triple's three puts, then the jitted call:
-            # dispatch_ms = put_ms + launch_ms
-            inc_t, inc_h, inc_w = (
-                jnp.asarray(inc_t), jnp.asarray(inc_h), jnp.asarray(inc_w)
-            )
-            _t_launch = time.perf_counter()
+            # the increase triple goes to the jitted call as the host
+            # arrays it is, as _patch_band takes its rows: the call's own
+            # argument path moves the three for less than a put of their
+            # own did (on the chip, PERF.md PR 52), so no explicit put is
+            # left between the host's preparation and the call, put_ms
+            # reads 0 and dispatch_ms = launch_ms
+            _t_launch = _t_dispatch = time.perf_counter()
             # openr-lint: disable=donation-hazard -- intentional: the warm
             # path CONSUMES the previous resident distances (d_prev is dead
             # after this dispatch) and self._d_dev is rebound to the fresh
@@ -2148,7 +2208,7 @@ class EllState:
             # (the overload mask the resident distances were solved under)
             self._ov_solved = np.array(patched.overloaded, copy=True)
             self._pending_structural = False
-            self.graph = _replace(patched, changed=None)
+            self.graph = _replace(patched, changed=None, widened=None)
             _total_ms = (_t_end - _t0) * 1000.0
             _dispatch_ms = (_t_end - _t_dispatch) * 1000.0
             _reg = _get_registry()
@@ -2156,6 +2216,7 @@ class EllState:
             _reg.observe(
                 "ops.ell.host_overhead_ms", _total_ms - _dispatch_ms
             )
+            _reg.observe("ops.ell.dispatch_puts", puts)
             if _span is not None:
                 _span.attrs.update(
                     warm=warm,
@@ -2163,6 +2224,11 @@ class EllState:
                     put_ms=round((_t_launch - _t_dispatch) * 1000.0, 4),
                     launch_ms=round((_t_end - _t_launch) * 1000.0, 4),
                     host_overhead_ms=round(_total_ms - _dispatch_ms, 4),
+                    # of host_overhead_ms, the staging of the scatter
+                    # triples and the source ids; and the host arrays
+                    # this dispatch handed to the device in all
+                    stage_ms=round(_stage_ms, 4),
+                    puts=puts,
                     # what every pass streams against what it needs: the
                     # bands' slots, padding included, and the filled ones
                     slots=sum(band.rows * band.k for band in patched.bands),
